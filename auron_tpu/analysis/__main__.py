@@ -30,7 +30,8 @@ def main(argv=None) -> int:
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("paths", nargs="*",
                     help="files/directories to analyze (default: the "
-                         "repo tree — auron_tpu/, tools/, bench.py)")
+                         "repo tree — auron_tpu/, tools/, bench.py, "
+                         "chip_smoke.py)")
     ap.add_argument("--baseline", default=None,
                     help="baseline JSON of grandfathered violations; "
                          "only NEW violations fail the run")

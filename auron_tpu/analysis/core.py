@@ -367,6 +367,7 @@ def default_targets(root: Optional[str] = None) -> list[str]:
     targets = [os.path.join(root, "auron_tpu"),
                os.path.join(root, "tools"),
                os.path.join(root, "bench.py"),
+               os.path.join(root, "chip_smoke.py"),
                os.path.join(root, "__graft_entry__.py")]
     return [t for t in targets if os.path.exists(t)]
 

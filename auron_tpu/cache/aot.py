@@ -49,10 +49,10 @@ _THREAD: Optional[threading.Thread] = None
 
 
 def aot_dir(conf=None) -> str:
-    """Inventory directory: rides next to the persistent XLA cache.
-    Empty string (= plane disarmed) when ``auron.xla_cache_dir`` is
-    unset — without a durable compile cache there is nothing for the
-    inventory to amortize across processes."""
+    """Inventory directory: ``aot_plans`` under ``auron.xla_cache_dir``.
+    Empty string (= plane disarmed) when that knob is unset — the
+    inventory is armed only by a deployment that names a directory,
+    wherever the compile cache itself lives (utils/xla_cache.py)."""
     from auron_tpu import config as cfg
     if conf is None:
         conf = cfg.get_config()

@@ -16,13 +16,14 @@ wire protocol is unchanged.
 
 from auron_tpu.fleet.router import FleetRouter
 from auron_tpu.fleet.replica import FleetHarness, ReplicaProc, \
-    spawn_replica
+    spawn_replica, tpu_chip_env
 from auron_tpu.fleet.snapshot import ReplicaSnapshot, \
     snapshot_from_bodies, unreachable
 from auron_tpu.fleet import routing
 
 __all__ = [
     "FleetRouter", "FleetHarness", "ReplicaProc", "spawn_replica",
+    "tpu_chip_env",
     "ReplicaSnapshot", "snapshot_from_bodies", "unreachable",
     "routing",
 ]

@@ -40,8 +40,6 @@ class ReplicaSnapshot:
     rejected: int = 0
     #: worst memmgr used/total ratio across the replica's managers
     mem_frac: float = 0.0
-    #: watchdog CPU fallbacks taken (a degraded-but-alive signal)
-    watchdog_fallbacks: int = 0
     #: warm plan fingerprints (result-cache inventory) — affinity keys
     warm_fps: frozenset = field(default_factory=frozenset)
     #: resumable journal stems visible to this replica (dead owners)
@@ -91,7 +89,6 @@ def snapshot_from_bodies(name: str, host: str, port: int,
         total = st.get("total") or 0
         if total > 0:
             mem_frac = max(mem_frac, st.get("used", 0) / total)
-    wd = health.get("watchdog") or {}
     stems = tuple(
         ent["stem"] for ent in queries.get("resume_inventory") or []
         if not ent.get("owner_alive") and not ent.get("claimed")
@@ -102,7 +99,6 @@ def snapshot_from_bodies(name: str, host: str, port: int,
         running=running, queued=queued,
         admitted=admitted, rejected=rejected,
         mem_frac=mem_frac,
-        watchdog_fallbacks=int(wd.get("fallbacks", 0) or 0),
         warm_fps=frozenset(queries.get("warm_plan_fps") or ()),
         resume_stems=stems,
         scraped_at=scraped_at)

@@ -116,12 +116,8 @@ class PhysicalPlanner:
         explicit = int(self.ctx.config.get(cfg.SCAN_BATCH_ROWS))
         if explicit > 0:
             return explicit
-        try:
-            import jax
-            platform = jax.default_backend()
-        except Exception:   # backend init failure: stay conservative
-            platform = "cpu"
-        if platform == "cpu":
+        import jax
+        if jax.default_backend() == "cpu":
             return 1 << 17
         return self.ctx.config.get(cfg.PARQUET_BATCH_ROWS)
 

@@ -66,11 +66,10 @@ class ChaosOutcome:
 
 
 #: span names that ARE recovery actions on the timeline: task-level
-#: retries, corrupt-map recomputes, watchdog CPU fallbacks, stall
-#: verdicts and pressure-ladder sheds (the lifecycle plane's recovery
-#: actions)
+#: retries, corrupt-map recomputes, stall verdicts and pressure-ladder
+#: sheds (the lifecycle plane's recovery actions)
 RECOVERY_SPAN_NAMES = ("task.retry", "shuffle.corruption_recompute",
-                       "watchdog.fallback", "watchdog.stall",
+                       "watchdog.stall",
                        "memmgr.shed", "sched.reject",
                        "exchange.demote", "mesh.quarantine")
 
@@ -81,9 +80,6 @@ RECOVERY_SPAN_NAMES = ("task.retry", "shuffle.corruption_recompute",
 #: injections when walking back for its cause
 _RECOVERY_CAUSE_KINDS = {
     "shuffle.corruption_recompute": ("corrupt",),
-    # any injected backend.init kind (hang, io_error, fatal) can force
-    # the CPU fallback, so the watchdog entry lists them all
-    "watchdog.fallback": ("hang", "io_error", "fatal"),
     # only a hang goes silent long enough for the stall monitor
     "watchdog.stall": ("hang",),
     # the pressure ladder sheds on injected denies
@@ -609,7 +605,9 @@ def fleet_failover(workdir: str) -> Scenario:
         import threading
 
         from auron_tpu.fleet.replica import FleetHarness
+        from auron_tpu.utils.envsafe import require_shareable_device
 
+        require_shareable_device("the fleet_failover scenario")
         task = _task()
         counter[0] += 1
         jdir = os.path.join(journal_root, f"run_{counter[0]}")
@@ -994,18 +992,17 @@ def _spawn_crash_child(workdir: str, kill_at: int,
     """Run one crash child; returns (rc, stdout)."""
     import subprocess
 
+    from auron_tpu.utils.envsafe import require_shareable_device
+    require_shareable_device("the crash sweep")
     repo = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    # children share a persistent XLA cache so only the first pays the
-    # compile bill — the sweep measures crash recovery, not tracing
-    env["AURON_CONF_XLA_CACHE_DIR"] = os.path.join(workdir, "xla_cache")
+    # the child inherits the parent's environment: its platform, and
+    # the one persistent XLA cache every process of a checkout shares
+    # (utils/xla_cache.py) — only the first child pays the compile bill
     proc = subprocess.run(
         [sys.executable, "-m", "auron_tpu.it.chaos", "--crash-child",
          workdir, str(kill_at)],
-        capture_output=True, text=True, timeout=timeout_s, cwd=repo,
-        env=env)
+        capture_output=True, text=True, timeout=timeout_s, cwd=repo)
     return proc.returncode, proc.stdout
 
 
@@ -1055,8 +1052,8 @@ def run_crash_point(workdir: str, kill_point: int,
     for sub in ("journal", "spill"):
         os.makedirs(os.path.join(point_dir, sub), exist_ok=True)
     # the child resolves journal/spill under ITS workdir: symlink the
-    # shared data/manifest/xla_cache into the per-point dir
-    for shared in ("data", "manifest.json", "xla_cache"):
+    # shared data/manifest into the per-point dir
+    for shared in ("data", "manifest.json"):
         src = os.path.join(workdir, shared)
         if os.path.exists(src):
             os.symlink(src, os.path.join(point_dir, shared))

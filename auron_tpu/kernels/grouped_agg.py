@@ -11,12 +11,11 @@ dense accumulation problem with two much cheaper formulations:
     tiles are built IN VMEM, the [hi, lo] sum/count grids accumulate IN
     VMEM across the whole grid, and HBM traffic collapses to the
     ~12 B/row inputs. The XLA one-hot formulation materializes
-    [n, 256..1024] one-hot operands in HBM (~4 GB per 1M rows); this
-    kernel is the route from that memory-bound 0.82x to the >=3x bar.
+    [n, 256..1024] one-hot operands in HBM (~4 GB per 1M rows).
     ``interpret=True`` runs the same kernel through the Pallas
     interpreter, so it executes (and is differentially verified) under
-    ``JAX_PLATFORMS=cpu``; real Mosaic compiles happen only when the
-    dispatch policy sees a TPU platform (kernels/dispatch.py).
+    ``JAX_PLATFORMS=cpu``; Mosaic compiles it when the dispatch policy
+    sees a TPU platform (kernels/dispatch.py).
 
 ``dense_matmul_sum_count``
     The one-hot einsum formulation (the flagship ``_q01_kernel`` math),
@@ -48,6 +47,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: lane width of the dense grids: keys decompose as (k >> 8, k & 255) so
 #: the minor grid dimension matches the TPU's 256-wide key byte
@@ -97,7 +98,13 @@ def _split3(v):
 
 def _vmem_agg_kernel(gh, k_ref, v_ref, c_ref, sums_ref, cnts_ref):
     """One grid step: fold a [1, blk] row block into the VMEM-resident
-    [gh, 256] sum/count grids. The one-hot tiles never leave VMEM."""
+    [gh, 256] sum/count grids. The one-hot tiles never leave VMEM.
+
+    Rows stay on the LANE axis throughout: the one-hots are built
+    transposed ([gh, blk] / [256, blk], the [1, blk] inputs broadcast
+    along sublanes) and the grids come from an A @ B^T contraction over
+    the lane axis — no lane->sublane relayout of the row block and no
+    transposed-LHS matmul, neither of which Mosaic lowers."""
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -110,22 +117,25 @@ def _vmem_agg_kernel(gh, k_ref, v_ref, c_ref, sums_ref, cnts_ref):
     c = c_ref[:]          # [1, blk] f32 0/1 valid mask
     blk = k.shape[1]
 
+    hi = (k >> 8) == lax.broadcasted_iota(jnp.int32, (gh, blk), 0)
+    lo = ((k & 255) == lax.broadcasted_iota(jnp.int32, (_LANES, blk), 0)
+          ).astype(jnp.float32).astype(jnp.bfloat16)
+
+    def grid(vals):
+        lhs = jnp.where(hi, vals, jnp.float32(0)).astype(jnp.bfloat16)
+        return lax.dot_general(lhs, lo, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
     v1, v2, v3 = _split3(v)
+    sums_ref[:] += grid(v1) + grid(v2) + grid(v3)
+    cnts_ref[:] += grid(c)
 
-    iota_h = lax.broadcasted_iota(jnp.int32, (blk, gh), 1)
-    iota_l = lax.broadcasted_iota(jnp.int32, (blk, _LANES), 1)
-    hi = (k.reshape(blk, 1) >> 8) == iota_h
-    lo = ((k.reshape(blk, 1) & 255) == iota_l).astype(jnp.bfloat16)
 
-    def masked(vals):
-        return jnp.where(hi, vals.reshape(blk, 1), 0.0).astype(jnp.bfloat16)
-
-    lhs = jnp.concatenate(
-        [masked(v1), masked(v2), masked(v3), masked(c)], axis=1)
-    out = lax.dot_general(lhs, lo, (((0,), (0,)), ((), ())),
-                          preferred_element_type=jnp.float32)
-    sums_ref[:] += out[:gh] + out[gh:2 * gh] + out[2 * gh:3 * gh]
-    cnts_ref[:] += out[3 * gh:]
+def _block_index(*idx):
+    """Index-map result pinned to int32: the package enables
+    jax_enable_x64, under which a bare ``0`` literal traces as i64 and
+    Mosaic refuses the index map."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in idx)
 
 
 @functools.partial(jax.jit,
@@ -145,16 +155,20 @@ def pallas_sum_count(k, v, c, key_domain: int, blk: int = 2048,
         raise ValueError(f"rows {n} not a multiple of block {blk}")
     gh, gl = grid_dims(key_domain)
     grid = n // blk
+    row_block = pl.BlockSpec((1, blk), lambda i: _block_index(0, i))
+    out_block = pl.BlockSpec((gh, gl), lambda i: _block_index(0, 0))
+    # under shard_map the grids vary over the mesh axes the rows do
+    out_grid = jax.ShapeDtypeStruct((gh, gl), jnp.float32,
+                                    vma=jax.typeof(k).vma)
     sums, cnts = pl.pallas_call(
         functools.partial(_vmem_agg_kernel, gh),
-        out_shape=(jax.ShapeDtypeStruct((gh, gl), jnp.float32),
-                   jax.ShapeDtypeStruct((gh, gl), jnp.float32)),
+        out_shape=(out_grid, out_grid),
         grid=(grid,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (0, i)),
-                  pl.BlockSpec((1, blk), lambda i: (0, i)),
-                  pl.BlockSpec((1, blk), lambda i: (0, i))],
-        out_specs=(pl.BlockSpec((gh, gl), lambda i: (0, 0)),
-                   pl.BlockSpec((gh, gl), lambda i: (0, 0))),
+        in_specs=[row_block, row_block, row_block],
+        out_specs=(out_block, out_block),
+        # the output grids accumulate across the whole grid axis
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(k.reshape(1, n), v.reshape(1, n), c.reshape(1, n))
     return sums.reshape(-1)[:key_domain], cnts.reshape(-1)[:key_domain]
@@ -248,14 +262,3 @@ def scatter_reduce(kind: str, k, v, valid, key_domain: int, dtype):
             return acc.at[k].min(vals, mode="drop")
         return acc.at[k].max(vals, mode="drop")
     raise ValueError(f"unknown scatter reduction {kind!r}")
-
-
-# Pallas imports last so the module loads (and scatter/dense paths work)
-# even if the installed jax lacks the experimental pallas package — the
-# dispatch policy gates pallas selection on PALLAS_AVAILABLE.
-try:  # pragma: no cover - environment probe
-    from jax.experimental import pallas as pl  # noqa: E402
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    pl = None
-    PALLAS_AVAILABLE = False

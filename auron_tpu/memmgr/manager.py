@@ -156,18 +156,22 @@ class MemManager:
     @staticmethod
     def default_budget() -> int:
         """auron.memory.fraction of the device's HBM (the reference's
-        spark.auron.memoryFraction × executor memory); falls back to a
-        conservative 8 GB figure when the backend doesn't report a limit
-        (e.g. the CPU test mesh)."""
+        spark.auron.memoryFraction × executor memory). The CPU platform
+        reports no limit and budgets against a nominal 8 GB; an
+        accelerator that reports none is an error, not an assumption."""
+        import jax
+
         from auron_tpu import config as cfg
         fraction = cfg.get_config().get(cfg.MEMORY_FRACTION)
-        limit = 8 << 30
-        try:
-            import jax
-            stats = jax.devices()[0].memory_stats() or {}
-            limit = int(stats.get("bytes_limit", limit)) or limit
-        except Exception:
-            pass
+        dev = jax.devices()[0]
+        limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+        if not limit:
+            if dev.platform != "cpu":
+                raise RuntimeError(
+                    f"{dev.platform} device {dev.device_kind!r} reports "
+                    "no memory_stats()['bytes_limit']; cannot size the "
+                    "memory budget")
+            limit = 8 << 30
         return int(limit * fraction)
 
     # -- registration -------------------------------------------------------

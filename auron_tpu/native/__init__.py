@@ -1,9 +1,14 @@
 """ctypes bindings for the host-side C++ kernels (native/auron_host.cc).
 
-Lazy build-on-first-use with a graceful numpy fallback: environments
-without a toolchain still run, native just accelerates (the reference's
-equivalent layer is mandatory Rust; here XLA is the compute path and this
-covers host-runtime hot spots: spill-merge ordering and row gathers)."""
+Built on first use with a numpy fallback: environments without a
+toolchain still run, native just accelerates (the reference's equivalent
+layer is mandatory Rust; here XLA is the compute path and this covers
+host-runtime hot spots: spill-merge ordering and row gathers).
+
+The library is compiled ``-march=native`` and git-ignored, so a binary
+is only ever valid on the machine and for the source that built it: it
+is rebuilt whenever it is missing or older than ``auron_host.cc``, and
+``status()`` says which path this process ended up on and why."""
 
 from __future__ import annotations
 
@@ -21,28 +26,48 @@ logger = logging.getLogger("auron_tpu.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libauron_host.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "auron_host.cc")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+#: ("built" | "loaded" | "numpy", why) once _load() has run
+_status: tuple[str, str] = ("numpy", "not loaded yet")
+
+
+def _stale() -> Optional[str]:
+    """Why the shared library must be (re)built, or None when the one on
+    disk is at least as new as its source."""
+    if not os.path.exists(_SO_PATH):
+        return "library missing"
+    if os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH):
+        return "library older than auron_host.cc"
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO_PATH):
+        why = _stale()
+        if why is not None:
             try:
                 subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
                                check=True, capture_output=True, timeout=120)
             except (OSError, subprocess.SubprocessError) as e:
-                logger.warning("native build failed, using numpy fallback: %s", e)
+                detail = getattr(e, "stderr", b"") or b""
+                _status = ("numpy", f"{why}; build failed: {e} "
+                           f"{detail.decode(errors='replace')[-200:]}"
+                           .strip())
+                logger.warning("native build failed, using numpy "
+                               "fallback: %s", _status[1])
                 return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError as e:
+            _status = ("numpy", f"load failed: {e}")
             logger.warning("native load failed, using numpy fallback: %s", e)
             return None
 
@@ -58,10 +83,21 @@ def _load() -> Optional[ctypes.CDLL]:
                                      ctypes.c_int64, u8p]
         lib.at_version.restype = ctypes.c_int64
         if lib.at_version() != 1:
+            _status = ("numpy", "ABI version mismatch")
             logger.warning("native ABI mismatch, using numpy fallback")
             return None
+        _status = (("built", why) if why is not None
+                   else ("loaded", "library not older than auron_host.cc"))
         _lib = lib
         return _lib
+
+
+def status() -> tuple[str, str]:
+    """(path, why): ``built`` (compiled by this process), ``loaded`` (an
+    up-to-date library was already on disk) or ``numpy`` (the fallback),
+    with the reason."""
+    _load()
+    return _status
 
 
 def available() -> bool:

@@ -5,7 +5,7 @@ When a production query is shed, misses its deadline, stalls out, loses
 its mesh, or trips over a corrupt journal, the operator's question is
 always the same: *what was the process doing in the seconds before?*
 Every plane that can answer already exists — the flight recorder's ring,
-the scheduler/memmgr/mesh stats, the probe and stall reports, the
+the scheduler/memmgr/mesh stats, the stall reports, the
 metric tree — but each lives somewhere else and most are gone once the
 process moves on. This module freezes them together at the unwind:
 
@@ -22,7 +22,6 @@ process moves on. This module freezes them together at the unwind:
     ``mesh.json``          mesh plane fault ledger (when armed)
     ``journal.json``       the query's journal state (when journaled)
     ``config.json``        resolved config snapshot + trace_salt
-    ``probe_report.json``  last backend probe-ladder report
     ``stall_report_*.json``copied from auron.trace.dir (when present)
 
 Triggering: ``maybe_write`` is called from the executor/serving unwind
@@ -175,7 +174,6 @@ def _write(exc, outcome: str, token=None, config=None, scheduler=None,
     art("mesh.json", _mesh_json)
     art("journal.json", lambda: _journal_json(token))
     art("config.json", lambda: _config_json(config))
-    art("probe_report.json", _probe_json)
     _copy_stall_reports(tmp, config)
     os.replace(tmp, path)
     _evict(root, config)
@@ -374,14 +372,6 @@ def _config_json(config) -> str:
     return json.dumps({"resolved": resolved,
                        "trace_salt": list(cfg.trace_salt())},
                       indent=2, default=str)
-
-
-def _probe_json() -> Optional[str]:
-    from auron_tpu.runtime import watchdog
-    report = watchdog.last_probe_report()
-    if report is None:
-        return None
-    return report.to_json()
 
 
 def _copy_stall_reports(tmp: str, config, limit: int = 8) -> None:

@@ -17,9 +17,10 @@ finalize:
 - **compile plane** — XLA compiles + seconds, program builds vs cache
   hits;
 - **robustness** — retry/recovery counters (attempts, transient
-  retries, corruption recomputes, watchdog fallbacks, injected faults);
+  retries, corruption recomputes, injected faults);
 - **serving identity** — rows, batches, partitions, cache hit,
-  served_from, outcome, wall seconds.
+  served_from, outcome, wall seconds, and the device that computed it
+  (platform, kind, count — an answer names what it ran on).
 
 The record rides the serving DONE frame (``cost_ledger`` key), is
 retained in a bounded process ring (``record``/``recent`` — the
@@ -45,8 +46,21 @@ HOST_BUCKETS = ("dispatch", "convert", "serde", "iter", "other")
 _NON_OP_KEYS = frozenset({"recovery", "mesh", "profile"})
 
 _RECOVERY_KEYS = ("attempts", "transient_retries",
-                  "corruption_recomputes", "watchdog_fallbacks",
-                  "faults_injected")
+                  "corruption_recomputes", "faults_injected")
+
+
+_DEVICE: Optional[dict] = None
+
+
+def _device() -> dict:
+    """The engine process's device as jax reports it (resolved once)."""
+    global _DEVICE
+    if _DEVICE is None:
+        import jax
+        devs = jax.devices()
+        _DEVICE = {"platform": devs[0].platform,
+                   "kind": devs[0].device_kind, "count": len(devs)}
+    return _DEVICE
 
 
 def enabled(config=None) -> bool:
@@ -105,6 +119,7 @@ def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
         "version": LEDGER_VERSION,
         "query_id": str(query_id),
         "outcome": str(outcome),
+        "device": dict(_device()),
         "wall_s": round(float(wall_s), 6),
         "device_s": round(device_ns * 1e-9, 6),
         "host_s": {b: round(v * 1e-9, 6) for b, v in host_ns.items()},
@@ -162,8 +177,10 @@ def augment_fleet(ledger, *, hops: Optional[int] = None,
 
 def fold(ledgers: Iterable[dict]) -> dict:
     """Aggregate many ledgers into fleet-scale totals (load_report's
-    capacity view): sums for seconds/bytes/rows/counters, a count, and
-    how many were cache hits / failovers."""
+    capacity view): sums for seconds/bytes/rows/counters, a count, how
+    many were cache hits / failovers, and the devices that computed
+    them (so a rate derived from the totals names its platform)."""
+    devices: set = set()
     tot = {"queries": 0, "device_s": 0.0, "host_total_s": 0.0,
            "host_s": dict.fromkeys(HOST_BUCKETS, 0.0),
            "shuffle_bytes": 0, "spill_bytes": 0, "rows": 0,
@@ -173,6 +190,10 @@ def fold(ledgers: Iterable[dict]) -> dict:
         if not isinstance(led, dict):
             continue
         tot["queries"] += 1
+        dev = led.get("device")
+        if isinstance(dev, dict):
+            devices.add(f"{dev.get('platform')}:{dev.get('kind')}"
+                        f"x{dev.get('count')}")
         tot["device_s"] += _f(led.get("device_s"))
         tot["host_total_s"] += _f(led.get("host_total_s"))
         host = led.get("host_s")
@@ -197,6 +218,7 @@ def fold(ledgers: Iterable[dict]) -> dict:
     tot["device_s"] = round(tot["device_s"], 6)
     tot["host_total_s"] = round(tot["host_total_s"], 6)
     tot["host_s"] = {b: round(v, 6) for b, v in tot["host_s"].items()}
+    tot["devices"] = sorted(devices)
     return tot
 
 

@@ -10,9 +10,9 @@ LIVE process operable:
 
 - ``GET /metrics``  — the registry's Prometheus text exposition
   (``obs/registry.render_prometheus``), conformance-pinned;
-- ``GET /healthz``  — ok-vs-degraded verdict assembled from the last
-  probe-ladder report, watchdog fallback/stall counters, scheduler
-  occupancy, memmgr pressure and the mesh plane's quarantine ledger;
+- ``GET /healthz``  — ok-vs-degraded verdict assembled from the
+  watchdog stall counters, scheduler occupancy, memmgr pressure and
+  the mesh plane's quarantine ledger;
 - ``GET /queries``  — the live query table (id, running|queued, wall so
   far, tasks done/total, per-query memory vs quota, program-cache
   hits) across every scheduler in the process;
@@ -46,21 +46,13 @@ logger = logging.getLogger("auron_tpu.ops")
 
 def health() -> dict:
     """The /healthz body: per-plane state plus an overall verdict.
-    ``degraded`` (not dead — the process is still serving) when the
-    accelerator probe failed, a watchdog CPU fallback was taken, mesh
-    devices sit in quarantine, or a memmgr runs past 90% of budget."""
+    ``degraded`` (not dead — the process is still serving) when mesh
+    devices sit in quarantine or a memmgr runs past 90% of budget."""
     reasons: list[str] = []
     out: dict = {"status": "ok"}
     try:
         from auron_tpu.runtime import watchdog
-        probe = watchdog.last_probe_report()
-        out["probe"] = probe.to_dict() if probe is not None else None
-        if probe is not None and not probe.ok:
-            reasons.append(f"probe_failed:{probe.summary()}")
-        wd = watchdog.stats()
-        out["watchdog"] = wd
-        if wd.get("fallbacks"):
-            reasons.append("watchdog_cpu_fallback")
+        out["watchdog"] = watchdog.stats()
     except Exception:   # pragma: no cover - collectors best-effort
         out["watchdog"] = None
     try:

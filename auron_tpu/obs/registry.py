@@ -247,7 +247,6 @@ _HELP = {
     "auron_backend_compiles_total": "Raw XLA backend compiles.",
     "auron_backend_compile_seconds_total": "Seconds spent in XLA compiles.",
     "auron_faults_injected_total": "Chaos-plane fault injections.",
-    "auron_watchdog_fallbacks_total": "Watchdog CPU fallbacks taken.",
     "auron_watchdog_stalls_total": "Task stalls flagged by the watchdog.",
     "auron_trace_dropped_spans": "Spans dropped past auron.trace.max_spans.",
     "auron_sched_running": "Queries running, per scheduler.",
@@ -351,9 +350,6 @@ def _collect_runtime() -> list[tuple]:
         pass
     try:
         from auron_tpu.runtime import watchdog
-        fams.append(("auron_watchdog_fallbacks_total", "counter",
-                     [f"auron_watchdog_fallbacks_total "
-                      f"{watchdog.totals()}"]))
         fams.append(("auron_watchdog_stalls_total", "counter",
                      [f"auron_watchdog_stalls_total "
                       f"{watchdog.stall_totals()}"]))

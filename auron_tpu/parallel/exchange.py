@@ -373,7 +373,14 @@ class _MeshExchangeBuffer:
         data-movement figure; the allocated buffers are zero-padded to
         ``n_dev² × quota`` row slots, which under skew overstates
         movement by an order of magnitude)."""
-        nbytes = sum(l.nbytes for l in jax.tree_util.tree_leaves(out_cols))
+        leaves = jax.tree_util.tree_leaves(out_cols)
+        nbytes = sum(l.nbytes for l in leaves)
+        if not self.entries and leaves:
+            # where this exchange's output actually sits: the distinct
+            # devices of the first round's output sharding (recorded
+            # once per exchange — every round shares it)
+            self.metrics.counter("mesh_shard_devices").add(
+                len(leaves[0].sharding.device_set))
         slots = self.n_out * self.n_out * max(int(quota), 1)
         live = int(counts.sum())
         live_bytes = int(nbytes * live / slots) if slots else 0

@@ -353,19 +353,11 @@ def current_plane() -> Optional[MeshPlane]:
             return _PLANE[1]
         plane = None
         if params[0]:
-            try:
-                import jax
-                devs = list(jax.devices())
-            except Exception:   # backend init failure: no mesh
-                devs = []
+            import jax
+            devs = list(jax.devices())
             limit = params[1] if params[1] > 0 else len(devs)
             devs = devs[:limit]
-            multihost = False
-            try:
-                import jax as _jax
-                multihost = _jax.process_count() > 1
-            except Exception:
-                pass
+            multihost = jax.process_count() > 1
             # single-host only: the reducer read path slices addressable
             # shards; multihost deployments shuffle through the RSS tier
             # by construction (the durable fallback)

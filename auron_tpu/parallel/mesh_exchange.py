@@ -26,15 +26,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from auron_tpu.runtime.programs import program_cache
-
-try:
-    from jax import shard_map
-except ImportError:          # older jax exposes it under experimental
-    from jax.experimental.shard_map import shard_map
 
 
 def make_mesh(num_devices: int | None = None, axis: str = "data") -> Mesh:

@@ -22,7 +22,7 @@ boundary), mirroring the reference's two-process RSS proof
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import numpy as np
 
@@ -31,23 +31,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def init_process_group(coordinator: str, num_processes: int,
-                       process_id: int,
-                       local_device_count: Optional[int] = None) -> None:
+                       process_id: int) -> None:
     """Join the multi-controller group (reference analogue: executor
     registration with the driver's block-manager/RSS endpoints).
 
-    Must run before any other jax call in the process. On CPU backends
-    ``local_device_count`` forces the per-host virtual device count
-    (the xla_force_host_platform_device_count flag) so tests can model an
-    N-device host without hardware.
+    Must run before any other jax call in the process.
     """
-    import os
-    if local_device_count is not None:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{local_device_count}").strip()
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

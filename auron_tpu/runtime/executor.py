@@ -289,21 +289,16 @@ class ExecutionRuntime:
         # recovery counters (robustness plane): attempts/retries from the
         # retry driver, corruption recomputes from the RSS exchange's
         # ctx counters (already under the "recovery" metrics key),
-        # fault/watchdog deltas from their monotonic totals
+        # the fault delta from its monotonic total
         from auron_tpu.runtime import faults as _faults
-        from auron_tpu.runtime import watchdog as _watchdog
         rec = snap.setdefault("recovery", {})
         rec.setdefault("corruption_recomputes", 0)
         rec["attempts"] = self.attempt + 1
         rec["transient_retries"] = self.retry_stats.get(
             "transient_retries", self.attempt)
-        # process-level, not a per-task delta: watchdog probes run at
-        # Session init (before any task exists), so the meaningful
-        # number is how many fallbacks this process has taken in total
-        rec["watchdog_fallbacks"] = _watchdog.totals()
         rec["faults_injected"] = _faults.totals() - self._faults_start
-        # SPMD plane occupancy (process-level like the watchdog number:
-        # the gang ledger spans queries by design — one slot = the mesh)
+        # SPMD plane occupancy (process-level: the gang ledger spans
+        # queries by design — one slot = the mesh)
         try:
             from auron_tpu.parallel import mesh as _mesh
             plane = _mesh.current_plane()

@@ -257,27 +257,49 @@ def clear_all() -> None:
 # donation-aware jit
 # ---------------------------------------------------------------------------
 
+def _aliased(args, argnums) -> bool:
+    """True when an array object donated at ``argnums`` appears a second
+    time anywhere in the call — among the donated leaves (columns of a
+    scan-fed batch commonly share one all-valid mask) or in an argument
+    that is not donated."""
+    import jax
+    donated = jax.tree_util.tree_leaves(
+        [a for i, a in enumerate(args) if i in argnums])
+    ids = {id(x) for x in donated}
+    if len(ids) != len(donated):
+        return True
+    kept = jax.tree_util.tree_leaves(
+        [a for i, a in enumerate(args) if i not in argnums])
+    return any(id(x) in ids for x in kept)
+
+
 def jit(fun=None, *, donate_argnums=(), **kwargs):
     """``jax.jit`` that applies ``donate_argnums`` only where donation is
-    real. The XLA CPU backend treats donation as advisory (every donated
-    buffer is copied anyway and jax warns about it), so kernels that
-    donate their dead inputs — the sort/gather kernels, the shuffle
-    split — compile with donation on accelerators and without it on the
-    CPU mesh, keeping tier-1 runs warning-free while halving peak HBM for
-    those steps on a real chip."""
+    real and legal. The XLA CPU backend treats donation as advisory
+    (every donated buffer is copied anyway and jax warns about it), so
+    kernels that donate their dead inputs — the sort/gather kernels, the
+    shuffle split — compile with donation on accelerators and without it
+    on the CPU mesh. On an accelerator a donated buffer may appear ONCE
+    in a call: a call whose donated leaves alias each other, or another
+    argument, runs the non-donating twin instead (XLA rejects it
+    otherwise — "Attempt to donate the same buffer twice", the first
+    thing the shuffle split hit on a real chip)."""
     import jax
 
     def wrap(f):
-        if donate_argnums:
-            try:
-                platform = jax.default_backend()
-            except Exception:   # backend init failure: stay conservative
-                platform = "cpu"
-            if platform != "cpu":
-                # graft: donation-ok -- the donation-aware wrapper
-                # itself; every caller annotates its own site
-                return jax.jit(f, donate_argnums=donate_argnums, **kwargs)
-        return jax.jit(f, **kwargs)
+        plain = jax.jit(f, **kwargs)
+        if not donate_argnums or jax.default_backend() == "cpu":
+            return plain
+        # graft: donation-ok -- the donation-aware wrapper itself;
+        # every caller annotates its own site
+        donating = jax.jit(f, donate_argnums=donate_argnums, **kwargs)
+
+        def call(*args, **kw):
+            if _aliased(args, donate_argnums):
+                return plain(*args, **kw)
+            return donating(*args, **kw)
+
+        return call
 
     if fun is None:
         return wrap

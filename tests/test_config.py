@@ -75,24 +75,84 @@ class TestRegistry:
             assert o.key in docs
             assert o.env_var in docs
 
-    def test_xla_cache_dir_bound_at_session_init(self, tmp_path):
-        """auron.xla_cache_dir (default off) binds jax's persistent
-        compilation cache when a Session is constructed — the first step
-        of the compile-budget diet (VERDICT round 5)."""
+    def test_compile_cache_is_never_off_and_lives_in_the_checkout(self):
+        """utils/xla_cache.py decides the directory in ONE place: with
+        nothing set it is <checkout>/.jax_cache, and a Session binds
+        it."""
         import jax
 
         from auron_tpu.frontend.session import Session
-        prev = getattr(jax.config, "jax_compilation_cache_dir", None)
+        from auron_tpu.utils import xla_cache
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert xla_cache.cache_dir(cfg.AuronConfig()) == want
+        Session(config=cfg.AuronConfig())
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_compile_cache_knob_names_a_directory(self, tmp_path):
+        import jax
+
+        from auron_tpu.frontend.session import Session
+        from auron_tpu.utils import xla_cache
+        cache = str(tmp_path / "xla-cache")
         try:
-            # default: off — no binding happens
-            Session(config=cfg.AuronConfig())
-            assert getattr(jax.config, "jax_compilation_cache_dir",
-                           None) == prev
-            cache = str(tmp_path / "xla-cache")
             Session(config=cfg.AuronConfig({cfg.XLA_CACHE_DIR: cache}))
             assert jax.config.jax_compilation_cache_dir == cache
         finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+            xla_cache.bind(cfg.AuronConfig())
+
+    def test_compile_cache_placed_from_outside_is_left_alone(
+            self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, so the
+        helper reports it and nothing updates jax's config — not even a
+        knob that names another directory."""
+        import jax
+
+        from auron_tpu.utils import xla_cache
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        calls = []
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda key, val: calls.append((key, val)))
+        conf = cfg.AuronConfig({cfg.XLA_CACHE_DIR: str(tmp_path / "k")})
+        assert xla_cache.bind(conf) == outside
+        assert not [c for c in calls
+                    if c[0] == "jax_compilation_cache_dir"], calls
+
+    def test_one_cache_call_site_and_no_platform_pins_in_the_product(self):
+        """The contracts a grep can hold: non-test code binds the compile
+        cache in exactly one place, derives no cache path from tempfile,
+        and pins no platform outside envsafe.cpu_child_env."""
+        import re
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        binds, pins = [], []
+        pin_re = re.compile(
+            r"""(environ\[["']JAX_PLATFORMS["']\]\s*=|"""
+            r"""setdefault\(["']JAX_PLATFORMS|"""
+            r"""["']JAX_PLATFORMS["']\s*:|"""
+            r"""update\(["']jax_platforms|"""
+            r"""xla_force_host_platform_device_count=)""")
+        for top in ("auron_tpu", "tools", "bench.py", "chip_smoke.py",
+                    "__graft_entry__.py"):
+            path = os.path.join(repo, top)
+            files = [path] if path.endswith(".py") else [
+                os.path.join(d, f) for d, _dirs, fs in os.walk(path)
+                for f in fs if f.endswith(".py")]
+            for fname in files:
+                with open(fname) as f:
+                    for no, line in enumerate(f, 1):
+                        rel = os.path.relpath(fname, repo)
+                        if re.search(r"update\(\s*[\"']jax_compilation"
+                                     r"_cache_dir", line):
+                            binds.append(f"{rel}:{no}")
+                        if pin_re.search(line) \
+                                and rel != "auron_tpu/utils/envsafe.py":
+                            pins.append(f"{rel}:{no}: {line.strip()}")
+        assert len(binds) == 1 and binds[0].startswith(
+            "auron_tpu/utils/xla_cache.py"), binds
+        assert not pins, pins
 
     def test_config_md_up_to_date(self):
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

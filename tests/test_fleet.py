@@ -222,8 +222,7 @@ class TestSnapshotFromBodies:
     def test_full_bodies(self):
         health = {"status": "degraded",
                   "memmgr": [{"used": 30, "total": 100},
-                             {"used": 90, "total": 100}],
-                  "watchdog": {"fallbacks": 2}}
+                             {"used": 90, "total": 100}]}
         queries = {
             "queries": [{"state": "running"}, {"state": "running"},
                         {"state": "queued"}, {"state": "done"}],
@@ -242,7 +241,6 @@ class TestSnapshotFromBodies:
         assert (s.admitted, s.rejected) == (7, 3)
         assert s.mem_frac == pytest.approx(0.9)
         assert s.status == "degraded"
-        assert s.watchdog_fallbacks == 2
         assert s.warm_fps == frozenset(("fp1", "fp2"))
         # only unclaimed dead-owner stems are resume inventory
         assert s.resume_stems == ("q1_9",)
@@ -257,6 +255,104 @@ class TestSnapshotFromBodies:
 # ---------------------------------------------------------------------------
 # fake replicas: scripted wire-protocol servers + fake ops endpoints
 # ---------------------------------------------------------------------------
+
+class TestReplicaEnvironment:
+    """spawn_replica / FleetHarness build each child's environment from
+    the parent's — platform selection included — plus what the caller
+    gives THAT replica: how a supervisor hands each replica its chip."""
+
+    @staticmethod
+    def _capture_popen(monkeypatch, stdout_line, stderr_text=b""):
+        from auron_tpu.fleet import replica
+        envs = []
+
+        class FakeProc:
+            pid = 4242
+            returncode = 3
+
+            def __init__(self, args, env=None, stderr=None, **kw):
+                envs.append(env)
+                if stderr_text:
+                    stderr.write(stderr_text)
+                import io
+                self.stdout = io.StringIO(stdout_line)
+
+            def poll(self):
+                return self.returncode if not stdout_line else None
+
+            def wait(self, timeout=None):
+                return self.returncode
+
+            def terminate(self):
+                pass
+
+            def kill(self):
+                pass
+
+        monkeypatch.setattr(replica.subprocess, "Popen", FakeProc)
+        return replica, envs
+
+    def test_child_inherits_platform_and_takes_its_own_env(
+            self, monkeypatch, tmp_path):
+        replica, envs = self._capture_popen(
+            monkeypatch, "AURON_SERVING 127.0.0.1:7\n")
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        rep = replica.spawn_replica(
+            str(tmp_path), env_extra={"TPU_VISIBLE_CHIPS": 1})
+        assert (rep.host, rep.port) == ("127.0.0.1", 7)
+        env = envs[0]
+        assert env["JAX_PLATFORMS"] == "tpu"      # inherited, never pinned
+        assert env["TPU_VISIBLE_CHIPS"] == "1"
+        assert env["AURON_CONF_JOURNAL_DIR"] == str(tmp_path)
+        monkeypatch.delenv("JAX_PLATFORMS")
+        replica.spawn_replica(str(tmp_path))
+        assert "JAX_PLATFORMS" not in envs[1]
+        rep.stop()
+
+    def test_harness_gives_each_replica_its_own_environment(
+            self, monkeypatch, tmp_path):
+        replica, envs = self._capture_popen(
+            monkeypatch, "AURON_SERVING 127.0.0.1:7\n")
+        from auron_tpu.fleet import router as router_mod
+
+        class FakeRouter:
+            def __init__(self, addrs, config=None):
+                pass
+
+            def start(self):
+                return self
+
+            def close(self):
+                pass
+
+        monkeypatch.setattr(router_mod, "FleetRouter", FakeRouter)
+        with replica.FleetHarness(
+                2, journal_dir=str(tmp_path), env_extra={"SHARED": "x"},
+                replica_env=[{"TPU_VISIBLE_CHIPS": "0"},
+                             {"TPU_VISIBLE_CHIPS": "1"}]):
+            pass
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+        assert [e["SHARED"] for e in envs] == ["x", "x"]
+        with pytest.raises(ValueError, match="replica_env"):
+            replica.FleetHarness(2, replica_env=[{}])
+
+    def test_tpu_chip_env_gives_each_replica_its_own_chip_and_port(self):
+        from auron_tpu.fleet import tpu_chip_env
+        a, b = tpu_chip_env(0), tpu_chip_env(3)
+        assert (a["TPU_VISIBLE_CHIPS"], b["TPU_VISIBLE_CHIPS"]) == ("0", "3")
+        assert a["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert a["TPU_MESH_CONTROLLER_PORT"] != b["TPU_MESH_CONTROLLER_PORT"]
+        assert "JAX_PLATFORMS" not in a       # placement, not platform
+
+    def test_boot_failure_says_why(self, monkeypatch, tmp_path):
+        """A replica that dies before announcing itself (say, its chip
+        is held by another process) surfaces its own stderr."""
+        replica, _envs = self._capture_popen(
+            monkeypatch, "", b"RuntimeError: TPU already in use\n")
+        with pytest.raises(errors.ReplicaUnavailable,
+                           match="TPU already in use"):
+            replica.spawn_replica(str(tmp_path), boot_timeout_s=5)
+
 
 def _dead_tag():
     """A liveness tag whose owner is PROVABLY dead: a reaped child's

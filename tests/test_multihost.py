@@ -26,8 +26,7 @@ _WORKER = textwrap.dedent("""
     pid = int(sys.argv[1]); nproc = int(sys.argv[2])
     port = sys.argv[3]
     from auron_tpu.parallel import multihost as mh
-    mh.init_process_group(f"127.0.0.1:{port}", nproc, pid,
-                          local_device_count=4)
+    mh.init_process_group(f"127.0.0.1:{port}", nproc, pid)
     import jax
     import jax.numpy as jnp
     assert len(jax.devices()) == 8, jax.devices()
@@ -117,7 +116,7 @@ def _run_workers(worker_path: str, port: int):
     from auron_tpu.utils.envsafe import cpu_child_env
     procs = []
     for pid in range(2):
-        env = cpu_child_env(REPO, n_devices=4)
+        env = cpu_child_env(n_devices=4)
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         procs.append(subprocess.Popen(
             [sys.executable, worker_path, str(pid), "2", str(port)],

@@ -17,6 +17,25 @@ class TestNativeBuild:
     def test_builds_and_loads(self):
         # the image ships g++ — the native path must actually engage here
         assert native.available()
+        how, why = native.status()
+        assert how in ("built", "loaded") and why
+
+    def test_rebuilds_when_missing_or_older_than_its_source(
+            self, tmp_path, monkeypatch):
+        """The library is -march=native and git-ignored: one that is
+        missing, or older than auron_host.cc, is never loaded as is."""
+        import os
+        so, src = tmp_path / "libauron_host.so", tmp_path / "auron_host.cc"
+        monkeypatch.setattr(native, "_SO_PATH", str(so))
+        monkeypatch.setattr(native, "_SRC_PATH", str(src))
+        src.write_text("// source")
+        assert native._stale() == "library missing"
+        so.write_text("binary")
+        os.utime(so, (100, 100))
+        os.utime(src, (200, 200))
+        assert native._stale() == "library older than auron_host.cc"
+        os.utime(so, (300, 300))
+        assert native._stale() is None
 
 
 class TestLexSort:

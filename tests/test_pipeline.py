@@ -392,6 +392,68 @@ class TestDonationSweep:
                       mode="complete")
         assert not agg_c._donate_contributions(ctx)
 
+    def test_programs_jit_never_donates_one_buffer_twice(
+            self, monkeypatch):
+        """The first thing the shuffle split hit on a real chip: a
+        scan-fed batch whose columns share ONE all-valid mask, donated
+        whole — "Attempt to donate the same buffer twice". The
+        donation-aware jit runs the non-donating twin for such a call
+        (accelerator branch emulated: the CPU never really donates)."""
+        import jax
+        import jax.numpy as jnp
+
+        from auron_tpu.runtime import programs
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        real_jit = jax.jit
+        calls = []
+
+        def fake_jit(f, donate_argnums=(), **kw):
+            jitted = real_jit(f, **kw)
+
+            def run(*a, **k):
+                calls.append(bool(donate_argnums))
+                return jitted(*a, **k)
+            return run
+
+        monkeypatch.setattr(jax, "jit", fake_jit)
+        fn = programs.jit(lambda cols, n: sum(cols) + n,
+                          donate_argnums=(0,))
+        a, b = jnp.ones(4), jnp.ones(4)
+        assert float(fn((a, b), 1.0)[0]) == 3.0     # distinct: donates
+        assert float(fn((a, a), 1.0)[0]) == 3.0     # aliased: plain twin
+        assert float(fn((a, b), a)[0]) == 3.0       # donated AND kept
+        assert calls == [True, False, False]
+
+    def test_q01_runs_with_the_accelerator_branches_taken(
+            self, monkeypatch, tmp_path):
+        """Donation checked by a RUN, not only by lint: with
+        default_backend() reporting an accelerator every donate gate
+        opens, and the CPU client enforces the same rules a chip does
+        (double donation, use after donation). q01's scan-fed shuffle
+        split is the plan that failed first light on the v5e."""
+        import jax
+
+        from auron_tpu import config as cfg
+        from auron_tpu.frontend.session import Session
+        from auron_tpu.it import tpcds_data
+        from auron_tpu.it.queries import q01_dataframe
+        from auron_tpu.runtime import programs
+        tables = tpcds_data.generate(str(tmp_path), scale=0.2)
+        want = q01_dataframe(Session(), tables, partitions=4).collect()
+        conf = cfg.get_config()
+        conf.set(cfg.KERNELS_BACKEND, "dense")   # no native Mosaic here
+        programs.clear_all()
+        jax.clear_caches()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        try:
+            got = q01_dataframe(Session(), tables, partitions=4).collect()
+        finally:
+            monkeypatch.undo()
+            conf.unset(cfg.KERNELS_BACKEND)
+            programs.clear_all()      # drop the donating programs
+            jax.clear_caches()
+        assert got.equals(want)
+
     def test_aliased_contributions_never_donate(self):
         """sum(x) + avg(x) share the x column object — the reduce must
         detect the aliasing and fall back to the non-donating program

@@ -144,7 +144,6 @@ def test_transient_taxonomy_classes_retried(exc_cls):
 @pytest.mark.parametrize("exc_cls", [
     errors.KernelLoweringError,    # deterministic lowering/shape defect
     errors.InjectedFatalError,     # chaos plans' deterministic kind
-    errors.BackendInitError,       # re-entering a wedged client can't help
     errors.ShuffleCorruption,      # needs map recompute, not reducer rerun
     errors.PlanError,
 ])
@@ -209,8 +208,6 @@ def test_exponential_backoff_full_jitter_bounds():
 
 
 def test_finalize_snapshot_carries_recovery_counters():
-    from auron_tpu.runtime import watchdog
-
     rb = pa.record_batch({"x": pa.array([1, 2], pa.int64())})
     scan = MemoryScanOp([[rb]], schema_from_arrow(rb.schema), capacity=8)
     rt = ExecutionRuntime(
@@ -221,9 +218,9 @@ def test_finalize_snapshot_carries_recovery_counters():
     assert rec["attempts"] == 3
     assert rec["transient_retries"] == 2
     assert rec["corruption_recomputes"] == 0
-    # process-level total (watchdog probes run at Session init, before
-    # any task exists — a per-task delta could never be nonzero)
-    assert rec["watchdog_fallbacks"] == watchdog.totals()
+    # the backend watchdog and its fallback counter are gone: a process
+    # runs on the platform jax gives it
+    assert "watchdog_fallbacks" not in rec
     assert rec["faults_injected"] == 0
 
 

@@ -205,7 +205,7 @@ class TestRssExchange:
                                      plan=node).SerializeToString()
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = cpu_child_env(repo, n_devices=2)
+        env = cpu_child_env(n_devices=2)
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         proc = subprocess.Popen(
             [sys.executable, "-m", "auron_tpu.runtime.serving"],

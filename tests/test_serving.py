@@ -182,7 +182,7 @@ def test_two_process_serving(tmp_path):
     from auron_tpu.utils.envsafe import cpu_child_env
     path, tbl = _dataset(str(tmp_path))
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = cpu_child_env(repo, n_devices=2)
+    env = cpu_child_env(n_devices=2)
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "auron_tpu.runtime.serving"],
@@ -416,7 +416,7 @@ def test_two_process_live_attach_all_fixtures(spark_fixture_env):
     fixture, by_basename, pd_tables = spark_fixture_env
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = cpu_child_env(repo, n_devices=2)
+    env = cpu_child_env(n_devices=2)
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "auron_tpu.runtime.serving"],

@@ -292,14 +292,13 @@ def _mesh_dataset(workdir):
     pq.write_table(tbl, os.path.join(workdir, "mesh.parquet"))
 
 
-def _spawn_mesh_child(workdir, jdir, kill_at, cache_dir):
+def _spawn_mesh_child(workdir, jdir, kill_at):
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "AURON_CONF_MESH_ENABLED": "1",
         "AURON_CONF_JOURNAL_DIR": jdir,
-        "AURON_CONF_XLA_CACHE_DIR": cache_dir,
     })
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     return subprocess.run(
@@ -322,8 +321,7 @@ def mesh_baseline(mesh_workdir):
     every resumed width."""
     jdir = os.path.join(mesh_workdir, "journal_base")
     os.makedirs(jdir, exist_ok=True)
-    proc = _spawn_mesh_child(mesh_workdir, jdir, 0,
-                             os.path.join(mesh_workdir, "xla_cache"))
+    proc = _spawn_mesh_child(mesh_workdir, jdir, 0)
     assert proc.returncode == 0, proc.stderr[-2000:]
     import pyarrow.feather as feather
     return feather.read_table(
@@ -343,8 +341,7 @@ def _resume_at_width(mesh_workdir, mesh_baseline, width):
     # commit = event 9 — kill right after the commit returns, so the
     # resume reuses a COMPLETE committed exchange and re-routes
     # everything downstream by the current (narrower) plane's verdict
-    proc = _spawn_mesh_child(mesh_workdir, jdir, 9,
-                             os.path.join(mesh_workdir, "xla_cache"))
+    proc = _spawn_mesh_child(mesh_workdir, jdir, 9)
     assert proc.returncode == -9, (proc.returncode, proc.stderr[-2000:])
     stems = [os.path.splitext(os.path.basename(p))[0]
              for p in glob.glob(os.path.join(jdir, "*.journal"))]

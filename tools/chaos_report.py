@@ -25,14 +25,6 @@ import os
 import sys
 import tempfile
 
-# CPU mesh before jax init: chaos verifies recovery logic, not device
-# perf — it must run on a wedged-accelerator host
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_xf = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _xf:
-    os.environ["XLA_FLAGS"] = (
-        _xf + " --xla_force_host_platform_device_count=8").strip()
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 #: the battery's (scenario, plan) pairs — one per site/kind with traffic

@@ -26,13 +26,6 @@ import json
 import os
 import sys
 
-# CPU mesh before jax init (accounting tool, not a perf gate)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_xf = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _xf:
-    os.environ["XLA_FLAGS"] = (
-        _xf + " --xla_force_host_platform_device_count=8").strip()
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 

@@ -382,14 +382,21 @@ def _journal_orphans(journal_dir: str) -> list:
     return sorted(leftovers)
 
 
-def run_fleet(n: int, clients: int, requests: int, rows: int) -> dict:
-    from auron_tpu.fleet import FleetHarness
+def run_fleet(n: int, clients: int, requests: int, rows: int,
+              chip_per_replica: bool = False) -> dict:
+    from auron_tpu.fleet import FleetHarness, tpu_chip_env
     root = tempfile.mkdtemp(prefix="auron_fleet_load_")
     # throttle each replica to 1 running + 1 queued query: on a small
     # host the fleet's win is ADMISSION capacity (more replicas admit
     # more of the same burst), and this makes that the measured axis
     env_extra = {"AURON_CONF_SCHED_MAX_CONCURRENT": "1",
                  "AURON_CONF_SCHED_QUEUE_DEPTH": "1"}
+
+    # on a TPU host every replica needs a chip of its own; this process
+    # (router + clients) never touches jax, so it holds none
+    def chips(k):
+        return ([tpu_chip_env(i) for i in range(k)]
+                if chip_per_replica else None)
     try:
         path = _dataset(root, rows)
         task = _task_bytes(path)
@@ -398,8 +405,8 @@ def run_fleet(n: int, clients: int, requests: int, rows: int) -> dict:
         os.makedirs(jdir_one)
         os.makedirs(jdir_n)
 
-        with FleetHarness(1, journal_dir=jdir_one,
-                          env_extra=env_extra) as h1:
+        with FleetHarness(1, journal_dir=jdir_one, env_extra=env_extra,
+                          replica_env=chips(1)) as h1:
             warm: list = []
             lock = threading.Lock()
             _drive(h1.address, task, 1, warm, lock)
@@ -410,8 +417,8 @@ def run_fleet(n: int, clients: int, requests: int, rows: int) -> dict:
                 h1, task, clients, requests)
             stats1 = h1.router.stats_dict()
 
-        with FleetHarness(n, journal_dir=jdir_n,
-                          env_extra=env_extra) as hn:
+        with FleetHarness(n, journal_dir=jdir_n, env_extra=env_extra,
+                          replica_env=chips(n)) as hn:
             _drive(hn.address, task, 1, [], lock)   # warm compiles
             outn, walln, tblsn, wedgedn, errsn, ledn = _fleet_burst(
                 hn, task, clients, requests, kill_index=0,
@@ -507,6 +514,10 @@ def main(argv=None) -> int:
                          "router, one SIGKILLed mid-burst; reports "
                          "admitted-throughput scale vs one replica, "
                          "failover latency, and journal cleanliness")
+    ap.add_argument("--chip-per-replica", action="store_true",
+                    help="with --fleet on a TPU host: confine replica "
+                         "i to chip i (fleet.tpu_chip_env); without it "
+                         "every replica inherits the whole host")
     ap.add_argument("--expect-scale", type=float, default=2.5,
                     metavar="X",
                     help="with --fleet: fail (exit 1) when aggregate "
@@ -518,7 +529,8 @@ def main(argv=None) -> int:
         rep = run_fleet(args.fleet,
                         args.clients or 4 * args.fleet,
                         args.requests or 1,
-                        args.rows or 3_000_000)
+                        args.rows or 3_000_000,
+                        chip_per_replica=args.chip_per_replica)
         o, f, fo = rep["one"], rep["fleet"], rep["failover"]
         print(f"fleet report: {args.fleet} replicas, "
               f"{rep['clients']} clients x "
@@ -539,7 +551,8 @@ def main(argv=None) -> int:
               f"journal orphans: {len(rep['journal_orphans'])}")
         cost = rep.get("cost") or {}
         if cost.get("queries"):
-            print(f"  cost ledgers: {cost['queries']} queries, "
+            print(f"  cost ledgers: {cost['queries']} queries on "
+                  f"{cost.get('devices')}, "
                   f"device {cost['device_s']}s / host "
                   f"{cost['host_total_s']}s, "
                   f"{cost['rows']} rows, "
@@ -619,7 +632,8 @@ def main(argv=None) -> int:
     print(f"  sheds by reason: {rep['sched']['rejected_by_reason']}")
     cost = rep.get("cost") or {}
     if cost.get("queries"):
-        print(f"  cost ledgers: {cost['queries']} queries, "
+        print(f"  cost ledgers: {cost['queries']} queries on "
+              f"{cost.get('devices')}, "
               f"device {cost['device_s']}s / host "
               f"{cost['host_total_s']}s, shuffle "
               f"{cost['shuffle_bytes']}B, spill {cost['spill_bytes']}B")

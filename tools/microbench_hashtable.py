@@ -18,7 +18,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -145,7 +144,11 @@ def main(argv=None) -> int:
     print(f"sort agg    {dt_s * 1e3:8.1f} ms   {n / dt_s:14,.0f} rows/s")
     print(f"hash vs sort: {dt_s / dt_h:.2f}x")
 
+    dev = jax.devices()[0]
+    print(f"platform: {dev.platform} ({dev.device_kind})")
     print(json.dumps({"metric": "microbench_hashtable",
+                      "platform": dev.platform,
+                      "device_kind": dev.device_kind,
                       "rows": n, "distinct_keys": n_keys,
                       "capacity": cap,
                       **{m: round(val, 1) for m, val in results.items()}}))

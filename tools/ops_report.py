@@ -177,11 +177,6 @@ def render_bundle(path: str) -> str:
     if mesh:
         out.append("")
         out.append(f"mesh plane: {json.dumps(mesh, default=str)[:500]}")
-    probe = _load_json(os.path.join(path, "probe_report.json"))
-    if probe:
-        out.append("")
-        out.append(f"backend probe: ok={probe.get('ok')} "
-                   f"platform={probe.get('platform')}")
     stalls = sorted(p for p in os.listdir(path)
                     if p.startswith("stall_report_"))
     for p in stalls:

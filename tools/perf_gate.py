@@ -1,12 +1,12 @@
 """Perf regression gate: fresh q01 bench vs the checked-in baseline.
 
-The ROADMAP [speed] item's third front: q01 CPU throughput decayed
-276k → 108k rows/s across BENCH_r03→r05 and nobody noticed until the
-round-5 verdict read the history side by side. This gate makes that
-trajectory a failing exit code: it takes a fresh ``bench.py`` record
-(or one from a file/stdin), looks up the platform's floor in
-``tools/perf_baseline.json`` (distilled from BENCH_r01–r05 — the
-weakest HONEST measurement per platform), applies the tolerance
+q01 CPU throughput decayed 276k → 108k rows/s across the driver's
+round 3→5 captures and nobody noticed until they were read side by
+side. This gate makes that trajectory a failing exit code: it takes a
+fresh ``bench.py`` record (or one from a file/stdin), looks up the
+platform's floor in ``tools/perf_baseline.json`` (CPU floors only —
+a tpu record has none until the ledger supplies it), applies the
+tolerance
 (CLI > ``auron.perf_gate.tolerance_pct`` > baseline default, sized to
 this container's measured wall-clock variance), and exits nonzero on a
 regression past it.
@@ -42,9 +42,8 @@ def load_baseline(path: str) -> dict:
 
 
 def fresh_bench_record(timeout_s: int = 1800) -> dict:
-    # sized for the bench child (900s budget) PLUS the mesh scaling
-    # child the parent runs afterwards (~540s budget)
-    """Run bench.py and parse its one-JSON-line contract."""
+    """Run bench.py (one process; this one stays off jax, so the child
+    gets the chip) and parse its one-JSON-line contract."""
     repo = os.path.dirname(_HERE)
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py")],
@@ -96,9 +95,7 @@ def evaluate(record: dict, baseline: dict, tolerance_pct: float,
         return {"perf_gate": "unusable",
                 "reason": f"bench errored: {record['error']}"}
     platform = record.get("platform", "")
-    aliases = baseline.get("platform_aliases", {})
-    entry = baseline.get("platforms", {}).get(
-        aliases.get(platform, platform))
+    entry = baseline.get("platforms", {}).get(platform)
     if entry is None:
         return {"perf_gate": "unusable",
                 "reason": f"no baseline for platform {platform!r}"}
@@ -166,14 +163,18 @@ def evaluate(record: dict, baseline: dict, tolerance_pct: float,
             if pval < pfloor:
                 verdict["perf_gate"] = "fail"
     # SPMD mesh floor: the virtual 8-device CPU mesh q01 scaling figure
-    # (bench's mesh child). Gated whenever the record carries a mesh
+    # (bench.bench_mesh). Gated whenever the record carries a mesh
     # section; a bench that TRIED and failed records mesh_error and
     # FAILS (the silent-decay hole stays closed for every fresh bench);
-    # records predating the mesh bench skip with the skip recorded.
+    # a single-device run says it skipped the sweep, and records
+    # predating the mesh bench skip with the skip recorded.
     mentry = baseline.get("platforms", {}).get("mesh")
     if mentry:
         mrec = record.get("mesh")
-        if isinstance(mrec, dict) and mrec.get("mesh_rows_per_sec"):
+        if isinstance(mrec, dict) and mrec.get("skipped"):
+            verdict["mesh"] = {"verdict": "skipped",
+                               "reason": str(mrec["skipped"])}
+        elif isinstance(mrec, dict) and mrec.get("mesh_rows_per_sec"):
             mscale = mentry.get("scale")
             mdev = int(mentry.get("devices", 8))
             if mscale is not None \
@@ -250,19 +251,9 @@ def evaluate(record: dict, baseline: dict, tolerance_pct: float,
                           "(predates the mesh bench)",
             }
     # carry the forensics along: a failing gate should arrive WITH the
-    # host/device attribution and the structured backend diagnosis
+    # host/device attribution
     if isinstance(record.get("profile"), dict):
         verdict["profile"] = record["profile"]
-    pr = record.get("probe_report")
-    if isinstance(pr, dict):
-        verdict["probe_ok"] = pr.get("ok")
-        if not pr.get("ok"):
-            failed = next((s for s in pr.get("steps", [])
-                           if not s.get("ok")), {})
-            verdict["probe_failed_step"] = failed.get("name")
-            verdict["probe_error"] = (
-                f"{failed.get('error_type', '')}: "
-                f"{failed.get('error_message', '')}").strip(": ")
     return verdict
 
 
@@ -410,7 +401,6 @@ def run_cache_gate(tables, smoke: dict) -> dict:
     warmed NOTHING all fail loudly. Returns
     ``{"cache_gate": "pass"|"fail", "cache_speedup_x": ..., ...}``."""
     import shutil
-    import tempfile
     import time
 
     from auron_tpu import config as cfg
@@ -422,15 +412,15 @@ def run_cache_gate(tables, smoke: dict) -> dict:
     floor_x = float(smoke.get("cache_speedup_floor_x", 5.0))
     conf = cfg.get_config()
     cache = get_cache()
-    # the AOT inventory rides next to the persistent XLA cache; Session
-    # binds jax_compilation_cache_dir to it, so remember the binding and
-    # restore it after — the gate's temp dir must not outlive the gate
-    aot_root = tempfile.mkdtemp(prefix="auron_cache_gate_")
-    try:
-        import jax
-        prev_xla_dir = jax.config.jax_compilation_cache_dir
-    except Exception:   # noqa: BLE001 — jax-version dependent attr
-        jax, prev_xla_dir = None, None
+    # naming auron.xla_cache_dir arms the AOT inventory under it — and
+    # moves the compile cache there unless the environment placed it.
+    # A fixed corner of the cache the process already uses, so the
+    # gate's compiles stay warm across runs; only its inventory is
+    # scratch, and the binding is restored after
+    from auron_tpu.utils import xla_cache
+    aot_root = os.path.join(xla_cache.cache_dir(conf), "perf_gate")
+    aot_plans = os.path.join(aot_root, "aot_plans")
+    shutil.rmtree(aot_plans, ignore_errors=True)
     conf.set(cfg.CACHE_ENABLED, True)
     conf.set(cfg.XLA_CACHE_DIR, aot_root)
     try:
@@ -501,13 +491,8 @@ def run_cache_gate(tables, smoke: dict) -> dict:
         conf.unset(cfg.CACHE_ENABLED)
         conf.unset(cfg.XLA_CACHE_DIR)
         cache.clear(reset_counters=True)
-        if jax is not None:
-            try:
-                jax.config.update(
-                    "jax_compilation_cache_dir", prev_xla_dir)
-            except Exception:   # noqa: BLE001 — best-effort restore
-                pass
-        shutil.rmtree(aot_root, ignore_errors=True)
+        xla_cache.bind(conf)
+        shutil.rmtree(aot_plans, ignore_errors=True)
 
 
 def run_fusion_gate(smoke: dict) -> dict:
@@ -585,7 +570,9 @@ def run_fleet_gate(smoke: dict) -> dict:
 
         from auron_tpu.fleet.replica import FleetHarness
         from auron_tpu.ir import pb
+        from auron_tpu.utils.envsafe import require_shareable_device
 
+        require_shareable_device("the fleet gate")
         root = tempfile.mkdtemp(prefix="auron_fleet_gate_")
         rng = np.random.default_rng(19)
         n = 600_000
