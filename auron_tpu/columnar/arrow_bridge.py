@@ -3,8 +3,11 @@
 This is the host<->device boundary, the analogue of the reference's Arrow
 C-FFI import/export between JVM and native (reference: auron-core/src/main/
 java/org/apache/auron/arrowio/..., native-engine/auron/src/rt.rs:252-282).
-On TPU the transfer is a single jax.device_put of dense padded buffers per
-column — no per-row work on either side of the wall.
+Host → device runs in two steps a batch, each under its layer span
+(obs/trace.py): *encode* builds every column as dense padded numpy buffers
+(the ``_*_to_device`` helpers below return columns with numpy leaves), then
+*h2d* transfers the batch's buffers — no per-row work on either side of the
+wall.
 """
 
 from __future__ import annotations
@@ -12,11 +15,13 @@ from __future__ import annotations
 import numpy as np
 import pyarrow as pa
 
+import jax
 import jax.numpy as jnp
 
 from auron_tpu.columnar.batch import (DeviceBatch, ListColumn,
                                       PrimitiveColumn, StringColumn)
 from auron_tpu.columnar.schema import DataType, Field, Schema
+from auron_tpu.obs import trace
 from auron_tpu.utils.shapes import bucket_rows, bucket_string_width
 
 #: fallback precision for a LIST-of-decimal field whose precision slot is
@@ -283,8 +288,8 @@ def _kv_lists_to_map_column(arr: pa.Array, karr: pa.Array, varr: pa.Array,
     kv = np.pad(kv, ((0, 0), (0, m - kv.shape[1])))
     vv = np.pad(vv, ((0, 0), (0, m - vv.shape[1])))
     vev = np.pad(vev, ((0, 0), (0, m - vev.shape[1])))
-    return MapColumn(jnp.asarray(kv), jnp.asarray(vv), jnp.asarray(vev),
-                     jnp.asarray(lens), jnp.asarray(validity))
+    return MapColumn(kv, vv, vev,
+                     lens, validity)
 
 
 def _decimal_list_to_device(field: Field, arr: pa.Array, cap: int):
@@ -314,15 +319,15 @@ def _decimal_list_to_device(field: Field, arr: pa.Array, cap: int):
                     else np.ones(n, bool))
     lens = np.where(validity, lens, 0).astype(np.int32)
     if field.precision <= 18:
-        return ListColumn(jnp.asarray(lo_m), jnp.asarray(ev),
-                          jnp.asarray(lens), jnp.asarray(validity))
+        return ListColumn(lo_m, ev,
+                          lens, validity)
     hi_list = pa.ListArray.from_arrays(
         off, pa.array(np.ascontiguousarray(limbs[:, 1]), pa.int64(),
                       mask=mask))
     hi_m, _hev, _l, _ = _list_arrays(hi_list, cap, np.int64)
-    return MapColumn(jnp.asarray(hi_m), jnp.asarray(lo_m),
-                     jnp.asarray(ev), jnp.asarray(lens),
-                     jnp.asarray(validity))
+    return MapColumn(hi_m, lo_m,
+                     ev, lens,
+                     validity)
 
 
 def _entry_list_to_device(field: Field, arr: pa.Array, cap: int):
@@ -388,7 +393,7 @@ def _struct_to_device(field: Field, arr: pa.Array, cap: int):
     validity = np.zeros(cap, bool)
     validity[:n] = (~np.asarray(arr.is_null()) if arr.null_count
                     else np.ones(n, bool))
-    return StructColumn(kids, jnp.asarray(validity))
+    return StructColumn(kids, validity)
 
 
 def to_device(rb: pa.RecordBatch, capacity: int | None = None,
@@ -399,9 +404,18 @@ def to_device(rb: pa.RecordBatch, capacity: int | None = None,
     cap = capacity if capacity is not None else bucket_rows(n)
     if n > cap:
         raise ValueError(f"batch of {n} rows exceeds capacity {cap}")
-    cols = [_column_to_device(field, arr, cap, string_widths)
-            for field, arr in zip(schema, rb.columns)]
-    return DeviceBatch(tuple(cols), jnp.asarray(n, jnp.int32)), schema
+    with trace.layer_span("scan", "encode"):
+        cols = [_column_to_device(field, arr, cap, string_widths)
+                for field, arr in zip(schema, rb.columns)]
+        leaves, treedef = jax.tree_util.tree_flatten(
+            DeviceBatch(tuple(cols), np.asarray(n, np.int32)))
+    with trace.layer_span("scan", "h2d"):
+        # one transfer a buffer, as before the split; the count is what
+        # a batched device_put would remove
+        leaves = [jnp.asarray(leaf) for leaf in leaves]
+        trace.count("h2d_transfers", len(leaves))
+        trace.count("h2d_bytes", sum(leaf.nbytes for leaf in leaves))
+    return jax.tree_util.tree_unflatten(treedef, leaves), schema
 
 
 def _string_list_to_device(arr: pa.Array, cap: int):
@@ -438,9 +452,9 @@ def _string_list_to_device(arr: pa.Array, cap: int):
             chars[i, j, :len(b)] = np.frombuffer(b, np.uint8)
             slens[i, j] = len(b)
             ev[i, j] = True
-    return StringListColumn(jnp.asarray(chars), jnp.asarray(slens),
-                            jnp.asarray(ev), jnp.asarray(lens),
-                            jnp.asarray(validity))
+    return StringListColumn(chars, slens,
+                            ev, lens,
+                            validity)
 
 
 def _string_map_to_device(arr: pa.Array, cap: int):
@@ -480,10 +494,10 @@ def _string_map_to_device(arr: pa.Array, cap: int):
                 vchars[i, j, :len(vb)] = np.frombuffer(vb, np.uint8)
                 vslens[i, j] = len(vb)
                 vv[i, j] = True
-    return StringMapColumn(jnp.asarray(kchars), jnp.asarray(kslens),
-                           jnp.asarray(vchars), jnp.asarray(vslens),
-                           jnp.asarray(vv), jnp.asarray(lens),
-                           jnp.asarray(validity))
+    return StringMapColumn(kchars, kslens,
+                           vchars, vslens,
+                           vv, lens,
+                           validity)
 
 
 def _column_to_device(field: Field, arr, cap: int,
@@ -496,8 +510,8 @@ def _column_to_device(field: Field, arr, cap: int,
     if field.dtype == DataType.STRING:
         w = (string_widths or {}).get(field.name)
         chars, lens, validity = _string_arrays(arr, cap, w)
-        return StringColumn(jnp.asarray(chars), jnp.asarray(lens),
-                            jnp.asarray(validity))
+        return StringColumn(chars, lens,
+                            validity)
     if field.dtype == DataType.LIST:
         if field.elem == DataType.STRING:
             return _string_list_to_device(arr, cap)
@@ -507,8 +521,8 @@ def _column_to_device(field: Field, arr, cap: int,
             return _decimal_list_to_device(field, arr, cap)
         values, ev, lens, validity = _list_arrays(arr, cap,
                                                   field.elem.to_np())
-        return ListColumn(jnp.asarray(values), jnp.asarray(ev),
-                          jnp.asarray(lens), jnp.asarray(validity))
+        return ListColumn(values, ev,
+                          lens, validity)
     if field.dtype == DataType.MAP:
         if field.key == DataType.STRING:
             return _string_map_to_device(arr, cap)
@@ -519,7 +533,7 @@ def _column_to_device(field: Field, arr, cap: int,
     validity = np.zeros(cap, bool)
     data = np.zeros(cap, np_dtype)
     if field.dtype == DataType.NULL:
-        return PrimitiveColumn(jnp.asarray(data), jnp.asarray(validity))
+        return PrimitiveColumn(data, validity)
     if field.dtype == DataType.DECIMAL:
         pyvals = arr.to_pylist()
         if field.precision > 18:
@@ -538,8 +552,8 @@ def _column_to_device(field: Field, arr, cap: int,
                                  .to_integral_value())
                         for v in pyvals]
             hi, lo, valid128 = limbs_from_ints(ints, cap)
-            return Decimal128Column(jnp.asarray(hi), jnp.asarray(lo),
-                                    jnp.asarray(valid128))
+            return Decimal128Column(hi, lo,
+                                    valid128)
         # <=18 digits: unscaled int64 payload (reference:
         # datafusion-ext-functions/src/spark_make_decimal.rs)
         unscaled = np.zeros(n, np.int64)
@@ -561,7 +575,7 @@ def _column_to_device(field: Field, arr, cap: int,
         vals = arr.fill_null(False) if field.dtype == DataType.BOOL else arr.fill_null(0)
         data[:n] = np.asarray(vals)
         validity[:n] = ~np.asarray(arr.is_null()) if arr.null_count else True
-    return PrimitiveColumn(jnp.asarray(data), jnp.asarray(validity))
+    return PrimitiveColumn(data, validity)
 
 
 def to_arrow(batch: DeviceBatch, schema: Schema) -> pa.RecordBatch:

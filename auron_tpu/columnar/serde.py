@@ -209,9 +209,16 @@ def fetch_leaves(leaves: list) -> list[np.ndarray]:
     A blocking per-array fetch pays a fixed latency regardless of size,
     so `np.asarray` per buffer (10+ per batch) adds up; `jax.device_get`
     on the whole list issues the transfers together and awaits them
-    once."""
+    once. It is one of the task's ``readbacks`` (obs/trace.py); the
+    layer span around it is its caller's (``auron:convert/to_arrow``,
+    an operator's timer)."""
     import jax
-    return list(jax.device_get(list(leaves)))
+
+    from auron_tpu.obs import trace
+    out = list(jax.device_get(list(leaves)))
+    trace.count("readbacks")
+    trace.count("d2h_bytes", sum(a.nbytes for a in out))
+    return out
 
 
 def host_col_from_device(c, it) -> HostColumn:

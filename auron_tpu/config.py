@@ -85,9 +85,7 @@ SCAN_BATCH_ROWS = _opt(
     "execution') — and auron.io.parquet.batch_rows (2^16) on "
     "accelerators. The scan clamps the conversion capacity to the "
     "partition's actual row count bucket, so small files never pad to "
-    "the full batch size. One flag for batch-size experiments; "
-    "tools/hotspot_report.py prints the achieved rows/batch per "
-    "operator next to the attribution table.")
+    "the full batch size. One flag for batch-size experiments.")
 
 # pipelined async execution (runtime/pipeline.py)
 PIPELINE_ENABLED = _opt(
@@ -498,18 +496,8 @@ QUERY_DEADLINE_S = _opt(
     "resource cleanup, task-level backoff sleeps clamped to the "
     "remaining budget. 0 (default) = no deadline.")
 
-# profiling
-PROFILE = _opt(
-    "auron.profile", bool, False,
-    "Wrap task execution in a jax.profiler trace and attach per-operator "
-    "device-time attribution to the finalize metrics (the role of the "
-    "reference's pprof flamegraph/heap HTTP endpoints, "
-    "auron/src/http/mod.rs:25-108).")
-PROFILE_DIR = _opt(
-    "auron.profile.dir", str, "",
-    "Directory for profiler trace output; empty = a per-task directory "
-    "under the system temp dir. The trace is viewable with "
-    "tensorboard/xprof.")
+# profiling (a device profile is any jax profiler session around the
+# process: the layer spans of obs/trace.py annotate it)
 PROFILE_ENABLED = _opt(
     "auron.profile.enabled", bool, True,
     "Host/device time attribution (auron_tpu/obs/profile.py): every "
@@ -520,9 +508,8 @@ PROFILE_ENABLED = _opt(
     "buckets (elapsed_host_{dispatch,convert,serde,iter,other}) "
     "alongside elapsed_device in the metric tree / EXPLAIN ANALYZE. "
     "Feeds the per-batch dispatch-overhead registry histograms and the "
-    "per-query profile_*.jsonl export into auron.trace.dir that "
-    "tools/hotspot_report.py ranks. Measured overhead < 2% (bench A/B, "
-    "PERF.md 'Performance forensics'); off reduces the hot-path cost to "
+    "served task's program-call count (cost_ledger.counts). Measured "
+    "overhead < 2% (bench A/B, CPU); off reduces the hot-path cost to "
     "one cached epoch compare per timer. Attribution requires the "
     "per-call sync point, so auron.metrics.device_sync=false (the "
     "maximum-throughput knob) disables the profiler too — profiling "
@@ -557,7 +544,7 @@ TRACE_EVENTS = _opt(
     "auron.trace.events", str, "",
     "Comma-separated span-category allowlist (query, task, program, "
     "shuffle, spill, fault, watchdog, memory, sched, mesh, journal, "
-    "cache, fleet); empty records every category. "
+    "cache, fleet, layer); empty records every category. "
     "Narrowing the list bounds tracing overhead on hot paths — e.g. "
     "'task,shuffle,fault' drops the per-hit program events.")
 TRACE_MAX_SPANS = _opt(

@@ -155,7 +155,7 @@ def _probe_kernel(num_hash_functions: int, bit_size: int):
     k = num_hash_functions
 
     @jax.jit
-    def kernel(words: jax.Array, values: jax.Array):
+    def auron_exprs_bloom_probe(words: jax.Array, values: jax.Array):
         h1 = hashing.murmur3_int64(values, jnp.uint32(0)).astype(jnp.int32)
         h2 = hashing.murmur3_int64(values, h1.view(jnp.uint32)) \
             .astype(jnp.int32)
@@ -168,7 +168,7 @@ def _probe_kernel(num_hash_functions: int, bit_size: int):
                 >> (idx & jnp.uint64(63))) & jnp.uint64(1)
         return jnp.all(bits == 1, axis=1)
 
-    return kernel
+    return auron_exprs_bloom_probe
 
 
 def might_contain_device(filter_bytes: bytes, values: jax.Array) -> jax.Array:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from auron_tpu.hashtable import core
+from auron_tpu.obs import profile as _profile
 from auron_tpu.runtime.programs import program_cache
 from auron_tpu.utils.shapes import next_pow2
 
@@ -62,7 +63,7 @@ def _agg_step_kernel(key_meta: tuple, acc_meta: tuple, n: int, cap: int,
     hash + insert + store winners + scatter accumulator contributions."""
 
     @jax.jit
-    def kernel(th, tw, store, accs, auxs, keys, contribs, live, ord_base):
+    def auron_hashtable_agg_step(th, tw, store, accs, auxs, keys, contribs, live, ord_base):
         h = _hashes(keys, n)
         w = core.key_words(keys, key_meta)
         claims, slot, resolved = core.insert_loop(th, tw, h, w, live,
@@ -75,7 +76,7 @@ def _agg_step_kernel(key_meta: tuple, acc_meta: tuple, n: int, cap: int,
         overflow = jnp.any(live & ~resolved)
         return th2, tw2, store2, accs2, auxs2, n_new, overflow
 
-    return kernel
+    return auron_hashtable_agg_step
 
 
 @program_cache("hashtable.agg_grow", maxsize=64)
@@ -87,7 +88,7 @@ def _grow_kernel(key_meta: tuple, acc_meta: tuple, old_cap: int,
     W = core.total_words(key_meta)
 
     @jax.jit
-    def kernel(th, store, accs, auxs):
+    def auron_hashtable_agg_grow(th, store, accs, auxs):
         occupied = th != core.EMPTY
         cols = core.store_columns(store, key_meta)
         w = core.key_words(cols, key_meta)
@@ -111,7 +112,7 @@ def _grow_kernel(key_meta: tuple, acc_meta: tuple, old_cap: int,
         return (nth, ntw, nstore, tuple(naccs), tuple(nauxs),
                 jnp.any(occupied & ~resolved))
 
-    return kernel
+    return auron_hashtable_agg_grow
 
 
 @program_cache("hashtable.agg_export", maxsize=64)
@@ -122,7 +123,7 @@ def _export_kernel(key_meta: tuple, acc_meta: tuple, cap: int):
     from auron_tpu.columnar.batch import gather_column
 
     @jax.jit
-    def kernel(th, store, accs):
+    def auron_hashtable_agg_export(th, store, accs):
         occupied = th != core.EMPTY
         ng = jnp.sum(occupied.astype(jnp.int32))
         perm = jnp.argsort(th, stable=True)     # EMPTY is max: dead last
@@ -132,7 +133,7 @@ def _export_kernel(key_meta: tuple, acc_meta: tuple, cap: int):
         accs_out = tuple(a[perm] for a in accs)
         return cols, accs_out, ng, th[perm]
 
-    return kernel
+    return auron_hashtable_agg_export
 
 
 def _pad_string_keys(keys, target_meta: tuple):
@@ -219,7 +220,7 @@ class HashAggState:
                                 new_cap, self.rounds)
             nth, ntw, nstore, naccs, nauxs, ovf = kern(
                 self.th, self.store, self.accs, self.auxs)
-            if bool(jax.device_get(ovf)):
+            if bool(_profile.timed_get(ovf)):
                 new_cap *= 2
                 continue
             self.th, self.tw, self.store = nth, ntw, nstore
@@ -250,7 +251,6 @@ class HashAggState:
             # sweep deliberately skips the step/grow kernels: the
             # overflow-retry protocol re-runs them with the SAME state
             # and batch inputs, which donation would have invalidated.
-            from auron_tpu.obs import profile as _profile
             n_new_h, ovf = _profile.timed_get([n_new, overflow])
             if not bool(ovf):
                 self.th, self.tw, self.store = th, tw, store

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from auron_tpu.hashtable import core
+from auron_tpu.obs import profile as _profile
 from auron_tpu.runtime.programs import program_cache
 from auron_tpu.utils.shapes import next_pow2
 
@@ -31,7 +32,7 @@ from auron_tpu.utils.shapes import next_pow2
 @program_cache("hashtable.build", maxsize=128)
 def _build_kernel(key_meta: tuple, n: int, cap: int, rounds: int):
     @jax.jit
-    def kernel(th, tw, store, keys, live):
+    def auron_hashtable_build(th, tw, store, keys, live):
         from auron_tpu.hashtable.agg import _hashes
         h = _hashes(keys, n)
         w = core.key_words(keys, key_meta)
@@ -45,19 +46,19 @@ def _build_kernel(key_meta: tuple, n: int, cap: int, rounds: int):
         return (th2, tw2, store2, slot, is_new, n_new,
                 jnp.any(live & ~resolved))
 
-    return kernel
+    return auron_hashtable_build
 
 
 @program_cache("hashtable.probe", maxsize=128)
 def _probe_kernel(key_meta: tuple, n: int, cap: int, rounds: int):
     @jax.jit
-    def kernel(th, tw, keys, live):
+    def auron_hashtable_probe(th, tw, keys, live):
         from auron_tpu.hashtable.agg import _hashes
         h = _hashes(keys, n)
         w = core.key_words(keys, key_meta)
         return core.probe_loop(th, tw, h, w, live, rounds)
 
-    return kernel
+    return auron_hashtable_probe
 
 
 @program_cache("hashtable.grow", maxsize=64)
@@ -66,7 +67,7 @@ def _table_grow_kernel(key_meta: tuple, old_cap: int, new_cap: int,
     W = core.total_words(key_meta)
 
     @jax.jit
-    def kernel(th, store):
+    def auron_hashtable_grow(th, store):
         occupied = th != core.EMPTY
         cols = core.store_columns(store, key_meta)
         w = core.key_words(cols, key_meta)
@@ -79,7 +80,7 @@ def _table_grow_kernel(key_meta: tuple, old_cap: int, new_cap: int,
             core.empty_store(key_meta, new_cap), cols, key_meta, claims)
         return nth, ntw, nstore, slot, jnp.any(occupied & ~resolved)
 
-    return kernel
+    return auron_hashtable_grow
 
 
 class DeviceHashTable:
@@ -120,7 +121,7 @@ class DeviceHashTable:
             kern = _table_grow_kernel(self.key_meta, self.cap, new_cap,
                                       self.rounds)
             nth, ntw, nstore, slot, ovf = kern(self.th, self.store)
-            if bool(jax.device_get(ovf)):
+            if bool(_profile.timed_get(ovf)):
                 new_cap *= 2
                 continue
             self.last_remap = (self.cap, slot, self.th != core.EMPTY)
@@ -150,7 +151,7 @@ class DeviceHashTable:
             kern = _build_kernel(self.key_meta, n, self.cap, self.rounds)
             th, tw, store, slot, is_new, n_new, ovf = kern(
                 self.th, self.tw, self.store, keys, live)
-            n_new_h, ovf_h = jax.device_get([n_new, ovf])
+            n_new_h, ovf_h = _profile.timed_get([n_new, ovf])
             if not bool(ovf_h):
                 self.th, self.tw, self.store = th, tw, store
                 self.count += int(n_new_h)
@@ -186,7 +187,7 @@ def _join_index_kernel(cap: int, table_cap: int, rounds: int):
     distinct 64-bit hash, payload = (run start, run length)."""
 
     @jax.jit
-    def kernel(h_sorted):
+    def auron_hashtable_join_index(h_sorted):
         idx = jnp.arange(cap, dtype=jnp.int32)
         first = jnp.concatenate(
             [jnp.ones(1, bool), h_sorted[1:] != h_sorted[:-1]])
@@ -212,7 +213,7 @@ def _join_index_kernel(cap: int, table_cap: int, rounds: int):
             jnp.any(first & ~resolved)
         return th, lo_arr, cnt_arr, bad
 
-    return kernel
+    return auron_hashtable_join_index
 
 
 #: build sides larger than this keep the searchsorted candidate search
@@ -257,6 +258,6 @@ def build_join_index(h_sorted: jax.Array,
     table_cap = max(16, next_pow2(cap) * 2)
     kern = _join_index_kernel(cap, table_cap, max_probe_rounds)
     th, lo, cnt, bad = kern(h_sorted)
-    if bool(jax.device_get(bad)):
+    if bool(_profile.timed_get(bad)):
         return None
     return JoinHashIndex(th, lo, cnt, max_probe_rounds)

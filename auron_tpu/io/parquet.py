@@ -28,6 +28,7 @@ from auron_tpu.columnar.arrow_bridge import schema_from_arrow, to_device
 from auron_tpu.columnar.batch import DeviceBatch
 from auron_tpu.columnar.schema import Schema
 from auron_tpu.exprs import ir
+from auron_tpu.obs import trace
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.utils.shapes import DEFAULT_BATCH_CAPACITY
 
@@ -112,6 +113,10 @@ class ScanPrefetcher:
         self._mem_lock = threading.Lock()
         if self._mem is not None:
             self._mem.register_consumer(self)
+        #: the served task that starts this scan: the worker's layer
+        #: spans (auron:scan/{decode,encode,h2d}) and transfer counts
+        #: are booked to it, beside the task thread's own time
+        self._task = trace.current_task()
         self._thread = threading.Thread(
             target=self._run, name="auron-scan-prefetch", daemon=True)
         self._thread.start()
@@ -150,6 +155,10 @@ class ScanPrefetcher:
     # -- worker -------------------------------------------------------------
 
     def _run(self) -> None:
+        with trace.worker_scope(self._task):
+            self._run_bound()
+
+    def _run_bound(self) -> None:
         try:
             for item in self._source:
                 with self._cond:
@@ -184,9 +193,10 @@ class ScanPrefetcher:
     def batches(self, io_time) -> Iterator[DeviceBatch]:
         """Drain in order. The dequeue wait is decode time the worker
         could not hide — attributed to the ``convert`` host bucket like
-        the serial path's inline decode."""
+        the serial path's inline decode, and to ``auron:scan/wait``."""
         while True:
-            with timer(io_time, bucket="convert"):
+            with timer(io_time, bucket="convert"), \
+                    trace.layer_span("scan", "wait"):
                 with self._cond:
                     while (not self._buf and not self._done
                            and self._err is None):
@@ -316,11 +326,21 @@ class ParquetScanOp(PhysicalOp):
         def host_batches():
             if not files:
                 return
-            ds = pa_ds.dataset(files, format=self._format,
-                               filesystem=self._fs)
-            scanner = ds.scanner(columns=self.columns, filter=arrow_filter,
-                                 batch_size=self.batch_rows)
-            for rb in scanner.to_batches():
+            # file -> Arrow is the reader's own work: opening the
+            # files, then each row group; every span closes before the
+            # yield hands a batch on
+            with trace.layer_span("scan", "decode"):
+                ds = pa_ds.dataset(files, format=self._format,
+                                   filesystem=self._fs)
+                scanner = ds.scanner(columns=self.columns,
+                                     filter=arrow_filter,
+                                     batch_size=self.batch_rows)
+                it = iter(scanner.to_batches())
+            while True:
+                with trace.layer_span("scan", "decode"):
+                    rb = next(it, None)
+                if rb is None:
+                    return
                 if rb.num_rows == 0:
                     continue
                 # split oversized batches (scanner batch_size is a
@@ -339,8 +359,9 @@ class ParquetScanOp(PhysicalOp):
             cap = capacity
             if rb.num_rows < cap and advised_rows(cap) < cap:
                 cap = bucket_rows(rb.num_rows)
-            return to_device(rb, capacity=cap,
-                             string_widths=self._widths_for(rb))[0]
+            with trace.layer_span("scan", "encode"):
+                widths = self._widths_for(rb)
+            return to_device(rb, capacity=cap, string_widths=widths)[0]
 
         from auron_tpu.runtime import pipeline
         if not pipeline.enabled():
@@ -350,8 +371,11 @@ class ParquetScanOp(PhysicalOp):
             def stream():
                 for rb in host_batches():
                     ctx.checkpoint("scan.decode")
+                    # the timer closes before the yield: held open it
+                    # would charge the consumer's time to the scan
                     with timer(io_time, bucket="convert"):
-                        yield convert(rb)
+                        batch = convert(rb)
+                    yield batch
 
             return count_output(stream(), metrics, timed=True)
 

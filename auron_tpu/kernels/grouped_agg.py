@@ -50,6 +50,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from auron_tpu.runtime.programs import named
+
 #: lane width of the dense grids: keys decompose as (k >> 8, k & 255) so
 #: the minor grid dimension matches the TPU's 256-wide key byte
 _LANES = 256
@@ -140,6 +142,7 @@ def _block_index(*idx):
 
 @functools.partial(jax.jit,
                    static_argnames=("key_domain", "blk", "interpret"))
+@named("auron_kernels_pallas_sum_count")
 def pallas_sum_count(k, v, c, key_domain: int, blk: int = 2048,
                      interpret: bool = False):
     """Dense grouped (sum, count) over ``key_domain`` keys.
@@ -179,6 +182,7 @@ def pallas_sum_count(k, v, c, key_domain: int, blk: int = 2048,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("key_domain", "block"))
+@named("auron_kernels_dense_matmul_sum_count")
 def dense_matmul_sum_count(k, v, c, key_domain: int, block: int = 1 << 16):
     """Same contract as ``pallas_sum_count`` via the one-hot einsum
     formulation: lax.map tiles the one-hots so the HBM working set stays

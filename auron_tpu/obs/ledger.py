@@ -22,6 +22,17 @@ finalize:
   served_from, outcome, wall seconds, and the device that computed it
   (platform, kind, count — an answer names what it ran on).
 
+Version 2 adds what the task's own accumulator measured
+(``obs/trace.TaskAccumulator``, filled by the layer spans and not by
+operator snapshots): ``queue_s``; ``layers_s``, exclusive seconds by
+layer on the task's thread, summing to ``wall_s``; ``ops_s``, the
+operators' part of it by operator; ``scan_worker_s``, the prefetch
+worker's decode / encode / transfer beside it; ``cpu_s``; ``counts`` of
+program calls, readbacks and transfers; and ``compile.task_*``, the
+compiles that fired on the task's own threads. Every version-1 key
+keeps its meaning — ``device_s`` is still the inclusive sum of
+``elapsed_compute``, a host wait — and its readers.
+
 The record rides the serving DONE frame (``cost_ledger`` key), is
 retained in a bounded process ring (``record``/``recent`` — the
 ``AuronClient.stats()`` and STATS-frame surface), lands in failure
@@ -37,13 +48,13 @@ import threading
 from collections import deque
 from typing import Iterable, Optional
 
-LEDGER_VERSION = 1
+LEDGER_VERSION = 2
 
 #: the PR 6 profiler's host-bucket vocabulary (ops/base per-op timers)
 HOST_BUCKETS = ("dispatch", "convert", "serde", "iter", "other")
 
 #: snapshot keys that are nested dicts but NOT per-op metric sets
-_NON_OP_KEYS = frozenset({"recovery", "mesh", "profile"})
+_NON_OP_KEYS = frozenset({"recovery", "mesh"})
 
 _RECOVERY_KEYS = ("attempts", "transient_retries",
                   "corruption_recomputes", "faults_injected")
@@ -72,14 +83,21 @@ def enabled(config=None) -> bool:
 def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
           rows: int = 0, batches: int = 0, partitions: int = 0,
           wall_s: float = 0.0, cache_hit: bool = False,
-          served_from: str = "", outcome: str = "ok") -> dict:
-    """Fold per-partition ``finalize()`` snapshots into one ledger.
+          served_from: str = "", outcome: str = "ok",
+          task=None) -> dict:
+    """Fold per-partition ``finalize()`` snapshots, and the task's
+    accumulator (``task``: an ``obs/trace.TaskAccumulator``), into one
+    ledger.
 
     Tolerant by contract: snapshots are observability output, so a
-    missing counter, a partial snapshot from a failed partition, or an
-    empty list all produce a valid (zeroed) ledger — assembly must
-    never fail a finished query.
+    missing counter, a partial snapshot from a failed partition, an
+    empty list or no accumulator all produce a valid (zeroed) ledger —
+    assembly must never fail a finished query.
     """
+    from auron_tpu.obs import trace as _trace
+    if task is None:
+        task = _trace.TaskAccumulator(query_id)
+    v2 = task.sealed(wall_s)
     device_ns = 0
     host_ns = dict.fromkeys(HOST_BUCKETS, 0)
     shuffle_write_ns = shuffle_read_ns = 0
@@ -121,6 +139,12 @@ def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
         "outcome": str(outcome),
         "device": dict(_device()),
         "wall_s": round(float(wall_s), 6),
+        "queue_s": v2["queue_s"],
+        "layers_s": v2["layers_s"],
+        "ops_s": v2["ops_s"],
+        "scan_worker_s": v2["scan_worker_s"],
+        "cpu_s": v2["cpu_s"],
+        "counts": v2["counts"],
         "device_s": round(device_ns * 1e-9, 6),
         "host_s": {b: round(v * 1e-9, 6) for b, v in host_ns.items()},
         "host_total_s": round(sum(host_ns.values()) * 1e-9, 6),
@@ -139,6 +163,10 @@ def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
             "seconds": round(compile_s, 4),
             "program_builds": program_builds,
             "program_hits": program_hits,
+            # the four above are process-wide deltas (concurrent tasks
+            # each see the others' compiles); these two are the events
+            # that fired on this task's own threads
+            **v2["compile"],
         },
         "rows": _i(rows),
         "batches": _i(batches),

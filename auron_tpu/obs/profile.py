@@ -42,18 +42,20 @@ histograms (``auron_dispatch_overhead_seconds`` /
 ``auron_device_call_seconds`` — the per-batch dispatch-overhead
 p50/p95/p99 of the registry scrape) and, when the ``program`` trace
 category records, a ``program.call`` span carrying the split so
-tools/trace_report.py can print host/device columns. ``export_task``
-appends one JSONL record per operator instance into ``auron.trace.dir``
-(``profile_<trace>.jsonl``) — the input ``tools/hotspot_report.py``
-ranks into its category×operator table.
+tools/trace_report.py can print host/device columns.
+
+The frames above are INCLUSIVE (a parent's timer runs across its
+child's ``next()``); the exclusive split by layer and operator is the
+layer spans' (obs/trace.layer_span), which the sync points here open as
+``auron:op/readback`` and count as the task's ``readbacks``.
 """
 
 from __future__ import annotations
 
-import json
-import os
 import threading
 from typing import Optional
+
+from auron_tpu.obs import trace as _trace
 
 #: host-bucket vocabulary (counter names are "elapsed_host_" + bucket)
 HOST_BUCKETS = ("dispatch", "convert", "serde", "iter", "other")
@@ -229,6 +231,7 @@ def on_call(dispatch_ns: int, device_ns: int, site: str) -> None:
         f.dispatch += dispatch_ns
         f.device += device_ns
         f.calls += 1
+    _trace.count_program_call(site)
     from auron_tpu.obs import registry as _registry
     if _registry.enabled():
         r = _registry.get_registry()
@@ -236,7 +239,6 @@ def on_call(dispatch_ns: int, device_ns: int, site: str) -> None:
                     buckets=CALL_BUCKETS).observe(dispatch_ns * 1e-9)
         r.histogram("auron_device_call_seconds",
                     buckets=CALL_BUCKETS).observe(device_ns * 1e-9)
-    from auron_tpu.obs import trace as _trace
     if _trace.category_enabled("program"):
         total = dispatch_ns + device_ns
         # start reconstructed from the durations: no clock reads beyond
@@ -273,7 +275,8 @@ class ProfiledProgram:
             # still sums to wall; per-call we record dispatch only.
             on_call(t1 - t0, 0, self._site)
         else:
-            _block(out)
+            with _trace.readback_span():
+                _block(out)
             on_call(t1 - t0, time.perf_counter_ns() - t1, self._site)
         return out
 
@@ -314,7 +317,8 @@ def device_fence(value, sink=None) -> int:
     the whole point of pipelining is that nothing else waits."""
     import time
     t0 = time.perf_counter_ns()
-    _block(value)
+    with _trace.readback_span():
+        _block(value)
     ns = time.perf_counter_ns() - t0
     if not enabled():
         return ns
@@ -341,65 +345,21 @@ def timed_get(values):
     import time
 
     import jax
-    st = getattr(_TLS, "stack", None)
-    if st is None or not st:
-        return jax.device_get(values)
     t0 = time.perf_counter_ns()
-    out = jax.device_get(values)
-    st[-1].device += time.perf_counter_ns() - t0
+    with _trace.readback_span():
+        out = jax.device_get(values)
+    st = getattr(_TLS, "stack", None)
+    if st:
+        st[-1].device += time.perf_counter_ns() - t0
+    _trace.count("d2h_bytes", sum(
+        getattr(leaf, "nbytes", 0)
+        for leaf in jax.tree_util.tree_leaves(out)))
     return out
 
 
 # ---------------------------------------------------------------------------
-# per-task export + aggregate views
+# aggregate views
 # ---------------------------------------------------------------------------
-
-def _lifecycle_query_id() -> str:
-    try:
-        from auron_tpu.runtime import lifecycle
-        return lifecycle.current_query_id()
-    except Exception:   # pragma: no cover - best-effort attribution
-        return ""
-
-
-def export_task(ctx, plan) -> None:
-    """Append one JSONL record per operator instance of a finished task
-    into ``auron.trace.dir`` (``profile_<trace>.jsonl``) — the
-    tools/hotspot_report.py input. Best-effort like every observability
-    sink; no-op unless profiling is on and a trace dir is configured."""
-    if not enabled():
-        return
-    from auron_tpu import config as cfg
-    trace_dir = cfg.get_config().get(cfg.TRACE_DIR)
-    if not trace_dir:
-        return
-    from auron_tpu.obs import trace as _trace
-    trace_id = _trace.tracer().current_trace
-    path = os.path.join(trace_dir, f"profile_{trace_id:08d}.jsonl")
-    try:
-        os.makedirs(trace_dir, exist_ok=True)
-        lines = []
-        for (oid, suffix), (op, ms) in list(ctx.op_metrics.items()):
-            snap = ms.snapshot()
-            if not snap:
-                continue
-            lines.append(json.dumps({
-                "task": ctx.task_id, "stage": ctx.stage_id,
-                "partition": ctx.partition_id,
-                # concurrent queries with tracing off share trace id 0
-                # (one jsonl file): the query id keeps their records
-                # attributable (cross-query safety audit)
-                "query": _lifecycle_query_id(),
-                "op": op.name + suffix, "repr": repr(op),
-                "metrics": snap}))
-        if lines:
-            with open(path, "a") as f:
-                f.write("\n".join(lines) + "\n")
-    except Exception:   # pragma: no cover - observability is best-effort
-        import logging
-        logging.getLogger(__name__).exception(
-            "profile export to %r failed", trace_dir)
-
 
 def summarize_tree(node) -> dict:
     """Host/device rollup over a metric tree (obs/metric_tree.MetricNode)

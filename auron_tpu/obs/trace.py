@@ -27,6 +27,19 @@ counters assign trace ids (one per top-level query scope) and span ids
 (global), never wall-clock or randomness, so two runs of the same
 single-threaded pipeline number their spans identically.
 
+*Layer spans* (:func:`layer_span`) are the spans at the boundaries of
+PERF.md's layers — entry, planner and compile, scan and convert,
+operators, exchange. They are the same context manager with three
+duties, two of them independent of ``auron.trace.enabled``: every entry
+opens a ``jax.profiler.TraceAnnotation("auron:<layer>/<name>")``, so any
+profiler session shows the program's layers on ``/host:CPU`` under the
+clock of the device planes; every exit charges the span's SELF time
+(its duration less what its child layer spans and the compile events
+inside it cover, per thread) to the running task's
+:class:`TaskAccumulator`, which ``obs/ledger.build`` folds into the
+version-2 ledger; and with tracing on the ``Span`` is recorded as any
+other. They live on the one per-thread stack this module already has.
+
 Config surface: ``auron.trace.{enabled,dir,events,max_spans}``
 (config.py). The knobs are deliberately NOT trace-semantic in the
 program-cache sense (config.TRACE_SEMANTIC_KEYS): flipping tracing must
@@ -64,9 +77,11 @@ from auron_tpu.obs.flight_recorder import get_role, set_role  # noqa: F401
 #: process link), ``fleet.route`` (router routing decision) and
 #: ``fleet.forward`` (router hop span around one replica
 #: conversation; failover shows as a second hop to the survivor).
+#: The ``layer`` category holds the layer spans that have no older
+#: category of their own (serve/scan/op/convert: up to ~2,000 a task).
 CATEGORIES = ("query", "task", "program", "shuffle", "spill", "fault",
               "watchdog", "memory", "sched", "mesh", "journal", "cache",
-              "fleet")
+              "fleet", "layer")
 
 _SPAN_IDS = itertools.count(1)     # next() is GIL-atomic
 _TRACE_IDS = itertools.count(1)
@@ -285,6 +300,9 @@ class _SpanCM:
     __slots__ = ("cat", "name", "attrs", "span_id", "_parent", "_t0",
                  "_max")
 
+    #: None on plain spans; a :class:`_LayerSpan` names its layer
+    layer = None
+
     def __init__(self, cat, name, attrs, max_spans):
         self.cat = cat
         self.name = name
@@ -299,27 +317,15 @@ class _SpanCM:
     def __enter__(self):
         tr = _TRACER
         stack = tr._stack()
-        self._parent = stack[-1] if stack else 0
+        self._parent = stack[-1].span_id if stack else 0
         self.span_id = next(_SPAN_IDS)
-        stack.append(self.span_id)
+        stack.append(self)
         self._t0 = tr.now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         tr = _TRACER
-        stack = tr._stack()
-        # pop by identity, not position: spans held open across
-        # generator yields (shuffle.fetch, spill.read wrap streams) can
-        # exit out of LIFO order when a consumer interleaves two
-        # streams — a positional pop would strand the dead id on the
-        # stack forever, misparenting every later span on the thread
-        if stack and stack[-1] == self.span_id:
-            stack.pop()
-        else:
-            try:
-                stack.remove(self.span_id)
-            except ValueError:
-                pass
+        self._pop(tr._stack())
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         t0 = self._t0
@@ -331,6 +337,21 @@ class _SpanCM:
                        self.cat, self.name, t0, dur,
                        threading.get_ident(), self.attrs), self._max)
         return False
+
+
+    def _pop(self, stack: list) -> None:
+        # pop by identity, not position: spans held open across
+        # generator yields (shuffle.fetch, spill.read wrap streams) can
+        # exit out of LIFO order when a consumer interleaves two
+        # streams — a positional pop would strand the dead span on the
+        # stack forever, misparenting every later span on the thread
+        if stack and stack[-1] is self:
+            stack.pop()
+        else:
+            try:
+                stack.remove(self)
+            except ValueError:
+                pass
 
 
 def span(cat: str, name: str, **attrs):
@@ -356,8 +377,9 @@ def event(cat: str, name: str, **attrs) -> None:
     tr = _TRACER
     stack = tr._stack()
     tr.record(Span(tr.current_trace, next(_SPAN_IDS),
-                   stack[-1] if stack else 0, cat, name, tr.now_ns(), 0,
-                   threading.get_ident(), attrs), st.max_spans)
+                   stack[-1].span_id if stack else 0, cat, name,
+                   tr.now_ns(), 0, threading.get_ident(), attrs),
+              st.max_spans)
 
 
 def complete_span(cat: str, name: str, start_ns: int, dur_ns: int,
@@ -378,7 +400,7 @@ def complete_span(cat: str, name: str, start_ns: int, dur_ns: int,
     tr = _TRACER
     stack = tr._stack()
     tr.record(Span(tr.current_trace, next(_SPAN_IDS),
-                   stack[-1] if stack else 0, cat, name, start_ns,
+                   stack[-1].span_id if stack else 0, cat, name, start_ns,
                    dur_ns, threading.get_ident(), attrs), st.max_spans)
 
 
@@ -417,6 +439,331 @@ def stream_spanned(cat: str, name: str, it, time_counter=None, **attrs):
         if record:
             complete_span(cat, name, start, produced_ns, items=n,
                           **attrs)
+
+
+# ---------------------------------------------------------------------------
+# layer spans and the per-task accumulator (module docstring)
+# ---------------------------------------------------------------------------
+
+#: ``layers_s`` keys a layer span charges (``compile`` comes from
+#: :func:`on_compile`; the ledger adds ``other`` = wall − the rest)
+_LAYER_KEY = {"plan": "plan", "scan": "scan_wait", "convert": "to_arrow",
+              "exchange": "exchange"}
+LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
+              "exchange", "to_arrow", "send")
+SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
+COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
+              "h2d_bytes")
+
+_ANNOTATION = None
+
+
+def _annotation(name: str, attrs: dict):
+    """A ``jax.profiler.TraceAnnotation`` (imported at first use: this
+    module loads in processes that must stay off jax until they have
+    chosen a platform). Outside a profiler session it costs under a
+    microsecond."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **attrs) if attrs else _ANNOTATION(name)
+
+
+class TaskAccumulator:
+    """One served task's exclusive nanoseconds by layer, and its counts.
+
+    Bound to the handler thread by :func:`task_scope` and to the scan
+    prefetch workers the task starts by :func:`worker_scope`. The task
+    thread writes its fields without a lock; a worker writes only the
+    ``worker_*`` fields, under ``_lock`` (several workers of one task
+    can be alive at once). :meth:`sealed` is what ``obs/ledger.build``
+    folds into the ledger."""
+
+    __slots__ = ("query_id", "queue_ns", "layers", "ops", "counts",
+                 "calls_by_site", "layer_spans", "compiles", "compile_ns",
+                 "worker_ns", "worker_counts", "worker_spans",
+                 "worker_cpu_ns", "worker_compiles", "worker_compile_ns",
+                 "_cpu0", "_lock")
+
+    def __init__(self, query_id: str = ""):
+        self.query_id = query_id
+        self.queue_ns = 0
+        self.layers = dict.fromkeys(LAYER_KEYS, 0)
+        #: op name -> [host ns, device-wait ns, op spans]
+        self.ops: dict[str, list] = {}
+        self.counts = dict.fromkeys(COUNT_KEYS, 0)
+        self.calls_by_site: dict[str, int] = {}
+        self.layer_spans = 0
+        self.compiles = 0
+        self.compile_ns = 0
+        self.worker_ns = dict.fromkeys(SCAN_WORKER_KEYS, 0)
+        self.worker_counts = dict.fromkeys(COUNT_KEYS, 0)
+        self.worker_spans = 0
+        self.worker_cpu_ns = 0
+        self.worker_compiles = 0
+        self.worker_compile_ns = 0
+        self._cpu0 = time.thread_time_ns()
+        self._lock = threading.Lock()
+
+    def start(self) -> None:
+        """The task holds its slot: its CPU clock starts here, with the
+        interval the ledger calls ``wall_s``."""
+        self._cpu0 = time.thread_time_ns()
+
+    def _charge(self, span: "_LayerSpan", self_ns: int) -> None:
+        """Book one closed layer span's self time (task thread)."""
+        self.layer_spans += 1
+        layer = span.layer
+        if layer == "op":
+            up = span._up
+            if span.key == "readback":
+                ent = self._op(up.key)
+                ent[1] += self_ns
+                self.layers["op_device_wait"] += self_ns
+            else:
+                ent = self._op(span.key)
+                ent[0] += self_ns
+                ent[2] += 1
+                self.layers["op_host"] += self_ns
+        elif layer == "serve":
+            if span.key == "queue":
+                self.queue_ns += self_ns
+            elif span.key == "send":
+                self.layers["send"] += self_ns
+            # serve/task is the root: what it alone covers is `other`
+        else:
+            key = _LAYER_KEY.get(layer)
+            if key is not None:
+                self.layers[key] += self_ns
+
+    def _op(self, name: str) -> list:
+        ent = self.ops.get(name)
+        if ent is None:
+            ent = self.ops[name] = [0, 0, 0]
+        return ent
+
+    def _charge_worker(self, span: "_LayerSpan", self_ns: int) -> None:
+        with self._lock:
+            self.worker_spans += 1
+            if span.layer == "scan" and span.key in self.worker_ns:
+                self.worker_ns[span.key] += self_ns
+
+    def sealed(self, wall_s: float) -> dict:
+        """The ledger's version-2 fields. ``layers_s.other`` is
+        ``wall_s`` less every other key, so the keys sum to ``wall_s`` by
+        construction and ``other`` is what no span covers yet."""
+        with self._lock:
+            worker_ns = dict(self.worker_ns)
+            worker_counts = dict(self.worker_counts)
+            worker_spans = self.worker_spans
+            worker_cpu = self.worker_cpu_ns
+            compiles = self.compiles + self.worker_compiles
+            compile_ns = self.compile_ns + self.worker_compile_ns
+        layers = {k: round(v * 1e-9, 6) for k, v in self.layers.items()}
+        layers["compile"] = round(self.compile_ns * 1e-9, 6)
+        layers["other"] = round(float(wall_s) - sum(layers.values()), 6)
+        counts = {k: self.counts[k] + worker_counts[k] for k in COUNT_KEYS}
+        counts["program_calls_by_site"] = dict(
+            sorted(self.calls_by_site.items()))
+        counts["layer_spans"] = self.layer_spans + worker_spans
+        return {
+            "queue_s": round(self.queue_ns * 1e-9, 6),
+            "layers_s": layers,
+            "ops_s": {name: {"host_s": round(h * 1e-9, 6),
+                             "device_wait_s": round(d * 1e-9, 6),
+                             "batches": n}
+                      for name, (h, d, n) in sorted(self.ops.items())},
+            "scan_worker_s": {k: round(v * 1e-9, 6)
+                              for k, v in worker_ns.items()},
+            "cpu_s": round((time.thread_time_ns() - self._cpu0
+                            + worker_cpu) * 1e-9, 6),
+            "counts": counts,
+            "compile": {"task_xla_compiles": compiles,
+                        "task_seconds": round(compile_ns * 1e-9, 4)},
+        }
+
+
+class _LayerSpan(_SpanCM):
+    """A span at a layer boundary: annotation always, self time to the
+    task's accumulator always, a recorded ``Span`` with tracing on."""
+
+    __slots__ = ("layer", "key", "_record", "_ann", "_acc", "_worker",
+                 "_up", "_child_ns")
+
+    def __init__(self, layer, key, cat, name, attrs, record, max_spans):
+        _SpanCM.__init__(self, cat, name, attrs, max_spans)
+        self.layer = layer
+        self.key = key
+        self._record = record
+        self._child_ns = 0
+
+    def __enter__(self):
+        tr = _TRACER
+        tls = tr._tls
+        stack = tr._stack()
+        up = None
+        for s in reversed(stack):
+            if s.layer is not None:
+                up = s
+                break
+        self._up = up
+        self._parent = stack[-1].span_id if stack else 0
+        # an unrecorded layer span passes its parent's id through, so a
+        # recorded child still links to the nearest recorded ancestor
+        self.span_id = next(_SPAN_IDS) if self._record else self._parent
+        stack.append(self)
+        self._acc = getattr(tls, "task", None)
+        self._worker = getattr(tls, "worker", False)
+        self._ann = ann = _annotation(
+            "auron:" + self.layer + "/" + self.key, self.attrs)
+        ann.__enter__()
+        self._t0 = tr.now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        tr = _TRACER
+        t0 = self._t0
+        dur = tr.now_ns() - t0
+        self._ann.__exit__(exc_type, exc, tb)
+        self._pop(tr._stack())
+        up = self._up
+        acc = self._acc
+        # a readback outside an operator (the to_arrow fence) stays in
+        # the layer that made it: ops_s sums to op_host + op_device_wait
+        own = not (self.key == "readback" and self.layer == "op"
+                   and (up is None or up.layer != "op"))
+        if acc is not None:
+            if self._worker:
+                acc._charge_worker(self, dur - self._child_ns)
+            elif own:
+                acc._charge(self, dur - self._child_ns)
+            else:
+                acc.layer_spans += 1
+        if own and up is not None:
+            up._child_ns += dur
+        if self._record:
+            if exc_type is not None:
+                self.attrs.setdefault("error", exc_type.__name__)
+            _flight.tee(self.cat, self.name, self.attrs, dur_ns=dur)
+            tr.record(Span(tr.current_trace, self.span_id, self._parent,
+                           self.cat, self.name, t0, dur,
+                           threading.get_ident(), self.attrs), self._max)
+        return False
+
+
+def layer_span(layer: str, key: str, *, cat: str = "layer",
+               name: Optional[str] = None, **attrs):
+    """Open the span of one layer boundary (module docstring). ``layer``
+    and ``key`` are fixed strings of low cardinality — the annotation
+    reads ``auron:<layer>/<key>``; ids and sizes go in ``attrs``.
+    ``cat`` / ``name`` are what the recorded ``Span`` carries with
+    tracing on (default: the ``layer`` category, ``<layer>.<key>``), so
+    a boundary that already had a span keeps its name in the exports.
+
+    Close it before a generator ``yield``: left open it would keep
+    charging its layer while the consumer runs."""
+    st = _settings()
+    record = st.enabled and (st.events is None or cat in st.events)
+    return _LayerSpan(layer, key, cat, name or layer + "." + key, attrs,
+                      record, st.max_spans)
+
+
+def current_task() -> Optional[TaskAccumulator]:
+    return getattr(_TRACER._tls, "task", None)
+
+
+class _TaskBinding:
+    """Binds a :class:`TaskAccumulator` to the current thread (and, for
+    a scan worker, adds the thread's CPU time when it leaves)."""
+
+    __slots__ = ("acc", "_worker", "_saved")
+
+    def __init__(self, acc, worker):
+        self.acc = acc
+        self._worker = worker
+
+    def __enter__(self):
+        tls = _TRACER._tls
+        self._saved = (getattr(tls, "task", None),
+                       getattr(tls, "worker", False))
+        tls.task = self.acc
+        tls.worker = self._worker
+        return self.acc
+
+    def __exit__(self, *exc):
+        tls = _TRACER._tls
+        tls.task, tls.worker = self._saved
+        acc = self.acc
+        if self._worker and acc is not None:
+            # the worker thread exists for this scan alone: its CPU
+            # clock since birth is what it spent on the task
+            with acc._lock:
+                acc.worker_cpu_ns += time.thread_time_ns()
+        return False
+
+
+def task_scope(query_id: str = "") -> _TaskBinding:
+    """A fresh accumulator bound to this (handler) thread."""
+    return _TaskBinding(TaskAccumulator(query_id), False)
+
+
+def worker_scope(acc: Optional[TaskAccumulator]) -> _TaskBinding:
+    """Bind a scan worker thread to the task that started it."""
+    return _TaskBinding(acc, True)
+
+
+def count(key: str, n: int = 1) -> None:
+    """Add to one of the task's ``counts`` (no-op outside a task)."""
+    tls = _TRACER._tls
+    acc = getattr(tls, "task", None)
+    if acc is None:
+        return
+    if getattr(tls, "worker", False):
+        with acc._lock:
+            acc.worker_counts[key] += n
+    else:
+        acc.counts[key] += n
+
+
+def readback_span() -> _LayerSpan:
+    """The span of one explicit device -> host sync point
+    (``auron:op/readback``), counted among the task's ``readbacks``."""
+    count("readbacks")
+    return layer_span("op", "readback")
+
+
+def count_program_call(site: str) -> None:
+    acc = getattr(_TRACER._tls, "task", None)
+    if acc is not None:
+        acc.counts["program_calls"] += 1
+        by = acc.calls_by_site
+        by[site] = by.get(site, 0) + 1
+
+
+def on_compile(seconds: float) -> None:
+    """One XLA compile or persistent-cache fetch finished on this
+    thread (``utils/compile_stats`` listener): it is the task's, and on
+    the task's own thread its seconds come out of whichever layer span
+    they fell in (``layers_s.compile``)."""
+    tls = _TRACER._tls
+    acc = getattr(tls, "task", None)
+    if acc is None:
+        return
+    ns = int(seconds * 1e9)
+    if getattr(tls, "worker", False):
+        with acc._lock:
+            acc.worker_compiles += 1
+            acc.worker_compile_ns += ns
+        return
+    acc.compiles += 1
+    acc.compile_ns += ns
+    stack = getattr(tls, "stack", None)
+    if stack:
+        for s in reversed(stack):
+            if s.layer is not None:
+                s._child_ns += ns
+                break
 
 
 class _QueryScope:
@@ -553,7 +900,7 @@ def wire_context() -> Optional[dict]:
     # role=router, or the stitcher resolves the parent span against
     # the wrong process group)
     role = getattr(tr._tls, "wire_role", None) or get_role()
-    return {"trace": t, "parent": stack[-1] if stack else 0,
+    return {"trace": t, "parent": stack[-1].span_id if stack else 0,
             "role": role, "pid": os.getpid()}
 
 

@@ -550,7 +550,7 @@ def _batch_reduce_kernel(n_keys: int, acc_meta: tuple, cap: int,
     advisory CPU backend)."""
     from auron_tpu.runtime import programs
 
-    def kernel(keys, accs, live):
+    def auron_ops_agg_batch_reduce(keys, accs, live):
         h = hashing.xxhash64_columns(list(keys), cap).view(jnp.uint64)
         h = jnp.where(live, h, _HASH_SENTINEL)  # dead rows to the end
         perm = jnp.argsort(h, stable=True)
@@ -562,7 +562,7 @@ def _batch_reduce_kernel(n_keys: int, acc_meta: tuple, cap: int,
 
     # graft: donation-ok -- per-batch contribution temporaries;
     # collect kinds/aliased leaves force donate=False upstream
-    return programs.jit(kernel,
+    return programs.jit(auron_ops_agg_batch_reduce,
                         donate_argnums=(0, 1, 2) if donate else ())
 
 
@@ -592,7 +592,8 @@ def _state_merge_kernel(n_keys: int, acc_meta: tuple, cap_s: int,
     open-addressing probe replaced by the sorted-merge primitive."""
 
     @jax.jit
-    def kernel(keys_s, accs_s, h_s, n_s, keys_b, accs_b, h_b, n_b):
+    def auron_ops_agg_state_merge(keys_s, accs_s, h_s, n_s,
+                                  keys_b, accs_b, h_b, n_b):
         live_s = jnp.arange(cap_s, dtype=jnp.int32) < n_s
         live_b = jnp.arange(cap_b, dtype=jnp.int32) < n_b
         # dead slots on both sides hold _HASH_SENTINEL (state invariant +
@@ -641,7 +642,7 @@ def _state_merge_kernel(n_keys: int, acc_meta: tuple, cap_s: int,
         live_m = scatter2(live_s, live_b)
         return _reduce_sorted(keys_m, accs_m, live_m, h_m, acc_meta, out_cap)
 
-    return kernel
+    return auron_ops_agg_state_merge
 
 
 # ---------------------------------------------------------------------------

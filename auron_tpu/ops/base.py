@@ -19,6 +19,7 @@ from typing import Iterator, Optional
 
 from auron_tpu.columnar.batch import DeviceBatch
 from auron_tpu.columnar.schema import Schema
+from auron_tpu.obs import trace as _trace
 
 
 class Metric:
@@ -53,9 +54,13 @@ class MetricsSet:
     the legacy name-keyed aggregate (``ctx.metrics[op.name]``) without
     double bookkeeping at call sites."""
 
-    def __init__(self, mirror: "Optional[MetricsSet]" = None):
+    def __init__(self, mirror: "Optional[MetricsSet]" = None,
+                 name: str = "op"):
         self._metrics: dict[str, Metric] = {}
         self._mirror = mirror
+        #: the operator's display name: the key of its layer spans
+        #: (``auron:op/<name>``) and of the ledger's ``ops_s``
+        self.name = name
 
     def counter(self, name: str) -> Metric:
         m = self._metrics.get(name)
@@ -81,8 +86,9 @@ def _device_sync(value) -> None:
     import jax
     leaves = [l for l in jax.tree_util.tree_leaves(value)
               if hasattr(l, "block_until_ready")]
-    # graft: disable=GL001 -- this IS the serial-mode sanctioned sync helper (timer.track attributes it)
-    jax.block_until_ready(leaves[-2:])
+    with _trace.readback_span():
+        # graft: disable=GL001 -- this IS the serial-mode sanctioned sync helper (timer.track attributes it)
+        jax.block_until_ready(leaves[-2:])
 
 
 class timer:
@@ -101,10 +107,15 @@ class timer:
     ``bucket`` classifies kernel-free host sections (scan decode waits
     → "convert", shuffle serde → "serde"). The flush lands
     ``elapsed_device`` / ``elapsed_host_*`` counters next to this
-    metric in the same set — EXPLAIN ANALYZE's host/device columns."""
+    metric in the same set — EXPLAIN ANALYZE's host/device columns.
+
+    Whatever the profiler says, a timer on an operator's metric is also
+    the operator's layer span (``auron:op/<name>``, obs/trace.py): its
+    self time — less its children's spans and its own readbacks — is
+    the operator's exclusive host time in the task's ledger."""
 
     __slots__ = ("metric", "t0", "_tracked", "sync", "_frame",
-                 "_bucket", "_t_track")
+                 "_bucket", "_t_track", "_span")
 
     def __init__(self, metric: Metric, sync: bool = True,
                  bucket: "Optional[str]" = None):
@@ -123,9 +134,14 @@ class timer:
         return value
 
     def __enter__(self):
-        if self.metric._owner is not None:
+        owner = self.metric._owner
+        if owner is not None:
             from auron_tpu.obs import profile as _profile
+            self._span = _trace.layer_span("op", owner.name)
+            self._span.__enter__()
             self._frame = _profile.push_frame()
+        else:
+            self._span = None
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -135,6 +151,9 @@ class timer:
             self._tracked = None
         wall = time.perf_counter_ns() - self.t0
         self.metric.add(wall)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            self._span = None
         if self._frame is not None:
             from auron_tpu.obs import profile as _profile
             _profile.pop_frame(
@@ -323,7 +342,7 @@ class ExecContext:
         if isinstance(op, str):
             name = op + suffix
             if name not in self.metrics:
-                self.metrics[name] = MetricsSet()
+                self.metrics[name] = MetricsSet(name=name)
             return self.metrics[name]
         key = (id(op), suffix)
         entry = self.op_metrics.get(key)
@@ -332,7 +351,8 @@ class ExecContext:
             # while the object lives, and a gc'd subquery plan's id can
             # be recycled by a later op in the same task
             entry = (op, MetricsSet(
-                mirror=self.metrics_for(op.name + suffix)))
+                mirror=self.metrics_for(op.name + suffix),
+                name=op.name + suffix))
             self.op_metrics[key] = entry
         return entry[1]
 
@@ -452,25 +472,26 @@ def count_output(stream, metrics: MetricsSet, timed: bool = False):
     host-side elapsed for operators that run no device kernels of their
     own (scans, limits, exchange reads) so EXPLAIN ANALYZE shows a
     nonzero elapsed on every plan node. Operators that time their
-    kernels explicitly must NOT pass it (they would double-count)."""
+    kernels explicitly must NOT pass it (they would double-count).
+
+    Timed or not, each ``next()`` runs inside the operator's layer span
+    (``auron:op/<name>``): the generator's own glue between its timers
+    and its children's spans is the operator's exclusive host time. The
+    span closes before the ``yield``."""
     rows = metrics.counter("output_rows")
     batches = metrics.counter("output_batches")
-    if not timed:
-        for b in stream:
-            rows.add(int(b.num_rows))
-            batches.add(1)
-            yield b
-        return
-    elapsed = metrics.counter("elapsed_compute")
+    elapsed = metrics.counter("elapsed_compute") if timed else None
+    name = metrics.name
     it = iter(stream)
     while True:
-        t0 = time.perf_counter_ns()
-        try:
-            b = next(it)
-        except StopIteration:
-            elapsed.add(time.perf_counter_ns() - t0)
+        with _trace.layer_span("op", name):
+            t0 = time.perf_counter_ns()
+            b = next(it, None)
+            if elapsed is not None:
+                elapsed.add(time.perf_counter_ns() - t0)
+            if b is not None:
+                rows.add(int(b.num_rows))
+                batches.add(1)
+        if b is None:
             return
-        elapsed.add(time.perf_counter_ns() - t0)
-        rows.add(int(b.num_rows))
-        batches.add(1)
         yield b

@@ -127,14 +127,16 @@ def build_stage_kernel(fragments: list[KernelFragment],
     program runs (programs.jit keeps donation off the advisory CPU
     backend)."""
 
-    def kernel(batch: DeviceBatch, partition_id, carries):
+    def auron_ops_fused_stage(batch: DeviceBatch, partition_id,
+                              carries):
         outs, new_carries = thread_fragments(fragments, batch,
                                              partition_id, carries)
         return outs, jnp.stack(new_carries)
 
     # graft: donation-ok -- donate gated on yields_owned_batches by
     # the caller; fused stages never retry on the same inputs
-    return programs.jit(kernel, donate_argnums=(0,) if donate else ())
+    return programs.jit(auron_ops_fused_stage,
+                        donate_argnums=(0,) if donate else ())
 
 
 def stage_program(frag_keys: tuple, in_schema: Schema, capacity: int,

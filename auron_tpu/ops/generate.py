@@ -36,7 +36,7 @@ def _explode_kernel(generator: ir.Expr, pass_through: tuple, with_pos: bool,
     """One launch: rows × list elements → flattened live rows."""
 
     @jax.jit
-    def kernel(batch: DeviceBatch):
+    def auron_ops_generate_explode(batch: DeviceBatch):
         ectx = EvalContext()
         from auron_tpu.columnar.batch import StringColumn, StringListColumn
         v = evaluate(generator, batch, in_schema, ectx)
@@ -83,7 +83,7 @@ def _explode_kernel(generator: ir.Expr, pass_through: tuple, with_pos: bool,
         flat = DeviceBatch(tuple(cols), jnp.asarray(flat_n, jnp.int32))
         return compact(flat, keep)
 
-    return kernel
+    return auron_ops_generate_explode
 
 
 class GenerateOp(PhysicalOp):

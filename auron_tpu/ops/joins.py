@@ -94,11 +94,11 @@ def _probe_count_body(probe: DeviceBatch, index_kind: str,
 def _probe_count_kernel(key_exprs: tuple, in_schema: Schema, capacity: int,
                         build_cap: int, index_kind: str, rounds: int):
     @jax.jit
-    def kernel(probe: DeviceBatch, *index_args):
+    def auron_ops_joins_probe_count(probe: DeviceBatch, *index_args):
         return _probe_count_body(probe, index_kind, index_args, rounds,
                                  key_exprs, in_schema)
 
-    return kernel
+    return auron_ops_joins_probe_count
 
 
 #: probe-prologue programs: the probe-side fused-stage chain + key hashing
@@ -134,8 +134,9 @@ def _gather_consumer_program(frag_keys: tuple, key_exprs: tuple,
         from auron_tpu.ops.fused import thread_fragments
         from auron_tpu.runtime import programs as _programs
 
-        def kernel(probe: DeviceBatch, build_batch: DeviceBatch,
-                   build_keys: tuple, lo, counts, partition_id, carries):
+        def auron_ops_joins_gather_consumer(
+                probe: DeviceBatch, build_batch: DeviceBatch,
+                build_keys: tuple, lo, counts, partition_id, carries):
             ctx = EvalContext()
             probe_key_cols = tuple(
                 evaluate(e, probe, probe_schema, ctx).col for e in key_exprs)
@@ -167,7 +168,7 @@ def _gather_consumer_program(frag_keys: tuple, key_exprs: tuple,
         # donation stays off: the probe batch may still feed a
         # left/full unmatched pass upstream in future variants; the
         # gather allocates fresh output arrays regardless
-        return _programs.jit(kernel)
+        return _programs.jit(auron_ops_joins_gather_consumer)
 
     return _GATHER_PROGRAMS.get_or_build(
         (frag_keys, key_exprs, probe_schema, build_schema, out_cap,
@@ -192,8 +193,8 @@ def _fused_probe_program(frag_keys: tuple, key_exprs: tuple,
         from auron_tpu.ops.fused import thread_fragments
         from auron_tpu.runtime import programs as _programs
 
-        def kernel(batch: DeviceBatch, partition_id, carries,
-                   *index_args):
+        def auron_ops_joins_fused_probe(batch: DeviceBatch, partition_id,
+                                        carries, *index_args):
             outs, new_carries = thread_fragments(fragments, batch,
                                                  partition_id, carries)
             (b,) = outs   # fan-out chains never take this path
@@ -203,7 +204,7 @@ def _fused_probe_program(frag_keys: tuple, key_exprs: tuple,
 
         # graft: donation-ok -- probe chain owns the raw batch
         # (fragment_computes gate); probe programs never re-run
-        return _programs.jit(kernel,
+        return _programs.jit(auron_ops_joins_fused_probe,
                              donate_argnums=(0,) if donate else ())
 
     return _PROBE_PROGRAMS.get_or_build(
@@ -216,7 +217,7 @@ def _expand_kernel(out_cap: int, capacity: int):
     """Expand candidate ranges to (probe_idx, build_idx) pairs."""
 
     @jax.jit
-    def kernel(lo, counts):
+    def auron_ops_joins_expand(lo, counts):
         starts = jnp.cumsum(counts) - counts  # exclusive prefix
         total = jnp.sum(counts)
         slots = jnp.arange(out_cap, dtype=jnp.int32)
@@ -228,7 +229,7 @@ def _expand_kernel(out_cap: int, capacity: int):
         in_range = slots < total
         return probe_idx, jnp.where(in_range, build_idx, 0), in_range
 
-    return kernel
+    return auron_ops_joins_expand
 
 
 class _BuildSide:

@@ -35,19 +35,21 @@ def _project_kernel(exprs: tuple, in_schema: Schema, capacity: int):
     """One compiled kernel per (expression tuple, schema, capacity)."""
 
     @jax.jit
-    def kernel(batch: DeviceBatch, partition_id, row_num_offset):
+    def auron_ops_project_project(batch: DeviceBatch, partition_id,
+                                  row_num_offset):
         ctx = EvalContext(partition_id=partition_id,
                           row_num_offset=row_num_offset, memo={})
         cols = tuple(evaluate(e, batch, in_schema, ctx).col for e in exprs)
         return DeviceBatch(cols, batch.num_rows)
 
-    return kernel
+    return auron_ops_project_project
 
 
 @program_cache("ops.project.filter", maxsize=512)
 def _filter_kernel(predicates: tuple, in_schema: Schema, capacity: int):
     @jax.jit
-    def kernel(batch: DeviceBatch, partition_id, row_num_offset):
+    def auron_ops_project_filter(batch: DeviceBatch, partition_id,
+                                 row_num_offset):
         ctx = EvalContext(partition_id=partition_id,
                           row_num_offset=row_num_offset, memo={})
         keep = batch.row_mask()
@@ -56,14 +58,15 @@ def _filter_kernel(predicates: tuple, in_schema: Schema, capacity: int):
             keep = keep & v.data.astype(bool) & v.validity
         return compact(batch, keep)
 
-    return kernel
+    return auron_ops_project_filter
 
 
 @program_cache("ops.project.filter_project", maxsize=512)
 def _filter_project_kernel(predicates: tuple, exprs: tuple, in_schema: Schema,
                            capacity: int):
     @jax.jit
-    def kernel(batch: DeviceBatch, partition_id, row_num_offset):
+    def auron_ops_project_filter_project(batch: DeviceBatch, partition_id,
+                                         row_num_offset):
         ctx = EvalContext(partition_id=partition_id,
                           row_num_offset=row_num_offset, memo={})
         keep = batch.row_mask()
@@ -74,7 +77,7 @@ def _filter_project_kernel(predicates: tuple, exprs: tuple, in_schema: Schema,
         cols = tuple(evaluate(e, filtered, in_schema, ctx).col for e in exprs)
         return DeviceBatch(cols, filtered.num_rows)
 
-    return kernel
+    return auron_ops_project_filter_project
 
 
 class ProjectOp(PhysicalOp):

@@ -67,7 +67,7 @@ def _key_words_kernel(key_exprs: tuple, in_schema: Schema, capacity: int):
     "never matches" mask (null key or dead row)."""
 
     @jax.jit
-    def kernel(batch: DeviceBatch):
+    def auron_ops_smj_key_words(batch: DeviceBatch):
         ctx = EvalContext()
         cols = [evaluate(e, batch, in_schema, ctx).col for e in key_exprs]
         dead = ~batch.row_mask()
@@ -78,7 +78,7 @@ def _key_words_kernel(key_exprs: tuple, in_schema: Schema, capacity: int):
             dead = dead | ~c.validity
         return tuple(per_key), dead
 
-    return kernel
+    return auron_ops_smj_key_words
 
 
 def _pad_and_join(per_key, widths: tuple[int, ...]) -> jax.Array:
@@ -126,7 +126,7 @@ def _probe_kernel(n_words: int, win_cap: int, cap: int, left_outer: bool):
     steps = max(win_cap, 1).bit_length() + 1
 
     @jax.jit
-    def kernel(win_words, win_n, q_words, q_dead, live_n):
+    def auron_ops_smj_probe(win_words, win_n, q_words, q_dead, live_n):
         def lex(mid):
             lt = jnp.zeros(cap, bool)
             eq = jnp.ones(cap, bool)
@@ -164,7 +164,7 @@ def _probe_kernel(n_words: int, win_cap: int, cap: int, left_outer: bool):
             emit = counts
         return lo, counts, emit, jnp.sum(emit)
 
-    return kernel
+    return auron_ops_smj_probe
 
 
 @program_cache("ops.smj.expand", maxsize=256)
@@ -174,7 +174,7 @@ def _expand_kernel(out_cap: int, cap: int):
     left row, then ascending window row: the order-preservation invariant."""
 
     @jax.jit
-    def kernel(lo, counts, emit):
+    def auron_ops_smj_expand(lo, counts, emit):
         starts = jnp.cumsum(emit) - emit
         total = jnp.sum(emit)
         slots = jnp.arange(out_cap, dtype=jnp.int32)
@@ -187,7 +187,7 @@ def _expand_kernel(out_cap: int, cap: int):
         win_idx = jnp.where(real, lo[left_idx] + offset, 0)
         return left_idx, win_idx, real, total
 
-    return kernel
+    return auron_ops_smj_expand
 
 
 def _gather_pairs(left: DeviceBatch, win: Optional[DeviceBatch], left_idx,
@@ -377,7 +377,7 @@ def _mark_kernel(win_cap: int):
     pair expansion."""
 
     @jax.jit
-    def kernel(lo, counts):
+    def auron_ops_smj_mark(lo, counts):
         has = counts > 0
         starts = jnp.where(has, lo, win_cap)
         ends = jnp.where(has, lo + counts, win_cap)
@@ -386,7 +386,7 @@ def _mark_kernel(win_cap: int):
         delta = delta.at[ends].add(-1, mode="drop")
         return jnp.cumsum(delta[:win_cap]) > 0
 
-    return kernel
+    return auron_ops_smj_mark
 
 
 # ---------------------------------------------------------------------------

@@ -164,7 +164,7 @@ def sort_permutation(batch: DeviceBatch, key_cols, orders) -> jax.Array:
 @program_cache("ops.sort.sort", maxsize=256)
 def _sort_kernel(sort_exprs: tuple, in_schema: Schema, capacity: int,
                  donate: bool):
-    def kernel(batch: DeviceBatch):
+    def auron_ops_sort_sort(batch: DeviceBatch):
         ctx = EvalContext()
         key_cols = [evaluate(s.expr, batch, in_schema, ctx).col
                     for s in sort_exprs]
@@ -175,7 +175,8 @@ def _sort_kernel(sort_exprs: tuple, in_schema: Schema, capacity: int,
     # the un-sorted input is dead after the gather — donating it halves
     # peak HBM for the sort step (callers gate on ownership + platform)
     # graft: donation-ok -- _sort_donate gate (owned batches only)
-    return programs.jit(kernel, donate_argnums=(0,) if donate else ())
+    return programs.jit(auron_ops_sort_sort,
+                        donate_argnums=(0,) if donate else ())
 
 
 def key_word_layout(sort_exprs: tuple, in_schema: Schema,
@@ -208,7 +209,7 @@ def _sort_with_words_kernel(sort_exprs: tuple, in_schema: Schema,
     into the spill so the host k-way merge (memmgr.merge) compares exactly
     what the device sorted."""
 
-    def kernel(batch: DeviceBatch):
+    def auron_ops_sort_sort_with_words(batch: DeviceBatch):
         ctx = EvalContext()
         key_cols = [evaluate(s.expr, batch, in_schema, ctx).col
                     for s in sort_exprs]
@@ -219,7 +220,8 @@ def _sort_with_words_kernel(sort_exprs: tuple, in_schema: Schema,
 
     # graft: donation-ok -- _sort_donate gate (owned batches only);
     # the k-way merge consumes each gathered run exactly once
-    return programs.jit(kernel, donate_argnums=(0,) if donate else ())
+    return programs.jit(auron_ops_sort_sort_with_words,
+                        donate_argnums=(0,) if donate else ())
 
 
 def _concat_all(batches: list[DeviceBatch]) -> DeviceBatch:

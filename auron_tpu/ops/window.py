@@ -190,7 +190,7 @@ def _window_kernel(partition_exprs: tuple, order_by: tuple, fn_specs: tuple,
     n_funcs = len(fn_specs)
 
     @jax.jit
-    def kernel(batch: DeviceBatch):
+    def auron_ops_window_window(batch: DeviceBatch):
         ectx = EvalContext(memo={})
         pcols = [evaluate(e, batch, in_schema, ectx).col
                  for e in partition_exprs]
@@ -544,7 +544,7 @@ def _window_kernel(partition_exprs: tuple, order_by: tuple, fn_specs: tuple,
             result = compact(result, keep)
         return result
 
-    return kernel
+    return auron_ops_window_window
 
 
 # ---------------------------------------------------------------------------

@@ -80,12 +80,13 @@ def _sort_by_pid_kernel(num_partitions: int, capacity: int, donate: bool):
     halves peak HBM for the split); callers pass it only for owned
     input streams on non-CPU backends (see yields_owned_batches)."""
 
-    def kernel(batch: DeviceBatch, pids):
+    def auron_parallel_exchange_sort_by_pid(batch: DeviceBatch, pids):
         return _split_body(batch, pids, num_partitions)
 
     # graft: donation-ok -- callers gate on owned input streams;
     # a task retry re-splits from source, never the donated array
-    return programs.jit(kernel, donate_argnums=(0,) if donate else ())
+    return programs.jit(auron_parallel_exchange_sort_by_pid,
+                        donate_argnums=(0,) if donate else ())
 
 
 #: fused split programs: the upstream fused-stage chain (when present),
@@ -120,7 +121,8 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
         n_frags = len(fragments)
         kind = part_sig[0]
 
-        def kernel(batch: DeviceBatch, partition_id, carries):
+        def auron_parallel_exchange_fused_split(batch: DeviceBatch,
+                                                partition_id, carries):
             outs, new_carries = thread_fragments(fragments, batch,
                                                  partition_id, carries)
             (b,) = outs   # fan-out chains never take this path
@@ -150,7 +152,7 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
 
         # graft: donation-ok -- host split path (the mesh exchange
         # keeps donation OFF by contract for its escalation re-run)
-        return programs.jit(kernel,
+        return programs.jit(auron_parallel_exchange_fused_split,
                             donate_argnums=(0,) if donate else ())
 
     return _SPLIT_PROGRAMS.get_or_build(
@@ -566,9 +568,10 @@ class ShuffleExchangeOp(PhysicalOp):
     def _materialize(self, ctx: ExecContext) -> _ExchangeBuffer:
         """Run all map tasks; ONE sort-by-pid compaction per batch."""
         from auron_tpu.obs import trace
-        with trace.span("shuffle", "shuffle.materialize",
-                        maps=self.input_partitions,
-                        partitions=self.num_partitions):
+        with trace.layer_span("exchange", "materialize", cat="shuffle",
+                              name="shuffle.materialize",
+                              maps=self.input_partitions,
+                              partitions=self.num_partitions):
             return self._materialize_inner(ctx)
 
     def _materialize_inner(self, ctx: ExecContext):
@@ -1462,8 +1465,9 @@ class RssShuffleExchangeOp(PhysicalOp):
         row_offset = 0
         donate = yields_owned_batches(self.child) \
             and jax.default_backend() != "cpu"
-        with trace.span("shuffle", "rss.map_write",
-                        shuffle=self.shuffle_id, map=in_p), \
+        with trace.layer_span("exchange", "map_write", cat="shuffle",
+                              name="rss.map_write",
+                              shuffle=self.shuffle_id, map=in_p), \
                 self.service.partition_writer(self.shuffle_id, in_p,
                                               n_out) as writer:
             for batch in itertools.chain(pending, batches):
@@ -1794,8 +1798,10 @@ class BroadcastExchangeOp(PhysicalOp):
                     self._subplan_key)
             if self._buffer is None and self._cached_entries is None:
                 from auron_tpu.obs import trace
-                with trace.span("shuffle", "broadcast.collect",
-                                maps=self.input_partitions):
+                with trace.layer_span("exchange", "broadcast_collect",
+                                      cat="shuffle",
+                                      name="broadcast.collect",
+                                      maps=self.input_partitions):
                     buf = _BroadcastBuffer(self, ctx.mem_manager, metrics,
                                            conf=ctx.config)
                     try:

@@ -162,7 +162,7 @@ def stage_exchange_program(mesh: Mesh, axis: str, n_dev: int,
         chain = sharded_fragment_chain(fragments) if fragments else None
         n_frags = len(fragments)
 
-        def local_fn(columns, num_rows, carries):
+        def auron_parallel_mesh_exchange_stage(columns, num_rows, carries):
             nr = num_rows[0]
             batch = DeviceBatch(columns, nr)
             # this device IS its map partition (maps assigned in order)
@@ -233,8 +233,8 @@ def stage_exchange_program(mesh: Mesh, axis: str, n_dev: int,
             out_specs = out_specs + (P(axis),)
         # donation deliberately OFF (see docstring): programs.jit with
         # no donate_argnums, on every backend
-        return _programs.jit(shard_map(local_fn, mesh=mesh,
-                                       in_specs=in_specs,
+        return _programs.jit(shard_map(auron_parallel_mesh_exchange_stage,
+                                       mesh=mesh, in_specs=in_specs,
                                        out_specs=out_specs))
 
     return _STAGE_EXCHANGE_PROGRAMS.get_or_build(key, build)
@@ -262,7 +262,7 @@ def _exchange_fn(mesh: Mesh, n_cols: int, quota: int, axis: str):
     """
     n_dev = mesh.shape[axis]
 
-    def local_fn(cols, pids, num_rows):
+    def auron_parallel_mesh_exchange_exchange(cols, pids, num_rows):
         cap = pids.shape[0]
         nr = num_rows[0]
         live = jnp.arange(cap, dtype=jnp.int32) < nr
@@ -317,7 +317,8 @@ def _exchange_fn(mesh: Mesh, n_cols: int, quota: int, axis: str):
     in_specs = (tuple(P(axis) for _ in range(n_cols)), P(axis), P(axis))
     out_specs = (tuple(P(axis) for _ in range(n_cols)), P(axis), P())
 
-    return jax.jit(shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    return jax.jit(shard_map(auron_parallel_mesh_exchange_exchange,
+                             mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs))
 
 

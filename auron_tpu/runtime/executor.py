@@ -96,34 +96,18 @@ class ExecutionRuntime:
         from auron_tpu.runtime import faults as _faults
         self._faults_start = _faults.totals()
 
-    def batches(self) -> Iterator[DeviceBatch]:
-        """Device-batch stream (stays on device; used for stage chaining).
-
-        Under ``auron.profile`` the whole task executes inside a
-        jax.profiler trace (xprof/tensorboard-viewable) — the reference
-        exposes the same capability as pprof flamegraph HTTP endpoints
-        (auron/src/http/mod.rs:25-108); here the profiler is the XLA
-        one, which attributes time to compiled kernels directly."""
-        from auron_tpu import config as cfg
-        conf = self.ctx.conf
-        if conf.get(cfg.PROFILE):
-            import tempfile
-            import jax
-            trace_dir = conf.get(cfg.PROFILE_DIR) or tempfile.mkdtemp(
-                prefix=f"auron_profile_t{self.task.task_id}_")
-            self.profile_dir = trace_dir
-            with jax.profiler.trace(trace_dir):
-                yield from self._batches_inner()
-            return
-        yield from self._batches_inner()
-
     def cancel(self) -> None:
         """Tear the running task down: operators polling the context's
         cancellation registry unwind within one batch (reference:
         cancel_all_tasks, rt.rs:296)."""
         self.ctx.cancel()
 
-    def _batches_inner(self) -> Iterator[DeviceBatch]:
+    def batches(self) -> Iterator[DeviceBatch]:
+        """Device-batch stream (stays on device; used for stage
+        chaining). A device profile of it is any profiler session
+        around the process (``jax.profiler.start_server``, the
+        benchmark's slice): the layer spans of obs/trace.py annotate
+        it, and one task cannot own the process's one session."""
         from auron_tpu import errors
         from auron_tpu.obs import profile as _profile
         from auron_tpu.obs import trace
@@ -221,6 +205,7 @@ class ExecutionRuntime:
         if transient."""
         from auron_tpu import errors
         from auron_tpu.obs import profile as _profile
+        from auron_tpu.obs import trace
         schema = self.plan.schema()
         profiling = _profile.enabled()
         # the device→host materialization is pure arrow↔jax conversion:
@@ -236,29 +221,34 @@ class ExecutionRuntime:
         fence_sink = (self.ctx.metrics_for(self.plan)
                       if (pipelined and profiling) else None)
         for batch in source:
-            if fence_sink is not None:
-                # materialization boundary: wait out batch N's in-flight
-                # kernels HERE (N+1 is already dispatched) and book the
-                # wait as device time — BEFORE the num_rows readback
-                # below silently absorbs it
-                _profile.device_fence(batch, fence_sink)
-            if int(batch.num_rows) > 0:
-                t0 = (time.perf_counter_ns() if convert_c is not None
-                      else 0)
-                try:
-                    rb = to_arrow(batch, schema)
-                except NotImplementedError:
-                    raise
-                except RuntimeError as e:
-                    if isinstance(e, errors.AuronError):
+            rb = None
+            # the span closes before the yield: the consumer's time is
+            # not this layer's
+            with trace.layer_span("convert", "to_arrow"):
+                if fence_sink is not None:
+                    # materialization boundary: wait out batch N's
+                    # in-flight kernels HERE (N+1 is already dispatched)
+                    # and book the wait as device time — BEFORE the
+                    # num_rows readback below silently absorbs it
+                    _profile.device_fence(batch, fence_sink)
+                if int(batch.num_rows) > 0:
+                    t0 = (time.perf_counter_ns() if convert_c is not None
+                          else 0)
+                    try:
+                        rb = to_arrow(batch, schema)
+                    except NotImplementedError:
                         raise
-                    logger.exception(
-                        "host materialization failed: stage=%d "
-                        "partition=%d task=%d", self.task.stage_id,
-                        self.task.partition_id, self.task.task_id)
-                    raise errors.classify_runtime(e) from e
-                if convert_c is not None:
-                    convert_c.add(time.perf_counter_ns() - t0)
+                    except RuntimeError as e:
+                        if isinstance(e, errors.AuronError):
+                            raise
+                        logger.exception(
+                            "host materialization failed: stage=%d "
+                            "partition=%d task=%d", self.task.stage_id,
+                            self.task.partition_id, self.task.task_id)
+                        raise errors.classify_runtime(e) from e
+                    if convert_c is not None:
+                        convert_c.add(time.perf_counter_ns() - t0)
+            if rb is not None:
                 yield rb
 
     def collect(self) -> pa.Table:
@@ -271,9 +261,8 @@ class ExecutionRuntime:
         return pa.Table.from_batches(batches)
 
     def finalize(self) -> dict:
-        """Metric mirror-back (reference: update_metric_node, rt.rs:302-308).
-        With profiling on, attaches the trace directory and the per-op
-        device-time attribution (the flamegraph's data, queryable)."""
+        """Metric mirror-back (reference: update_metric_node,
+        rt.rs:302-308)."""
         snap = self.ctx.metrics_snapshot()
         if self._compile_start is not None:
             from auron_tpu.utils import compile_stats
@@ -306,18 +295,6 @@ class ExecutionRuntime:
                 snap["mesh"] = plane.stats()
         except Exception:   # pragma: no cover - observability only  # graft: disable=GL004 -- observability export is best-effort by contract
             pass
-        if getattr(self, "profile_dir", None):
-            op_times = {
-                op: vals["elapsed_compute"] * 1e-9   # counters are ns
-                for op, vals in snap.items()
-                if isinstance(vals, dict) and "elapsed_compute" in vals
-            }
-            snap["profile"] = {
-                "trace_dir": self.profile_dir,
-                "op_device_time_s": op_times,
-                "device_time_total_s": round(sum(op_times.values()), 6),
-                "wall_time_s": round(time.time() - self._started, 6),
-            }
         return snap
 
 
@@ -341,13 +318,9 @@ def _observe_task(rt: "ExecutionRuntime", table: pa.Table,
     task."""
     try:
         from auron_tpu.obs import metric_tree as mt
-        from auron_tpu.obs import profile as obs_profile
         from auron_tpu.obs import registry as obs_registry
         if metric_tree is not None:
             mt.mirror(metric_tree, rt.plan, rt.ctx)
-        # per-op host/device attribution record into auron.trace.dir
-        # (profile_<trace>.jsonl — the tools/hotspot_report.py input)
-        obs_profile.export_task(rt.ctx, rt.plan)
         if obs_registry.enabled():
             # finalize(), not the raw ctx snapshot: only finalize
             # injects the recovery counters (transient_retries from the

@@ -130,7 +130,8 @@ class ProgramCache:
         from auron_tpu import errors as _errors
         from auron_tpu.runtime import faults as _faults
         _faults.maybe_fail("program.build", _errors.DeviceExecutionError)
-        with _trace.span("program", "program.build", site=self.site):
+        with _trace.layer_span("plan", "build", cat="program",
+                               name="program.build", site=self.site):
             value = builder()   # build outside the lock: builders recurse
         with self._lock:
             if key in self._memo:   # raced with another thread: keep first
@@ -254,6 +255,33 @@ def clear_all() -> None:
 
 
 # ---------------------------------------------------------------------------
+# names a trace can read
+# ---------------------------------------------------------------------------
+
+def site_function_name(site_name: str) -> str:
+    """The ``__name__`` a site's jitted function carries:
+    ``ops.joins.probe_count`` -> ``auron_ops_joins_probe_count``. jax
+    names the XLA module ``jit_<__name__>`` and the host dispatch event
+    ``PjitFunction(<__name__>)``, and those two names are all a device
+    trace keeps of a program (event stats read back empty), so every
+    builder defines its traced function under this name."""
+    return "auron_" + site_name.replace(".", "_")
+
+
+def named(name: str):
+    """Give a function the ``__name__`` jax will read — for module-level
+    jits whose public name stays (kernels/grouped_agg.py). Apply UNDER
+    the jit decorator: the dispatch event's name is fixed when
+    ``jax.jit`` wraps the function."""
+
+    def deco(fn: Callable) -> Callable:
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return deco
+
+
+# ---------------------------------------------------------------------------
 # donation-aware jit
 # ---------------------------------------------------------------------------
 
@@ -294,6 +322,7 @@ def jit(fun=None, *, donate_argnums=(), **kwargs):
         # every caller annotates its own site
         donating = jax.jit(f, donate_argnums=donate_argnums, **kwargs)
 
+        @functools.wraps(f)
         def call(*args, **kw):
             if _aliased(args, donate_argnums):
                 return plain(*args, **kw)

@@ -410,16 +410,18 @@ class _TaskHandler(socketserver.BaseRequestHandler):
                 self._cancel.raise_for_status()
             raise _Cancelled()
 
-        while not self._window.acquire(timeout=0.1):
+        from auron_tpu.obs import trace as _trace
+        with _trace.layer_span("serve", "send"):
+            while not self._window.acquire(timeout=0.1):
+                if self._cancel.is_set():
+                    stop()
             if self._cancel.is_set():
                 stop()
-        if self._cancel.is_set():
-            stop()
-        try:
-            write_frame(self.request, KIND_BATCH, _ipc_bytes(rb))
-            self.server.stats["batches_sent"] += 1
-        except OSError:
-            raise _Cancelled()
+            try:
+                write_frame(self.request, KIND_BATCH, _ipc_bytes(rb))
+                self.server.stats["batches_sent"] += 1
+            except OSError:
+                raise _Cancelled()
 
     @staticmethod
     def _parse_query_id(payload: bytes) -> str:
@@ -684,17 +686,24 @@ class _TaskHandler(socketserver.BaseRequestHandler):
 
     def _execute_inner(self, task_bytes: bytes, planner_ctx, report,
                        journal=None, partitions=None) -> None:
-        # imported lazily so the server process controls jax platform
-        # selection before anything initializes a backend
-        from auron_tpu.columnar.arrow_bridge import (schema_to_arrow,
-                                                     to_arrow)
-        from auron_tpu.ir import pb
-        from auron_tpu.ir.planner import plan_from_bytes
+        """The task under its accumulator (obs/trace.py): the wait for
+        a slot is ``auron:serve/queue``, outside the ledger's ``wall_s``;
+        from the slot on it is ``auron:serve/task``, the root every
+        other layer span of the task nests in."""
+        from auron_tpu.obs import trace as _trace
+        with _trace.task_scope(self._cancel.query_id) as acc:
+            with _trace.layer_span("serve", "queue"):
+                slot = self._admit()
+            acc.start()
+            with _trace.layer_span("serve", "task",
+                                   query_id=self._cancel.query_id):
+                self._execute_admitted(slot, acc, task_bytes, planner_ctx,
+                                       report, journal, partitions)
+
+    def _admit(self):
         from auron_tpu import errors
         from auron_tpu.ops.base import TaskCancelled
         from auron_tpu.runtime import lifecycle
-        from auron_tpu.runtime.executor import (ExecutionRuntime,
-                                                TaskDefinition)
         # admission control BEFORE any plan building: the server's
         # scheduler bounds concurrent executing tasks; past the bounded
         # queue (or a breached registry signal) this request is shed
@@ -718,6 +727,22 @@ class _TaskHandler(socketserver.BaseRequestHandler):
             lifecycle.observe_unwind(
                 self._cancel, kind=self._cancel.reason or "cancel")
             raise _Cancelled()
+        return slot
+
+    def _execute_admitted(self, slot, acc, task_bytes: bytes, planner_ctx,
+                          report, journal, partitions) -> None:
+        # imported lazily so the server process controls jax platform
+        # selection before anything initializes a backend
+        from auron_tpu.columnar.arrow_bridge import (schema_to_arrow,
+                                                     to_arrow)
+        from auron_tpu.ir import pb
+        from auron_tpu.ir.planner import plan_from_bytes
+        from auron_tpu import errors
+        from auron_tpu.obs import trace as _trace
+        from auron_tpu.ops.base import TaskCancelled
+        from auron_tpu.runtime import lifecycle
+        from auron_tpu.runtime.executor import (ExecutionRuntime,
+                                                TaskDefinition)
         self._cancel.slot = slot
         prev_bind = lifecycle.bind_token(self._cancel)
         import time as _time
@@ -743,13 +768,14 @@ class _TaskHandler(socketserver.BaseRequestHandler):
                                   None) == "cache",
                 served_from=getattr(self._cancel, "served_from",
                                     None) or "",
-                outcome=outcome)
+                outcome=outcome, task=acc)
             self._cancel.cost_ledger = led
             _ledger.record(led)
             return led
         try:
             task = pb.TaskDefinition()
-            task.ParseFromString(task_bytes)
+            with _trace.layer_span("plan", "decode"):
+                task.ParseFromString(task_bytes)
             # warm-path lookup (auron_tpu/cache) BEFORE journal/plan
             # work — plain SUBMITs only (a RESUME or pre-adopted
             # journal means committed partial state exists and must be
@@ -794,7 +820,8 @@ class _TaskHandler(socketserver.BaseRequestHandler):
                 jr = jrn.begin(self._cancel, task_bytes,
                                task.num_partitions or 1,
                                planner_ctx.catalog, scope="task")
-            op = plan_from_bytes(task_bytes, planner_ctx)
+            with _trace.layer_span("plan", "decode"):
+                op = plan_from_bytes(task_bytes, planner_ctx)
             # SUBMIT serves the host engine's one-task-per-partition
             # model (one runtime at task.partition_id); RESUME of a
             # collect-scoped journal passes the full partition list —
@@ -822,7 +849,8 @@ class _TaskHandler(socketserver.BaseRequestHandler):
                             task_id=task.task_id),
                         cancel_token=self._cancel)
                     for batch in rt.batches():
-                        rb = to_arrow(batch, op.schema())
+                        with _trace.layer_span("convert", "to_arrow"):
+                            rb = to_arrow(batch, op.schema())
                         if rb.num_rows:
                             self._send_batch(rb)
                             rows_sent += rb.num_rows

@@ -48,12 +48,18 @@ def install() -> None:
             return
         import jax.monitoring as mon
 
+        from auron_tpu.obs import trace as _trace
+
         def _listen(name: str, dur: float, **_kw) -> None:
             if name == _EVENT:
                 with _LOCK:
                     _N["count"] += 1
                     _S["seconds"] += dur
                     _SINCE_CLEAR["count"] += 1
+                # the event fires on the thread that compiled: the task
+                # bound to that thread owns it (the process-wide totals
+                # above cannot say whose it was)
+                _trace.on_compile(dur)
 
         def _listen_cache(name: str, **_kw) -> None:
             key = _CACHE_EVENTS.get(name)

@@ -3,8 +3,7 @@
 - tools/perf_gate.py pass/fail/unusable mechanics on synthetic bench
   records + the checked-in baseline's shape;
 - the baseline carries CPU floors only: no platform alias table, no
-  device floor inherited from a record the tree no longer holds;
-- tools/hotspot_report.py aggregation/ranking mechanics.
+  device floor inherited from a record the tree no longer holds.
 """
 
 import json
@@ -636,57 +635,3 @@ class TestBenchRunsInOneProcess:
         import bench
         monkeypatch.setattr(jax, "devices", lambda: [object()])
         assert bench.bench_mesh() == {"skipped": "1 device visible"}
-
-
-class TestHotspotReport:
-    _MS = 1_000_000   # ns per ms (records carry nanosecond counters)
-
-    def _records(self):
-        ms = self._MS
-        mk = lambda op, **m: {"task": 0, "stage": 0, "partition": 0,
-                              "op": op, "repr": op, "metrics": m}
-        return [
-            mk("agg", elapsed_compute=100 * ms, elapsed_device=10 * ms,
-               elapsed_host_dispatch=80 * ms,
-               elapsed_host_other=10 * ms),
-            mk("agg", elapsed_compute=50 * ms, elapsed_device=5 * ms,
-               elapsed_host_dispatch=40 * ms),
-            mk("parquet_scan", elapsed_compute=30 * ms,
-               elapsed_host_convert=200 * ms),
-            mk("shuffle_exchange", elapsed_host_serde=60 * ms,
-               elapsed_device=1 * ms),
-        ]
-
-    def test_aggregate_and_rank(self):
-        import hotspot_report as hr
-        ms = self._MS
-        agg = hr.aggregate(self._records())
-        assert agg["by_cat"]["dispatch"] == 120 * ms
-        assert agg["by_cat"]["convert"] == 200 * ms
-        assert agg["by_cat"]["device"] == 16 * ms
-        rep = hr.report(agg, top=3)
-        # host categories ranked: convert(200) > dispatch(120) > serde(60)
-        assert rep["top_host_categories"] == ["convert", "dispatch",
-                                              "serde"]
-        assert rep["top_sinks"][0]["op"] == "parquet_scan"
-        assert rep["top_sinks"][0]["category"] == "convert"
-        assert rep["device_ms"] == 16.0
-
-    def test_load_dir_and_cli(self, tmp_path, capsys):
-        import hotspot_report as hr
-        p = tmp_path / "profile_00000001.jsonl"
-        with open(p, "w") as f:
-            for r in self._records():
-                f.write(json.dumps(r) + "\n")
-        rc = hr.main([str(tmp_path), "--top", "2"])
-        assert rc == 0
-        out = capsys.readouterr().out
-        last = json.loads(out.strip().splitlines()[-1])
-        assert last["profile_records"] == 4
-        assert last["top_host_categories"][0] == "convert"
-        assert len(last["top_sinks"]) == 2
-
-    def test_empty_dir_is_actionable(self, tmp_path):
-        import hotspot_report as hr
-        with pytest.raises(SystemExit, match="profile_"):
-            hr.load_dir(str(tmp_path))

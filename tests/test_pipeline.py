@@ -18,7 +18,6 @@ The contracts this file holds:
 """
 
 import os
-import tempfile
 import threading
 import time
 
@@ -302,28 +301,19 @@ class TestPipelinedScan:
 
     def test_pipelined_attribution_sums_and_fences_device(self, data):
         """Async-aware timing: with profiling on and pipelining on, the
-        export still carries elapsed_device (fenced at the to_arrow
+        metric tree still carries elapsed_device (fenced at the to_arrow
         boundary / control readbacks), and per-op attribution never
         exceeds wall by more than the documented tolerance."""
         from auron_tpu.frontend.dataframe import col
         from auron_tpu.frontend.session import Session
-        conf = cfg.get_config()
-        with tempfile.TemporaryDirectory() as td:
-            conf.set(cfg.TRACE_DIR, td)
-            try:
-                s = Session()
-                (s.read_parquet([data]).filter(col("k") < 10).collect())
-                profs = [f for f in os.listdir(td)
-                         if f.startswith("profile_")]
-                assert profs, os.listdir(td)
-                import json
-                records = []
-                for f in profs:
-                    with open(os.path.join(td, f)) as fh:
-                        records += [json.loads(l) for l in fh
-                                    if l.strip()]
-            finally:
-                conf.unset(cfg.TRACE_DIR)
+        from auron_tpu.obs import metric_tree as mt
+        s = Session()
+        df = s.read_parquet([data]).filter(col("k") < 10)
+        tree, _table = mt.explain_analyze(
+            s.plan_physical(df), num_partitions=df.num_partitions,
+            mem_manager=s.mem_manager, config=s.config)
+        records = [{"op": n.name, "metrics": n.metrics}
+                   for n in tree.walk() if n.metrics]
         assert records
         total_device = sum(r["metrics"].get("elapsed_device", 0)
                            for r in records)

@@ -1,16 +1,8 @@
-"""Profiler hooks — two planes:
-
-- ``auron.profile`` (VERDICT r3 directive 8): wrap a task in a
-  jax.profiler trace; finalize() carries per-op device-time attribution
-  (role of the reference's pprof endpoints, auron/src/http/mod.rs).
-- ``auron.profile.enabled`` (PR 6, obs/profile.py): host/device time
-  attribution — per-operator ``elapsed_device`` + ``elapsed_host_*``
-  buckets, the program-call wrapper, the per-task JSONL export that
-  tools/hotspot_report.py ranks, and the near-zero disabled path.
+"""Profiler hooks — ``auron.profile.enabled`` (PR 6, obs/profile.py):
+host/device time attribution — per-operator ``elapsed_device`` +
+``elapsed_host_*`` buckets, the program-call wrapper, and the near-zero
+disabled path.
 """
-
-import json
-import os
 
 import numpy as np
 import pyarrow as pa
@@ -20,49 +12,9 @@ from auron_tpu.columnar.arrow_bridge import schema_from_arrow
 from auron_tpu.exprs import ir
 from auron_tpu.io.parquet import MemoryScanOp
 from auron_tpu.obs import profile as obs_profile
-from auron_tpu.ops.agg import AggOp
 from auron_tpu.runtime.executor import ExecutionRuntime, TaskDefinition
 
 C = ir.ColumnRef
-
-
-def test_profile_trace_and_op_attribution(tmp_path):
-    rng = np.random.default_rng(0)
-    rb = pa.record_batch({"k": pa.array(rng.integers(0, 40, 4096),
-                                        pa.int64()),
-                          "v": pa.array(rng.normal(size=4096))})
-    scan = MemoryScanOp([[rb]], schema_from_arrow(rb.schema),
-                        capacity=4096)
-    op = AggOp(scan, [C(0)], [ir.AggFunction("sum", C(1))],
-               mode="complete")
-    conf = cfg.AuronConfig({cfg.PROFILE: True,
-                            cfg.PROFILE_DIR: str(tmp_path / "trace")})
-    rt = ExecutionRuntime(op, TaskDefinition(task_id=42), config=conf)
-    tbl = rt.collect()
-    assert tbl.num_rows == 40
-    snap = rt.finalize()
-    prof = snap["profile"]
-    # a real trace directory with xplane output exists
-    assert prof["trace_dir"] == str(tmp_path / "trace")
-    found = []
-    for root, _dirs, files in os.walk(prof["trace_dir"]):
-        found.extend(files)
-    assert found, "profiler produced no trace files"
-    # per-op attribution covers the plan's operators and sums to the
-    # device-time total, which is within the task's wall time
-    assert "agg" in prof["op_device_time_s"]
-    assert prof["device_time_total_s"] > 0
-    assert abs(sum(prof["op_device_time_s"].values())
-               - prof["device_time_total_s"]) < 1e-6
-    assert prof["device_time_total_s"] <= prof["wall_time_s"] * 1.05
-
-
-def test_profile_off_adds_nothing():
-    rb = pa.record_batch({"k": pa.array([1, 2], pa.int64())})
-    scan = MemoryScanOp([[rb]], schema_from_arrow(rb.schema), capacity=16)
-    rt = ExecutionRuntime(scan, TaskDefinition())
-    rt.collect()
-    assert "profile" not in rt.finalize()
 
 
 # ---------------------------------------------------------------------------
@@ -102,8 +54,14 @@ class TestAttribution:
         snap = sets[0].snapshot()
         wall = snap["elapsed_compute"]
         assert wall > 0
+        # the project is the plan's root, and the executor books its own
+        # drive loop and the result's to_arrow on the root node as the
+        # iter / convert buckets OUTSIDE any timer (a cold to_arrow is
+        # tens of ms when this test is the process's first): they are
+        # not part of the timer's identity
         attributed = snap.get("elapsed_device", 0) + sum(
-            v for k, v in snap.items() if k.startswith("elapsed_host_"))
+            v for k, v in snap.items() if k.startswith("elapsed_host_")
+            and k not in ("elapsed_host_convert", "elapsed_host_iter"))
         assert attributed > 0
         # within 5% of wall (the flush itself costs a few clock reads)
         assert abs(attributed - wall) <= max(wall * 0.05, 200_000), snap
@@ -204,27 +162,6 @@ class TestAttribution:
         assert snap.get("elapsed_host_convert", 0) > 1_000_000, snap
         assert "elapsed_host_other" not in snap or \
             snap["elapsed_host_other"] < snap["elapsed_host_convert"]
-
-    def test_export_task_writes_hotspot_records(self, tmp_path):
-        g = cfg.get_config()
-        g.set(cfg.TRACE_DIR, str(tmp_path))
-        try:
-            op, rt = _run_project_plan()
-            obs_profile.export_task(rt.ctx, rt.plan)
-        finally:
-            g.unset(cfg.TRACE_DIR)
-        files = [f for f in os.listdir(tmp_path)
-                 if f.startswith("profile_") and f.endswith(".jsonl")]
-        assert files, os.listdir(tmp_path)
-        records = []
-        with open(tmp_path / files[0]) as f:
-            for line in f:
-                records.append(json.loads(line))
-        ops_seen = {r["op"] for r in records}
-        assert "project" in ops_seen
-        proj = next(r for r in records if r["op"] == "project")
-        assert proj["metrics"]["elapsed_compute"] > 0
-        assert "elapsed_device" in proj["metrics"]
 
     def test_summarize_tree_rollup(self):
         from auron_tpu.obs import metric_tree as mt

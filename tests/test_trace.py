@@ -297,10 +297,17 @@ class TestMetricTree:
         # the DSL face renders the same tree
         text = df.explain(analyze=True)
         assert "output_rows=" in text and "elapsed_compute=" in text
-        # one line per node + the per-query program-cache footer (the
-        # shared central cache means a query's hit rate is its OWN
-        # ledger's, surfaced here)
-        assert text.count("\n") == len(nodes) + 1
+        # one line per node, then the footer: the per-query
+        # program-cache line (the shared central cache means a query's
+        # hit rate is its OWN ledger's, surfaced here) and the
+        # process's result-cache line — counted as the renderer writes
+        # them, one ``[name] ...`` line each
+        lines = text.splitlines()
+        footer = [l for l in lines if l.startswith("[")]
+        assert len(lines) - len(footer) == len(nodes)
+        assert lines[-len(footer):] == footer
+        assert [l.split("]")[0] for l in footer] == ["[program cache",
+                                                      "[result cache"]
         assert "[program cache] builds=" in text and "hit_rate=" in text
 
     def test_render_formats_and_totals(self):
