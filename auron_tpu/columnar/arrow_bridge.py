@@ -292,6 +292,18 @@ def _kv_lists_to_map_column(arr: pa.Array, karr: pa.Array, varr: pa.Array,
                      lens, validity)
 
 
+def _decimal128_limbs(arr: pa.Array) -> np.ndarray:
+    """int64[n, 2] view of a decimal128 array's value buffer: column 0
+    the low limb's bit pattern, column 1 the signed high limb (Arrow
+    stores a value as 16 little-endian two's-complement bytes). Null
+    slots hold whatever the writer left there."""
+    buf = arr.buffers()[1]
+    if not len(arr) or buf is None:
+        return np.zeros((len(arr), 2), np.int64)
+    return np.frombuffer(buf, np.int64, count=2 * len(arr),
+                         offset=arr.offset * 16).reshape(-1, 2)
+
+
 def _decimal_list_to_device(field: Field, arr: pa.Array, cap: int):
     """list<decimal128(p,s)> → ListColumn with scaled-int64 payload
     (p<=18) or the MapColumn limb carrier (p>18). The child decimal
@@ -303,9 +315,7 @@ def _decimal_list_to_device(field: Field, arr: pa.Array, cap: int):
         arr = arr.combine_chunks()
     n = len(arr)
     child = arr.values
-    limbs = np.frombuffer(child.buffers()[1], dtype=np.int64,
-                          count=2 * len(child) if len(child) else 0,
-                          offset=child.offset * 16).reshape(-1, 2)
+    limbs = _decimal128_limbs(child)
     mask = (np.asarray(child.is_null()) if child.null_count
             else np.zeros(len(child), bool))
     offsets = np.asarray(arr.offsets)[: n + 1]
@@ -426,6 +436,7 @@ def _string_list_to_device(arr: pa.Array, cap: int):
         arr = arr.combine_chunks()
     arr = arr.cast(pa.list_(pa.string()))
     n = len(arr)
+    trace.count("encode_pyloop_values", n)
     pyrows = arr.to_pylist()
     max_e, max_w = 1, 1
     for row in pyrows:
@@ -463,6 +474,7 @@ def _string_map_to_device(arr: pa.Array, cap: int):
     from auron_tpu.utils.shapes import bucket_string_width
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
+    trace.count("encode_pyloop_values", len(arr))
     pyrows = arr.to_pylist()
     max_e, kw, vw = 1, 1, 1
     for row in pyrows:
@@ -500,8 +512,77 @@ def _string_map_to_device(arr: pa.Array, cap: int):
                            validity)
 
 
+def _decimal_to_device(field: Field, arr: pa.Array, cap: int):
+    """decimal(p, s) → scaled-int64 ``PrimitiveColumn`` (p <= 18; reference:
+    datafusion-ext-functions/src/spark_make_decimal.rs) or the two-limb
+    ``Decimal128Column`` (p 19..38; columnar/decimal128.py, the reference
+    stores Decimal128 and computes in i128, arrow/cast.rs decimal paths).
+    An Arrow decimal128 value buffer IS two little-endian int64 limbs a
+    value, so the column is a strided view of it; any other decimal width
+    or scale is brought there by Arrow's cast, and only what Arrow cannot
+    cast takes the counted per-row loop. Null and padding slots hold 0
+    (Arrow leaves a null slot's bytes undefined; hashing sees the
+    payload)."""
+    want = pa.decimal128(field.precision, field.scale)
+    if arr.type != want:
+        try:
+            arr = arr.cast(want)
+        except pa.ArrowInvalid:
+            return _decimal_pyloop_to_device(field, arr, cap)
+    n = len(arr)
+    limbs = _decimal128_limbs(arr)
+    valid = ~np.asarray(arr.is_null()) if arr.null_count else True
+    validity = np.zeros(cap, bool)
+    validity[:n] = valid
+    lo = np.zeros(cap, np.int64)
+    np.copyto(lo[:n], limbs[:, 0], where=valid)
+    if field.precision > 18:
+        from auron_tpu.columnar.decimal128 import Decimal128Column
+        hi = np.zeros(cap, np.int64)
+        np.copyto(hi[:n], limbs[:, 1], where=valid)
+        return Decimal128Column(hi, lo, validity)
+    return PrimitiveColumn(lo, validity)
+
+
+def _decimal_pyloop_to_device(field: Field, arr: pa.Array, cap: int):
+    """The per-row decimal conversion, for what Arrow cannot cast to
+    ``decimal128(field.precision, field.scale)`` (a decimal256 value past
+    38 digits, a rescale that drops digits): one Python ``Decimal`` a
+    value, rounded to the field's scale, holding the interpreter lock."""
+    import decimal as _dec
+
+    from auron_tpu.columnar.decimal128 import (Decimal128Column,
+                                               limbs_from_ints)
+    trace.count("encode_pyloop_values", len(arr))
+    with _dec.localcontext() as _ctx:
+        # the default context (prec=28) would silently round longer
+        # values during scaleb; a decimal256 holds up to 76 digits
+        _ctx.prec = 80
+        ints = [None if v is None
+                else int(v.scaleb(field.scale).to_integral_value())
+                for v in arr.to_pylist()]
+    if field.precision > 18:
+        hi, lo, validity = limbs_from_ints(ints, cap)
+        return Decimal128Column(hi, lo, validity)
+    validity = np.zeros(cap, bool)
+    data = np.zeros(cap, np.int64)
+    validity[:len(ints)] = [v is not None for v in ints]
+    data[:len(ints)] = [v or 0 for v in ints]
+    return PrimitiveColumn(data, validity)
+
+
 def _column_to_device(field: Field, arr, cap: int,
                       string_widths: dict[str, int] | None):
+    """One Arrow column → one device-layout column with numpy leaves
+    (``to_device`` transfers them). Chunked and dictionary arrays are
+    flattened first. Read from the Arrow buffers with no per-row Python:
+    primitives, dates, timestamps, strings (offsets + data), lists and
+    maps of primitives, entry lists, list<decimal>, and decimal of any
+    Arrow width (32/64/128/256 bits, sliced or not) through
+    ``_decimal_to_device``'s view of the decimal128 limbs. Per-row
+    fallbacks, each counted in the task's ``counts.encode_pyloop_values``:
+    list<string>, map<string,string>, and a decimal Arrow cannot cast to
+    the field's decimal128(p, s)."""
     n = len(arr)
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
@@ -529,40 +610,14 @@ def _column_to_device(field: Field, arr, cap: int,
         return _map_to_device(field, arr, cap)
     if field.dtype == DataType.STRUCT:
         return _struct_to_device(field, arr, cap)
+    if field.dtype == DataType.DECIMAL:
+        return _decimal_to_device(field, arr, cap)
     np_dtype = field.dtype.to_np()
     validity = np.zeros(cap, bool)
     data = np.zeros(cap, np_dtype)
     if field.dtype == DataType.NULL:
         return PrimitiveColumn(data, validity)
-    if field.dtype == DataType.DECIMAL:
-        pyvals = arr.to_pylist()
-        if field.precision > 18:
-            # precision 19..38: two-limb device representation
-            # (columnar/decimal128.py; reference stores Decimal128 and
-            # computes in i128, arrow/cast.rs decimal paths)
-            from auron_tpu.columnar.decimal128 import (Decimal128Column,
-                                                       limbs_from_ints)
-            import decimal as _dec
-            with _dec.localcontext() as _ctx:
-                # default context (prec=28) would silently round
-                # 29-38 digit values during scaleb
-                _ctx.prec = 60
-                ints = [None if v is None
-                        else int(v.scaleb(field.scale)
-                                 .to_integral_value())
-                        for v in pyvals]
-            hi, lo, valid128 = limbs_from_ints(ints, cap)
-            return Decimal128Column(hi, lo,
-                                    valid128)
-        # <=18 digits: unscaled int64 payload (reference:
-        # datafusion-ext-functions/src/spark_make_decimal.rs)
-        unscaled = np.zeros(n, np.int64)
-        for i, v in enumerate(pyvals):
-            if v is not None:
-                unscaled[i] = int(v.scaleb(field.scale).to_integral_value())
-        data[:n] = unscaled
-        validity[:n] = [v is not None for v in pyvals]
-    elif field.dtype == DataType.TIMESTAMP_US:
+    if field.dtype == DataType.TIMESTAMP_US:
         arr_us = arr.cast(pa.timestamp("us"))
         vals = arr_us.cast(pa.int64())
         data[:n] = np.asarray(vals.fill_null(0))

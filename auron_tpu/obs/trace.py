@@ -453,7 +453,7 @@ LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
               "exchange", "to_arrow", "send")
 SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
 COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
-              "h2d_bytes")
+              "h2d_bytes", "encode_pyloop_values")
 
 _ANNOTATION = None
 

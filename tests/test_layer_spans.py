@@ -37,7 +37,7 @@ V1_KEYS = {"version", "query_id", "outcome", "device", "wall_s",
 V1_COMPILE_KEYS = {"xla_compiles", "seconds", "program_builds",
                    "program_hits"}
 COUNTS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
-          "h2d_bytes", "layer_spans")
+          "h2d_bytes", "encode_pyloop_values", "layer_spans")
 
 
 def _busy(seconds: float) -> None:
@@ -242,6 +242,61 @@ class TestLayerSpanAccounting:
         assert spans["inside"].parent_id == spans["outer"].span_id
 
 
+def _encode_on_a_scan_worker(rb) -> dict:
+    """``to_device(rb)`` on a prefetch worker of one task; the sealed
+    ``counts`` of that task."""
+    from auron_tpu.columnar.arrow_bridge import to_device
+
+    def worker(acc):
+        with trace.worker_scope(acc):
+            to_device(rb)
+
+    with trace.task_scope("q-encode") as acc:
+        th = threading.Thread(target=worker, args=(acc,))
+        th.start()
+        th.join(timeout=60)
+        assert not th.is_alive()
+        return acc.sealed(1.0)["counts"]
+
+
+def test_a_store_sales_shaped_batch_encodes_with_no_python_loop():
+    """int64 + decimal(7,2) + nullable keys, sliced as the scan slices a
+    row group: every column is a view or a native copy of its Arrow
+    buffers, so the count of values sent through a per-row Python encode
+    is 0 (before PR 26 each decimal value was one Python object)."""
+    import decimal
+
+    import pyarrow as pa
+    n = 1000
+    money = pa.array([None if i % 11 == 0
+                      else decimal.Decimal(i * 37 - 9999).scaleb(-2)
+                      for i in range(n)], pa.decimal128(7, 2))
+    rb = pa.record_batch({
+        "ss_sold_date_sk": pa.array(
+            [None if i % 13 == 0 else 2450816 + i for i in range(n)],
+            pa.int64()),
+        "ss_item_sk": pa.array(range(n), pa.int64()),
+        "ss_quantity": pa.array(range(n), pa.int64()),
+        "ss_sales_price": money,
+        "ss_ext_sales_price": money,
+        "ss_net_profit": money,
+    }).slice(100, 700)
+    counts = _encode_on_a_scan_worker(rb)
+    assert counts["encode_pyloop_values"] == 0
+    assert counts["h2d_transfers"] == 13    # 6 x (data, validity) + rows
+
+
+def test_a_list_of_strings_column_is_counted_as_a_python_loop():
+    import pyarrow as pa
+    rows = [["a", "bb"], None, [], ["ccc", None, "d"]] * 5
+    rb = pa.record_batch({
+        "k": pa.array(range(len(rows)), pa.int64()),
+        "tags": pa.array(rows, pa.list_(pa.string())),
+    })
+    counts = _encode_on_a_scan_worker(rb)
+    assert counts["encode_pyloop_values"] == len(rows)
+
+
 def test_many_scan_workers_lose_no_update():
     """Several prefetch workers of one task write its worker fields at
     once (a join's dimension scans beside the fact scan): with more
@@ -384,7 +439,10 @@ class TestServedLedger:
         a, b = served("q3"), served("q3")
         for key in COUNTS:
             assert a["counts"][key] == b["counts"][key], key
-            assert a["counts"][key] > 0, key
+            # the star-join scans (int64, decimal(7,2), strings) send no
+            # value through a per-row Python encode; all else is counted
+            assert (a["counts"][key] > 0) \
+                == (key != "encode_pyloop_values"), key
         assert a["counts"]["program_calls_by_site"] \
             == b["counts"]["program_calls_by_site"]
         assert sum(a["counts"]["program_calls_by_site"].values()) \
@@ -396,7 +454,15 @@ class TestServedLedger:
         conf.set(cfg.TRACE_ENABLED, True)
         try:
             on = served("q3")
-            recorded = {s.name for s in trace.tracer().spans()}
+            # the root span closes on the server thread after the DONE
+            # frame has gone out: give it a moment to be recorded
+            deadline = time.monotonic() + 5.0
+            while True:
+                recorded = {s.name for s in trace.tracer().spans()}
+                if "serve.task" in recorded \
+                        or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
         finally:
             conf.unset(cfg.TRACE_ENABLED)
             trace.reset()
