@@ -453,7 +453,12 @@ LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
               "exchange", "to_arrow", "send")
 SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
 COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
-              "h2d_bytes", "encode_pyloop_values")
+              "h2d_bytes", "encode_pyloop_values",
+              # the mesh route of an exchange (0 on every other path):
+              # completed all-to-all rounds, quota re-runs, live bytes
+              # received, and the padded slot buffers that held them
+              "mesh_rounds", "mesh_escalations", "mesh_bytes",
+              "mesh_slot_bytes")
 
 _ANNOTATION = None
 

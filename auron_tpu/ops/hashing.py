@@ -178,6 +178,16 @@ def _f64_bits(d: jax.Array) -> tuple[jax.Array, jax.Array]:
     return pair[..., 0], pair[..., 1]
 
 
+def _vary_like(x: jax.Array, ref: jax.Array) -> jax.Array:
+    """``x`` varying over the mesh axes ``ref`` varies over. Inside a
+    ``shard_map`` (the mesh stage program hashes its partition keys and
+    its combine stage's group keys there) a loop's carry must enter with
+    the axes it leaves with, and a constant seed mixed with a shard's
+    strings enters with none. Outside one this is ``x``."""
+    missing = tuple(jax.typeof(ref).vma - jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
 def murmur3_string(chars: jax.Array, lens: jax.Array, seed) -> jax.Array:
     """murmur3 over variable-length bytes held in a fixed-width matrix.
 
@@ -193,8 +203,9 @@ def murmur3_string(chars: jax.Array, lens: jax.Array, seed) -> jax.Array:
              | (u32[:, :, 3] << 24))  # LE words [n, nwords]
     nfull = (lens // 4).astype(jnp.int32)  # number of full words per row
 
-    seed_arr = jnp.broadcast_to(jnp.uint32(seed) if jnp.ndim(seed) == 0
-                                else seed.astype(jnp.uint32), (n,))
+    seed_arr = _vary_like(
+        jnp.broadcast_to(jnp.uint32(seed) if jnp.ndim(seed) == 0
+                         else seed.astype(jnp.uint32), (n,)), lens)
 
     def word_step(i, h1):
         active = i < nfull
@@ -288,8 +299,9 @@ def xxhash64_string(chars: jax.Array, lens: jax.Array, seed) -> jax.Array:
                | (w32[:, :, 3] << 24)).astype(jnp.uint64)
 
     lens_u = lens.astype(jnp.uint64)
-    seed_arr = jnp.broadcast_to(jnp.uint64(seed) if jnp.ndim(seed) == 0
-                                else seed.astype(jnp.uint64), (n,))
+    seed_arr = _vary_like(
+        jnp.broadcast_to(jnp.uint64(seed) if jnp.ndim(seed) == 0
+                         else seed.astype(jnp.uint64), (n,)), lens)
 
     nstripes = (lens // 32).astype(jnp.int32)  # 32-byte stripes
     has_stripes = lens >= 32

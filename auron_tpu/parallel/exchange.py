@@ -390,6 +390,10 @@ class _MeshExchangeBuffer:
             self.entries.append((out_cols, counts, quota))
             self._dev_bytes += nbytes
         self.metrics.counter("mesh_bytes_moved").add(live_bytes)
+        from auron_tpu.obs import trace
+        trace.count("mesh_rounds")
+        trace.count("mesh_bytes", live_bytes)
+        trace.count("mesh_slot_bytes", nbytes)
         if self.mem is not None:
             # the ledger's unit is ONE device's HBM (the memmgr budget
             # is a fraction of a single chip): account the per-device
@@ -512,6 +516,20 @@ class _DemotedExchangeBuffer:
     def close(self) -> None:
         self.mesh_buffer.close()
         self.host_buffer.close()
+
+
+def _run_mesh_round(kern, cols, num_rows, carries):
+    """One launch of the sharded stage program and its ONE fence at the
+    stage's output boundary: the round's only readback, booked as device
+    wait (PR 8 discipline — never per shard, never per program step).
+    Returns the program's outputs and, from the host, ``(global max
+    bucket, recv counts[, pre-combine rows])`` — the last rides the same
+    fence when a combine stage is folded."""
+    from auron_tpu.obs import profile as _profile
+    from auron_tpu.obs import trace
+    with trace.layer_span("exchange", "mesh_round"):
+        outs = kern(cols, num_rows, carries)
+        return outs, _profile.timed_get((outs[3], outs[1]) + tuple(outs[5:]))
 
 
 class ShuffleExchangeOp(PhysicalOp):
@@ -658,6 +676,7 @@ class ShuffleExchangeOp(PhysicalOp):
         from auron_tpu import config as cfg
         from auron_tpu import errors
         from auron_tpu.obs import profile as _profile
+        from auron_tpu.obs import trace
         from auron_tpu.parallel import mesh as mesh_mod
         from auron_tpu.parallel.mesh_exchange import stage_exchange_program
         from auron_tpu.runtime import faults
@@ -754,9 +773,11 @@ class ShuffleExchangeOp(PhysicalOp):
                             # the mesh fault domain's per-round site
                             mex.round_fault_check(ctx)
                             with timer(write_time, sync=False):
-                                cols, num_rows, cap = \
-                                    mesh_mod.stack_global_batch(
-                                        batches, mesh, axis)
+                                with trace.layer_span("exchange",
+                                                      "mesh_stack"):
+                                    cols, num_rows, cap = \
+                                        mesh_mod.stack_global_batch(
+                                            batches, mesh, axis)
                                 if quota is None:
                                     quota = bucket_rows(
                                         max((2 * cap) // n_out, 1))
@@ -768,30 +789,13 @@ class ShuffleExchangeOp(PhysicalOp):
                                         combine, combine_sig)
                                     round_built |= built
                                     (built_c if built else hit_c).add(1)
-                                    if combine is not None:
-                                        (out_cols, rc, _nr, gmax,
-                                         new_carries, comb_in) = kern(
-                                            cols, num_rows, carries)
-                                    else:
-                                        (out_cols, rc, _nr, gmax,
-                                         new_carries) = kern(
-                                            cols, num_rows, carries)
-                                        comb_in = None
-                                    # ONE fence at the sharded stage's
-                                    # output boundary: the round's only
-                                    # readback, booked as device wait
-                                    # (PR 8 discipline — never per
-                                    # shard, never per program step);
-                                    # the pre-combine row count rides
-                                    # the same fence
-                                    if comb_in is not None:
-                                        gmax_h, rc_h, comb_h = \
-                                            _profile.timed_get(
-                                                (gmax, rc, comb_in))
-                                    else:
-                                        gmax_h, rc_h = _profile.timed_get(
-                                            (gmax, rc))
-                                        comb_h = None
+                                    outs, fenced = _run_mesh_round(
+                                        kern, cols, num_rows, carries)
+                                    (out_cols, rc, _nr, gmax,
+                                     new_carries) = outs[:5]
+                                    gmax_h, rc_h = fenced[:2]
+                                    comb_h = fenced[2] \
+                                        if combine is not None else None
                                     needed = int(np.asarray(gmax_h))
                                     if needed <= quota:
                                         break
@@ -801,6 +805,7 @@ class ShuffleExchangeOp(PhysicalOp):
                                     # the un-donated inputs are still
                                     # live for this re-run
                                     escalations += 1
+                                    trace.count("mesh_escalations")
                                     quota = bucket_rows(needed)
                     except BaseException as e:
                         err = mex.classify_collective(e)
@@ -879,7 +884,6 @@ class ShuffleExchangeOp(PhysicalOp):
                     if slow:
                         plane.record_straggler()
                         metrics.counter("mesh_stragglers").add(1)
-                        from auron_tpu.obs import trace
                         trace.event(
                             "mesh", "mesh.straggler", op=repr(self),
                             round=rounds - 1,

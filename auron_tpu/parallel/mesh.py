@@ -255,6 +255,25 @@ class MeshPlane:
         if reentrant:
             yield self
             return
+        from auron_tpu.obs import trace
+        # the door is the stage's wait for the mesh: the scheduler's turn
+        # and the FIFO behind another query's sharded stage
+        with trace.layer_span("exchange", "gang_wait"):
+            qid, wait_ns, contended = self._acquire_gang(token, heartbeat)
+        trace.event("mesh", "mesh.gang", query=qid,
+                    wait_ms=round(wait_ns / 1e6, 3), contended=contended)
+        try:
+            yield self
+        finally:
+            with self._cond:
+                self._holder = None
+                self._holder_thread = None
+                self._cond.notify_all()
+
+    def _acquire_gang(self, token, heartbeat) -> tuple:
+        """The gang door: returns ``(query id, wait ns, contended)``
+        once this thread holds the mesh."""
+        me = threading.current_thread()
         from auron_tpu.runtime import faults as _faults
         from auron_tpu.runtime import scheduler as _scheduler
         _scheduler.turn(token)
@@ -294,16 +313,7 @@ class MeshPlane:
                 self.gang_contended += 1
             wait_ns = time.perf_counter_ns() - t0
             self.gang_wait_ns += wait_ns
-        from auron_tpu.obs import trace
-        trace.event("mesh", "mesh.gang", query=qid,
-                    wait_ms=round(wait_ns / 1e6, 3), contended=contended)
-        try:
-            yield self
-        finally:
-            with self._cond:
-                self._holder = None
-                self._holder_thread = None
-                self._cond.notify_all()
+        return qid, wait_ns, contended
 
     def gang_holder(self) -> Optional[str]:
         with self._cond:
