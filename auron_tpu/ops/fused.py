@@ -200,7 +200,7 @@ class FusedStageOp(PhysicalOp):
     def _consumer_fold(self, ctx: ExecContext):
         """(fragments, frag_keys) when this stage's input is an inner
         hash join whose matched output can run through the join's
-        gather+chain program (ops/joins._gather_consumer_program) — the
+        match program (ops/joins._match_program, with this chain) — the
         probe-into-consumer fold. The planner's cost pass gates it per
         site via ``probe_fold_consumer`` (ir/cost.choose_probe_fold);
         fan-out members and fused limits keep the stage on its own

@@ -26,11 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from auron_tpu.columnar.batch import (DeviceBatch, PrimitiveColumn, StringColumn,
-                                      compact, gather_column)
+                                      compact, gather_batch, gather_column)
 from auron_tpu.memmgr.consumer import BufferedSpillConsumer
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, evaluate
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops import hashing
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.ops.sort import _concat_all
@@ -108,71 +109,108 @@ def _probe_count_kernel(key_exprs: tuple, in_schema: Schema, capacity: int,
 _PROBE_PROGRAMS = programs.register(
     programs.ProgramCache("ops.joins.fused_probe", maxsize=256))
 
-#: probe-epilogue programs (Fusion 2.0): candidate expansion + exact-key
-#: verification + pair gather + compaction + the CONSUMER stage's fragment
-#: chain in ONE XLA program — the inner join's matched output feeds the
-#: downstream fused chain without materializing the joined batch between
-#: two program launches (the dual of the probe prologue above)
-_GATHER_PROGRAMS = programs.register(
-    programs.ProgramCache("ops.joins.gather_consumer", maxsize=256))
+#: match programs: candidate expansion + exact-key verification + pair
+#: gather + compaction — the whole match phase of ONE probe batch in ONE
+#: XLA program, for every join type (the type is a static key of the
+#: program). With a consumer chain (Fusion 2.0's probe-into-consumer fold)
+#: the downstream FusedStageOp's fragments run in the same launch, so the
+#: inner join's matched output feeds the chain without materializing
+#: between two programs (the dual of the probe prologue above)
+_MATCH_PROGRAMS = programs.register(
+    programs.ProgramCache("ops.joins.match", maxsize=256))
 
 
-def _gather_consumer_program(frag_keys: tuple, key_exprs: tuple,
-                             probe_schema: Schema, build_schema: Schema,
-                             out_cap: int, capacity: int, build_cap: int,
-                             fragments):
-    """One program per (consumer chain, join keys, schemas, capacities):
-    the inner join's match/gather phase — expand, verify, gather both
-    sides, compact — runs fused with the consumer FusedStageOp's member
-    fragments. The compacted joined batch the chain sees is exactly the
-    batch ``_probe_one`` would have yielded standalone (same expand, same
-    ``_keys_match``, same stable compaction), and the fragments are the
-    same traced bodies the consumer's own stage program would run, so the
-    fold is bit-identical — it only removes one program boundary."""
+def _expand(lo, counts, out_cap: int, capacity: int):
+    """Expand candidate ranges to (probe_idx, build_idx) pairs."""
+    starts = jnp.cumsum(counts) - counts  # exclusive prefix
+    total = jnp.sum(counts)
+    slots = jnp.arange(out_cap, dtype=jnp.int32)
+    # probe row owning slot t: last row with starts <= t
+    probe_idx = jnp.searchsorted(
+        starts, slots, side="right").astype(jnp.int32) - 1
+    probe_idx = jnp.clip(probe_idx, 0, capacity - 1)
+    offset = slots - starts[probe_idx]
+    build_idx = lo[probe_idx] + offset
+    in_range = slots < total
+    return probe_idx, jnp.where(in_range, build_idx, 0), in_range
+
+
+def _match_program(join_type: str, frag_keys: tuple, key_exprs: tuple,
+                   probe_schema: Schema, build_schema: Schema,
+                   out_cap: int, capacity: int, build_cap: int, fragments):
+    """One program per (join type, consumer chain or none, join keys,
+    schemas, capacities): expand, verify, gather both sides, compact.
+    Returns ``(outs, matched, carries)``; ``outs`` by type:
+
+    - inner / right: (pairs,)
+    - left / full: (pairs, unmatched probe rows with null build columns)
+    - semi / anti: (the kept probe rows,); existence: (probe + flag,)
+
+    ``matched`` is the build side's or-accumulated match mask (right /
+    full; None otherwise) — an operand and a result. ``fragments`` is
+    the consumer FusedStageOp's member chain (inner joins only, possibly
+    empty): the compacted pair batch runs through it with ``carries``
+    advancing exactly as the consumer's own stage program would advance
+    them, so the fold only removes a program boundary."""
 
     def build():
         from auron_tpu.ops.fused import thread_fragments
-        from auron_tpu.runtime import programs as _programs
 
-        def auron_ops_joins_gather_consumer(
-                probe: DeviceBatch, build_batch: DeviceBatch,
-                build_keys: tuple, lo, counts, partition_id, carries):
+        def auron_ops_joins_match(probe: DeviceBatch,
+                                  build_batch: DeviceBatch,
+                                  build_keys: tuple, lo, counts, matched,
+                                  partition_id, carries):
             ctx = EvalContext()
             probe_key_cols = tuple(
                 evaluate(e, probe, probe_schema, ctx).col for e in key_exprs)
-            # candidate expansion (same body as _expand_kernel)
-            starts = jnp.cumsum(counts) - counts
-            total = jnp.sum(counts)
-            slots = jnp.arange(out_cap, dtype=jnp.int32)
-            probe_idx = jnp.searchsorted(
-                starts, slots, side="right").astype(jnp.int32) - 1
-            probe_idx = jnp.clip(probe_idx, 0, capacity - 1)
-            offset = slots - starts[probe_idx]
-            build_idx = lo[probe_idx] + offset
-            in_range = slots < total
-            build_idx = jnp.where(in_range, build_idx, 0)
+            probe_idx, build_idx, in_range = _expand(lo, counts, out_cap,
+                                                     capacity)
             ok = _keys_match(probe_key_cols, probe_idx, build_keys,
                              build_idx) & in_range
+            if join_type in ("right", "full"):
+                matched = matched.at[jnp.where(ok, build_idx, build_cap)] \
+                    .set(True, mode="drop") | matched
+            if join_type in ("semi", "anti", "existence", "left", "full"):
+                matched_probe = jnp.zeros(capacity, bool).at[
+                    jnp.where(ok, probe_idx, capacity)].set(True, mode="drop")
+            if join_type == "semi":
+                return (compact(probe, matched_probe),), matched, carries
+            if join_type == "anti":
+                keep = ~matched_probe & probe.row_mask()
+                return (compact(probe, keep),), matched, carries
+            if join_type == "existence":
+                cols = probe.columns + (PrimitiveColumn(
+                    matched_probe, jnp.ones(capacity, bool)),)
+                return (DeviceBatch(cols, probe.num_rows),), matched, carries
+
             out_probe = _take_cols(probe.columns, probe_idx,
                                    jnp.ones_like(probe_idx, bool))
             out_build = _take_cols(build_batch.columns, build_idx,
                                    jnp.ones_like(build_idx, bool))
             pair = DeviceBatch(tuple(out_probe) + tuple(out_build),
                                jnp.asarray(out_cap, jnp.int32))
-            matched = compact(pair, ok)
-            outs, new_carries = thread_fragments(fragments, matched,
-                                                 partition_id, carries)
-            (b,) = outs   # fan-out chains rejected by eligibility
-            return b, jnp.stack(new_carries)
+            outs = (compact(pair, ok),)
+            if fragments:
+                outs, new_carries = thread_fragments(
+                    fragments, outs[0], partition_id, carries)
+                carries = jnp.stack(new_carries)
+            if join_type in ("left", "full"):
+                # unmatched probe rows with nulls on the build side
+                left_out = compact(probe,
+                                   ~matched_probe & probe.row_mask())
+                null_build = tuple(_null_column_like(c, capacity)
+                                   for c in build_batch.columns)
+                outs += (DeviceBatch(left_out.columns + null_build,
+                                     left_out.num_rows),)
+            return outs, matched, carries
 
-        # donation stays off: the probe batch may still feed a
-        # left/full unmatched pass upstream in future variants; the
-        # gather allocates fresh output arrays regardless
-        return _programs.jit(auron_ops_joins_gather_consumer)
+        # donation stays off: the probe batch is an output of the
+        # semi/anti/existence types and the build side serves every batch
+        return programs.jit(auron_ops_joins_match)
 
-    return _GATHER_PROGRAMS.get_or_build(
-        (frag_keys, key_exprs, probe_schema, build_schema, out_cap,
-         capacity, build_cap), build)
+    return _MATCH_PROGRAMS.get_or_build(
+        (join_type, frag_keys, key_exprs, probe_schema, build_schema,
+         out_cap, capacity, build_cap), build)
 
 
 def _fused_probe_program(frag_keys: tuple, key_exprs: tuple,
@@ -212,24 +250,37 @@ def _fused_probe_program(frag_keys: tuple, key_exprs: tuple,
          index_kind, rounds, donate), build)
 
 
-@program_cache("ops.joins.expand", maxsize=256)
-def _expand_kernel(out_cap: int, capacity: int):
-    """Expand candidate ranges to (probe_idx, build_idx) pairs."""
+@program_cache("ops.joins.build_side", maxsize=256)
+def _build_side_program(key_exprs: tuple, schema: Schema, capacity: int):
+    """The build side's sort by key hash — key evaluation, hashing, the
+    stable argsort and the gathers of the batch, the hashes and the key
+    columns — as one program."""
 
     @jax.jit
-    def auron_ops_joins_expand(lo, counts):
-        starts = jnp.cumsum(counts) - counts  # exclusive prefix
-        total = jnp.sum(counts)
-        slots = jnp.arange(out_cap, dtype=jnp.int32)
-        # probe row owning slot t: last row with starts <= t
-        probe_idx = jnp.searchsorted(starts, slots, side="right").astype(jnp.int32) - 1
-        probe_idx = jnp.clip(probe_idx, 0, capacity - 1)
-        offset = slots - starts[probe_idx]
-        build_idx = lo[probe_idx] + offset
-        in_range = slots < total
-        return probe_idx, jnp.where(in_range, build_idx, 0), in_range
+    def auron_ops_joins_build_side(batch: DeviceBatch):
+        ctx = EvalContext()
+        keys = tuple(evaluate(e, batch, schema, ctx).col for e in key_exprs)
+        h = _key_hashes(keys, capacity, batch.row_mask(), _NULL_BUILD)
+        perm = jnp.argsort(h, stable=True)
+        return (gather_batch(batch, perm, batch.num_rows), h[perm],
+                _take_cols(keys, perm, jnp.ones(capacity, bool)))
 
-    return auron_ops_joins_expand
+    return auron_ops_joins_build_side
+
+
+@program_cache("ops.joins.unmatched_build", maxsize=256)
+def _unmatched_build_program(probe_schema: Schema, capacity: int):
+    """Right / full joins' last batch: the build rows no probe batch
+    matched, with nulls on the probe side."""
+
+    @jax.jit
+    def auron_ops_joins_unmatched_build(build_batch: DeviceBatch, matched):
+        build_out = compact(build_batch, ~matched & build_batch.row_mask())
+        null_probe = tuple(_null_column_like_schema(f, capacity)
+                           for f in probe_schema)
+        return DeviceBatch(null_probe + build_out.columns, build_out.num_rows)
+
+    return auron_ops_joins_unmatched_build
 
 
 class _BuildSide:
@@ -237,21 +288,14 @@ class _BuildSide:
     fits) the hash-table candidate index over its hash runs."""
 
     def __init__(self, batch: DeviceBatch, schema: Schema, key_exprs,
-                 metrics, conf=None):
+                 metrics, conf=None, track_matched: bool = False):
         self.schema = schema
-        cap = batch.capacity
-        ctx = EvalContext()
-        keys = tuple(evaluate(e, batch, schema, ctx).col for e in key_exprs)
-        h = _key_hashes(keys, cap, batch.row_mask(), _NULL_BUILD)
-        perm = jnp.argsort(h, stable=True)
-        from auron_tpu.columnar.batch import gather_batch
-        self.batch = gather_batch(batch, perm, batch.num_rows)
-        self.hashes = h[perm]
-        self.keys = tuple(gather_column(c, perm, jnp.ones(cap, bool))
-                          for c in keys)
-        self.capacity = cap
+        self.capacity = cap = batch.capacity
+        self.batch, self.hashes, self.keys = _build_side_program(
+            tuple(key_exprs), schema, cap)(batch)
         # matched mask for right/full joins, or-accumulated across batches
-        self.matched = jnp.zeros(cap, bool)
+        # by the match program (a host constant until its first launch)
+        self.matched = np.zeros(cap, bool) if track_matched else None
         # hash-run candidate index (auron_tpu/hashtable): probe hash →
         # (run lo, run length) in O(probe rounds) gathers instead of two
         # O(log B) searchsorted passes; None keeps the searchsorted path
@@ -292,6 +336,31 @@ def _keys_match(probe_keys, probe_idx, build_keys, build_idx) -> jax.Array:
         bv = bc.validity[build_idx]
         ok = ok & pv & bv & pairwise_eq(pc, probe_idx, bc, build_idx)
     return ok
+
+
+class _MatchState:
+    """What one ``HashJoinOp.execute`` threads through its probe batches'
+    match programs: the folded consumer chain (or none) with its carries,
+    the program-cache counters, and the per-run probe statistics the
+    ir/cost history reads."""
+
+    __slots__ = ("partition", "fragments", "frag_keys", "carries",
+                 "built_c", "hit_c", "fold_built_c", "fold_hit_c",
+                 "rows_out", "batches")
+
+    def __init__(self, partition: int, built_c, hit_c):
+        self.partition = partition
+        self.fragments = ()
+        self.frag_keys = ()
+        self.carries = None
+        self.built_c, self.hit_c = built_c, hit_c
+        self.fold_built_c = self.fold_hit_c = None
+        self.rows_out = self.batches = 0
+
+    def count(self, built: bool) -> None:
+        (self.built_c if built else self.hit_c).add(1)
+        if self.fold_built_c is not None:   # the folded case keeps its own
+            (self.fold_built_c if built else self.fold_hit_c).add(1)
 
 
 class HashJoinOp(PhysicalOp):
@@ -349,11 +418,11 @@ class HashJoinOp(PhysicalOp):
         """``_consumer`` is the probe-into-consumer fold handshake
         (ops/fused.FusedStageOp.execute): ``(consumer_op, fragments,
         frag_keys)`` of the downstream fused chain. The inner join's
-        matched output then runs through ``_gather_consumer_program`` —
-        match phase + consumer chain in one launch — and every batch this
-        generator yields is ALREADY chained; degraded paths (SMJ
-        fallback, empty build) chain via the consumer's ordinary stage
-        program instead so the contract holds on every route."""
+        match program then runs that chain too — match phase + consumer
+        chain in one launch — and every batch this generator yields is
+        ALREADY chained; degraded paths (SMJ fallback, empty build)
+        chain via the consumer's ordinary stage program instead so the
+        contract holds on every route."""
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         build_time = metrics.counter("build_hash_map_time")
@@ -362,30 +431,24 @@ class HashJoinOp(PhysicalOp):
         mem = ctx.mem_manager
         spillable = mem is not None and \
             getattr(mem, "spill_manager", None) is not None
-        fold_state = None
+        km = ctx.metrics_for("kernels")
+        match = _MatchState(partition,
+                            km.counter("join_match_programs_built"),
+                            km.counter("join_match_program_hits"))
+        consumer_op = None
         if _consumer is not None:
-            consumer_op, cfrags, cfrag_keys = _consumer
-            fold_state = {
-                "op": consumer_op, "fragments": cfrags,
-                "frag_keys": cfrag_keys, "partition": partition,
-                "carries": jnp.asarray([f.init_carry for f in cfrags],
-                                       jnp.int64),
-            }
+            consumer_op, match.fragments, match.frag_keys = _consumer
+            # graft: disable=GL001 -- a host list of python ints
+            match.carries = np.asarray(
+                [f.init_carry for f in match.fragments], np.int64)
             ctx.metrics_for(consumer_op).counter(
                 "probe_consumer_folded").add(1)
-            km = ctx.metrics_for("kernels")
-            fold_state["built_c"] = km.counter(
-                "gather_consumer_programs_built")
-            fold_state["hit_c"] = km.counter("gather_consumer_program_hits")
+            match.fold_built_c = km.counter("gather_consumer_programs_built")
+            match.fold_hit_c = km.counter("gather_consumer_program_hits")
 
         def stream():
             consumer = _JoinBuildConsumer(self, mem, metrics, ctx.conf) \
                 if spillable else None
-            # per-run probe statistics for the ir/cost history: matched
-            # candidate totals are already host-synced (int(total) gates
-            # the output capacity), so observing them adds no sync
-            probe_rows_out = 0
-            probe_batches = 0
             try:
                 build_batches = []
                 with timer(build_time):
@@ -405,8 +468,8 @@ class HashJoinOp(PhysicalOp):
                     # memory-safe direction).
                     metrics.counter("fallback_smj_count").add(1)
                     out = self._smj_fallback(consumer, partition, ctx)
-                    if fold_state is not None:
-                        out = fold_state["op"].run_chain(out, partition, ctx)
+                    if consumer_op is not None:
+                        out = consumer_op.run_chain(out, partition, ctx)
                     yield from out
                     return
                 if consumer is not None:
@@ -419,38 +482,36 @@ class HashJoinOp(PhysicalOp):
                 if merged is None:
                     out = self._empty_build_stream(partition, ctx,
                                                    probe_schema)
-                    if fold_state is not None:
-                        out = fold_state["op"].run_chain(out, partition, ctx)
+                    if consumer_op is not None:
+                        out = consumer_op.run_chain(out, partition, ctx)
                     yield from out
                     return
-                side = _BuildSide(merged, build_schema, self.build_keys,
-                                  metrics, conf=ctx.conf)
+                side = _BuildSide(
+                    merged, build_schema, self.build_keys, metrics,
+                    conf=ctx.conf,
+                    track_matched=self.join_type in ("right", "full"))
 
-                stats = [0, 0]
                 fold = self._probe_fold(ctx)
                 if fold is not None:
                     yield from self._probe_fused(fold, side, partition, ctx,
                                                  probe_schema, build_schema,
-                                                 elapsed, fold_state, stats)
+                                                 elapsed, match)
                 else:
                     for probe in self.probe.execute(partition, ctx):
                         yield from self._probe_one(probe, side, probe_schema,
                                                    build_schema, elapsed,
-                                                   ctx.device_sync,
-                                                   fold_state=fold_state,
-                                                   stats=stats)
-                probe_rows_out, probe_batches = stats
+                                                   ctx.device_sync, match)
 
                 if self.join_type in ("right", "full"):
-                    yield self._unmatched_build(side, probe_schema,
-                                                build_schema)
+                    yield _unmatched_build_program(
+                        probe_schema, side.capacity)(side.batch, side.matched)
             finally:
                 if consumer is not None:
                     consumer.close()
-                if probe_batches:
+                if match.batches:
                     from auron_tpu.ir import cost as cost_mod
-                    cost_mod.observe(self.cost_site, probe_rows_out,
-                                     probe_rows_out, probe_batches)
+                    cost_mod.observe(self.cost_site, match.rows_out,
+                                     match.rows_out, match.batches)
 
         return count_output(stream(), metrics)
 
@@ -491,7 +552,7 @@ class HashJoinOp(PhysicalOp):
 
     def _probe_fused(self, fold, side: _BuildSide, partition: int,
                      ctx: ExecContext, probe_schema, build_schema, elapsed,
-                     fold_state=None, stats=None):
+                     match: "_MatchState"):
         """Probe loop with the chain folded into the probe program: one
         XLA launch runs the member fragments AND the candidate search;
         the transformed batch comes back for the match/gather phase."""
@@ -517,7 +578,8 @@ class HashJoinOp(PhysicalOp):
         donate = (any(getattr(m, "fragment_computes", False)
                       for m in self.probe.members)
                   and yields_owned_batches(input_op))
-        carries = jnp.asarray([f.init_carry for f in fragments], jnp.int64)
+        # graft: disable=GL001 -- a host list of python ints
+        carries = np.asarray([f.init_carry for f in fragments], np.int64)
         for raw in input_op.execute(partition, ctx):
             ctx.check_cancelled()
             kern, built = _fused_probe_program(
@@ -527,18 +589,17 @@ class HashJoinOp(PhysicalOp):
             (built_c if built else hit_c).add(1)
             with timer(f_elapsed, sync=_sync) as t:
                 probe, lo, counts, total, carries = t.track(
-                    kern(raw, jnp.int32(partition), carries,
+                    kern(raw, np.int32(partition), carries,
                          *side.index_args()))
             f_rows.add(int(probe.num_rows))
             f_batches.add(1)
             yield from self._probe_one(probe, side, probe_schema,
-                                       build_schema, elapsed, _sync,
-                                       pre=(lo, counts, total),
-                                       fold_state=fold_state, stats=stats)
+                                       build_schema, elapsed, _sync, match,
+                                       pre=(lo, counts, total))
 
     def _probe_one(self, probe: DeviceBatch, side: _BuildSide, probe_schema,
-                   build_schema, elapsed, _sync: bool = True, pre=None,
-                   fold_state=None, stats=None):
+                   build_schema, elapsed, _sync: bool, match: "_MatchState",
+                   pre=None):
         cap = probe.capacity
         if pre is None:
             kern = _probe_count_kernel(self.probe_keys, probe_schema, cap,
@@ -549,105 +610,29 @@ class HashJoinOp(PhysicalOp):
                     kern(probe, *side.index_args()))
         else:   # the fused probe program already ran the candidate search
             lo, counts, total = pre
-        total_i = int(total)
-        if stats is not None:
-            stats[0] += total_i
-            stats[1] += 1
-
-        if fold_state is not None:
-            # probe-into-consumer fold (inner joins only — eligibility is
-            # the consumer's _consumer_fold): expand + verify + gather +
-            # compact + consumer chain, one launch; the consumer carries
-            # advance across matched batches exactly as its own stage
-            # program would have advanced them
-            if total_i == 0:
-                # no candidates → the unfused join yields no batch here,
-                # so the consumer chain (and its carries) never see one
-                return
-            out_cap = bucket_rows(total_i)
-            kern, built = _gather_consumer_program(
-                fold_state["frag_keys"], self.probe_keys, probe_schema,
-                build_schema, out_cap, cap, side.capacity,
-                fold_state["fragments"])
-            (fold_state["built_c"] if built else fold_state["hit_c"]).add(1)
-            with timer(elapsed, sync=_sync) as t:
-                out, fold_state["carries"] = t.track(kern(
-                    probe, side.batch, side.keys, lo, counts,
-                    jnp.int32(fold_state["partition"]),
-                    fold_state["carries"]))
-            yield out
+        # the one sync a probe batch: the exact candidate count sizes the
+        # match program's output capacity; it is also the cost history's
+        # per-run probe statistic (ir/cost), so observing adds no sync
+        total_i = int(_profile.timed_get(total))
+        match.rows_out += total_i
+        match.batches += 1
+        if total_i == 0 and self.join_type in ("inner", "right"):
+            # no candidates → no pair batch, and a folded consumer chain
+            # (with its carries) never sees one
             return
 
-        ctx = EvalContext()
-        probe_key_cols = tuple(evaluate(e, probe, probe_schema, ctx).col
-                               for e in self.probe_keys)
-
-        if self.join_type in ("semi", "anti", "existence", "left", "full") \
-                or total_i > 0:
-            out_cap = bucket_rows(max(total_i, 1))
-            expand = _expand_kernel(out_cap, cap)
-            with timer(elapsed, sync=_sync) as t:
-                probe_idx, build_idx, in_range = expand(lo, counts)
-                ok = t.track(_keys_match(probe_key_cols, probe_idx, side.keys,
-                                         build_idx) & in_range)
-        else:
-            probe_idx = build_idx = ok = None
-
-        if self.join_type in ("right", "full") and ok is not None:
-            side.matched = side.matched.at[jnp.where(ok, build_idx, side.capacity)] \
-                .set(True, mode="drop") | side.matched
-
-        if self.join_type in ("semi", "anti", "existence"):
-            matched_probe = jnp.zeros(cap, bool)
-            if ok is not None:
-                matched_probe = matched_probe.at[
-                    jnp.where(ok, probe_idx, cap)].set(True, mode="drop")
-            if self.join_type == "semi":
-                out = compact(probe, matched_probe)
-                yield out
-            elif self.join_type == "anti":
-                out = compact(probe, ~matched_probe & probe.row_mask())
-                yield out
-            else:  # existence
-                cols = probe.columns + (PrimitiveColumn(
-                    matched_probe, jnp.ones(cap, bool)),)
-                yield DeviceBatch(cols, probe.num_rows)
-            return
-
-        outputs = []
-        if total_i > 0:
-            n_valid = jnp.sum(ok.astype(jnp.int32))
-            valid_slots = ok
-            out_probe = _take_cols(probe.columns, probe_idx,
-                                   jnp.ones_like(probe_idx, bool))
-            out_build = _take_cols(side.batch.columns, build_idx,
-                                   jnp.ones_like(build_idx, bool))
-            pair_batch = DeviceBatch(tuple(out_probe) + tuple(out_build),
-                                     jnp.asarray(ok.shape[0], jnp.int32))
-            matched_out = compact(pair_batch, valid_slots)
-            outputs.append(matched_out)
-
-        if self.join_type in ("left", "full"):
-            # unmatched probe rows with nulls on build side
-            matched_probe = jnp.zeros(cap, bool)
-            if ok is not None:
-                matched_probe = matched_probe.at[
-                    jnp.where(ok, probe_idx, cap)].set(True, mode="drop")
-            unmatched = ~matched_probe & probe.row_mask()
-            left_out = compact(probe, unmatched)
-            null_build = tuple(_null_column_like(c, cap)
-                               for c in side.batch.columns)
-            outputs.append(DeviceBatch(left_out.columns + null_build,
-                                       left_out.num_rows))
-        yield from outputs
-
-    def _unmatched_build(self, side: _BuildSide, probe_schema, build_schema):
-        unmatched = ~side.matched & side.batch.row_mask()
-        build_out = compact(side.batch, unmatched)
-        cap = side.capacity
-        null_probe = tuple(_null_column_like_schema(f, cap)
-                           for f in probe_schema)
-        return DeviceBatch(null_probe + build_out.columns, build_out.num_rows)
+        kern, built = _match_program(
+            self.join_type, match.frag_keys, self.probe_keys, probe_schema,
+            build_schema, bucket_rows(max(total_i, 1)), cap, side.capacity,
+            match.fragments)
+        match.count(built)
+        with timer(elapsed, sync=_sync) as t:
+            outs, side.matched, match.carries = t.track(kern(
+                probe, side.batch, side.keys, lo, counts, side.matched,
+                np.int32(match.partition), match.carries))
+        if total_i == 0 and self.join_type in ("left", "full"):
+            outs = outs[1:]     # no candidates → no pair batch
+        yield from outs
 
     def _empty_build_stream(self, partition, ctx, probe_schema):
         for probe in self.probe.execute(partition, ctx):

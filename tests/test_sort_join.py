@@ -362,3 +362,69 @@ def test_hash_join_build_spill_falls_back_to_smj():
     es = exp.sort_values(["k", "lv", "rv"]).reset_index(drop=True)
     np.testing.assert_array_equal(gs["lv"], es["lv"])
     np.testing.assert_array_equal(gs["rv"], es["rv"])
+
+
+# ---------------------------------------------------------------------------
+# PR 29: the join's match phase runs as one program a probe batch. Its
+# rows equal the rows of the eager path it replaced, in order: the
+# expected rows were recorded from the parent commit (680d28f) by
+# running these same cases there (tests/fixtures/join_rows_eager.json)
+# ---------------------------------------------------------------------------
+
+import decimal  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+
+_JOIN_TYPES = ("inner", "left", "right", "full", "semi", "anti", "existence")
+_KEY_KINDS = {
+    "int": (pa.int64(), lambda i: i),
+    "string": (pa.string(), lambda i: "s" * (i % 4 + 1) + str(i)),
+    # past 18 digits: two int64 limbs, equal only if both are
+    "decimal128": (pa.decimal128(38, 2),
+                   lambda i: decimal.Decimal(f"{10 ** 28 + i}.25")),
+}
+
+
+def _identity_join(join_type, kind):
+    """Four probe batches over one build batch: null keys on both sides,
+    duplicate build keys (2) and probe keys (2, 4, 7), a probe batch the
+    filter empties (``f`` all null), and one without a candidate."""
+    from auron_tpu.ops.project import FilterOp
+    typ, key = _KEY_KINDS[kind]
+    keys = lambda ids: pa.array(    # noqa: E731
+        [None if i is None else key(i) for i in ids], typ)
+    probe = [pa.record_batch({
+        "lk": keys(ids),
+        "lv": pa.array([10 * b + j for j in range(len(ids))], pa.int64()),
+        "f": pa.array([None if b == 1 else 1] * len(ids), pa.int64()),
+    }) for b, ids in enumerate(([1, 2, 3, None, 2], [2, 4, 6],
+                                [7, 8, 9, 7, None], [4, 4, 2, 5, 6]))]
+    build = pa.record_batch({
+        "rk": keys([2, 2, 4, None, 6]),
+        "rv": pa.array([20, 21, 40, 99, 60], pa.int64()),
+    })
+    scan = MemoryScanOp([probe], schema_from_arrow(probe[0].schema),
+                        capacity=8)
+    return HashJoinOp(FilterOp(scan, [ir.IsNotNull(C(2))]),
+                      mem_scan(build, capacity=8), [C(0)], [C(0)],
+                      join_type=join_type)
+
+
+def _plain_rows(table):
+    return [[v if v is None or isinstance(v, (int, bool, str)) else str(v)
+             for v in row.values()] for row in table.to_pylist()]
+
+
+@pytest.fixture(scope="module")
+def eager_rows():
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures", "join_rows_eager.json")
+    with open(path) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("kind", sorted(_KEY_KINDS))
+@pytest.mark.parametrize("join_type", _JOIN_TYPES)
+def test_join_rows_equal_the_eager_paths(join_type, kind, eager_rows):
+    got = _plain_rows(collect(_identity_join(join_type, kind)))
+    assert got == eager_rows[f"{join_type}.{kind}"]
