@@ -458,6 +458,14 @@ def _run(args, cell, plan_modules, workdir, child, client_wrapper) -> dict:
                          "persistent_cache": stats["persistent_cache"]}
     if args.scale != 1.0:
         result["rehearsal_scale"] = args.scale
+    # last in the line: each number the verdict compared, beside its limit
+    result["compared"] = {
+        "answers": verdict["tasks_compared"],
+        "exact_mismatches": {"value": verdict["exact_mismatches"],
+                             "limit": verdict["exact_limit"]},
+        "max_double_rel": {"value": verdict["max_double_rel"],
+                           "limit": verdict["double_rel_limit"]},
+        "shape_errors": {"value": verdict["shape_errors"], "limit": 0}}
     return result
 
 
@@ -483,6 +491,9 @@ def main(argv=None) -> int:
         log(f"the run failed: {type(e).__name__}: {e}")
         return 1
     print(json.dumps(result), flush=True)
+    for name, num in result["compared"].items():
+        print(f"compared {name}: {json.dumps(num)}", file=sys.stderr,
+              flush=True)
     return 0
 
 

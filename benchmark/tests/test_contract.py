@@ -104,3 +104,50 @@ def test_metrics_cover_every_cell(bench):
             assert m["moves"] in mine
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert set(m.get("workloads", [])) <= cells
+
+
+#: the readers every cell runs, and what each cell reads besides: held
+#: by name, so that no edit of a `workloads` list or of a `moves` drops
+#: a reader from a cell unseen (a metric with no `workloads` list is
+#: read only where the end-to-end metric it moves is reported): 21 in
+#: every cell, so 22 / 21 / 28 in the three
+IN_EVERY_CELL = {
+    "compile.task_ms", "compile.warm_s", "compile.xla_in_window",
+    "convert.to_arrow_ms", "ops.device_wait_ms", "ops.dispatch_ms",
+    "ops.host_ms", "ops.program_calls", "ops.readbacks", "ops.sync_wait_ms",
+    "plan.decode_ms", "scan.convert_ms", "scan.decode_ms", "scan.encode_ms",
+    "scan.h2d_ms", "scan.h2d_transfers", "serve.cpu_share",
+    "serve.overhead_ms", "serve.queue_wait_ms", "serve.send_ms",
+    "serve.unattributed_ms"}
+READERS = {
+    "tpcds_sf1.star_join": (
+        {"fact_rows_per_s", "task_p50_ms", "setup_s"},
+        {"serve.task_p90_ms.thin"}),
+    "tpcds_sf1_smallfiles.star_join": (
+        {"fact_rows_per_s", "task_p50_ms", "task_p90_ms", "setup_s"},
+        set()),
+    "tpcds_sf1_mesh2x2.star_join_serial": (
+        {"fact_rows_per_s", "setup_s"},
+        {"serve.task_p50_ms.serial", "exchange.all_to_all_ms",
+         "exchange.mesh_bytes", "exchange.host_ms", "exchange.rounds",
+         "exchange.ici_share", "device.busy_min_share"}),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(READERS))
+def test_a_cell_reports_these_metrics_and_runs_these_readers(workload):
+    from harness.cell import Cell
+    end_to_end, own = READERS[workload]
+    cell = Cell(workload)
+    assert set(cell.end_to_end()) == end_to_end
+    assert set(cell.per_layer()) == IN_EVERY_CELL | own
+
+
+def test_a_reader_for_every_cell_moves_what_every_cell_reports(bench):
+    # ... cells added later included: it lists no cell, and the metric
+    # it moves has no list either
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in IN_EVERY_CELL:
+        assert "workloads" not in by_name[name], name
+        assert "workloads" not in e2e[by_name[name]["moves"]], name

@@ -45,7 +45,10 @@ def _result(proc):
     (MESH, 4)])
 def test_end_to_end_line(workload, devices):
     from harness.cell import Cell
-    res = _result(_run(workload, devices, trace=0))
+    proc = _run(workload, devices, trace=0)
+    res = _result(proc)
+    assert proc.stderr.strip().splitlines()[-1].startswith(
+        "compared shape_errors: ")
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] >= 4
     assert res["device"]["platform"] == "cpu"
@@ -56,6 +59,12 @@ def test_end_to_end_line(workload, devices):
     for m in res["metrics"].values():
         assert m["value"] > 0 and m["unit"]
     assert res["rehearsal_scale"] == 0.02
+    # each number compared beside its limit: last in the line, and the
+    # last lines of stderr
+    assert list(res)[-1] == "compared"
+    for name in ("exact_mismatches", "max_double_rel", "shape_errors"):
+        assert res["compared"][name]["value"] <= res["compared"][name]["limit"]
+    assert res["compared"]["answers"] == res["attempted"]
 
 
 def test_traced_line_has_the_layer_metrics():

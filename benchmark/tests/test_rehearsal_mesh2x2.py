@@ -18,6 +18,7 @@ CELL = "tpcds_sf1_mesh2x2.star_join_serial"
 SHIPPED = ("exchange.all_to_all_ms", "exchange.mesh_bytes")
 NEW = ("exchange.host_ms", "exchange.rounds", "exchange.ici_share",
        "device.busy_min_share")
+SERIAL_P50 = "serve.task_p50_ms.serial"
 #: what a CPU trace has nothing for: no device plane, so no all-to-all
 #: time and no busy seconds by chip
 NEED_DEVICE_PLANE = {"exchange.all_to_all_ms", "exchange.ici_share",
@@ -75,15 +76,20 @@ def test_benchmark_json_lists_the_cell_and_its_six_metrics():
     assert cell.config["engine"] == {"auron.mesh.enabled": True,
                                      "auron.mesh.devices": 4,
                                      "auron.max_live_programs": 0}
-    assert set(cell.end_to_end()) == {"fact_rows_per_s", "task_p50_ms",
-                                      "setup_s"}
+    # one client: latency is rows over the rate, so the rate judges the
+    # cell and the median stays as a per-layer reading
+    assert set(cell.end_to_end()) == {"fact_rows_per_s", "setup_s"}
     layer = cell.per_layer()
+    assert layer[SERIAL_P50]["workloads"] == [CELL]
+    assert layer[SERIAL_P50]["moves"] == "fact_rows_per_s"
+    assert layer[SERIAL_P50]["layer"] == "entry"
     for name in SHIPPED + NEW:
         assert layer[name]["workloads"] == [CELL]
         assert layer[name]["moves"] == "fact_rows_per_s"
     # no one-chip cell reads them
     for other in ("tpcds_sf1.star_join", "tpcds_sf1_smallfiles.star_join"):
-        assert not set(SHIPPED + NEW) & set(Cell(other).per_layer())
+        assert not {*SHIPPED, *NEW, SERIAL_P50} & set(Cell(other).per_layer())
+        assert "task_p50_ms" in Cell(other).end_to_end()
 
 
 def test_the_configuration_differs_from_the_shipped_one_only_as_said():
@@ -127,6 +133,20 @@ def test_reader_gives_none_or_a_number_where_something_is_missing(name):
         assert got is None or isinstance(got, (int, float))
 
 
+def test_the_serial_median_is_the_median_of_the_clients_latencies():
+    read = reader(SERIAL_P50)
+    tasks = [{"ok": True, "t_submit": 0.0, "t_done": 1.4},
+             {"ok": True, "t_submit": 1.4, "t_done": 3.1},
+             {"ok": True, "t_submit": 3.1, "t_done": 4.6},
+             {"ok": True, "t_submit": 4.6, "t_done": 6.2},
+             {"ok": False, "t_submit": 6.2, "t_done": 6.3}]
+    # 1400, 1700, 1500, 1600 ms: the failed task gives no latency
+    assert read({"tasks": tasks}) == pytest.approx(1550.0)
+    assert read({"tasks": tasks[:3]}) == pytest.approx(1500.0)
+    assert read({"tasks": []}) is None
+    assert read({"tasks": tasks[4:]}) is None
+
+
 def test_a_chip_without_a_plane_counts_as_idle():
     got = reader("device.busy_min_share")(
         ctx([frame()], tpu_trace(busy=(0.04,))))
@@ -166,3 +186,4 @@ def test_the_cell_rehearsed_traced_on_four_virtual_devices():
     assert res["metrics"]["exchange.rounds"]["value"] >= 1
     assert res["metrics"]["exchange.mesh_bytes"]["value"] > 0
     assert res["metrics"]["exchange.host_ms"]["value"] > 0
+    assert res["metrics"][SERIAL_P50]["value"] > 0
