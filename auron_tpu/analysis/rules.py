@@ -49,7 +49,7 @@ def _contains_call(node: ast.AST, suffixes: tuple) -> bool:
 # ---------------------------------------------------------------------------
 
 #: the sanctioned sync wrappers (obs/profile.py): waits routed through
-#: them are credited as device time at the moved sync points
+#: them are credited as device time
 _SANCTIONED = ("timed_get", "device_fence")
 
 #: call roots that mark a host-side value (skipped as candidates)

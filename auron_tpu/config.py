@@ -87,31 +87,12 @@ SCAN_BATCH_ROWS = _opt(
     "partition's actual row count bucket, so small files never pad to "
     "the full batch size. One flag for batch-size experiments.")
 
-# pipelined async execution (runtime/pipeline.py)
-PIPELINE_ENABLED = _opt(
-    "auron.pipeline.enabled", bool, True,
-    "Pipelined asynchronous execution (the [speed] overlap plane): the "
-    "file scans decode row-group N+1 on a bounded background worker "
-    "while the device computes batch N (auron.scan.prefetch_batches "
-    "deep, decoded bytes registered with the memory manager so depth "
-    "degrades under pressure), per-batch device syncs inside operator "
-    "timers and the profiler's program wrapper are skipped — XLA's "
-    "async dispatch queues batch N+1 while N's arrays are in flight — "
-    "and execution synchronizes only at operator boundaries that "
-    "semantically require it (sort collect, shuffle materialize, "
-    "to_arrow), where the wait is attributed to elapsed_device. Off "
-    "restores fully serial decode → dispatch → block per batch "
-    "(the differential baseline: pipelined and serial results are "
-    "bit-identical by construction — overlap never reorders batches). "
-    "PROCESS-GLOBAL by contract (resolved from get_config(), the "
-    "map-key-dedup precedent): the mode moves sync points across "
-    "planes that cannot see a session config (the profiler's program "
-    "wrapper), so per-Session overrides are not honored for it.")
+# prefetching scan (io/parquet.ScanPrefetcher)
 SCAN_PREFETCH_BATCHES = _opt(
     "auron.scan.prefetch_batches", int, 2,
     "Decoded-batch lookahead of the prefetching file scan (bounded "
     "queue depth between the background decode worker and the drive "
-    "loop) when auron.pipeline.enabled is on. The prefetcher registers "
+    "loop). The prefetcher registers "
     "its buffered decoded bytes with the memory manager and degrades "
     "to depth 1 while the pressure ladder's shrink rung is active. "
     "<= 1 keeps the decode worker but no lookahead beyond the batch "
@@ -136,7 +117,7 @@ MESH_ENABLED = _opt(
     "(errors.MeshUnavailable) demotes the remaining rounds to the host "
     "path and quarantines the chip (auron.mesh.quarantine) — the plane "
     "degrades, never the query. PROCESS-GLOBAL by contract (the device "
-    "set is process state, like auron.pipeline.enabled): resolved from "
+    "set is process state): resolved from "
     "get_config(), per-Session overrides are not honored. Default off; "
     "tests/bench force a virtual CPU mesh via "
     "--xla_force_host_platform_device_count.")
@@ -503,17 +484,17 @@ PROFILE_ENABLED = _opt(
     "Host/device time attribution (auron_tpu/obs/profile.py): every "
     "jitted-program invocation through the central registry "
     "(runtime/programs.py) is timed as dispatch (host python glue until "
-    "the async call returns) + device (block_until_ready wait), and "
+    "the async call returns), the device wait is timed where execution "
+    "synchronizes (device_fence at the materialization boundaries, "
+    "timed_get at the control-scalar readbacks), and "
     "per-operator timers classify the remaining wall into named host "
     "buckets (elapsed_host_{dispatch,convert,serde,iter,other}) "
     "alongside elapsed_device in the metric tree / EXPLAIN ANALYZE. "
     "Feeds the per-batch dispatch-overhead registry histograms and the "
     "served task's program-call count (cost_ledger.counts). Measured "
     "overhead < 2% (bench A/B, CPU); off reduces the hot-path cost to "
-    "one cached epoch compare per timer. Attribution requires the "
-    "per-call sync point, so auron.metrics.device_sync=false (the "
-    "maximum-throughput knob) disables the profiler too — profiling "
-    "never silently serializes a run that asked for async overlap.")
+    "one cached epoch compare per timer. Profiling adds no sync point: "
+    "it times the waits execution already makes.")
 PERF_GATE_TOLERANCE_PCT = _opt(
     "auron.perf_gate.tolerance_pct", float, 50.0,
     "Allowed q01 rows/s shortfall vs the checked-in per-platform "
@@ -700,12 +681,7 @@ LEDGER_ENABLED = _opt(
     "Overhead is gated < 2% by the perf-gate obs-fleet arm; off skips "
     "assembly entirely (no ledger on DONE, none retained).")
 
-# metrics / sinks
-METRICS_DEVICE_SYNC = _opt(
-    "auron.metrics.device_sync", bool, True,
-    "Block on kernel outputs inside per-operator timers so "
-    "elapsed_compute measures device compute, not async dispatch. "
-    "Costs pipelining overlap; disable for maximum throughput runs.")
+# sinks
 SINK_BUFFER_ROWS = _opt(
     "auron.sink.buffer_rows", int, 1 << 17,
     "Rows a file sink buffers before flushing a row group / dataset "

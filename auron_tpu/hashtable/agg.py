@@ -246,8 +246,8 @@ class HashAggState:
             th, tw, store, accs, auxs, n_new, overflow = kern(
                 self.th, self.tw, self.store, self.accs, self.auxs,
                 keys, contribs, live, ord_base)
-            # this readback is the per-batch sync point (pipelined mode
-            # attributes the wait as device time). NOTE the donation
+            # this readback is the per-batch sync point (the wait is
+            # attributed as device time). NOTE the donation
             # sweep deliberately skips the step/grow kernels: the
             # overflow-retry protocol re-runs them with the SAME state
             # and batch inputs, which donation would have invalidated.

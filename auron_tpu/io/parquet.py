@@ -9,9 +9,7 @@ on-ramp, deliberately kept off the device's critical path by the prefetching
 worker (``ScanPrefetcher``): while the device crunches batch N, a bounded
 background thread decodes and transfers batch N+1 (and beyond, up to
 ``auron.scan.prefetch_batches``), with the decoded bytes registered with the
-memory manager so lookahead degrades to 1 under pressure. With
-``auron.pipeline.enabled`` off the scan decodes inline on the query thread —
-the fully serial differential baseline.
+memory manager so lookahead degrades to 1 under pressure.
 """
 
 from __future__ import annotations
@@ -192,8 +190,8 @@ class ScanPrefetcher:
 
     def batches(self, io_time) -> Iterator[DeviceBatch]:
         """Drain in order. The dequeue wait is decode time the worker
-        could not hide — attributed to the ``convert`` host bucket like
-        the serial path's inline decode, and to ``auron:scan/wait``."""
+        could not hide — attributed to the ``convert`` host bucket and
+        to ``auron:scan/wait``."""
         while True:
             with timer(io_time, bucket="convert"), \
                     trace.layer_span("scan", "wait"):
@@ -362,22 +360,6 @@ class ParquetScanOp(PhysicalOp):
             with trace.layer_span("scan", "encode"):
                 widths = self._widths_for(rb)
             return to_device(rb, capacity=cap, string_widths=widths)[0]
-
-        from auron_tpu.runtime import pipeline
-        if not pipeline.enabled():
-            # serial baseline: decode → transfer inline on the query
-            # thread (the differential twin the pipelined==serial
-            # battery compares against)
-            def stream():
-                for rb in host_batches():
-                    ctx.checkpoint("scan.decode")
-                    # the timer closes before the yield: held open it
-                    # would charge the consumer's time to the scan
-                    with timer(io_time, bucket="convert"):
-                        batch = convert(rb)
-                    yield batch
-
-            return count_output(stream(), metrics, timed=True)
 
         from auron_tpu import config as cfg
         depth = max(1, int(ctx.conf.get(cfg.SCAN_PREFETCH_BATCHES)))

@@ -13,11 +13,13 @@ This module splits every operator's wall into:
 - ``elapsed_device`` — time spent waiting on the accelerator. The
   central program registry (runtime/programs.py) wraps every jitted
   program it hands out; each invocation times the async dispatch
-  (call → return) and then ``block_until_ready`` on the outputs
-  (return → results materialized). Kernels that bypass the registry
-  (the dense grouped-agg module jits) still get a split through the
-  ``timer.track`` fallback: the tracked-value registration marks the
-  dispatch/device boundary and the timer's exit sync bounds the wait.
+  (call → return) and leaves the arrays in flight. The wait is timed
+  where execution synchronizes: ``device_fence`` at the boundaries
+  that need materialized results (to_arrow, sort collect, shuffle
+  materialize) and ``timed_get`` at the control-scalar readbacks.
+  Kernels that bypass the registry (the dense grouped-agg module jits)
+  still get a split through the ``timer.track`` fallback: the
+  tracked-value registration marks the dispatch/device boundary.
 - ``elapsed_host_*`` — named host buckets for the remainder:
   ``dispatch`` (python glue until the async call returns: arg prep,
   cache lookups, jax dispatch), ``convert`` (arrow↔device transfers:
@@ -80,19 +82,7 @@ def enabled() -> bool:
     if epoch == cfg.config_epoch() and val is not None:
         return val
     epoch = cfg.config_epoch()
-    conf = cfg.get_config()
-    # serial mode's attribution NEEDS the per-call sync point
-    # (block_until_ready is what separates device wait from host glue),
-    # so it must never override auron.metrics.device_sync=False — the
-    # legacy maximum-throughput knob that trades metrics honesty for
-    # async-dispatch overlap. Pipelined mode (auron.pipeline.enabled)
-    # times asynchronously instead — dispatch per call, device at the
-    # moved sync points (device_fence/timed_get) — so it keeps the
-    # profiler on WITHOUT serializing anything: there is no per-call
-    # block left to defeat the overlap.
-    val = bool(conf.get(cfg.PROFILE_ENABLED)
-               and (conf.get(cfg.METRICS_DEVICE_SYNC)
-                    or conf.get(cfg.PIPELINE_ENABLED)))
+    val = bool(cfg.get_config().get(cfg.PROFILE_ENABLED))
     _CACHED = (epoch, val)
     return val
 
@@ -251,7 +241,7 @@ def on_call(dispatch_ns: int, device_ns: int, site: str) -> None:
 
 
 class ProfiledProgram:
-    """Transparent callable proxy timing dispatch + device wait per
+    """Transparent callable proxy timing the dispatch of each
     invocation. Attribute access (``cache_info``-style introspection)
     passes through to the wrapped program."""
 
@@ -265,19 +255,11 @@ class ProfiledProgram:
         import time
         t0 = time.perf_counter_ns()
         out = self._fn(*args, **kwargs)
-        t1 = time.perf_counter_ns()
-        from auron_tpu.runtime import pipeline
-        if pipeline.enabled():
-            # pipelined mode: the arrays stay in flight — batch N+1
-            # dispatches while N computes. The device wait is measured
-            # where execution actually synchronizes (device_fence /
-            # timed_get at the semantic boundaries), so attribution
-            # still sums to wall; per-call we record dispatch only.
-            on_call(t1 - t0, 0, self._site)
-        else:
-            with _trace.readback_span():
-                _block(out)
-            on_call(t1 - t0, time.perf_counter_ns() - t1, self._site)
+        # the arrays stay in flight — batch N+1 dispatches while N
+        # computes. The device wait is measured where execution
+        # synchronizes (device_fence / timed_get), so attribution still
+        # sums to wall; per call we record dispatch only.
+        on_call(time.perf_counter_ns() - t0, 0, self._site)
         return out
 
     def __getattr__(self, name):
@@ -294,27 +276,18 @@ def wrap_program(value, site: str):
 
 
 # ---------------------------------------------------------------------------
-# moved sync points (pipelined mode — runtime/pipeline.py)
+# sync points: where execution waits for the device
 # ---------------------------------------------------------------------------
 
-def add_device(ns: int) -> None:
-    """Credit ``ns`` device-wait nanoseconds to the innermost open
-    frame (no-op without one) — the async twin of ``on_call``'s device
-    half for waits measured at a moved sync point."""
-    st = getattr(_TLS, "stack", None)
-    if st:
-        st[-1].device += ns
-
-
 def device_fence(value, sink=None) -> int:
-    """Pipelined mode's materialization point: block until every array
+    """The materialization point: block until every array
     leaf of ``value`` is ready and attribute the wait as device time —
     to the innermost open frame when one is recording, else to ``sink``
     (a MetricsSet) when given. Returns the wait in nanoseconds.
 
     Call this ONLY where execution semantically requires materialized
     results (the to_arrow export, sort collect, shuffle materialize):
-    the whole point of pipelining is that nothing else waits."""
+    nothing else waits, so batch N+1 dispatches while N computes."""
     import time
     t0 = time.perf_counter_ns()
     with _trace.readback_span():
@@ -339,9 +312,9 @@ def timed_get(values):
     """``jax.device_get`` with the wait credited to the innermost open
     frame's device bucket — for the per-batch control-scalar readbacks
     (agg group counts, hashtable overflow flags, fused limit budgets)
-    that ARE real sync points: under pipelined execution they carry the
-    device wait the per-call block used to absorb, and attributing them
-    as device keeps the host buckets honest."""
+    that ARE real sync points: they carry the device wait of the
+    programs dispatched before them, and attributing them as device
+    keeps the host buckets honest."""
     import time
 
     import jax

@@ -1497,10 +1497,9 @@ class AggOp(PhysicalOp):
                 bk, ba, bh, bn, needed = kern(tuple(keys), tuple(accs),
                                               live)
                 # one batched round trip for every control scalar — each
-                # separate int() readback is its own device→host sync,
-                # and the readback doubles as the device sync
-                # (under pipelining it IS the sync point: attributed as
-                # device wait, obs/profile.timed_get)
+                # separate int() readback is its own device→host sync.
+                # The readback IS the sync point: attributed as device
+                # wait, obs/profile.timed_get
                 from auron_tpu.obs import profile as _profile
                 ng, needed_h = _profile.timed_get([bn, needed])
                 ng = int(ng)
@@ -2131,7 +2130,7 @@ class AggOp(PhysicalOp):
 
         for batch in self.child.execute(partition, ctx):
             ctx.check_cancelled()
-            with timer(elapsed, ctx.device_sync) as t:
+            with timer(elapsed) as t:
                 live = batch.row_mask()
                 kv = evaluate(self.group_exprs[0], batch, in_schema, ectx)
                 kdata = kv.col.data.astype(jnp.int64)
@@ -2173,7 +2172,7 @@ class AggOp(PhysicalOp):
         # ONE batched readback for every control scalar (each separate
         # int() is its own device→host sync); routed
         # through the profiler so the wait books as device time at this
-        # moved sync point, like the grow/overflow readbacks above
+        # sync point, like the grow/overflow readbacks above
         ng, mx, mn, nulls, nrows = _profile.timed_get(
             [ng_dev, max_k, min_k, saw_null, total_rows])
         ng = int(ng)

@@ -74,30 +74,12 @@ class MetricsSet:
         return {k: m.value for k, m in self._metrics.items()}
 
 
-def _device_sync(value) -> None:
-    """Block until a kernel result is materialized on device. All
-    outputs of an executable complete together, so the representative
-    wait is on the LAST two leaves: a tracked value may mix pass-through
-    inputs with fresh outputs (e.g. a batch whose first columns are
-    inputs and last column is the computed one), and the tail leaves are
-    the freshly computed ones in every tracked shape this engine
-    produces. A device error surfaces HERE, at the sync point, and
-    propagates."""
-    import jax
-    leaves = [l for l in jax.tree_util.tree_leaves(value)
-              if hasattr(l, "block_until_ready")]
-    with _trace.readback_span():
-        # graft: disable=GL001 -- this IS the serial-mode sanctioned sync helper (timer.track attributes it)
-        jax.block_until_ready(leaves[-2:])
-
-
 class timer:
     """Context manager adding wall nanoseconds to a metric
-    (reference: common/timer_helper.rs). ``track(x)`` registers kernel
-    outputs to block on before the clock stops, so elapsed_compute means
-    device compute rather than async dispatch (round-3 honest metrics;
-    gate: auron.metrics.device_sync, resolved once per ExecContext and
-    passed as ``sync``).
+    (reference: common/timer_helper.rs). It never waits for the device:
+    kernels stay in flight past the scope, and the device wait is timed
+    where execution synchronizes (obs/profile ``device_fence`` /
+    ``timed_get``).
 
     When the profiler is on (``auron.profile.enabled``, obs/profile.py)
     and the metric belongs to a MetricsSet, the scope additionally opens
@@ -114,21 +96,17 @@ class timer:
     self time — less its children's spans and its own readbacks — is
     the operator's exclusive host time in the task's ledger."""
 
-    __slots__ = ("metric", "t0", "_tracked", "sync", "_frame",
-                 "_bucket", "_t_track", "_span")
+    __slots__ = ("metric", "t0", "_frame", "_bucket", "_t_track", "_span")
 
-    def __init__(self, metric: Metric, sync: bool = True,
-                 bucket: "Optional[str]" = None):
+    def __init__(self, metric: Metric, bucket: "Optional[str]" = None):
         self.metric = metric
-        self.sync = sync
-        self._tracked = None
         self._bucket = bucket
         self._frame = None
         self._t_track = 0
 
     def track(self, value):
-        """Register a kernel result to sync on at exit; returns it."""
-        self._tracked = value
+        """Mark the dispatch→device boundary of the scope's profile
+        frame at a kernel result; returns it."""
         if self._frame is not None:
             self._t_track = time.perf_counter_ns()
         return value
@@ -146,9 +124,6 @@ class timer:
         return self
 
     def __exit__(self, *exc):
-        if self._tracked is not None and exc[0] is None and self.sync:
-            _device_sync(self._tracked)
-            self._tracked = None
         wall = time.perf_counter_ns() - self.t0
         self.metric.add(wall)
         if self._span is not None:
@@ -285,27 +260,9 @@ class ExecContext:
         return self.config
 
     @property
-    def device_sync(self) -> bool:
-        """Should per-operator timers block on kernel outputs? Resolved
-        once per context (timers are on the hot path; see timer.track):
-        auron.metrics.device_sync, overridden to False by pipelined
-        execution (auron.pipeline.enabled) — under pipelining the
-        per-batch sync points move to the semantic materialization
-        boundaries (runtime/pipeline.py), and a timer that blocked per
-        batch would serialize exactly the overlap the mode exists to
-        create."""
-        cached = getattr(self, "_device_sync", None)
-        if cached is None:
-            from auron_tpu import config as cfg
-            cached = (self.conf.get(cfg.METRICS_DEVICE_SYNC)
-                      and not self.pipelined)
-            self._device_sync = cached
-        return cached
-
-    @property
     def mesh_plane(self):
         """The process's SPMD mesh plane (parallel/mesh.current_plane),
-        resolved once per context like ``pipelined`` — None when
+        resolved once per context — None when
         ``auron.mesh.enabled`` is off or fewer than 2 devices exist.
         PROCESS-GLOBAL by the knob's contract (the device set is
         process state)."""
@@ -315,19 +272,6 @@ class ExecContext:
             cached = (mesh.current_plane(),)
             self._mesh_plane = cached
         return cached[0]
-
-    @property
-    def pipelined(self) -> bool:
-        """auron.pipeline.enabled resolved once per context — from the
-        PROCESS-GLOBAL config by the knob's contract (sync points must
-        move consistently across planes that cannot see a session
-        config; see runtime/pipeline.enabled)."""
-        cached = getattr(self, "_pipelined", None)
-        if cached is None:
-            from auron_tpu.runtime import pipeline
-            cached = pipeline.enabled()
-            self._pipelined = cached
-        return cached
 
     def metrics_for(self, op, suffix: str = "") -> MetricsSet:
         """The metric set for ``op``.

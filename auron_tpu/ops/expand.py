@@ -83,7 +83,7 @@ class ExpandOp(PhysicalOp):
             for batch in self.child.execute(partition, ctx):
                 for proj in self.projections:
                     kern = _project_kernel(proj, in_schema, batch.capacity)
-                    with timer(elapsed, sync=ctx.device_sync) as t:
+                    with timer(elapsed) as t:
                         out = t.track(kern(batch, jnp.int32(partition),
                                            jnp.int64(row_off)))
                     yield out

@@ -266,7 +266,6 @@ class FusedStageOp(PhysicalOp):
         fragments, frag_keys = self.fragment_pipeline()
         limit_slots = [i for i, f in enumerate(fragments) if f.is_limit]
         init = [f.init_carry for f in fragments]
-        _sync = ctx.device_sync
         # donation sweep: an owned input batch is dead once the chain
         # gathered/projected it into fresh arrays — donate it to XLA
         # (no-op on CPU; pass-through chains alias their input in the
@@ -285,7 +284,7 @@ class FusedStageOp(PhysicalOp):
                                             batch.capacity, fragments,
                                             donate)
                 (built_c if built else hit_c).add(1)
-                with timer(elapsed, sync=_sync) as t:
+                with timer(elapsed) as t:
                     outs, carries = t.track(
                         kern(batch, jnp.int32(partition), carries))
                     if limit_slots:

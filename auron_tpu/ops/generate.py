@@ -199,7 +199,7 @@ class GenerateOp(PhysicalOp):
                         self.generator, tuple(self.required_child_output),
                         self.kind == "posexplode", self.outer,
                         in_schema, batch.capacity)
-                    with timer(elapsed, sync=ctx.device_sync) as t:
+                    with timer(elapsed) as t:
                         out = t.track(kern(batch))
                     yield out
                 else:

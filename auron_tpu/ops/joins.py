@@ -500,7 +500,7 @@ class HashJoinOp(PhysicalOp):
                     for probe in self.probe.execute(partition, ctx):
                         yield from self._probe_one(probe, side, probe_schema,
                                                    build_schema, elapsed,
-                                                   ctx.device_sync, match)
+                                                   match)
 
                 if self.join_type in ("right", "full"):
                     yield _unmatched_build_program(
@@ -571,7 +571,6 @@ class HashJoinOp(PhysicalOp):
         f_batches = fmetrics.counter("output_batches")
         fmetrics.counter("probe_search_folded").add(1)
         in_schema = input_op.schema()
-        _sync = ctx.device_sync
         # donation sweep: the raw probe batch is dead once the chain
         # produced the transformed batch — donate it when owned
         from auron_tpu.ops.base import yields_owned_batches
@@ -587,25 +586,25 @@ class HashJoinOp(PhysicalOp):
                 raw.capacity, side.capacity, fragments,
                 side.index_kind, side.rounds, donate)
             (built_c if built else hit_c).add(1)
-            with timer(f_elapsed, sync=_sync) as t:
+            with timer(f_elapsed) as t:
                 probe, lo, counts, total, carries = t.track(
                     kern(raw, np.int32(partition), carries,
                          *side.index_args()))
             f_rows.add(int(probe.num_rows))
             f_batches.add(1)
             yield from self._probe_one(probe, side, probe_schema,
-                                       build_schema, elapsed, _sync, match,
+                                       build_schema, elapsed, match,
                                        pre=(lo, counts, total))
 
     def _probe_one(self, probe: DeviceBatch, side: _BuildSide, probe_schema,
-                   build_schema, elapsed, _sync: bool, match: "_MatchState",
+                   build_schema, elapsed, match: "_MatchState",
                    pre=None):
         cap = probe.capacity
         if pre is None:
             kern = _probe_count_kernel(self.probe_keys, probe_schema, cap,
                                        side.capacity, side.index_kind,
                                        side.rounds)
-            with timer(elapsed, sync=_sync) as t:
+            with timer(elapsed) as t:
                 _h, lo, counts, total = t.track(
                     kern(probe, *side.index_args()))
         else:   # the fused probe program already ran the candidate search
@@ -626,7 +625,7 @@ class HashJoinOp(PhysicalOp):
             build_schema, bucket_rows(max(total_i, 1)), cap, side.capacity,
             match.fragments)
         match.count(built)
-        with timer(elapsed, sync=_sync) as t:
+        with timer(elapsed) as t:
             outs, side.matched, match.carries = t.track(kern(
                 probe, side.batch, side.keys, lo, counts, side.matched,
                 np.int32(match.partition), match.carries))

@@ -117,13 +117,12 @@ class ProjectOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         in_schema = self.child.schema()
-        _sync = ctx.device_sync
 
         def stream():
             row_off = 0
             for batch in self.child.execute(partition, ctx):
                 kern = _project_kernel(self.exprs, in_schema, batch.capacity)
-                with timer(elapsed, sync=_sync) as t:
+                with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
                 row_off += int(batch.num_rows)
@@ -172,13 +171,12 @@ class FilterOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         in_schema = self.child.schema()
-        _sync = ctx.device_sync
 
         def stream():
             row_off = 0
             for batch in self.child.execute(partition, ctx):
                 kern = _filter_kernel(self.predicates, in_schema, batch.capacity)
-                with timer(elapsed, sync=_sync) as t:
+                with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
                 row_off += int(batch.num_rows)
@@ -241,14 +239,13 @@ class FilterProjectOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         in_schema = self.child.schema()
-        _sync = ctx.device_sync
 
         def stream():
             row_off = 0
             for batch in self.child.execute(partition, ctx):
                 kern = _filter_project_kernel(self.predicates, self.exprs,
                                               in_schema, batch.capacity)
-                with timer(elapsed, sync=_sync) as t:
+                with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
                 row_off += int(batch.num_rows)

@@ -442,7 +442,6 @@ class SortMergeJoinOp(PhysicalOp):
             null_left = tuple(_null_column(f, cap) for f in left_schema)
             return DeviceBatch(null_left + rows.columns, rows.num_rows)
 
-        _sync = ctx.device_sync
 
         def stream():
             right_iter = self.build.execute(partition, ctx)
@@ -457,7 +456,7 @@ class SortMergeJoinOp(PhysicalOp):
                         continue
                     kern = _key_words_kernel(self.probe_keys, left_schema,
                                              left.capacity)
-                    with timer(elapsed, sync=_sync) as t:
+                    with timer(elapsed) as t:
                         q_per_key, q_dead = t.track(kern(left))
                     lmax = _host_row(q_per_key, nL - 1)
                     # pull right batches until the window covers lmax
@@ -473,7 +472,7 @@ class SortMergeJoinOp(PhysicalOp):
                             continue
                         rkern = _key_words_kernel(self.build_keys,
                                                   right_schema, rb.capacity)
-                        with timer(elapsed, sync=_sync) as t:
+                        with timer(elapsed) as t:
                             r_per_key, _ = t.track(rkern(rb))
                         last_right_max = _host_row(r_per_key, nR - 1)
                         win.append(rb, r_per_key)
@@ -483,8 +482,7 @@ class SortMergeJoinOp(PhysicalOp):
                         for out in self._probe_one(left, nL, q_per_key,
                                                    q_dead, win, elapsed,
                                                    track, left_outer,
-                                                   null_extended_right,
-                                                   _sync):
+                                                   null_extended_right):
                             yield out
                     finally:
                         win.unpin()
@@ -508,7 +506,7 @@ class SortMergeJoinOp(PhysicalOp):
 
     def _probe_one(self, left: DeviceBatch, nL: int, q_per_key, q_dead,
                    win: _MergeWindow, elapsed, track: bool, left_outer: bool,
-                   null_extended_right, _sync: bool = True):
+                   null_extended_right):
         jt = self.join_type
         cap = left.capacity
 
@@ -528,14 +526,14 @@ class SortMergeJoinOp(PhysicalOp):
 
         pkern = _probe_kernel(int(win_words.shape[1]), win_cap, cap,
                               left_outer)
-        with timer(elapsed, sync=_sync) as t:
+        with timer(elapsed) as t:
             lo, counts, emit, total = t.track(pkern(win_words, win.n, q_words,
                                                     q_dead, left.num_rows))
         total_i = int(total)
 
         if jt in ("semi", "anti", "existence"):
             has = counts > 0
-            with timer(elapsed, sync=_sync) as t:
+            with timer(elapsed) as t:
                 if jt == "semi":
                     out = compact(left, has)
                 elif jt == "anti":
@@ -549,7 +547,7 @@ class SortMergeJoinOp(PhysicalOp):
         elif total_i > 0:
             out_cap = bucket_rows(total_i)
             expand = _expand_kernel(out_cap, cap)
-            with timer(elapsed, sync=_sync) as t:
+            with timer(elapsed) as t:
                 left_idx, win_idx, real, tot = expand(lo, counts, emit)
                 out = t.track(_gather_pairs(left, win.batch, left_idx,
                                             win_idx, real, tot))

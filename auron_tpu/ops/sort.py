@@ -276,9 +276,9 @@ class _SortSpillConsumer(BufferedSpillConsumer):
                                        merged.capacity,
                                        _sort_donate(batches, self.op.child))
         run, words = kern(merged)
-        # the sort-collect spill's semantic sync point: under pipelined
-        # execution this readback carries the device wait (booked as
-        # device when a timer frame is open, obs/profile.timed_get)
+        # the sort-collect spill's semantic sync point: this readback
+        # carries the device wait (booked as device when a timer frame
+        # is open, obs/profile.timed_get)
         from auron_tpu.obs import profile as _profile
         n = int(_profile.timed_get(run.num_rows))
         host = batch_to_host(run, n)
@@ -328,7 +328,6 @@ class SortOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         in_schema = self.child.schema()
-        _sync = ctx.device_sync
         mem = ctx.mem_manager
         spillable = mem is not None and getattr(mem, "spill_manager", None) is not None
 
@@ -336,7 +335,7 @@ class SortOp(PhysicalOp):
             if not batches:
                 return
             donate = _sort_donate(batches, self.child)
-            with timer(elapsed, sync=_sync) as t:
+            with timer(elapsed) as t:
                 merged = _concat_all(batches) if len(batches) > 1 else batches[0]
                 kern = _sort_kernel(self.sort_exprs, in_schema,
                                     merged.capacity, donate)

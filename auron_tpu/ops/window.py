@@ -582,13 +582,12 @@ class WindowOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         elapsed = metrics.counter("elapsed_compute")
         in_schema = self.child.schema()
-        _sync = ctx.device_sync
 
         def stream():
             batches = list(self.child.execute(partition, ctx))
             if not batches:
                 return
-            with timer(elapsed, sync=_sync) as t:
+            with timer(elapsed) as t:
                 merged = _concat_all(batches) if len(batches) > 1 else batches[0]
                 kern = _window_kernel(self.partition_by, self.order_by,
                                       self.functions, in_schema,

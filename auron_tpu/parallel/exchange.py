@@ -772,7 +772,7 @@ class ShuffleExchangeOp(PhysicalOp):
                         with guard:
                             # the mesh fault domain's per-round site
                             mex.round_fault_check(ctx)
-                            with timer(write_time, sync=False):
+                            with timer(write_time):
                                 with trace.layer_span("exchange",
                                                       "mesh_stack"):
                                     cols, num_rows, cap = \
@@ -986,7 +986,6 @@ class ShuffleExchangeOp(PhysicalOp):
         host_rows = 0
         comb_in_total, comb_out_total, comb_batches = comb_totals
         pending_by_map = dict(pending)
-        _sync = ctx.device_sync
         from auron_tpu.obs import profile as _profile
 
         def route_batch(in_p: int, batch: DeviceBatch, carries):
@@ -997,7 +996,7 @@ class ShuffleExchangeOp(PhysicalOp):
             # the mesh program had one folded — rides along), entry
             # tagged with its source map so the combined read path can
             # interleave map-major
-            with timer(write_time, sync=_sync) as t:
+            with timer(write_time) as t:
                 if use_fused:
                     kern, _built = _fused_split_program(
                         frag_keys, ("hash", part_exprs), in_schema,
@@ -1093,7 +1092,6 @@ class ShuffleExchangeOp(PhysicalOp):
         from auron_tpu import config as cfg
         schema = self.child.schema()
         n_out = self.num_partitions
-        _sync = ctx.device_sync
 
         part_sig = _split_signature(self.partitioning)
         fold = self._fold_spec() \
@@ -1135,7 +1133,7 @@ class ShuffleExchangeOp(PhysicalOp):
             # count BEFORE the call (afterwards the donated leaves are
             # poisoned)
             n_in = int(batch.num_rows) if donate else None
-            with timer(write_time, sync=_sync) as t:
+            with timer(write_time) as t:
                 if isinstance(partitioning, RoundRobinPartitioning):
                     part = RoundRobinPartitioning(n_out, row_offset)
                     pids = part.partition_ids(batch, schema)
@@ -1145,7 +1143,7 @@ class ShuffleExchangeOp(PhysicalOp):
                 sorted_batch, counts = t.track(kern(batch, pids))
                 # the counts readback is the shuffle materialize's
                 # semantic sync point: read it inside the timer frame so
-                # pipelined mode books the wait as device, not serde
+                # the wait is booked as device, not serde
                 from auron_tpu.obs import profile as _profile
                 counts_h = np.asarray(_profile.timed_get(counts))
             row_offset += n_in if donate else int(batch.num_rows)
@@ -1216,7 +1214,6 @@ class ShuffleExchangeOp(PhysicalOp):
         (and its RSS spill frames) are per-batch GROUPS, not rows."""
         n_out = self.num_partitions
         out_schema = self.child.schema()
-        _sync = ctx.device_sync
         kmetrics = ctx.metrics_for("kernels")
         built_c = kmetrics.counter("fused_split_programs_built")
         hit_c = kmetrics.counter("fused_split_program_hits")
@@ -1260,7 +1257,7 @@ class ShuffleExchangeOp(PhysicalOp):
                     combine, combine_sig)
                 (built_c if built else hit_c).add(1)
                 t0v = f_elapsed.value
-                with timer(f_elapsed, sync=_sync) as t:
+                with timer(f_elapsed) as t:
                     from auron_tpu.obs import profile as _profile
                     if combine is not None:
                         sorted_batch, counts, carries, comb_in = t.track(
@@ -1458,7 +1455,6 @@ class RssShuffleExchangeOp(PhysicalOp):
         from auron_tpu.obs import trace
         metrics = ctx.metrics_for(self)
         write_time = metrics.counter("shuffle_write_total_time")
-        _sync = ctx.device_sync
         n_out = self.num_partitions
         schema = self.child.schema()
         codec_level = ctx.conf.get(cfg.SPILL_CODEC_LEVEL)
@@ -1480,7 +1476,7 @@ class RssShuffleExchangeOp(PhysicalOp):
                 # left behind) and the heartbeat shows write progress
                 ctx.checkpoint("rss.map_write")
                 n_in = int(batch.num_rows) if donate else None
-                with timer(write_time, sync=_sync) as t:
+                with timer(write_time) as t:
                     if isinstance(partitioning, RoundRobinPartitioning):
                         part = RoundRobinPartitioning(n_out, row_offset)
                         pids = part.partition_ids(batch, schema)

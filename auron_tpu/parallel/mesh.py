@@ -7,10 +7,9 @@ them, and WHO may occupy the mesh right now:
 
 - ``current_plane()`` resolves the ``auron.mesh.*`` knobs into one
   process-wide :class:`MeshPlane` (the device set is process state, so
-  the plane is process-global by contract, like
-  ``auron.pipeline.enabled``). The plane survives unrelated config
-  flips: it is rebuilt only when its OWN parameters change, because it
-  owns live scheduling state (the gang lock below).
+  the plane is process-global by contract). The plane survives
+  unrelated config flips: it is rebuilt only when its OWN parameters
+  change, because it owns live scheduling state (the gang lock below).
 - Per-buffer replicate-vs-shard decisions (:func:`buffer_spec`, the
   SNIPPETS.md [2]/[3] pattern): scan batches and shuffle entries shard
   on the batch dim (``PartitionSpec(axis)``), broadcast relations and

@@ -114,9 +114,9 @@ def stage_exchange_program(mesh: Mesh, axis: str, n_dev: int,
     The program NEVER donates its inputs: a bucket overflowing the row
     quota triggers the one-shot host-side re-run at the exact needed
     pow2 quota (the ``exchange_device_batches`` contract), and a donated
-    input would be poisoned for that re-run — the donate sweep from the
-    pipelined-execution work must not reach across the exchange
-    (``yields_owned_batches`` notwithstanding).
+    input would be poisoned for that re-run — the donate sweep must
+    not reach across the exchange (``yields_owned_batches``
+    notwithstanding).
 
     ``combine`` (ops/agg.AggOp.build_combine_stage, keyed by
     ``combine_sig``) is the map-side combine fold: each shard merges its

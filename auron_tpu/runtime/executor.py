@@ -24,6 +24,42 @@ from auron_tpu.ops.base import ExecContext, PhysicalOp
 
 logger = logging.getLogger("auron_tpu")
 
+_SENTINEL = object()
+
+
+def lookahead(it: Iterator, depth: int = 1) -> Iterator:
+    """Double-buffered drive: pull item N+1 from ``it`` BEFORE yielding
+    item N, so the producer's async work (kernel dispatch, prefetch
+    refill) for the next batch is already queued while the consumer
+    blocks on the current one (host materialization, sink writes).
+
+    Order is preserved exactly — this is a window, not a reorder. A
+    producer exception surfaces on the pull that raised it, which is up
+    to ``depth`` items earlier than plain iteration would have surfaced
+    it; all-or-nothing consumers (collect) can't tell the difference.
+    ``close()`` propagates to the inner iterator so cancellation
+    unwinds generators exactly as plain iteration does."""
+    if depth <= 0:
+        yield from it
+        return
+    it = iter(it)
+    window: list = []
+    try:
+        for _ in range(depth):
+            item = next(it, _SENTINEL)
+            if item is _SENTINEL:
+                break
+            window.append(item)
+        while window:
+            nxt = next(it, _SENTINEL)
+            yield window.pop(0)
+            if nxt is not _SENTINEL:
+                window.append(nxt)
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+
 
 @dataclass
 class TaskDefinition:
@@ -188,15 +224,13 @@ class ExecutionRuntime:
     def arrow_batches(self) -> Iterator[pa.RecordBatch]:
         """Host materialization (the FFI export boundary of the reference).
 
-        Under pipelined execution (auron.pipeline.enabled) the drive is
-        double-buffered: batch N+1 is pulled from the operator chain —
-        dispatching its kernels asynchronously and refilling the scan
-        prefetcher — BEFORE batch N materializes to Arrow, so the
-        device computes N+1 while the host converts N. to_arrow is the
-        semantic sync point; the wait for N's in-flight arrays is
-        fenced explicitly there and attributed to the root node's
-        ``elapsed_device`` (async-aware timing: the sync moved, the
-        attribution still sums to wall).
+        The drive is double-buffered (``lookahead``): batch N+1 is
+        pulled from the operator chain — dispatching its kernels
+        asynchronously and refilling the scan prefetcher — BEFORE batch
+        N materializes to Arrow, so the device computes N+1 while the
+        host converts N. to_arrow is the semantic sync point; the wait
+        for N's in-flight arrays is fenced explicitly there and
+        attributed to the root node's ``elapsed_device``.
 
         The device→host export runs jitted gather/concat programs, so
         XLA's ambiguous RuntimeErrors surface here exactly as they do in
@@ -208,19 +242,14 @@ class ExecutionRuntime:
         from auron_tpu.obs import trace
         schema = self.plan.schema()
         profiling = _profile.enabled()
-        # the device→host materialization is pure arrow↔jax conversion:
-        # attributed to the root plan node's "convert" host bucket
-        convert_c = (self.ctx.metrics_for(self.plan)
-                     .counter("elapsed_host_convert")
+        # the root plan node's metrics take what happens outside any
+        # operator's timer: the fence's device wait, and the device→host
+        # materialization (pure arrow↔jax conversion) as the "convert"
+        # host bucket
+        fence_sink = self.ctx.metrics_for(self.plan) if profiling else None
+        convert_c = (fence_sink.counter("elapsed_host_convert")
                      if profiling else None)
-        source = self.batches()
-        pipelined = self.ctx.pipelined
-        if pipelined:
-            from auron_tpu.runtime import pipeline
-            source = pipeline.lookahead(source, depth=1)
-        fence_sink = (self.ctx.metrics_for(self.plan)
-                      if (pipelined and profiling) else None)
-        for batch in source:
+        for batch in lookahead(self.batches(), depth=1):
             rb = None
             # the span closes before the yield: the consumer's time is
             # not this layer's

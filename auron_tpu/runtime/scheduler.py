@@ -173,7 +173,7 @@ class QueryScheduler:
         self.mem_manager = mem_manager
         #: knob source: the owning Session's config when given (its
         #: auron.sched.* overrides are honored — scheduler state is
-        #: per-Session, unlike the process-global pipeline contract),
+        #: per-Session),
         #: else the process config (the serving process)
         self.config = config
         # RLock-backed: admission helpers (_reject, _retry_after_s) run

@@ -196,17 +196,30 @@ class _DeadLeaf:
         raise RuntimeError("device halted")
 
 
-def test_device_sync_propagates_device_errors():
-    from auron_tpu.ops import base
-    with pytest.raises(RuntimeError, match="device halted"):
-        base._device_sync({"out": _DeadLeaf()})
-    base._device_sync({"nothing": "to wait on"})   # no array leaves
-
-
 def test_profile_block_propagates_device_errors():
     from auron_tpu.obs import profile
     with pytest.raises(RuntimeError, match="device halted"):
         profile._block([_DeadLeaf()])
+
+
+def test_a_profiled_program_call_leaves_its_outputs_in_flight():
+    """The program wrapper times the dispatch and hands the outputs
+    back unwaited: a leaf whose wait raises passes through the call and
+    the call books no device time — the error belongs to the sync point
+    above (``device_fence``), where it surfaces."""
+    from auron_tpu.obs import profile
+    leaf = _DeadLeaf()
+    prog = profile.ProfiledProgram(lambda x: {"out": x}, "test.faults.site")
+    frame = profile.push_frame()
+    assert frame is not None
+    try:
+        assert prog(leaf)["out"] is leaf
+        assert (frame.calls, frame.device) == (1, 0)
+        assert frame.dispatch > 0
+        with pytest.raises(RuntimeError, match="device halted"):
+            profile.device_fence({"out": leaf})
+    finally:
+        profile._stack().remove(frame)
 
 
 def test_default_budget_needs_the_accelerator_to_report_its_memory(

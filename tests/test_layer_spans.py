@@ -297,6 +297,63 @@ def test_a_list_of_strings_column_is_counted_as_a_python_loop():
     assert counts["encode_pyloop_values"] == len(rows)
 
 
+@pytest.mark.parametrize("fmt", ["parquet", "orc"])
+def test_a_file_scan_always_runs_on_the_prefetch_worker(fmt, tmp_path):
+    """There is one scan: decode, encode and transfer happen on the
+    prefetch worker (``scan_worker_s``, beside the task thread) and the
+    task thread books only its wait for them."""
+    import pyarrow as pa
+    from auron_tpu.io.orc import OrcScanOp
+    from auron_tpu.io.parquet import ParquetScanOp
+    from auron_tpu.ops.base import ExecContext
+    table = pa.table({"k": pa.array(range(3000), pa.int64()),
+                      "s": pa.array([f"r{i % 7}" for i in range(3000)])})
+    path = str(tmp_path / f"t.{fmt}")
+    if fmt == "orc":
+        import pyarrow.orc as orc
+        orc.write_table(table, path)
+        op = OrcScanOp([path], batch_rows=1024)
+    else:
+        import pyarrow.parquet as pq
+        pq.write_table(table, path, row_group_size=1024)
+        op = ParquetScanOp([path], batch_rows=1024)
+    with trace.task_scope("q-scan") as acc:
+        acc.start()
+        with trace.layer_span("serve", "task", query_id=acc.query_id):
+            rows = sum(int(b.num_rows) for b in op.execute(0, ExecContext()))
+            v2 = acc.sealed(1.0)
+    assert rows == 3000
+    assert set(v2["scan_worker_s"]) == {"decode", "encode", "h2d"}
+    assert all(v > 0 for v in v2["scan_worker_s"].values())
+    assert v2["layers_s"]["scan_wait"] > 0
+    assert v2["counts"]["h2d_transfers"] > 0
+
+
+def test_an_operator_timer_waits_for_nothing_and_opens_no_readback():
+    """``ops/base.timer`` books host time only: ``track`` hands its
+    value back without waiting on it (a leaf whose wait raises passes
+    through), and no ``auron:op/readback`` span opens — the device wait
+    belongs to ``device_fence`` / ``timed_get``."""
+    from auron_tpu.ops.base import MetricsSet, timer
+
+    class DeadLeaf:
+        def block_until_ready(self):
+            raise RuntimeError("device halted")
+
+    metrics = MetricsSet(name="agg")
+    leaf = DeadLeaf()
+    with trace.task_scope("q-timer") as acc:
+        acc.start()
+        with trace.layer_span("serve", "task", query_id=acc.query_id):
+            with timer(metrics.counter("elapsed_compute")) as t:
+                assert t.track({"out": leaf})["out"] is leaf
+            v2 = acc.sealed(1.0)
+    assert metrics.snapshot()["elapsed_compute"] > 0
+    assert v2["counts"]["readbacks"] == 0
+    assert v2["ops_s"]["agg"]["batches"] == 1
+    assert v2["ops_s"]["agg"]["device_wait_s"] == 0.0
+
+
 def test_many_scan_workers_lose_no_update():
     """Several prefetch workers of one task write its worker fields at
     once (a join's dimension scans beside the fact scan): with more
@@ -667,7 +724,7 @@ def test_auron_profile_and_the_hotspot_tool_are_gone():
     names = {l.split("`")[1] for l in rows}
     assert knob not in names and knob + ".dir" not in names
     assert knob + ".enabled" in names
-    assert len(rows) == 81
+    assert len(rows) == 79
     assert not os.path.exists(os.path.join(_REPO, "tools", tool))
     from auron_tpu.obs import profile
     assert not hasattr(profile, export)

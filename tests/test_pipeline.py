@@ -1,9 +1,10 @@
-"""Pipelined async execution (runtime/pipeline.py + the prefetching
-scan + double-buffered dispatch + async-aware attribution).
+"""Pipelined async execution (the prefetching scan + the look-ahead
+drive of runtime/executor.py + async-aware attribution).
 
 The contracts this file holds:
 
-- ``lookahead`` preserves order exactly and propagates close/errors;
+- ``lookahead`` preserves order exactly and propagates close/errors,
+  alone and at its caller (``ExecutionRuntime.arrow_batches``);
 - the scan prefetcher streams batches in source order, registers its
   decoded bytes with the memory manager, unregisters on close (the
   tier-1 leak-audit fixtures watch the same ledger), re-raises worker
@@ -11,10 +12,11 @@ The contracts this file holds:
   pressure-ladder rung 1;
 - a cancel mid-prefetch unwinds classified and leaks neither consumers
   nor spill files;
-- pipelined-mode attribution still sums to wall (device measured at
-  the moved sync points, per-call dispatch kept);
-- bit-identity of pipelined vs serial on a real parquet query (the
-  full TPC-DS battery lives in tests/test_zz_pipeline_battery.py).
+- attribution still sums to wall (device measured at the sync points,
+  per-call dispatch kept);
+- a real parquet query answers as pyarrow does over the same file
+  (that its task reports the prefetch worker's spans and seconds is
+  held in tests/test_layer_spans.py).
 """
 
 import os
@@ -29,7 +31,7 @@ import pytest
 from auron_tpu import config as cfg
 from auron_tpu.memmgr.manager import MemManager
 from auron_tpu.ops.base import ExecContext
-from auron_tpu.runtime import pipeline
+from auron_tpu.runtime import executor
 
 
 # ---------------------------------------------------------------------------
@@ -39,9 +41,9 @@ from auron_tpu.runtime import pipeline
 class TestLookahead:
     def test_preserves_order_and_exhausts(self):
         for depth in (0, 1, 2, 5, 100):
-            assert list(pipeline.lookahead(iter(range(7)), depth)) \
+            assert list(executor.lookahead(iter(range(7)), depth)) \
                 == list(range(7))
-        assert list(pipeline.lookahead(iter([]), 1)) == []
+        assert list(executor.lookahead(iter([]), 1)) == []
 
     def test_pulls_ahead_of_yield(self):
         pulled = []
@@ -51,7 +53,7 @@ class TestLookahead:
                 pulled.append(i)
                 yield i
 
-        it = pipeline.lookahead(src(), depth=1)
+        it = executor.lookahead(src(), depth=1)
         assert next(it) == 0
         # item 1 was pulled BEFORE item 0 was yielded (the overlap)
         assert pulled == [0, 1]
@@ -66,7 +68,7 @@ class TestLookahead:
             finally:
                 closed.append(True)
 
-        it = pipeline.lookahead(src(), depth=1)
+        it = executor.lookahead(src(), depth=1)
         assert next(it) == 0
         it.close()
         assert closed == [True]
@@ -76,46 +78,67 @@ class TestLookahead:
             yield 1
             raise ValueError("decode failed")
 
-        it = pipeline.lookahead(src(), depth=1)
+        it = executor.lookahead(src(), depth=1)
         with pytest.raises(ValueError, match="decode failed"):
             list(it)
 
 
-# ---------------------------------------------------------------------------
-# knob resolution
-# ---------------------------------------------------------------------------
+class TestArrowBatchesDrive:
+    """The look-ahead at its one caller, ``ExecutionRuntime
+    .arrow_batches``, over a recording stand-in for ``batches()``."""
 
-def test_enabled_tracks_config_epoch():
-    conf = cfg.get_config()
-    assert pipeline.enabled()          # default on
-    conf.set(cfg.PIPELINE_ENABLED, False)
-    try:
-        assert not pipeline.enabled()
-    finally:
-        conf.unset(cfg.PIPELINE_ENABLED)
-    assert pipeline.enabled()
+    def _runtime(self, nbatches=5, fail_at=None):
+        from auron_tpu.columnar.arrow_bridge import schema_from_arrow
+        from auron_tpu.io.parquet import MemoryScanOp
+        rbs = [pa.record_batch({"x": pa.array([10 * i, 10 * i + 1],
+                                              pa.int64())})
+               for i in range(nbatches)]
+        scan = MemoryScanOp([rbs], schema_from_arrow(rbs[0].schema),
+                            capacity=4)
+        rt = executor.ExecutionRuntime(scan, executor.TaskDefinition())
+        events = []
+        real = rt.batches
 
+        def recording():
+            try:
+                for i, batch in enumerate(real()):
+                    if i == fail_at:
+                        raise ValueError("kernel failed")
+                    events.append(i)
+                    yield batch
+            finally:
+                events.append("closed")
 
-def test_ctx_device_sync_off_under_pipelining():
-    ctx = ExecContext()
-    assert ctx.pipelined
-    assert not ctx.device_sync     # pipelining moves the sync points
-    # the knob is PROCESS-GLOBAL by contract: every plane (timers, the
-    # profiler's program wrapper, the executor's fence) must agree on
-    # where the sync points live, and the wrapper cannot see a session
-    # config — so only the global flips the mode
-    conf = cfg.get_config()
-    conf.set(cfg.PIPELINE_ENABLED, False)
-    try:
-        ctx2 = ExecContext()
-        assert not ctx2.pipelined
-        assert ctx2.device_sync
-        # a session-scoped override is deliberately NOT honored
-        ctx3 = ExecContext(config=cfg.AuronConfig(
-            {cfg.PIPELINE_ENABLED: True}))
-        assert not ctx3.pipelined
-    finally:
-        conf.unset(cfg.PIPELINE_ENABLED)
+        rt.batches = recording
+        return rt, events
+
+    def test_yields_batches_order_one_batch_ahead(self):
+        rt, events = self._runtime()
+        it = rt.arrow_batches()
+        first = next(it)
+        assert first.column("x").to_pylist() == [0, 1]
+        assert events == [0, 1]      # batch 1 was pulled before 0 came
+        rest = [rb.column("x").to_pylist() for rb in it]
+        assert rest == [[10 * i, 10 * i + 1] for i in range(1, 5)]
+        assert events == [0, 1, 2, 3, 4, "closed"]
+
+    def test_early_stop_closes_the_operator_chain(self):
+        rt, events = self._runtime()
+        it = rt.arrow_batches()
+        next(it)
+        it.close()
+        assert events == [0, 1, "closed"]
+
+    def test_a_producer_error_surfaces_with_its_type_one_batch_early(self):
+        """An operator's error reaches the consumer unwrapped, on the
+        pull that raised it: batch 2's failure comes when batch 1 is
+        asked for, after batch 0 was delivered."""
+        rt, events = self._runtime(fail_at=2)
+        it = rt.arrow_batches()
+        assert next(it).column("x").to_pylist() == [0, 1]
+        with pytest.raises(ValueError, match="kernel failed"):
+            next(it)
+        assert events == [0, 1, "closed"]
 
 
 # ---------------------------------------------------------------------------
@@ -250,7 +273,7 @@ class TestScanPrefetcher:
 
 
 # ---------------------------------------------------------------------------
-# end-to-end: parquet scan, pipelined vs serial
+# end-to-end: parquet scan
 # ---------------------------------------------------------------------------
 
 class TestPipelinedScan:
@@ -272,15 +295,19 @@ class TestPipelinedScan:
                     .alias("sv"))
                 .collect())
 
-    def test_bit_identical_on_off(self, data):
-        conf = cfg.get_config()
-        pipelined = self._q(data)
-        conf.set(cfg.PIPELINE_ENABLED, False)
-        try:
-            serial = self._q(data)
-        finally:
-            conf.unset(cfg.PIPELINE_ENABLED)
-        assert pipelined.equals(serial)
+    def test_answer_matches_pyarrow(self, data):
+        """scan → filter → group-by answers as pyarrow does over the
+        same file (a reference independent of the engine; the engine's
+        order is not part of the answer, so both sort on ``k``)."""
+        import pyarrow.compute as pc
+        got = self._q(data).sort_by("k")
+        t = pq.read_table(data)
+        want = (t.filter(pc.less(t["k"], 50)).group_by("k")
+                .aggregate([("v", "sum")]).sort_by("k"))
+        assert got.column("k").to_pylist() == want.column("k").to_pylist()
+        np.testing.assert_allclose(got.column("sv").to_numpy(),
+                                   want.column("v_sum").to_numpy(),
+                                   rtol=1e-9)
 
     def test_scan_cancel_through_session_is_clean(self, data):
         """df.collect(timeout_s=tiny) during a parquet scan: classified

@@ -66,21 +66,25 @@ class TestAttribution:
         # within 5% of wall (the flush itself costs a few clock reads)
         assert abs(attributed - wall) <= max(wall * 0.05, 200_000), snap
 
-    def test_program_calls_record_device_time(self):
-        """The registry's ProfiledProgram wrapper recorded at least one
-        real call: elapsed_device nonzero on the compute op. Serial
-        mode — pipelined execution moves the per-call device wait to
-        the sync boundaries (see TestPipelinedAttribution)."""
-        g = cfg.get_config()
-        g.set(cfg.PIPELINE_ENABLED, False)
-        try:
-            op, rt = _run_project_plan(
-                config=cfg.AuronConfig({cfg.PIPELINE_ENABLED: False}))
-            snap = rt.ctx.op_metric_sets(op)[0].snapshot()
-        finally:
-            g.unset(cfg.PIPELINE_ENABLED)
-        assert snap.get("elapsed_device", 0) > 0, snap
+    def test_program_calls_record_device_time(self, monkeypatch):
+        """The registry's ProfiledProgram wrapper records the dispatch
+        of every call and never waits: the operator's device time is
+        exactly what the executor's fence at to_arrow measured (what
+        test_pipeline's attribution test shows for a scan)."""
+        fences = []
+        real_fence = obs_profile.device_fence
+
+        def fence(value, sink=None):
+            ns = real_fence(value, sink)
+            fences.append(ns)
+            return ns
+
+        monkeypatch.setattr(obs_profile, "device_fence", fence)
+        op, rt = _run_project_plan()
+        snap = rt.ctx.op_metric_sets(op)[0].snapshot()
         assert snap.get("elapsed_host_dispatch", 0) > 0, snap
+        assert len(fences) == 1                    # one batch, one fence
+        assert snap.get("elapsed_device", 0) == fences[0] > 0, snap
 
     def test_disabled_path_records_nothing(self):
         conf = cfg.AuronConfig({cfg.PROFILE_ENABLED: False})
@@ -97,27 +101,6 @@ class TestAttribution:
             assert obs_profile.push_frame() is None
         finally:
             g.unset(cfg.PROFILE_ENABLED)
-
-    def test_device_sync_off_disables_profiler(self):
-        """auron.metrics.device_sync=false is the legacy
-        maximum-throughput knob (async overlap); in SERIAL mode the
-        profiler's per-call block would silently defeat it, so it must
-        turn the profiler off rather than override the knob. Pipelined
-        mode keeps the profiler on — its async timing has no per-call
-        block left to defeat."""
-        g = cfg.get_config()
-        g.set(cfg.METRICS_DEVICE_SYNC, False)
-        try:
-            # pipelined (default): profiler stays on, no block per call
-            assert obs_profile.enabled()
-            g.set(cfg.PIPELINE_ENABLED, False)
-            # serial: the legacy contract holds
-            assert not obs_profile.enabled()
-            assert obs_profile.push_frame() is None
-        finally:
-            g.unset(cfg.METRICS_DEVICE_SYNC)
-            g.unset(cfg.PIPELINE_ENABLED)
-        assert obs_profile.enabled()
 
     def test_wrapper_passthrough_and_identity(self):
         """The registry memo keeps the RAW program; the wrapper is
