@@ -3,8 +3,14 @@
 The reference reads parquet through a JVM FileSystem wrapper into DataFusion's
 parquet opener with row-group/page pruning (reference: datafusion-ext-plans/
 src/parquet_exec.rs:151-237, scan/internal_file_reader.rs). Here the host side
-is pyarrow (column pruning + row-group statistics pruning + dictionary-aware
-reads) feeding padded DeviceBatches to the TPU; the scan is the host→device
+is pyarrow (row-group statistics pruning + dictionary-aware reads, of the
+columns the scan names) feeding padded DeviceBatches to the TPU. Which columns
+a scan names is decided above it: a Spark host ships its scans pruned, and for
+every other plan the planner's required-columns pass (``ir/pruning.py``) fills
+``columns`` from the expressions of the project / filter / sort / limit / agg
+chain over the scan; a scan under a node kind that pass has no rule for reads
+its files whole. ``counts.scan_columns_read`` / ``scan_columns_pruned`` say
+what each scan did. The scan is the host→device
 on-ramp, deliberately kept off the device's critical path by the prefetching
 worker (``ScanPrefetcher``): while the device crunches batch N, a bounded
 background thread decodes and transfers batch N+1 (and beyond, up to
@@ -58,6 +64,14 @@ def _expr_to_arrow_filter(e: ir.Expr, names: list[str]):
     except Exception:
         return None
     return None
+
+
+def file_schema(files: list[str], fmt: str = "parquet") -> pa.Schema:
+    """The Arrow schema a scan of ``files`` emits when it names no
+    columns (the first file's, as the scan itself takes it)."""
+    from auron_tpu.io.fs import resolve_many
+    fs, paths = resolve_many(list(files))
+    return pa_ds.dataset(paths, format=fmt, filesystem=fs).schema
 
 
 class ScanPrefetcher:
@@ -252,14 +266,17 @@ class ParquetScanOp(PhysicalOp):
         # filesystems — hdfs://, s3://, gs://, registered providers)
         from auron_tpu.io.fs import resolve_many
         self._fs, self.files = resolve_many(self.files)
-        ds = pa_ds.dataset(self.files, format=self._format,
-                           filesystem=self._fs)
-        arrow_schema = ds.schema
-        if columns:
-            arrow_schema = pa.schema([arrow_schema.field(c) for c in columns])
-        self._arrow_schema = arrow_schema
-        self._schema = schema or schema_from_arrow(arrow_schema)
-        self._dataset = ds
+        if schema is None:
+            # a caller that hands over the schema has read the files (a
+            # host's plan; the planner's required-columns pass): only a
+            # bare scan opens them to plan
+            arrow_schema = pa_ds.dataset(self.files, format=self._format,
+                                         filesystem=self._fs).schema
+            if columns:
+                arrow_schema = pa.schema(
+                    [arrow_schema.field(c) for c in columns])
+            schema = schema_from_arrow(arrow_schema)
+        self._schema = schema
         # Pre-size string widths from the data unless caller pinned them, so
         # every batch of a file lands in the same compiled kernel bucket.
         self.string_widths = dict(string_widths or {})
@@ -330,6 +347,9 @@ class ParquetScanOp(PhysicalOp):
             with trace.layer_span("scan", "decode"):
                 ds = pa_ds.dataset(files, format=self._format,
                                    filesystem=self._fs)
+                trace.count("scan_columns_read", len(self._schema))
+                trace.count("scan_columns_pruned",
+                            len(ds.schema.names) - len(self._schema))
                 scanner = ds.scanner(columns=self.columns,
                                      filter=arrow_filter,
                                      batch_size=self.batch_rows)
@@ -404,7 +424,8 @@ class ParquetScanOp(PhysicalOp):
         return widths
 
     def __repr__(self):
-        return f"{type(self).__name__}[{len(self.files)} files]"
+        cols = f", columns={self.columns}" if self.columns else ""
+        return f"{type(self).__name__}[{len(self.files)} files{cols}]"
 
 
 class MemoryScanOp(PhysicalOp):

@@ -73,14 +73,18 @@ class PhysicalPlanner:
     # -- entry points -------------------------------------------------------
 
     def plan_task(self, task: pb.TaskDefinition) -> PhysicalOp:
-        if _collect_subqueries(task.plan):
+        # the file scans read the columns the plan reads (ir/pruning.py);
+        # a scalar subquery's own plan is pruned when the binder plans it
+        from auron_tpu.ir.pruning import prune_scan_columns
+        plan = prune_scan_columns(task.plan)
+        if _collect_subqueries(plan):
             # resolve every uncorrelated scalar subquery in the tree ONCE
             # at task start, then re-plan with literals substituted
             # (reference: spark_scalar_subquery_wrapper.rs role); the
             # binder applies the stage-fusion pass after substitution
             from auron_tpu.ops.subquery import ScalarSubqueryBinderOp
-            return ScalarSubqueryBinderOp(task.plan, self.ctx)
-        return self.finalize_plan(self.create_plan(task.plan))
+            return ScalarSubqueryBinderOp(plan, self.ctx)
+        return self.finalize_plan(self.create_plan(plan))
 
     def finalize_plan(self, op: PhysicalOp) -> PhysicalOp:
         """Post-planning passes over the materialized operator tree:

@@ -454,6 +454,9 @@ LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
 SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
 COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
               "h2d_bytes", "encode_pyloop_values",
+              # a file scan's width: the columns it reads, and those of
+              # its files it leaves unread (ir/pruning.py)
+              "scan_columns_read", "scan_columns_pruned",
               # the mesh route of an exchange (0 on every other path):
               # completed all-to-all rounds, quota re-runs, live bytes
               # received, and the padded slot buffers that held them

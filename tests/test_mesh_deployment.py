@@ -146,6 +146,23 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
 
 
 @pytest.mark.parametrize("plan", PLANS)
+def test_mesh_stage_moves_the_columns_its_plan_reads(plan, stage, answers):
+    """The planner's required-columns pass (``ir/pruning.py``) narrows
+    the scans before the stage's input spec is taken from them: all four
+    shards stack the same three fact columns."""
+    table, done = answers[plan]["mesh"]
+    counts = done["cost_ledger"]["counts"]
+    # data + validity a column (chars + lens + validity the one string),
+    # and the row count a batch: 4 fact batches of 3 columns, date_dim's
+    # 3 columns, item's 4
+    assert counts["h2d_transfers"] == SPLITS_PER_TASK * 7 + 7 + 10
+    assert counts["scan_columns_read"] == SPLITS_PER_TASK * 3 + 3 + 4
+    assert counts["scan_columns_pruned"] > counts["scan_columns_read"]
+    assert _leaf_sum(done, "exchange_route_all_to_all") >= 1
+    assert table.num_rows == stage.oracle(plan).num_rows
+
+
+@pytest.mark.parametrize("plan", PLANS)
 def test_one_chip_frame_has_no_mesh_count(plan, answers):
     _table, done = answers[plan]["single"]
     led = done["cost_ledger"]
