@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from auron_tpu.hashtable import core
 from auron_tpu.obs import profile as _profile
+from auron_tpu.obs import trace as _trace
 from auron_tpu.runtime.programs import program_cache
 from auron_tpu.utils.shapes import next_pow2
 
@@ -225,6 +226,9 @@ class HashAggState:
                 continue
             self.th, self.tw, self.store = nth, ntw, nstore
             self.accs, self.auxs = naccs, nauxs
+            # one a doubling: a re-bucket that overflowed doubled again
+            _trace.count("agg_state_grows",
+                         (new_cap // self.cap).bit_length() - 1)
             self.cap = new_cap
             return
 
@@ -257,6 +261,7 @@ class HashAggState:
                 self.accs, self.auxs = accs, auxs
                 self.count += int(n_new_h)
                 self.rows_seen += n
+                _trace.count("agg_hash_batches")
                 if self.count > self.load_factor * self.cap:
                     try:
                         self._grow()

@@ -1175,60 +1175,82 @@ _q("q89", "monthly sales deviating >10% from partition average")(
 # q65: store/item pairs whose revenue is below 10% of the store average
 # ===========================================================================
 
-def _q65_run(s, t):
-    ss = _rd(s, t, "store_sales").select("ss_sold_date_sk", "ss_item_sk",
-                                         "ss_store_sk", "ss_sales_price")
-    dd = _rd(s, t, "date_dim").filter(
-        (col("d_month_seq") >= 24) & (col("d_month_seq") <= 35)) \
-        .select("d_date_sk")
-    sa = (_join_dim(ss, dd, "ss_sold_date_sk", "d_date_sk")
-          .group_by("ss_store_sk", "ss_item_sk")
-          .agg(F.sum(col("ss_sales_price").cast(DataType.FLOAT64))
-               .alias("revenue")))
-    sb = (sa.group_by(col("ss_store_sk").alias("st2"))
-          .agg(F.avg(col("revenue")).alias("ave")))
-    j = _join_dim(sa, sb, "ss_store_sk", "st2")
-    j = j.filter(col("revenue") <= col("ave") * lit(0.1))
-    st = _rd(s, t, "store").select("s_store_sk", "s_store_name")
-    it = _rd(s, t, "item").select("i_item_sk", "i_item_desc",
-                                  "i_current_price")
-    j = _join_dim(j, st, "ss_store_sk", "s_store_sk")
-    j = _join_dim(j, it, "ss_item_sk", "i_item_sk")
-    return (j.select("s_store_name", "i_item_desc", "revenue",
-                     "i_current_price")
-            .sort(col("s_store_name").asc(), col("i_item_desc").asc())
-            .limit(100).collect())
+# Two forms. q65 is the library's older one: money cast to double before
+# it is summed (a float sum: the sort path of the general aggregation).
+# q65m has money as the specification has it (benchmark/plans/q65m.py is
+# the cell benchmark's copy): revenue = sum(ss_sales_price) stays decimal
+# (decimal(7,2) -> decimal(17,2): the hash-table aggregation). Departures
+# of q65m from Spark's plan: ave = avg(cast(revenue as double)) over the
+# stores and the filter compares in double, where Spark carries
+# decimal(21,6).
+
+def _q65_pair(decimal_money: bool):
+    def as_double(c):
+        return c.cast(DataType.FLOAT64)
+
+    def run(s, t):
+        ss = _rd(s, t, "store_sales").select(
+            "ss_sold_date_sk", "ss_item_sk", "ss_store_sk", "ss_sales_price")
+        dd = _rd(s, t, "date_dim").filter(
+            (col("d_month_seq") >= 24) & (col("d_month_seq") <= 35)) \
+            .select("d_date_sk")
+        price = col("ss_sales_price")
+        sa = (_join_dim(ss, dd, "ss_sold_date_sk", "d_date_sk")
+              .group_by("ss_store_sk", "ss_item_sk")
+              .agg(F.sum(price if decimal_money else as_double(price))
+                   .alias("revenue")))
+        revenue = as_double(col("revenue")) if decimal_money \
+            else col("revenue")
+        sb = (sa.group_by(col("ss_store_sk").alias("st2"))
+              .agg(F.avg(revenue).alias("ave")))
+        j = _join_dim(sa, sb, "ss_store_sk", "st2")
+        j = j.filter(revenue <= col("ave") * lit(0.1))
+        st = _rd(s, t, "store").select("s_store_sk", "s_store_name")
+        it = _rd(s, t, "item").select("i_item_sk", "i_item_desc",
+                                      "i_current_price")
+        j = _join_dim(j, st, "ss_store_sk", "s_store_sk")
+        j = _join_dim(j, it, "ss_item_sk", "i_item_sk")
+        return (j.select("s_store_name", "i_item_desc", "revenue",
+                         "i_current_price")
+                .sort(col("s_store_name").asc(), col("i_item_desc").asc())
+                .limit(100).collect())
+
+    def oracle(a):
+        dd = a["date_dim"].filter(pc.and_(
+            pc.greater_equal(a["date_dim"]["d_month_seq"], 24),
+            pc.less_equal(a["date_dim"]["d_month_seq"], 35))) \
+            .select(["d_date_sk"])
+        ssj = _oj(a["store_sales"], dd, ["ss_sold_date_sk"], ["d_date_sk"])
+        if not decimal_money:
+            ssj = ssj.set_column(ssj.column_names.index("ss_sales_price"),
+                                 "ss_sales_price",
+                                 ssj["ss_sales_price"].cast(pa.float64()))
+        sa = ssj.group_by(["ss_store_sk", "ss_item_sk"]).aggregate(
+            [("ss_sales_price", "sum")]) \
+            .rename_columns(["ss_store_sk", "ss_item_sk", "revenue"])
+        if decimal_money:
+            sa = sa.set_column(2, "revenue",
+                               sa["revenue"].cast(pa.decimal128(17, 2)))
+        sa = sa.append_column("revenue_d", sa["revenue"].cast(pa.float64()))
+        sb = sa.group_by(["ss_store_sk"]).aggregate(
+            [("revenue_d", "mean")]).rename_columns(["st2", "ave"])
+        j = _oj(sa, sb, ["ss_store_sk"], ["st2"])
+        j = j.filter(pc.less_equal(j["revenue_d"],
+                                   pc.multiply(j["ave"], 0.1)))
+        j = _oj(j, a["store"].select(["s_store_sk", "s_store_name"]),
+                ["ss_store_sk"], ["s_store_sk"])
+        j = _oj(j, a["item"].select(["i_item_sk", "i_item_desc",
+                                     "i_current_price"]),
+                ["ss_item_sk"], ["i_item_sk"])
+        g = j.select(["s_store_name", "i_item_desc", "revenue",
+                      "i_current_price"])
+        return _topn(g, [("s_store_name", "ascending"),
+                         ("i_item_desc", "ascending")])
+
+    return run, oracle
 
 
-def _q65_oracle(a):
-    dd = a["date_dim"].filter(pc.and_(
-        pc.greater_equal(a["date_dim"]["d_month_seq"], 24),
-        pc.less_equal(a["date_dim"]["d_month_seq"], 35))) \
-        .select(["d_date_sk"])
-    ssj = _oj(a["store_sales"], dd, ["ss_sold_date_sk"], ["d_date_sk"])
-    ssj = ssj.set_column(ssj.column_names.index("ss_sales_price"),
-                         "ss_sales_price",
-                         ssj["ss_sales_price"].cast(pa.float64()))
-    sa = ssj.group_by(["ss_store_sk", "ss_item_sk"]).aggregate(
-        [("ss_sales_price", "sum")]) \
-        .rename_columns(["ss_store_sk", "ss_item_sk", "revenue"])
-    sb = sa.group_by(["ss_store_sk"]).aggregate([("revenue", "mean")]) \
-        .rename_columns(["st2", "ave"])
-    j = _oj(sa, sb, ["ss_store_sk"], ["st2"])
-    j = j.filter(pc.less_equal(j["revenue"],
-                               pc.multiply(j["ave"], 0.1)))
-    j = _oj(j, a["store"].select(["s_store_sk", "s_store_name"]),
-            ["ss_store_sk"], ["s_store_sk"])
-    j = _oj(j, a["item"].select(["i_item_sk", "i_item_desc",
-                                 "i_current_price"]),
-            ["ss_item_sk"], ["i_item_sk"])
-    g = j.select(["s_store_name", "i_item_desc", "revenue",
-                  "i_current_price"])
-    return _topn(g, [("s_store_name", "ascending"),
-                     ("i_item_desc", "ascending")])
-
-
-_q("q65", "under-performing store/item pairs")((_q65_run, _q65_oracle))
+_q("q65", "under-performing store/item pairs")(_q65_pair(False))
 
 
 # ===========================================================================
@@ -6297,3 +6319,9 @@ def _q64_oracle(a):
 
 _q("q64", "returned-item purchase chains self-joined across two years")(
     (_q64_run, _q64_oracle))
+
+
+# appended after the 99 (see _q65_pair): an insertion beside q65 would
+# move every later query to another tier-1 shard
+_q("q65m", "q65 with money kept decimal (hash-table aggregation)")(
+    _q65_pair(True))

@@ -461,7 +461,20 @@ COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
               # completed all-to-all rounds, quota re-runs, live bytes
               # received, and the padded slot buffers that held them
               "mesh_rounds", "mesh_escalations", "mesh_bytes",
-              "mesh_slot_bytes")
+              "mesh_slot_bytes",
+              # the general (unbounded-key) aggregation: batches folded
+              # into the hash table's state and into the sort path's,
+              # groups the keyed aggregations emitted, capacity
+              # growths of either state mid-stream, and hash-table
+              # overflows that latched the sort path; the latter under
+              # a second name too, the one a client's fault rule reads
+              # (a DONE frame with a nonzero "demot..." leaf is a failed
+              # task to benchmark/run.py and the served smoke, as a
+              # demoted exchange route is): the answer stays exact, the
+              # task was not computed on the path its plan was given
+              "agg_hash_batches", "agg_sort_batches", "agg_groups",
+              "agg_state_grows", "agg_sort_fallbacks",
+              "agg_demoted_to_sort")
 
 _ANNOTATION = None
 

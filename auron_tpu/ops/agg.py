@@ -38,6 +38,7 @@ from auron_tpu.columnar.batch import (DeviceBatch, PrimitiveColumn, StringColumn
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, TypedValue, evaluate, infer_dtype
+from auron_tpu.obs import trace as _trace
 from auron_tpu.ops import hashing
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.utils.shapes import bucket_rows
@@ -1444,7 +1445,11 @@ class AggOp(PhysicalOp):
                 if nd > out_elems[i]:
                     ok = False
                     out_elems[i] = max(4, next_pow2(nd))
-        return ok, (bucket_rows(ng) if ng > out_cap else out_cap)
+        if ng > out_cap:
+            # the merge that found this runs again at the wider capacity
+            _trace.count("agg_state_grows")
+            return ok, bucket_rows(ng)
+        return ok, out_cap
 
     def _shrink_table(self, tbl, ng: int):
         """Slice a group table down to its occupancy bucket. Live groups
@@ -1595,6 +1600,8 @@ class AggOp(PhysicalOp):
                 # through the sort path
                 ht.disabled = True
                 ht.metrics.counter("hashtable_overflow_fallback").add(1)
+                _trace.count("agg_sort_fallbacks")
+                _trace.count("agg_demoted_to_sort")
                 tbl = hs.to_sorted_table()
                 sorted_state = None if tbl is None else \
                     (self._shrink_table(tbl, hs.count), None)
@@ -1636,6 +1643,7 @@ class AggOp(PhysicalOp):
         ~_HOT_FACTOR batches instead of per batch. The reference's
         open-addressing AggTable gets the same amortization from its
         in-memory table + sorted bucket spills (agg_table.rs:68-356)."""
+        _trace.count("agg_sort_batches")
         # graft: donation-ok -- _donate_contributions gate (owned
         # child, no collect-kind growth retry, no aliased leaves)
         batch_tbl = self._reduce_batch(keys, accs, live, elapsed,
@@ -1681,6 +1689,8 @@ class AggOp(PhysicalOp):
         keys, accs, num_groups, cap, _hashes = state
         valid = jnp.arange(cap, dtype=jnp.int32) < num_groups
         ng = int(num_groups)
+        if self.group_exprs:
+            _trace.count("agg_groups", ng)
 
         # A global bloom state serializes to ~100 KB+ per row; shrink the
         # (single-group) output capacity before attaching it so the string

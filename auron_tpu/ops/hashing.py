@@ -169,9 +169,11 @@ def pairwise_eq(pc, probe_idx, bc, build_idx) -> jax.Array:
 
 
 def _f64_bits(d: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Canonicalized bits of f64 as (low, high) uint32 words. Avoids
-    f64<->s64 bitcast, which TPU's 64-bit-rewriting pass does not
-    implement; f64→2×u32 bitcast is supported."""
+    """Canonicalized bits of f64 as (low, high) uint32 words. Not on
+    the TPU: it carries a double as a pair of float32 and its compiler
+    refuses every bitcast of one (UNIMPLEMENTED in the X64 rewrite, PR
+    34's chip run), so a plan that hashes a double KEY does not compile
+    there yet; a sort key goes through ops/sort.f64_order_word."""
     v = canonicalize_float(d)
     pair = lax.bitcast_convert_type(v, jnp.uint32)  # [..., 2]
     # trailing dim order: index 0 = least-significant word on LE targets

@@ -66,6 +66,51 @@ def string_be_words(chars: "jax.Array") -> "jax.Array":
     return jnp.sum(u << shifts[None, None, :], axis=2)
 
 
+def _f32_order_bits(d: "jax.Array") -> "jax.Array":
+    """A (canonicalized) float32 as the uint64 whose low 32 bits order as
+    the float does: negatives complemented, the rest sign-flipped."""
+    b = d.view(jnp.int32).astype(jnp.int64).astype(jnp.uint64) \
+        & jnp.uint64(0xFFFFFFFF)
+    sign = (b >> 31) & 1
+    return jnp.where(sign == 1, (~b) & jnp.uint64(0xFFFFFFFF),
+                     b | jnp.uint64(0x80000000))
+
+
+def f64_split_order_word(d: "jax.Array") -> "jax.Array":
+    """The order word of a float64 on a device that has no float64 bits.
+    The TPU carries a double as a pair of float32 (its compiler rewrites
+    every 64-bit type away and has no rule for a bitcast of one), so the
+    pair is the word: ``hi`` = the double rounded to float32, ``lo`` =
+    what is left, exact there. Rounding is monotone, so (hi, lo) orders
+    as the doubles do, and equal doubles give equal words."""
+    from auron_tpu.ops.hashing import canonicalize_float
+    d = canonicalize_float(d)
+    hi = d.astype(jnp.float32)
+    # inf - inf and NaN - NaN are NaN: one word for every such key
+    lo = canonicalize_float((d - hi.astype(jnp.float64))
+                            .astype(jnp.float32))
+    return (_f32_order_bits(hi) << 32) | _f32_order_bits(lo)
+
+
+def f64_order_word(d: "jax.Array") -> "jax.Array":
+    """One order-preserving uint64 for a float64 key: its IEEE bits where
+    the backend has them, the float32 pair on the TPU. The fork exists
+    only for the TPU compiler's X64 rewrite, which has no rule for a
+    bitcast of a double; the pair cannot serve everywhere, because it
+    drops 5 of a CPU double's 53 mantissa bits. Both go (the IEEE branch
+    alone stays) once the TPU compiles ``bitcast_convert_type`` of a
+    float64."""
+    if jax.default_backend() == "tpu":
+        return f64_split_order_word(d)
+    from jax import lax
+    from auron_tpu.ops.hashing import canonicalize_float
+    pair = lax.bitcast_convert_type(canonicalize_float(d), jnp.uint32)
+    b = pair[..., 0].astype(jnp.uint64) \
+        | (pair[..., 1].astype(jnp.uint64) << 32)
+    sign = (b >> 63) & 1
+    return jnp.where(sign == 1, ~b, b | jnp.uint64(1 << 63))
+
+
 def order_words(col, ascending: bool, nulls_first: bool) -> list[jax.Array]:
     """Normalize one sort key column into order-preserving uint64 words,
     most significant first (excluding the null-rank word, which the caller
@@ -108,20 +153,9 @@ def order_words(col, ascending: bool, nulls_first: bool) -> list[jax.Array]:
             # equal-under-Spark keys produce identical order words (SMJ
             # and window group detection compare words for equality)
             from auron_tpu.ops.hashing import canonicalize_float
-            d = canonicalize_float(d)
-            b = d.view(jnp.int32).astype(jnp.int64).astype(jnp.uint64) \
-                & jnp.uint64(0xFFFFFFFF)
-            sign = (b >> 31) & 1
-            u = jnp.where(sign == 1, (~b) & jnp.uint64(0xFFFFFFFF),
-                          b | jnp.uint64(0x80000000))
+            u = _f32_order_bits(canonicalize_float(d))
         elif d.dtype == jnp.dtype(jnp.float64):
-            from jax import lax
-            from auron_tpu.ops.hashing import canonicalize_float
-            d = canonicalize_float(d)
-            pair = lax.bitcast_convert_type(d, jnp.uint32)
-            b = pair[..., 0].astype(jnp.uint64) | (pair[..., 1].astype(jnp.uint64) << 32)
-            sign = (b >> 63) & 1
-            u = jnp.where(sign == 1, ~b, b | jnp.uint64(1 << 63))
+            u = f64_order_word(d)
         else:
             u = d.astype(jnp.uint64)
         words.append(u)
