@@ -69,6 +69,9 @@ class PlannerContext:
 class PhysicalPlanner:
     def __init__(self, ctx: Optional[PlannerContext] = None):
         self.ctx = ctx or PlannerContext()
+        #: subplans of the tree being planned that several parents read
+        #: (ir/reuse.py): serialised AggNode -> what its handles share
+        self._shared: dict = {}
 
     # -- entry points -------------------------------------------------------
 
@@ -84,6 +87,17 @@ class PhysicalPlanner:
             # binder applies the stage-fusion pass after substitution
             from auron_tpu.ops.subquery import ScalarSubqueryBinderOp
             return ScalarSubqueryBinderOp(plan, self.ctx)
+        return self.plan_tree(plan)
+
+    def plan_tree(self, plan: pb.PlanNode) -> PhysicalOp:
+        """Operators for a pruned plan without scalar subqueries: a
+        subplan that several parents read is planned once (ir/reuse.py),
+        then the post-planning passes run over the whole tree, which
+        reach that subplan through the one handle that owns it."""
+        from auron_tpu.ir.reuse import find_shared_subplans
+        from auron_tpu.ops.reuse import SharedSubplan
+        self._shared = {key: SharedSubplan()
+                        for key in find_shared_subplans(plan)}
         return self.finalize_plan(self.create_plan(plan))
 
     def finalize_plan(self, op: PhysicalOp) -> PhysicalOp:
@@ -205,6 +219,18 @@ class PhysicalPlanner:
                          list(n.names))
 
     def _plan_agg(self, n: pb.AggNode) -> PhysicalOp:
+        if self._shared:
+            from auron_tpu.ir.reuse import subplan_key
+            shared = self._shared.get(subplan_key(n))
+            if shared is not None:
+                # the first parent to be planned brings the producer
+                from auron_tpu.ops.reuse import SubplanReadOp
+                if shared.owner is None:
+                    return SubplanReadOp(shared, self._plan_agg_node(n))
+                return SubplanReadOp(shared)
+        return self._plan_agg_node(n)
+
+    def _plan_agg_node(self, n: pb.AggNode) -> PhysicalOp:
         from auron_tpu import config as cfg
         from auron_tpu.ops.agg import AggOp
         child = self.create_plan(n.child)

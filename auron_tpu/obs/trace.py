@@ -474,7 +474,10 @@ COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
               # task was not computed on the path its plan was given
               "agg_hash_batches", "agg_sort_batches", "agg_groups",
               "agg_state_grows", "agg_sort_fallbacks",
-              "agg_demoted_to_sort")
+              "agg_demoted_to_sort",
+              # reads of a shared subplan's result that found it held,
+              # the producer having run for another parent (ops/reuse.py)
+              "subplan_reuse_hits")
 
 _ANNOTATION = None
 

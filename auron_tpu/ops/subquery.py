@@ -140,10 +140,9 @@ class ScalarSubqueryBinderOp(PhysicalOp):
                                  q.scale)
                 values[key] = expr_to_proto(lit)
             node = substitute_subqueries(self._node, values)
-            planner = PhysicalPlanner(self._planner_ctx)
-            # finalize_plan: the substituted plan gets the same
-            # stage-fusion pass a subquery-free task would
-            self._inner = planner.finalize_plan(planner.create_plan(node))
+            # plan_tree: the substituted plan gets the same subplan reuse
+            # and stage-fusion passes a subquery-free task would
+            self._inner = PhysicalPlanner(self._planner_ctx).plan_tree(node)
             return self._inner
 
     def execute(self, partition: int,

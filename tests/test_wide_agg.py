@@ -19,13 +19,14 @@ Held here:
     the sort path (``kernels/dispatch.select_hash_agg`` under ``auto``);
 (c) the five counters of the DONE frame's ``cost_ledger.counts``, exact:
     batches by path, groups against the oracle's group counts (the plan's
-    shared subtree runs twice: there is no common-subplan reuse), growths
-    against the programs that ran, no fall-back;
+    shared subtree runs ONCE, both parents read the one result:
+    ``subplan_reuse_hits`` 1, and 0 in the check plans, which have no
+    duplicate), growths against the programs that ran, no fall-back;
 (d) a hash table that cannot place its keys latches the sort path
     mid-stream (``agg_sort_fallbacks`` 1, and ``agg_demoted_to_sort`` 1:
     the name under which a client's fault rule fails the task) and the
     answer is still right;
-(e) the scans read 4 + 2 + 4 + 2 + 2 + 3 = 17 columns.
+(e) the scans read 4 + 2 + 2 + 3 = 11 columns.
 """
 
 import os
@@ -49,8 +50,9 @@ CHECKS = ("q65sa", "q65sam")
 SPLIT_ROWS, BATCH_ROWS = 40_960, 4_096
 BATCHES = SPLIT_ROWS // BATCH_ROWS
 INITIAL_CAPACITY = 64
-#: what the aggregate's sa subtree costs twice, sb once (q65's shape)
-SA_RUNS = 2
+#: runs of the sa subtree a task: both of its parents (sb, and the join
+#: of sa to sb) read one result since PR 37 (ir/reuse.py)
+SA_RUNS = 1
 
 
 @pytest.fixture(scope="module")
@@ -213,7 +215,7 @@ def test_the_done_frame_counts_the_aggregation(served):
     sites_d = double["program_calls_by_site"]
     sites_m = money["program_calls_by_site"]
     # every scan batch reaches the (store, item) aggregation, which runs
-    # twice; the 12-store average folds the one batch of its output and
+    # once; the 12-store average folds the one batch of its output and
     # sums doubles in both plans
     assert double["agg_sort_batches"] == SA_RUNS * BATCHES + 1
     assert double["agg_hash_batches"] == 0
@@ -253,7 +255,8 @@ def test_an_overflowing_hash_table_latches_the_sort_path(served,
     """The first table's second batch overflows at every capacity: the
     operator salvages the table as a sorted state, pushes the failed
     batch and the rest of its stream through the sort path, and says so
-    once. The aggregation's second run keeps its table."""
+    once. (The aggregation runs once a task: no second run keeps a
+    table.)"""
     from auron_tpu.hashtable import HashTableOverflow
     from auron_tpu.hashtable import agg as htagg
     from harness import compare
@@ -274,7 +277,7 @@ def test_an_overflowing_hash_table_latches_the_sort_path(served,
     counts = led["counts"]
     assert counts["agg_sort_fallbacks"] == 1
     assert counts["agg_demoted_to_sort"] == 1
-    assert counts["agg_hash_batches"] == 1 + BATCHES
+    assert counts["agg_hash_batches"] == 1, "the batch before the overflow"
     assert counts["agg_sort_batches"] == (BATCHES - 1) + 1
     pairs, stores = _group_counts(served.rows)
     assert counts["agg_groups"] == SA_RUNS * pairs + stores
@@ -285,12 +288,20 @@ def test_an_overflowing_hash_table_latches_the_sort_path(served,
 # -- (e) the scans' width ----------------------------------------------------
 
 @pytest.mark.parametrize("plan", PLANS)
-def test_a_wide_agg_task_reads_seventeen_columns(plan, served):
+def test_a_wide_agg_task_reads_eleven_columns(plan, served):
     counts = served(plan)[1]["counts"]
-    # fact 4 + date_dim 2, twice (the subtree both sides of the self-join
-    # hang from runs twice), store 2, item 3; of 20 + 9 twice, 7, 16
-    assert counts["scan_columns_read"] == 2 * (4 + 2) + 2 + 3
-    assert counts["scan_columns_pruned"] == 2 * (16 + 7) + 5 + 13
+    # fact 4 + date_dim 2 (the subtree both sides of the self-join hang
+    # from runs once), store 2, item 3; of 20 + 9, 7, 16
+    assert counts["scan_columns_read"] == SA_RUNS * (4 + 2) + 2 + 3
+    assert counts["scan_columns_pruned"] == SA_RUNS * (16 + 7) + 5 + 13
+    assert counts["subplan_reuse_hits"] == 1
+
+
+@pytest.mark.parametrize("plan", CHECKS)
+def test_a_check_plan_has_no_subplan_to_share(plan, served):
+    counts = served(plan)[1]["counts"]
+    assert counts["subplan_reuse_hits"] == 0
+    assert counts["scan_columns_read"] == 4 + 2
 
 
 # -- (f) the sort on a double key, where a double has no bits ---------------
