@@ -25,8 +25,9 @@ finalize:
 Version 2 adds what the task's own accumulator measured
 (``obs/trace.TaskAccumulator``, filled by the layer spans and not by
 operator snapshots): ``queue_s``; ``layers_s``, exclusive seconds by
-layer on the task's thread, summing to ``wall_s``; ``ops_s``, the
-operators' part of it by operator; ``scan_worker_s``, the prefetch
+layer on the task's thread, summing to ``wall_s``; ``exchange_s``, the
+exchange layer's part of it by span; ``ops_s``, the operators' part of
+it by operator; ``scan_worker_s``, the prefetch
 worker's decode / encode / transfer beside it; ``cpu_s``; ``counts`` of
 program calls, readbacks and transfers; and ``compile.task_*``, the
 compiles that fired on the task's own threads. Every version-1 key
@@ -141,6 +142,7 @@ def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
         "wall_s": round(float(wall_s), 6),
         "queue_s": v2["queue_s"],
         "layers_s": v2["layers_s"],
+        "exchange_s": v2["exchange_s"],
         "ops_s": v2["ops_s"],
         "scan_worker_s": v2["scan_worker_s"],
         "cpu_s": v2["cpu_s"],

@@ -452,6 +452,10 @@ _LAYER_KEY = {"plan": "plan", "scan": "scan_wait", "convert": "to_arrow",
 LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
               "exchange", "to_arrow", "send")
 SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
+#: the exchange layer's spans: ``exchange_s`` splits ``layers_s.exchange``
+#: by them (the last three only on the mesh route)
+EXCHANGE_KEYS = ("materialize", "map_write", "broadcast_collect",
+                 "gang_wait", "mesh_stack", "mesh_round")
 COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
               "h2d_bytes", "encode_pyloop_values",
               # a file scan's width: the columns it reads, and those of
@@ -462,6 +466,11 @@ COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
               # received, and the padded slot buffers that held them
               "mesh_rounds", "mesh_escalations", "mesh_bytes",
               "mesh_slot_bytes",
+              # its read side: the non-empty (partition, source, round)
+              # slices the reducers read, one gather each, and the
+              # bytes of those that a device_put then moved from
+              # another chip to the home chip
+              "mesh_read_batches", "mesh_home_bytes",
               # the general (unbounded-key) aggregation: batches folded
               # into the hash table's state and into the sort path's,
               # groups the keyed aggregations emitted, capacity
@@ -504,8 +513,9 @@ class TaskAccumulator:
     can be alive at once). :meth:`sealed` is what ``obs/ledger.build``
     folds into the ledger."""
 
-    __slots__ = ("query_id", "queue_ns", "layers", "ops", "counts",
-                 "calls_by_site", "layer_spans", "compiles", "compile_ns",
+    __slots__ = ("query_id", "queue_ns", "layers", "exchange", "ops",
+                 "counts", "calls_by_site", "layer_spans", "compiles",
+                 "compile_ns",
                  "worker_ns", "worker_counts", "worker_spans",
                  "worker_cpu_ns", "worker_compiles", "worker_compile_ns",
                  "_cpu0", "_lock")
@@ -514,6 +524,7 @@ class TaskAccumulator:
         self.query_id = query_id
         self.queue_ns = 0
         self.layers = dict.fromkeys(LAYER_KEYS, 0)
+        self.exchange = dict.fromkeys(EXCHANGE_KEYS, 0)
         #: op name -> [host ns, device-wait ns, op spans]
         self.ops: dict[str, list] = {}
         self.counts = dict.fromkeys(COUNT_KEYS, 0)
@@ -560,6 +571,9 @@ class TaskAccumulator:
             key = _LAYER_KEY.get(layer)
             if key is not None:
                 self.layers[key] += self_ns
+                if layer == "exchange":
+                    self.exchange[span.key] = \
+                        self.exchange.get(span.key, 0) + self_ns
 
     def _op(self, name: str) -> list:
         ent = self.ops.get(name)
@@ -594,6 +608,8 @@ class TaskAccumulator:
         return {
             "queue_s": round(self.queue_ns * 1e-9, 6),
             "layers_s": layers,
+            "exchange_s": {k: round(v * 1e-9, 6)
+                           for k, v in self.exchange.items()},
             "ops_s": {name: {"host_s": round(h * 1e-9, 6),
                              "device_wait_s": round(d * 1e-9, 6),
                              "batches": n}

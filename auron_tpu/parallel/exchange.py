@@ -438,11 +438,13 @@ class _MeshExchangeBuffer:
         in order — the per-source slice the demoted read path
         interleaves with host entries."""
         from auron_tpu.columnar.batch import DeviceBatch as _DB
+        from auron_tpu.obs import trace
         with self._lock:
             entries = list(self.entries)
         if _shards is None:
             _shards = self.partition_shards(p)
         home = self.mesh.devices.flat[0]
+        away = self.mesh.devices.flat[p] != home
         for (cols, counts, quota), shard_cols in zip(entries, _shards):
             n_s = int(counts[p, source])
             if n_s <= 0:
@@ -453,6 +455,11 @@ class _MeshExchangeBuffer:
                 source * quota + jnp.arange(cap, dtype=jnp.int32),
                 base.capacity - 1)
             out = gather_batch(base, idx, jnp.asarray(n_s, jnp.int32))
+            trace.count("mesh_read_batches")
+            if away:
+                # the second crossing: the slice's padded leaves
+                trace.count("mesh_home_bytes", sum(
+                    l.nbytes for l in jax.tree_util.tree_leaves(out)))
             # rebase onto the engine's home device: downstream
             # operators mix these rows with build sides / agg state
             # committed there (one ICI hop on a real slice; the

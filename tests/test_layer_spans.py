@@ -99,6 +99,49 @@ class TestLayerSpanAccounting:
         assert wait == pytest.approx(v2["layers_s"]["op_device_wait"],
                                      abs=2e-6)
 
+    def test_exchange_self_times_split_by_span(self):
+        """``exchange_s``: the exchange layer's self time by span, the
+        mesh route's three inside ``materialize``, an operator the
+        exchange drives outside it; it sums to ``layers_s.exchange``."""
+        with trace.task_scope("q-exchange") as acc:
+            with self._root(acc):
+                with trace.layer_span("exchange", "materialize"):
+                    _busy(0.002)
+                    with trace.layer_span("exchange", "gang_wait"):
+                        _busy(0.004)
+                    with trace.layer_span("op", "fused_stage"):
+                        _busy(0.003)
+                    with trace.layer_span("exchange", "mesh_round"):
+                        _busy(0.001)
+                        with trace.layer_span("op", "readback"):
+                            _busy(0.002)
+                with trace.layer_span("exchange", "map_write"):
+                    _busy(0.001)
+                v2 = acc.sealed(1.0)
+        split = v2["exchange_s"]
+        assert set(split) == set(trace.EXCHANGE_KEYS)
+        assert sum(split.values()) == pytest.approx(
+            v2["layers_s"]["exchange"], abs=2e-6)
+        assert 0.002 <= split["materialize"] < 0.004
+        assert 0.004 <= split["gang_wait"] < 0.006
+        # the round's one fence is no operator's: it stays in the span
+        # that made it (1 ms of launch + 2 ms of fence)
+        assert 0.003 <= split["mesh_round"] < 0.005
+        assert split["map_write"] >= 0.001
+        assert split["mesh_stack"] == 0 and split["broadcast_collect"] == 0
+        assert set(v2["ops_s"]) == {"fused_stage"}
+        assert v2["layers_s"]["op_device_wait"] == 0.0
+
+    def test_a_task_without_an_exchange_has_an_all_zero_split(self):
+        with trace.task_scope("q-none") as acc:
+            with self._root(acc):
+                with trace.layer_span("op", "agg"):
+                    _busy(0.001)
+                v2 = acc.sealed(1.0)
+        assert v2["exchange_s"] == dict.fromkeys(trace.EXCHANGE_KEYS, 0.0)
+        assert all(v2["counts"][k] == 0 for k in (
+            "mesh_read_batches", "mesh_home_bytes", "mesh_slot_bytes"))
+
     def test_readback_outside_an_operator_stays_in_its_layer(self):
         with trace.task_scope("q-fence") as acc:
             with self._root(acc):

@@ -1,20 +1,32 @@
 """The served 4-device mesh path against the plain reference (PR 28).
 
 TPC-DS as 4-partition stages over a 4-device mesh at a small scale, on 4
-of the 8 virtual CPU devices: each of the four star-join plans the
-benchmark serves (``it/tpcds_queries.py``'s q3 / q42 / q52 / q55) as ONE
-task — a 4-partition stage over 4 input splits — through ``AuronServer``
-/ ``AuronClient`` with ``auron.mesh.enabled``.
+of the 8 virtual CPU devices: each of the eight plans the benchmark
+serves — the star joins (``it/tpcds_queries.py``'s q3 / q42 / q52 / q55)
+and the wide aggregation (its q65 / q65m, and their check plans q65sa /
+q65sam, built here from the same helpers: q65's first aggregate answered
+by its hundred best-selling pairs) — as ONE task, a 4-partition stage
+over 4 input splits, through ``AuronServer`` / ``AuronClient`` with
+``auron.mesh.enabled``.
 
 - every answer equals the plan's own Acero oracle over exactly the
-  task's rows (integers, strings and decimal money exact) and the
-  mesh-off answer bit for bit;
+  task's rows (integers, strings and decimal money exact, doubles to
+  1e-7) and the mesh-off answer bit for bit;
 - the DONE frame of a mesh stage carries the exchange layer's three
   spans inside ``layers_s.exchange`` (``gang_wait``, ``mesh_stack``,
-  ``mesh_round``), ``layers_s`` still summing to ``wall_s``, the four
-  ``counts`` of the mesh route, and the recorded ``all_to_all`` route;
-- a one-chip task's frame has none of them non-zero.
+  ``mesh_round``), ``layers_s`` still summing to ``wall_s`` and
+  ``exchange_s`` to ``layers_s.exchange``, the ``counts`` of the mesh
+  route and of its read side, and the recorded ``all_to_all`` route:
+  three hash exchanges in a q65 / q65m stage (the (store, item)
+  aggregation is planned twice across its exchange, and the per-store
+  average above it), one in every other;
+- every exchange a stage leaves on ``device_buffer`` has a reason that is
+  not a hash exchange's;
+- a one-chip task's frame has none of them non-zero;
+- four stages at the gang door at once wait there, and answer right.
 """
+
+import threading
 
 import time
 
@@ -25,9 +37,15 @@ import jax
 from auron_tpu import config as cfg
 from auron_tpu.obs import trace
 
-PLANS = ("q3", "q42", "q52", "q55")
+STAR = ("q3", "q42", "q52", "q55")
+WIDE = ("q65", "q65m", "q65sa", "q65sam")
+PLANS = STAR + WIDE
+#: hash exchanges of a stage that cross the mesh
+MESH_EXCHANGES = dict.fromkeys(STAR + ("q65sa", "q65sam"), 1) \
+    | dict.fromkeys(("q65", "q65m"), 3)
 MESH_COUNTS = ("mesh_rounds", "mesh_escalations", "mesh_bytes",
-               "mesh_slot_bytes")
+               "mesh_slot_bytes", "mesh_read_batches", "mesh_home_bytes")
+MESH_SPAN_KEYS = ("gang_wait", "mesh_stack", "mesh_round")
 MESH_SPANS = ("exchange.gang_wait", "exchange.mesh_stack",
               "exchange.mesh_round")
 SCALE = 0.2                  # 100,000 fact rows in the task: every plan answers
@@ -35,6 +53,58 @@ SPLITS_PER_TASK = 4          # of the generator's 8 ``store_sales`` files
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 4,
                                 reason="needs 4 virtual devices")
+
+
+def _best_sellers(decimal_money: bool):
+    """The check plan of q65 (double money) / q65m (decimal): the same
+    ``sa`` subtree answered by its hundred best-selling (store, item)
+    pairs, ranked by the exact count of sales and the keys."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    from auron_tpu.columnar.schema import DataType
+    from auron_tpu.frontend.dataframe import col, functions as F
+    from auron_tpu.it.tpcds_queries import _join_dim, _oj, _rd, _topn
+
+    def run(s, t):
+        ss = _rd(s, t, "store_sales").select(
+            "ss_sold_date_sk", "ss_item_sk", "ss_store_sk", "ss_sales_price")
+        dd = _rd(s, t, "date_dim").filter(
+            (col("d_month_seq") >= 24) & (col("d_month_seq") <= 35)) \
+            .select("d_date_sk")
+        price = col("ss_sales_price")
+        if not decimal_money:
+            price = price.cast(DataType.FLOAT64)
+        return (_join_dim(ss, dd, "ss_sold_date_sk", "d_date_sk")
+                .group_by("ss_store_sk", "ss_item_sk")
+                .agg(F.sum(price).alias("revenue"),
+                     F.count(col("ss_sales_price")).alias("sales"))
+                .sort(col("sales").desc(), col("ss_store_sk").asc(),
+                      col("ss_item_sk").asc())
+                .limit(100).collect())
+
+    def oracle(a):
+        seq = a["date_dim"]["d_month_seq"]
+        dd = a["date_dim"].filter(pc.and_(
+            pc.greater_equal(seq, 24), pc.less_equal(seq, 35))) \
+            .select(["d_date_sk"])
+        ssj = _oj(a["store_sales"], dd, ["ss_sold_date_sk"], ["d_date_sk"])
+        money = pa.decimal128(17, 2) if decimal_money else pa.float64()
+        if not decimal_money:
+            ssj = ssj.set_column(
+                ssj.column_names.index("ss_sales_price"), "ss_sales_price",
+                ssj["ss_sales_price"].cast(money))
+        sa = ssj.group_by(["ss_store_sk", "ss_item_sk"], use_threads=False) \
+            .aggregate([("ss_sales_price", "sum"),
+                        ("ss_sales_price", "count")]) \
+            .rename_columns(["ss_store_sk", "ss_item_sk", "revenue",
+                             "sales"])
+        sa = sa.set_column(2, "revenue", sa["revenue"].cast(money))
+        return _topn(sa, [("sales", "descending"),
+                          ("ss_store_sk", "ascending"),
+                          ("ss_item_sk", "ascending")])
+
+    return run, oracle
 
 
 def _leaf_sum(tree, key) -> float:
@@ -56,7 +126,7 @@ def stage(tmp_path_factory):
     plan's."""
     from auron_tpu.frontend.session import Session
     from auron_tpu.it import tpcds
-    from auron_tpu.it.tpcds_queries import QUERIES
+    from auron_tpu.it.tpcds_queries import QUERIES, Query
     from auron_tpu.parallel import mesh
     from auron_tpu.runtime.serving import AuronClient, AuronServer
 
@@ -73,10 +143,12 @@ def stage(tmp_path_factory):
     # ``store_sales`` as 4 partitions)
     tables["store_sales"] = tables["store_sales"][:SPLITS_PER_TASK]
     queries = {q.name: q for q in QUERIES if q.name in PLANS}
+    queries["q65sa"] = Query("q65sa", "q65's check", *_best_sellers(False))
+    queries["q65sam"] = Query("q65sam", "q65m's check", *_best_sellers(True))
     session = PlanOnly()
     tasks = {p: queries[p].run(session, tables) for p in PLANS}
     arrow = tpcds.load_arrow({name: tables[name] for name in
-                              ("store_sales", "date_dim", "item")})
+                              ("store_sales", "date_dim", "item", "store")})
     server = AuronServer()
     server.serve_background()
     host, port = server.address
@@ -94,6 +166,8 @@ def stage(tmp_path_factory):
             conf.unset(cfg.MESH_DEVICES)
 
     run.oracle = lambda plan: queries[plan].oracle(arrow)
+    run.client = lambda: AuronClient(host, port, timeout_s=600)
+    run.tasks = tasks
     yield run
     server.shutdown()
     server.server_close()
@@ -115,8 +189,10 @@ def test_mesh_answer_equals_the_oracle_and_the_single_device_answer(
     table, _done = answers[plan]["mesh"]
     single, _ = answers[plan]["single"]
     assert table.num_rows > 0
-    # money is decimal(7,2) summed as decimal: compared exactly
-    res = QueryResultComparator().compare(plan, table, stage.oracle(plan))
+    # money is decimal(7,2) summed as decimal: compared exactly; q65's
+    # and q65sa's double sums crossed the chips as partial sums
+    res = QueryResultComparator(double_rel_tol=1e-7).compare(
+        plan, table, stage.oracle(plan))
     assert res.ok, res.report()
     assert table.equals(single), \
         f"{plan}: the mesh stage's answer differs from mesh-off " \
@@ -133,19 +209,29 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
     assert sum(layers.values()) == pytest.approx(led["wall_s"], abs=1e-5)
     assert layers["exchange"] > 0
     assert layers["other"] > -1e-4
+    # the layer's self time by span: the door, the stack and the round
+    # are in it, and nothing of the layer is outside the split
+    split = led["exchange_s"]
+    assert sum(split.values()) == pytest.approx(layers["exchange"], abs=1e-5)
+    assert all(split[k] > 0 for k in MESH_SPAN_KEYS + ("materialize",))
     counts = led["counts"]
-    assert counts["mesh_rounds"] >= 1
+    assert counts["mesh_rounds"] == MESH_EXCHANGES[plan]
+    # every reducer partition reads at most one slice a source and round
+    assert 0 < counts["mesh_read_batches"] <= 16 * counts["mesh_rounds"]
+    # three of the four partitions live on another chip than the home one
+    assert counts["mesh_home_bytes"] > 0
     assert counts["mesh_escalations"] >= 0
     assert 0 < counts["mesh_bytes"] <= counts["mesh_slot_bytes"]
     # one number under two names: the version-1 key and the new count
     assert counts["mesh_bytes"] == led["mesh_bytes"]
-    assert _leaf_sum(done, "exchange_route_all_to_all") >= 1
+    assert _leaf_sum(done, "exchange_route_all_to_all") \
+        == MESH_EXCHANGES[plan]
     assert _leaf_sum(done, "exchange_route_demoted") == 0
     # the stage's program calls are counted like every other program's
     assert counts["program_calls"] > 0 and counts["readbacks"] > 0
 
 
-@pytest.mark.parametrize("plan", PLANS)
+@pytest.mark.parametrize("plan", STAR)
 def test_mesh_stage_moves_the_columns_its_plan_reads(plan, stage, answers):
     """The planner's required-columns pass (``ir/pruning.py``) narrows
     the scans before the stage's input spec is taken from them: all four
@@ -171,6 +257,9 @@ def test_one_chip_frame_has_no_mesh_count(plan, answers):
     assert _leaf_sum(done, "exchange_route_all_to_all") == 0
     layers = led["layers_s"]
     assert sum(layers.values()) == pytest.approx(led["wall_s"], abs=1e-5)
+    split = led["exchange_s"]
+    assert sum(split.values()) == pytest.approx(layers["exchange"], abs=1e-5)
+    assert all(split[k] == 0 for k in MESH_SPAN_KEYS)
 
 
 def _recorded_spans(run):
@@ -223,6 +312,82 @@ def test_the_three_spans_lie_inside_the_exchange_layer(stage, answers):
     inside_s = sum(s.dur_ns for s in mine) * 1e-9
     layers = led["layers_s"]
     assert 0 < inside_s <= layers["exchange"] + layers["compile"] + 1e-4
+
+
+@pytest.mark.parametrize("plan", WIDE)
+def test_every_hash_exchange_of_a_wide_stage_takes_the_mesh(plan, stage,
+                                                            answers):
+    """The recorded decisions of one stage, exchange by exchange: each
+    ``HashPartitioning`` exchange of two or more outputs is on
+    ``all_to_all``, and what stays on ``device_buffer`` says why in
+    ``exchange_route``'s own words — another partitioning: the global
+    sort's range exchange and the gather into the one partition the
+    limit reads."""
+    (_table, done), spans = _recorded_spans(lambda: stage(plan, True))
+    routes = [s.attrs for s in spans if s.name == "exchange.route"]
+    assert len(routes) == _leaf_sum(done, "exchange_route_all_to_all") \
+        + _leaf_sum(done, "exchange_route_device_buffer")
+    for r in routes:
+        hashed = "HashPartitioning" in r["op"]
+        if r["route"] == "all_to_all":
+            assert hashed and r["partitions"] == 4 and r["reason"] == "mesh"
+        else:
+            assert r["route"] == "device_buffer"
+            assert r["reason"] == "single_output" and r["partitions"] == 1 \
+                or r["reason"].startswith("partitioning_") and not hashed
+    on_mesh = [r for r in routes if r["route"] == "all_to_all"]
+    assert len(on_mesh) == MESH_EXCHANGES[plan]
+    assert sorted(r["reason"] for r in routes if r not in on_mesh) == [
+        "partitioning_RangePartitioning", "partitioning_SinglePartitioning"]
+
+
+def test_four_stages_at_the_gang_door_wait_and_answer_right(stage):
+    """As many clients as task slots, each with a wide stage: the map
+    side runs inside the door, so someone parks there, and the frame of
+    the stage that did says for how long."""
+    from auron_tpu.it.comparator import QueryResultComparator
+    from auron_tpu.parallel import mesh
+    conf = cfg.get_config()
+    conf.set(cfg.MESH_ENABLED, True)
+    conf.set(cfg.MESH_DEVICES, 4)
+    got, errors = {}, []
+
+    def client(plan):
+        try:
+            got[plan] = stage.client().execute(stage.tasks[plan])
+        except Exception as e:      # seen below, on the test's thread
+            errors.append((plan, e))
+
+    try:
+        before = mesh.current_plane().stats()["gang_contended"]
+        threads = [threading.Thread(target=client, args=(p,)) for p in WIDE]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        contended = mesh.current_plane().stats()["gang_contended"] - before
+    finally:
+        conf.unset(cfg.MESH_ENABLED)
+        conf.unset(cfg.MESH_DEVICES)
+    assert not errors, errors
+    assert contended >= 1
+    waits = {}
+    for plan in WIDE:
+        table, done = got[plan]
+        res = QueryResultComparator(double_rel_tol=1e-7).compare(
+            plan, table, stage.oracle(plan))
+        assert res.ok, res.report()
+        led = done["cost_ledger"]
+        assert _leaf_sum(done, "exchange_route_all_to_all") \
+            == MESH_EXCHANGES[plan]
+        assert _leaf_sum(done, "exchange_route_demoted") == 0
+        split = led["exchange_s"]
+        assert sum(split.values()) == pytest.approx(
+            led["layers_s"]["exchange"], abs=1e-5)
+        waits[plan] = split["gang_wait"]
+    # a parked ticket polls every 50 ms: the wait of the one that parked
+    # longest is far over the door's own cost
+    assert max(waits.values()) > 0.02, waits
 
 
 def test_one_chip_task_opens_none_of_the_three_spans(stage, answers):
