@@ -89,6 +89,36 @@ def _sort_by_pid_kernel(num_partitions: int, capacity: int, donate: bool):
                         donate_argnums=(0,) if donate else ())
 
 
+@program_cache("parallel.exchange.read_cut", maxsize=256)
+def _read_cut_kernel(n_slices: int, capacity: int):
+    """The reduce side's read: ``n_slices`` row ranges of ONE column
+    tree, each cut out as a batch of ``capacity`` rows. The ranges are
+    an OPERAND (``bounds``: int32[2, n_slices], starts over live
+    counts), so the key holds shapes only and no run's counts compile;
+    rows past a slice's count are padding, masked invalid — the same
+    rows in the same order the eager ``gather_batch`` gave."""
+
+    def auron_parallel_exchange_read_cut(columns, bounds):
+        base = DeviceBatch(columns, bounds[1, 0])
+        last = base.capacity - 1
+        rows = jnp.arange(capacity, dtype=jnp.int32)
+        return tuple(
+            gather_batch(base, jnp.minimum(bounds[0, i] + rows, last),
+                         bounds[1, i])
+            for i in range(n_slices))
+
+    return programs.jit(auron_parallel_exchange_read_cut)
+
+
+def _cut(columns, starts, counts, capacity: int) -> tuple:
+    """One launch of the read-cut program: rows ``[starts[i], starts[i]
+    + counts[i])`` of ``columns`` as a batch of ``capacity`` rows, for
+    every i, on the device the columns live on. The host's starts and
+    counts ride the call as its one small operand."""
+    bounds = np.array([starts, counts], np.int32)
+    return _read_cut_kernel(bounds.shape[1], capacity)(columns, bounds)
+
+
 #: fused split programs: the upstream fused-stage chain (when present),
 #: the partition-id computation and the sort-by-pid compaction in ONE
 #: XLA program — the whole-stage-fusion prologue of the exchange
@@ -285,11 +315,8 @@ class _ExchangeBuffer:
             # "dev" or "dev-spilling": the device batch in this
             # snapshot's entry list stays valid even if a concurrent
             # spill swaps the entry afterwards
-            batch = e[1]
-            cap = bucket_rows(n_p)
-            idx = jnp.minimum(lo + jnp.arange(cap, dtype=jnp.int32),
-                              batch.capacity - 1)
-            return gather_batch(batch, idx, jnp.asarray(n_p, jnp.int32))
+            (out,) = _cut(e[1].columns, [lo], [n_p], bucket_rows(n_p))
+            return out
         host, _extras = deserialize_host_batch(e[1].frame_at(p))
         return host_to_batch(host, bucket_rows(n_p))
 
@@ -421,58 +448,63 @@ class _MeshExchangeBuffer:
     def spill(self) -> int:
         return 0   # device-resident by design (see class docstring)
 
-    def partition_shards(self, p: int) -> list:
-        """Device ``p``'s zero-copy shard tree of every round — hoisted
-        ONCE per partition by both read paths (recomputing per source
-        would tree_map n_out× per reducer)."""
+    def partition_cuts(self, p: int) -> list:
+        """Partition ``p``'s received rows on the home device, cut by
+        source: for every round that brought ``p`` anything, the row of
+        its live counts a source and the ``n_out`` batches of ONE
+        read-cut call (``parallel.exchange.read_cut``) over device
+        ``p``'s shard. A shard on another chip crosses to the engine's
+        home device first, in ONE ``device_put`` of its tree, and is
+        cut there: downstream operators mix these rows with build sides
+        and aggregation state committed at home, and one program serves
+        every partition (cut where it lies, the program would be
+        loaded a chip and its ``n_out`` slices would cross leaf by
+        leaf). The slices of a round share one capacity, the bucket of
+        its fullest slice. Hoisted ONCE a partition by both read
+        paths."""
+        from auron_tpu.obs import trace
         from auron_tpu.parallel import mesh as mesh_mod
         with self._lock:
             entries = list(self.entries)
-        return [jax.tree_util.tree_map(
-            lambda a: mesh_mod.local_shard(a, p, self.mesh), cols)
-            for cols, _counts, _quota in entries]
-
-    def source_batches(self, p: int, source: int,
-                       _shards=None) -> Iterator[DeviceBatch]:
-        """Partition ``p``'s rows received from ONE source map, rounds
-        in order — the per-source slice the demoted read path
-        interleaves with host entries."""
-        from auron_tpu.columnar.batch import DeviceBatch as _DB
-        from auron_tpu.obs import trace
-        with self._lock:
-            entries = list(self.entries)
-        if _shards is None:
-            _shards = self.partition_shards(p)
         home = self.mesh.devices.flat[0]
         away = self.mesh.devices.flat[p] != home
-        for (cols, counts, quota), shard_cols in zip(entries, _shards):
-            n_s = int(counts[p, source])
-            if n_s <= 0:
+        cuts = []
+        for cols, counts, quota in entries:
+            live = counts[p]
+            top = int(live.max())
+            if top <= 0:
                 continue
-            cap = bucket_rows(n_s)
-            base = _DB(shard_cols, jnp.asarray(n_s, jnp.int32))
-            idx = jnp.minimum(
-                source * quota + jnp.arange(cap, dtype=jnp.int32),
-                base.capacity - 1)
-            out = gather_batch(base, idx, jnp.asarray(n_s, jnp.int32))
-            trace.count("mesh_read_batches")
+            shard = jax.tree_util.tree_map(
+                lambda a: mesh_mod.local_shard(a, p, self.mesh), cols)
             if away:
-                # the second crossing: the slice's padded leaves
+                # the second crossing: the shard's padded leaves
                 trace.count("mesh_home_bytes", sum(
-                    l.nbytes for l in jax.tree_util.tree_leaves(out)))
-            # rebase onto the engine's home device: downstream
-            # operators mix these rows with build sides / agg state
-            # committed there (one ICI hop on a real slice; the
-            # HBM-tier item keeps them resident per-device later)
-            yield jax.device_put(out, home)
+                    l.nbytes for l in jax.tree_util.tree_leaves(shard)))
+                shard = jax.device_put(shard, home)
+            cuts.append((live, _cut(
+                shard, np.arange(self.n_out) * quota, live,
+                bucket_rows(top))))
+        return cuts
+
+    def source_batches(self, p: int, source: int,
+                       _cuts=None) -> Iterator[DeviceBatch]:
+        """Partition ``p``'s rows received from ONE source map, rounds
+        in order — the per-source slice the demoted read path
+        interleaves with host entries; empty slices are skipped."""
+        from auron_tpu.obs import trace
+        if _cuts is None:
+            _cuts = self.partition_cuts(p)
+        for live, batches in _cuts:
+            if live[source] > 0:
+                trace.count("mesh_read_batches")
+                yield batches[source]
 
     def partition_batches(self, p: int) -> Iterator[DeviceBatch]:
-        # device p's shard of every round, materialized zero-copy once
-        shards = self.partition_shards(p)
+        cuts = self.partition_cuts(p)
         # SOURCE-major, rounds-minor: map s's round-r rows appear where
         # the host path's entry (map s, batch r) would
         for s in range(self.n_out):
-            yield from self.source_batches(p, s, _shards=shards)
+            yield from self.source_batches(p, s, _cuts=cuts)
 
     def close(self) -> None:
         if self.mem is not None:
@@ -510,12 +542,11 @@ class _DemotedExchangeBuffer:
         by_source: dict[int, list[int]] = {}
         for i, s in enumerate(self.host_sources):
             by_source.setdefault(s, []).append(i)
-        # hoist the per-round shard trees ONCE per partition (the pure-
-        # mesh read path's discipline) instead of once per source
-        shards = self.mesh_buffer.partition_shards(p)
+        # the partition's ONE cut a round, hoisted as the pure-mesh
+        # read path hoists it
+        cuts = self.mesh_buffer.partition_cuts(p)
         for s in range(self.n_out):
-            yield from self.mesh_buffer.source_batches(p, s,
-                                                       _shards=shards)
+            yield from self.mesh_buffer.source_batches(p, s, _cuts=cuts)
             idxs = by_source.get(s)
             if idxs:
                 yield from self.host_buffer.entry_batches(p, idxs)

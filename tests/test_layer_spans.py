@@ -491,6 +491,15 @@ def served(tmp_path_factory):
 
 
 class TestServedLedger:
+    def test_a_one_chip_task_reads_no_exchange(self, served):
+        """A task of the one-chip cells is one scan partition: its plan
+        holds no exchange, so the read program's site is not in it."""
+        counts = served("q3")["counts"]
+        assert counts["program_calls"] > 0
+        assert "parallel.exchange.read_cut" not in \
+            counts["program_calls_by_site"]
+        assert counts["mesh_read_batches"] == 0
+
     def test_version_2_keeps_every_version_1_key(self, served):
         led = served("q3")
         assert led["version"] == 2

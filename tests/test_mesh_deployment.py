@@ -22,6 +22,10 @@ over 4 input splits, through ``AuronServer`` / ``AuronClient`` with
   average above it), one in every other;
 - every exchange a stage leaves on ``device_buffer`` has a reason that is
   not a hash exchange's;
+- a mesh partition's slices of a round are cut by ONE call of the read
+  program and every delivered batch of the host route by one: the site's
+  count in the frame is those two, and the slices delivered are what the
+  eager read delivered (16 / 44 a check / q65 stage);
 - a one-chip task's frame has none of them non-zero;
 - four stages at the gang door at once wait there, and answer right.
 """
@@ -46,6 +50,12 @@ MESH_EXCHANGES = dict.fromkeys(STAR + ("q65sa", "q65sam"), 1) \
 MESH_COUNTS = ("mesh_rounds", "mesh_escalations", "mesh_bytes",
                "mesh_slot_bytes", "mesh_read_batches", "mesh_home_bytes")
 MESH_SPAN_KEYS = ("gang_wait", "mesh_stack", "mesh_round")
+#: the program the reduce side reads an exchange buffer with (PR 39)
+READ_CUT = "parallel.exchange.read_cut"
+#: non-empty (partition, source, round) slices a wide stage's reducers
+#: read: 16 an ``sa`` exchange, 12 the per-store average's (12 stores)
+WIDE_SLICES = dict.fromkeys(("q65sa", "q65sam"), 16) \
+    | dict.fromkeys(("q65", "q65m"), 44)
 MESH_SPANS = ("exchange.gang_wait", "exchange.mesh_stack",
               "exchange.mesh_round")
 SCALE = 0.2                  # 100,000 fact rows in the task: every plan answers
@@ -229,6 +239,30 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
     assert _leaf_sum(done, "exchange_route_demoted") == 0
     # the stage's program calls are counted like every other program's
     assert counts["program_calls"] > 0 and counts["readbacks"] > 0
+
+
+@pytest.mark.parametrize("plan", PLANS)
+def test_mesh_stage_reads_its_exchanges_with_the_cut_program(plan, answers):
+    """One call a mesh partition and round that brought it anything, one
+    a batch the host route delivers (the range and the single exchange
+    of the stage's tail); the batches delivered do not change."""
+    _table, done = answers[plan]["mesh"]
+    counts = done["cost_ledger"]["counts"]
+    slices = counts["mesh_read_batches"]
+    delivered = done["shuffle_exchange_read"]["output_batches"]
+    mesh_cuts = counts["program_calls_by_site"][READ_CUT] \
+        - (delivered - slices)
+    partition_rounds = 4 * counts["mesh_rounds"]
+    assert -(-slices // 4) <= mesh_cuts <= partition_rounds
+    if plan in WIDE:
+        assert slices == WIDE_SLICES[plan]
+        # thousands of (store, item) groups fill every partition of an
+        # ``sa`` exchange; the 12 stores of the third may leave one empty
+        assert mesh_cuts >= partition_rounds - (plan in ("q65", "q65m"))
+    # the route without a mesh reads every batch with one call
+    _table, single = answers[plan]["single"]
+    assert single["cost_ledger"]["counts"]["program_calls_by_site"][
+        READ_CUT] == single["shuffle_exchange_read"]["output_batches"]
 
 
 @pytest.mark.parametrize("plan", STAR)

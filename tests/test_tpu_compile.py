@@ -53,3 +53,45 @@ def test_a_double_sort_key_compiles_for_the_chip(one_chip):
         return jnp.argsort(sort.f64_split_order_word(d), stable=True)
 
     assert _compile(permutation, rows) is not None
+
+
+def _columns(one_chip, rows, layout):
+    """Shapes of a column tree on the described chip: ``layout`` names a
+    dtype a primitive column, or a string's width."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import PrimitiveColumn, StringColumn
+
+    def leaf(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    valid = leaf((rows,), jnp.bool_)
+    return tuple(
+        StringColumn(leaf((rows, kind), jnp.uint8),
+                     leaf((rows,), jnp.int32), valid)
+        if isinstance(kind, int) else
+        PrimitiveColumn(leaf((rows,), kind), valid)
+        for kind in layout)
+
+
+@pytest.mark.parametrize("layout, quota, capacity", [
+    # q65's partial state across the all_to_all: (store, item), the
+    # double partial sum and the count; a shard of 4 x 2,048 slots
+    (("int32", "int32", "float64", "int64"), 2048, 2048),
+    # the star joins': a brand string beside its ids and a decimal sum
+    # held as one int64 word; 4 x 32,768 slots, a dozen rows a slice
+    ((64, "int32", "int32", "int64"), 32768, 16),
+], ids=["q65_state", "star_join_string_key"])
+def test_the_exchange_read_cut_compiles_for_the_chip(one_chip, layout,
+                                                     quota, capacity):
+    """The reduce side's read as one program (PR 39): a double partial
+    sum is a gathered PAYLOAD in it, never a key or a bitcast, and the
+    slice bounds are a small int32 operand."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.parallel import exchange
+    cut = exchange._read_cut_kernel(4, capacity)
+    bounds = jax.ShapeDtypeStruct((2, 4), jnp.int32, sharding=one_chip)
+    compiled = cut.lower(_columns(one_chip, 4 * quota, layout),
+                         bounds).compile()
+    assert compiled is not None
