@@ -50,7 +50,7 @@ def _contains_call(node: ast.AST, suffixes: tuple) -> bool:
 
 #: the sanctioned sync wrappers (obs/profile.py): waits routed through
 #: them are credited as device time
-_SANCTIONED = ("timed_get", "device_fence")
+_SANCTIONED = ("timed_get", "device_fence", "row_count")
 
 #: call roots that mark a host-side value (skipped as candidates)
 _HOST_FUNCS = frozenset((
@@ -76,7 +76,8 @@ class SyncDiscipline(Rule):
     rule_id = "GL001"
     title = "sync-discipline"
     hint = ("route the readback through profile.timed_get(...) inside "
-            "the operator's timer frame, or fence the semantic "
+            "the operator's timer frame (a batch's row count through "
+            "profile.row_count(batch)), or fence the semantic "
             "boundary with profile.device_fence(...); a provably "
             "host-only conversion may carry "
             "'# graft: disable=GL001 -- <why it is host-side>'")

@@ -25,11 +25,18 @@ finalize:
 Version 2 adds what the task's own accumulator measured
 (``obs/trace.TaskAccumulator``, filled by the layer spans and not by
 operator snapshots): ``queue_s``; ``layers_s``, exclusive seconds by
-layer on the task's thread, summing to ``wall_s``; ``exchange_s``, the
+layer on the task's thread, summing to ``wall_s``; ``layers_cpu_s``,
+the CPU the task's thread spent inside those same self times (no
+``compile``, no ``other``: each at most its layer's wall, the rest of
+which is waiting; None, like the other CPU fields, in a task that was
+not CPU-timed: ``obs/trace.CPU_TIMED_EVERY``); ``exchange_s``, the
 exchange layer's part of it by span; ``ops_s``, the operators' part of
-it by operator; ``scan_worker_s``, the prefetch
-worker's decode / encode / transfer beside it; ``cpu_s``; ``counts`` of
-program calls, readbacks and transfers; and ``compile.task_*``, the
+it by operator (``host_s`` and the ``cpu_s`` inside it, ``device_wait_s``);
+``scan_worker_s``, the prefetch
+worker's decode / encode / transfer beside it, and ``scan_worker_cpu_s``,
+their CPU; ``cpu_s``; ``counts`` of
+program calls, readbacks, row-count reads and transfers; and
+``compile.task_*``, the
 compiles that fired on the task's own threads. Every version-1 key
 keeps its meaning — ``device_s`` is still the inclusive sum of
 ``elapsed_compute``, a host wait — and its readers.
@@ -142,9 +149,11 @@ def build(snaps: Optional[Iterable[dict]], *, query_id: str = "",
         "wall_s": round(float(wall_s), 6),
         "queue_s": v2["queue_s"],
         "layers_s": v2["layers_s"],
+        "layers_cpu_s": v2["layers_cpu_s"],
         "exchange_s": v2["exchange_s"],
         "ops_s": v2["ops_s"],
         "scan_worker_s": v2["scan_worker_s"],
+        "scan_worker_cpu_s": v2["scan_worker_cpu_s"],
         "cpu_s": v2["cpu_s"],
         "counts": v2["counts"],
         "device_s": round(device_ns * 1e-9, 6),

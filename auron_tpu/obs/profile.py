@@ -39,17 +39,15 @@ Recording contract (same shape as obs/trace.py):
   so nested/inclusive timers keep today's inclusive semantics and the
   residue lands in the inner operator's ``other``.
 
-Beyond the per-op counters, each wrapped call feeds two process
-histograms (``auron_dispatch_overhead_seconds`` /
-``auron_device_call_seconds`` — the per-batch dispatch-overhead
-p50/p95/p99 of the registry scrape) and, when the ``program`` trace
-category records, a ``program.call`` span carrying the split so
-tools/trace_report.py can print host/device columns.
+Beyond the per-op counters, when the ``program`` trace category
+records, each wrapped call drops a ``program.call`` span carrying the
+split so tools/trace_report.py can print host/device columns.
 
 The frames above are INCLUSIVE (a parent's timer runs across its
 child's ``next()``); the exclusive split by layer and operator is the
 layer spans' (obs/trace.layer_span), which the sync points here open as
-``auron:op/readback`` and count as the task's ``readbacks``.
+``auron:op/readback`` and count as the task's ``readbacks`` (``timed_get``,
+``device_fence``) or ``row_syncs`` (``row_count``).
 """
 
 from __future__ import annotations
@@ -61,12 +59,6 @@ from auron_tpu.obs import trace as _trace
 
 #: host-bucket vocabulary (counter names are "elapsed_host_" + bucket)
 HOST_BUCKETS = ("dispatch", "convert", "serde", "iter", "other")
-
-#: finer-than-default histogram buckets (seconds): python dispatch glue
-#: and single-batch device calls live in the 10µs–100ms range the
-#: registry's 1ms-floor latency buckets cannot resolve
-CALL_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
-                5e-3, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0, 5.0)
 
 #: (config epoch, enabled) verdict cache — the disabled hot path is one
 #: int compare (the trace/faults pattern)
@@ -182,24 +174,6 @@ def pop_frame(frame: Frame, sink, wall_ns: int,
         sink.counter("elapsed_host_other").add(other)
 
 
-def add_host(bucket: str, ns: int) -> None:
-    """Credit ``ns`` host nanoseconds of ``bucket`` to the innermost
-    open frame (no-op without one) — for host sections nested inside a
-    compute timer."""
-    st = getattr(_TLS, "stack", None)
-    if not st:
-        return
-    f = st[-1]
-    if bucket == "convert":
-        f.convert += ns
-    elif bucket == "serde":
-        f.serde += ns
-    elif bucket == "iter":
-        f.iter += ns
-    else:
-        f.dispatch += ns
-
-
 # ---------------------------------------------------------------------------
 # program-call instrumentation (runtime/programs.py wraps through here)
 # ---------------------------------------------------------------------------
@@ -213,8 +187,8 @@ def _block(out) -> None:
 
 def on_call(dispatch_ns: int, device_ns: int, site: str) -> None:
     """One wrapped program invocation's split: credit the innermost
-    frame, feed the registry histograms, and drop a ``program.call``
-    span when that trace category records."""
+    frame and drop a ``program.call`` span when that trace category
+    records."""
     st = getattr(_TLS, "stack", None)
     if st:
         f = st[-1]
@@ -222,13 +196,6 @@ def on_call(dispatch_ns: int, device_ns: int, site: str) -> None:
         f.device += device_ns
         f.calls += 1
     _trace.count_program_call(site)
-    from auron_tpu.obs import registry as _registry
-    if _registry.enabled():
-        r = _registry.get_registry()
-        r.histogram("auron_dispatch_overhead_seconds",
-                    buckets=CALL_BUCKETS).observe(dispatch_ns * 1e-9)
-        r.histogram("auron_device_call_seconds",
-                    buckets=CALL_BUCKETS).observe(device_ns * 1e-9)
     if _trace.category_enabled("program"):
         total = dispatch_ns + device_ns
         # start reconstructed from the durations: no clock reads beyond
@@ -300,12 +267,22 @@ def device_fence(value, sink=None) -> int:
         st[-1].device += ns
     elif sink is not None:
         sink.counter("elapsed_device").add(ns)
-    from auron_tpu.obs import registry as _registry
-    if _registry.enabled():
-        _registry.get_registry().histogram(
-            "auron_device_call_seconds",
-            buckets=CALL_BUCKETS).observe(ns * 1e-9)
     return ns
+
+
+def _get(values, counted_as: str):
+    """``jax.device_get`` inside ``auron:op/readback``, the wait
+    credited to the innermost open frame's device bucket."""
+    import time
+
+    import jax
+    t0 = time.perf_counter_ns()
+    with _trace.readback_span(counted_as):
+        out = jax.device_get(values)
+    st = getattr(_TLS, "stack", None)
+    if st:
+        st[-1].device += time.perf_counter_ns() - t0
+    return out
 
 
 def timed_get(values):
@@ -315,19 +292,31 @@ def timed_get(values):
     that ARE real sync points: they carry the device wait of the
     programs dispatched before them, and attributing them as device
     keeps the host buckets honest."""
-    import time
-
     import jax
-    t0 = time.perf_counter_ns()
-    with _trace.readback_span():
-        out = jax.device_get(values)
-    st = getattr(_TLS, "stack", None)
-    if st:
-        st[-1].device += time.perf_counter_ns() - t0
+    out = _get(values, "readbacks")
     _trace.count("d2h_bytes", sum(
         getattr(leaf, "nbytes", 0)
         for leaf in jax.tree_util.tree_leaves(out)))
     return out
+
+
+def row_count(batch) -> int:
+    """``int(batch.num_rows)`` of a batch, or of a count that is handed
+    in itself (a state's group count). A count that is still a device
+    scalar is a sync point like any other — the read waits for the
+    program that makes the batch, and for the chip's queue before it —
+    so it is read as ``timed_get`` reads (``jax.device_get`` inside
+    ``auron:op/readback``: device wait of the operator whose span it is
+    in), and counted among the task's ``row_syncs``, not its
+    ``readbacks``. A count already on the host returns at once with no
+    span."""
+    n = getattr(batch, "num_rows", batch)
+    if type(n) is int:
+        return n
+    import jax
+    if not isinstance(n, jax.Array):
+        return int(n)
+    return int(_get(n, "row_syncs"))
 
 
 # ---------------------------------------------------------------------------

@@ -39,6 +39,18 @@ inside it cover, per thread) to the running task's
 :class:`TaskAccumulator`, which ``obs/ledger.build`` folds into the
 version-2 ledger; and with tracing on the ``Span`` is recorded as any
 other. They live on the one per-thread stack this module already has.
+Beside the wall clock a layer span reads the thread's CPU clock
+(``time.thread_time_ns``), and its CPU self time is charged where its
+wall self time is: what is left of a span's wall after its CPU is a
+wait the span did not declare — for the interpreter lock, in a C call
+that blocks, or descheduled. That clock is a system call — 6 µs on the
+chip's host where the wall clock is 0.07, in ticks of 10 ms, the
+interpreter held while it runs: read round every span of every task it
+cost the host-bound cells 5 % of their rate. So one task in
+:data:`CPU_TIMED_EVERY` is *CPU-timed* and only its spans read it (a
+window's mean is the reading; the other tasks' frames lack the CPU
+fields), and the spans that ARE a declared wait
+(:data:`DECLARED_WAITS`) leave it alone in every task.
 
 Config surface: ``auron.trace.{enabled,dir,events,max_spans}``
 (config.py). The knobs are deliberately NOT trace-semantic in the
@@ -451,12 +463,30 @@ _LAYER_KEY = {"plan": "plan", "scan": "scan_wait", "convert": "to_arrow",
               "exchange": "exchange"}
 LAYER_KEYS = ("plan", "compile", "scan_wait", "op_host", "op_device_wait",
               "exchange", "to_arrow", "send")
+#: ``layers_cpu_s`` keys: the layers a span charges (a compile's CPU is
+#: not known apart from the span it fired in)
+LAYER_CPU_KEYS = tuple(k for k in LAYER_KEYS if k != "compile")
+#: one task in this many reads the CPU clock round its spans (a prime, so
+#: that the timed tasks of a round-robin mix of 4 plans from 4 clients are
+#: not all of one plan)
+CPU_TIMED_EVERY = 7
+_TASK_SEQ = itertools.count()
+#: (layer, key) of the spans that wait by declaration — for the device,
+#: the scan worker, the gang door, a scheduler slot: their CPU is ~0 by
+#: construction, so they book none and do not read the CPU clock
+DECLARED_WAITS = frozenset({("op", "readback"), ("scan", "wait"),
+                            ("exchange", "gang_wait"),
+                            ("serve", "queue")})
 SCAN_WORKER_KEYS = ("decode", "encode", "h2d")
 #: the exchange layer's spans: ``exchange_s`` splits ``layers_s.exchange``
 #: by them (the last three only on the mesh route)
 EXCHANGE_KEYS = ("materialize", "map_write", "broadcast_collect",
                  "gang_wait", "mesh_stack", "mesh_round")
-COUNT_KEYS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
+COUNT_KEYS = ("program_calls", "readbacks",
+              # row-count reads of a device batch (``obs/profile.
+              # row_count``): syncs too, counted apart from the control
+              # readbacks above
+              "row_syncs", "d2h_bytes", "h2d_transfers",
               "h2d_bytes", "encode_pyloop_values",
               # a file scan's width: the columns it reads, and those of
               # its files it leaves unread (ir/pruning.py)
@@ -513,19 +543,26 @@ class TaskAccumulator:
     can be alive at once). :meth:`sealed` is what ``obs/ledger.build``
     folds into the ledger."""
 
-    __slots__ = ("query_id", "queue_ns", "layers", "exchange", "ops",
+    __slots__ = ("query_id", "cpu_timed", "queue_ns", "layers", "layers_cpu",
+                 "exchange", "ops",
                  "counts", "calls_by_site", "layer_spans", "compiles",
                  "compile_ns",
-                 "worker_ns", "worker_counts", "worker_spans",
+                 "worker_ns", "worker_span_cpu_ns", "worker_counts",
+                 "worker_spans",
                  "worker_cpu_ns", "worker_compiles", "worker_compile_ns",
                  "_cpu0", "_lock")
 
     def __init__(self, query_id: str = ""):
         self.query_id = query_id
+        #: do this task's spans read the CPU clock (module docstring)
+        self.cpu_timed = next(_TASK_SEQ) % CPU_TIMED_EVERY == 0
         self.queue_ns = 0
         self.layers = dict.fromkeys(LAYER_KEYS, 0)
+        #: the same spans' CPU self time (thread CPU clock): what of a
+        #: layer's wall the task's thread spent computing
+        self.layers_cpu = dict.fromkeys(LAYER_CPU_KEYS, 0)
         self.exchange = dict.fromkeys(EXCHANGE_KEYS, 0)
-        #: op name -> [host ns, device-wait ns, op spans]
+        #: op name -> [host ns, device-wait ns, op spans, host CPU ns]
         self.ops: dict[str, list] = {}
         self.counts = dict.fromkeys(COUNT_KEYS, 0)
         self.calls_by_site: dict[str, int] = {}
@@ -533,6 +570,7 @@ class TaskAccumulator:
         self.compiles = 0
         self.compile_ns = 0
         self.worker_ns = dict.fromkeys(SCAN_WORKER_KEYS, 0)
+        self.worker_span_cpu_ns = dict.fromkeys(SCAN_WORKER_KEYS, 0)
         self.worker_counts = dict.fromkeys(COUNT_KEYS, 0)
         self.worker_spans = 0
         self.worker_cpu_ns = 0
@@ -546,46 +584,54 @@ class TaskAccumulator:
         interval the ledger calls ``wall_s``."""
         self._cpu0 = time.thread_time_ns()
 
-    def _charge(self, span: "_LayerSpan", self_ns: int) -> None:
-        """Book one closed layer span's self time (task thread)."""
+    def _charge(self, span: "_LayerSpan", self_ns: int,
+                cpu_ns: int) -> None:
+        """Book one closed layer span's self time, wall and CPU (task
+        thread)."""
         self.layer_spans += 1
         layer = span.layer
         if layer == "op":
             up = span._up
             if span.key == "readback":
-                ent = self._op(up.key)
-                ent[1] += self_ns
-                self.layers["op_device_wait"] += self_ns
+                self._op(up.key)[1] += self_ns
+                key = "op_device_wait"
             else:
                 ent = self._op(span.key)
                 ent[0] += self_ns
                 ent[2] += 1
-                self.layers["op_host"] += self_ns
+                ent[3] += cpu_ns
+                key = "op_host"
         elif layer == "serve":
             if span.key == "queue":
                 self.queue_ns += self_ns
-            elif span.key == "send":
-                self.layers["send"] += self_ns
-            # serve/task is the root: what it alone covers is `other`
+                return
+            if span.key != "send":
+                # serve/task is the root: what it alone covers is `other`
+                return
+            key = "send"
         else:
             key = _LAYER_KEY.get(layer)
-            if key is not None:
-                self.layers[key] += self_ns
-                if layer == "exchange":
-                    self.exchange[span.key] = \
-                        self.exchange.get(span.key, 0) + self_ns
+            if key is None:
+                return
+            if layer == "exchange":
+                self.exchange[span.key] = \
+                    self.exchange.get(span.key, 0) + self_ns
+        self.layers[key] += self_ns
+        self.layers_cpu[key] += cpu_ns
 
     def _op(self, name: str) -> list:
         ent = self.ops.get(name)
         if ent is None:
-            ent = self.ops[name] = [0, 0, 0]
+            ent = self.ops[name] = [0, 0, 0, 0]
         return ent
 
-    def _charge_worker(self, span: "_LayerSpan", self_ns: int) -> None:
+    def _charge_worker(self, span: "_LayerSpan", self_ns: int,
+                       cpu_ns: int) -> None:
         with self._lock:
             self.worker_spans += 1
             if span.layer == "scan" and span.key in self.worker_ns:
                 self.worker_ns[span.key] += self_ns
+                self.worker_span_cpu_ns[span.key] += cpu_ns
 
     def sealed(self, wall_s: float) -> dict:
         """The ledger's version-2 fields. ``layers_s.other`` is
@@ -593,11 +639,17 @@ class TaskAccumulator:
         construction and ``other`` is what no span covers yet."""
         with self._lock:
             worker_ns = dict(self.worker_ns)
+            worker_span_cpu = dict(self.worker_span_cpu_ns)
             worker_counts = dict(self.worker_counts)
             worker_spans = self.worker_spans
             worker_cpu = self.worker_cpu_ns
             compiles = self.compiles + self.worker_compiles
             compile_ns = self.compile_ns + self.worker_compile_ns
+        # (a key's CPU is cut at its wall here, key by key and not span
+        # by span: a compile leaves its span's wall and not its CPU, and
+        # where the CPU clock is coarse — 10 ms ticks on the chip's host
+        # — a tick lands whole in a span a hundredth its size)
+        timed = self.cpu_timed
         layers = {k: round(v * 1e-9, 6) for k, v in self.layers.items()}
         layers["compile"] = round(self.compile_ns * 1e-9, 6)
         layers["other"] = round(float(wall_s) - sum(layers.values()), 6)
@@ -605,17 +657,27 @@ class TaskAccumulator:
         counts["program_calls_by_site"] = dict(
             sorted(self.calls_by_site.items()))
         counts["layer_spans"] = self.layer_spans + worker_spans
+        ops = {}
+        for name, (h, d, n, c) in sorted(self.ops.items()):
+            ops[name] = {"host_s": round(h * 1e-9, 6),
+                         "device_wait_s": round(d * 1e-9, 6), "batches": n}
+            if timed:
+                ops[name]["cpu_s"] = round(min(c, h) * 1e-9, 6)
         return {
             "queue_s": round(self.queue_ns * 1e-9, 6),
             "layers_s": layers,
+            # None in a task that was not CPU-timed
+            "layers_cpu_s": {k: round(min(v, self.layers[k]) * 1e-9, 6)
+                             for k, v in self.layers_cpu.items()}
+            if timed else None,
             "exchange_s": {k: round(v * 1e-9, 6)
                            for k, v in self.exchange.items()},
-            "ops_s": {name: {"host_s": round(h * 1e-9, 6),
-                             "device_wait_s": round(d * 1e-9, 6),
-                             "batches": n}
-                      for name, (h, d, n) in sorted(self.ops.items())},
+            "ops_s": ops,
             "scan_worker_s": {k: round(v * 1e-9, 6)
                               for k, v in worker_ns.items()},
+            "scan_worker_cpu_s": {k: round(min(v, worker_ns[k]) * 1e-9, 6)
+                                  for k, v in worker_span_cpu.items()}
+            if timed else None,
             "cpu_s": round((time.thread_time_ns() - self._cpu0
                             + worker_cpu) * 1e-9, 6),
             "counts": counts,
@@ -629,7 +691,7 @@ class _LayerSpan(_SpanCM):
     task's accumulator always, a recorded ``Span`` with tracing on."""
 
     __slots__ = ("layer", "key", "_record", "_ann", "_acc", "_worker",
-                 "_up", "_child_ns")
+                 "_up", "_child_ns", "_cpu", "_c0", "_child_cpu_ns")
 
     def __init__(self, layer, key, cat, name, attrs, record, max_spans):
         _SpanCM.__init__(self, cat, name, attrs, max_spans)
@@ -637,6 +699,7 @@ class _LayerSpan(_SpanCM):
         self.key = key
         self._record = record
         self._child_ns = 0
+        self._child_cpu_ns = 0
 
     def __enter__(self):
         tr = _TRACER
@@ -653,16 +716,22 @@ class _LayerSpan(_SpanCM):
         # recorded child still links to the nearest recorded ancestor
         self.span_id = next(_SPAN_IDS) if self._record else self._parent
         stack.append(self)
-        self._acc = getattr(tls, "task", None)
+        self._acc = acc = getattr(tls, "task", None)
         self._worker = getattr(tls, "worker", False)
+        self._cpu = acc is not None and acc.cpu_timed \
+            and (self.layer, self.key) not in DECLARED_WAITS
         self._ann = ann = _annotation(
             "auron:" + self.layer + "/" + self.key, self.attrs)
         ann.__enter__()
+        # the CPU clock is read inside the wall clock's interval, so a
+        # span's CPU never exceeds its duration
         self._t0 = tr.now_ns()
+        self._c0 = time.thread_time_ns() if self._cpu else 0
         return self
 
     def __exit__(self, exc_type, exc, tb):
         tr = _TRACER
+        cpu = time.thread_time_ns() - self._c0 if self._cpu else 0
         t0 = self._t0
         dur = tr.now_ns() - t0
         self._ann.__exit__(exc_type, exc, tb)
@@ -674,14 +743,17 @@ class _LayerSpan(_SpanCM):
         own = not (self.key == "readback" and self.layer == "op"
                    and (up is None or up.layer != "op"))
         if acc is not None:
+            self_ns = dur - self._child_ns
+            cpu_ns = max(cpu - self._child_cpu_ns, 0)
             if self._worker:
-                acc._charge_worker(self, dur - self._child_ns)
+                acc._charge_worker(self, self_ns, cpu_ns)
             elif own:
-                acc._charge(self, dur - self._child_ns)
+                acc._charge(self, self_ns, cpu_ns)
             else:
                 acc.layer_spans += 1
         if own and up is not None:
             up._child_ns += dur
+            up._child_cpu_ns += cpu
         if self._record:
             if exc_type is not None:
                 self.attrs.setdefault("error", exc_type.__name__)
@@ -766,10 +838,11 @@ def count(key: str, n: int = 1) -> None:
         acc.counts[key] += n
 
 
-def readback_span() -> _LayerSpan:
+def readback_span(counted_as: str = "readbacks") -> _LayerSpan:
     """The span of one explicit device -> host sync point
-    (``auron:op/readback``), counted among the task's ``readbacks``."""
-    count("readbacks")
+    (``auron:op/readback``), counted among the task's ``readbacks`` (a
+    row-count read among its ``row_syncs``)."""
+    count(counted_as)
     return layer_span("op", "readback")
 
 

@@ -38,6 +38,7 @@ from auron_tpu.columnar.batch import (DeviceBatch, PrimitiveColumn, StringColumn
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, TypedValue, evaluate, infer_dtype
+from auron_tpu.obs import profile as _profile
 from auron_tpu.obs import trace as _trace
 from auron_tpu.ops import hashing
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
@@ -685,17 +686,15 @@ def _column_pyvalues(col, n: int) -> list:
     from auron_tpu.columnar.batch import StructColumn
     if isinstance(col, StructColumn):
         kids = [_column_pyvalues(ch, n) for ch in col.children]
-        val = np.asarray(col.validity[:n])
+        val = _profile.timed_get(col.validity[:n])
         return [tuple(k[i] for k in kids) if val[i] else None
                 for i in range(n)]
     if isinstance(col, StringColumn):
-        chars = np.asarray(col.chars[:n])
-        lens = np.asarray(col.lens[:n])
-        val = np.asarray(col.validity[:n])
+        chars, lens, val = _profile.timed_get(
+            (col.chars[:n], col.lens[:n], col.validity[:n]))
         return [bytes(chars[i, :lens[i]]).decode("utf-8", "surrogateescape")
                 if val[i] else None for i in range(n)]
-    data = np.asarray(col.data[:n])
-    val = np.asarray(col.validity[:n])
+    data, val = _profile.timed_get((col.data[:n], col.validity[:n]))
     return [data[i].item() if val[i] else None for i in range(n)]
 
 
@@ -1044,14 +1043,14 @@ class _HostAggState:
             self._update_locked(batch, ectx)
 
     def _update_locked(self, batch: DeviceBatch, ectx: EvalContext) -> None:
-        n = int(batch.num_rows)
+        n = _profile.row_count(batch)
         key_tuples = None
         for si, ent in self.entries.items():
             agg = self.op.aggs[si]
             v = evaluate(agg.arg, batch, self.in_schema, ectx)
             if ent[0] == "bloom":
-                data = np.asarray(v.col.data[:n])
-                valid = np.asarray((v.validity & batch.row_mask())[:n])
+                data, valid = _profile.timed_get(
+                    (v.col.data[:n], (v.validity & batch.row_mask())[:n]))
                 ent[1].put_longs(data[valid].astype(np.int64))
             else:
                 _, udaf, bufs = ent
@@ -1109,7 +1108,7 @@ class _HostAggState:
     def _merge_partial_locked(self, batch: DeviceBatch) -> None:
         import base64
         import pickle
-        n = int(batch.num_rows)
+        n = _profile.row_count(batch)
         n_keys = len(self.op.group_exprs)
         key_tuples = _key_tuples_host(batch.columns[:n_keys], n)
         # state column index per spec in the partial layout
@@ -1247,7 +1246,7 @@ class _AggSpillConsumer:
             if lvl is None:
                 continue
             state_batch = self.op._state_batch(lvl)
-            n = int(state_batch.num_rows)
+            n = _profile.row_count(state_batch)
             if n == 0:
                 continue
             self.spilled_groups += n
@@ -1440,6 +1439,7 @@ class AggOp(PhysicalOp):
         ni = 0
         for i, k in enumerate(kinds):
             if k in ("collect_list", "collect_set") or k in _DCOLLECT:
+                # graft: disable=GL001 -- `needed` came to the host with the group count (timed_get)
                 nd = int(needed[ni])
                 ni += 1
                 if nd > out_elems[i]:
@@ -1505,9 +1505,8 @@ class AggOp(PhysicalOp):
                 # separate int() readback is its own device→host sync.
                 # The readback IS the sync point: attributed as device
                 # wait, obs/profile.timed_get
-                from auron_tpu.obs import profile as _profile
                 ng, needed_h = _profile.timed_get([bn, needed])
-                ng = int(ng)
+                ng = int(ng)   # graft: disable=GL001 -- read by timed_get above
             ok, _cap = self._grow_check(kinds, out_elems, ng, cap_b,
                                         needed_h)
             if ok:
@@ -1537,9 +1536,8 @@ class AggOp(PhysicalOp):
             with timer(elapsed) as t:
                 new_keys, new_accs, h_out, num_groups, needed = kern(
                     s_keys, s_accs, s_h, s_n, bk, ba, bh, bn)
-                from auron_tpu.obs import profile as _profile
                 ng, needed_h = _profile.timed_get([num_groups, needed])
-                ng = int(ng)
+                ng = int(ng)   # graft: disable=GL001 -- read by timed_get above
             ok, out_cap = self._grow_check(kinds, out_elems, ng, out_cap,
                                            needed_h)
             if ok:
@@ -1688,7 +1686,9 @@ class AggOp(PhysicalOp):
         from auron_tpu.columnar.batch import ListColumn, resize
         keys, accs, num_groups, cap, _hashes = state
         valid = jnp.arange(cap, dtype=jnp.int32) < num_groups
-        ng = int(num_groups)
+        # where the state's last merge was dispatched and not read (the
+        # sort path's, the table's final fold) this read waits for it
+        ng = _profile.row_count(num_groups)
         if self.group_exprs:
             _trace.count("agg_groups", ng)
 
@@ -2178,15 +2178,15 @@ class AggOp(PhysicalOp):
         touched = rows > 0
         ng_dev = jnp.sum(touched.astype(jnp.int32))
         order = jnp.argsort(~touched, stable=True)   # touched keys first
-        from auron_tpu.obs import profile as _profile
         # ONE batched readback for every control scalar (each separate
         # int() is its own device→host sync); routed
         # through the profiler so the wait books as device time at this
         # sync point, like the grow/overflow readbacks above
         ng, mx, mn, nulls, nrows = _profile.timed_get(
             [ng_dev, max_k, min_k, saw_null, total_rows])
-        ng = int(ng)
-        kdispatch.record_rows(decision, int(nrows), kmetrics)
+        # graft: disable=GL001 -- the five came to the host in the timed_get above
+        ng, mx, mn, nrows = int(ng), int(mx), int(mn), int(nrows)
+        kdispatch.record_rows(decision, nrows, kmetrics)
         # the key_domain hint is a plan-time promise — violations are
         # deterministic defects and must fail the task, not mis-aggregate
         # (run_task_with_retries treats ValueError as no-retry)
@@ -2194,10 +2194,10 @@ class AggOp(PhysicalOp):
             raise ValueError(
                 "dense grouped-agg: NULL group keys under key_domain="
                 f"{domain}; the planner's bound is invalid for this data")
-        if int(mx) >= domain or int(mn) < 0:
+        if mx >= domain or mn < 0:
             raise ValueError(
-                f"dense grouped-agg: observed key range [{int(mn)}, "
-                f"{int(mx)}] violates the planner's key_domain={domain}")
+                f"dense grouped-agg: observed key range [{mn}, "
+                f"{mx}] violates the planner's key_domain={domain}")
         cap = max(bucket_rows(max(ng, 1)), 16)
         take = order
         if cap > domain:
@@ -2271,7 +2271,7 @@ class AggOp(PhysicalOp):
                     if skipping:
                         keys, accs, live = self._contributions(
                             batch, in_schema, ectx)
-                        skipped_rows.add(int(batch.num_rows))
+                        skipped_rows.add(_profile.row_count(batch))
                         yield self._passthrough_batch(keys, accs, live,
                                                       batch.num_rows)
                         continue
@@ -2296,7 +2296,7 @@ class AggOp(PhysicalOp):
                     # either way (the reference also decides at a fixed
                     # observation point, agg_ctx.rs:63-196) — so the steady
                     # state pays no per-batch device sync for bookkeeping
-                    rows_seen += int(batch.num_rows)
+                    rows_seen += _profile.row_count(batch)
                     if rows_seen < skip_min_rows:
                         continue
                     skip_pending = False  # decision point reached: latch
@@ -2306,7 +2306,7 @@ class AggOp(PhysicalOp):
                     # present in both hot and main would count twice
                     tbl = self._compact(state, elapsed)
                     state = None if tbl is None else (tbl, None)
-                    ng = 0 if tbl is None else int(tbl[2])
+                    ng = 0 if tbl is None else _profile.row_count(tbl[2])
                     # groups living only in spill runs are invisible in the
                     # in-memory table; without them a pre-decision spill
                     # would suppress skipping in exactly the

@@ -19,6 +19,7 @@ from typing import Iterator, Optional
 
 from auron_tpu.columnar.batch import DeviceBatch
 from auron_tpu.columnar.schema import Schema
+from auron_tpu.obs import profile as _profile
 from auron_tpu.obs import trace as _trace
 
 
@@ -114,7 +115,6 @@ class timer:
     def __enter__(self):
         owner = self.metric._owner
         if owner is not None:
-            from auron_tpu.obs import profile as _profile
             self._span = _trace.layer_span("op", owner.name)
             self._span.__enter__()
             self._frame = _profile.push_frame()
@@ -130,7 +130,6 @@ class timer:
             self._span.__exit__(*exc)
             self._span = None
         if self._frame is not None:
-            from auron_tpu.obs import profile as _profile
             _profile.pop_frame(
                 self._frame, self.metric._owner, wall,
                 (self._t_track - self.t0) if self._t_track else None,
@@ -421,7 +420,10 @@ def count_output(stream, metrics: MetricsSet, timed: bool = False):
     Timed or not, each ``next()`` runs inside the operator's layer span
     (``auron:op/<name>``): the generator's own glue between its timers
     and its children's spans is the operator's exclusive host time. The
-    span closes before the ``yield``."""
+    span closes before the ``yield``. The row count of a batch is read
+    inside it through ``profile.row_count``: where the count is still on
+    the device the read waits for the chip, and that wait is the
+    operator's device wait, not its host time."""
     rows = metrics.counter("output_rows")
     batches = metrics.counter("output_batches")
     elapsed = metrics.counter("elapsed_compute") if timed else None
@@ -434,7 +436,7 @@ def count_output(stream, metrics: MetricsSet, timed: bool = False):
             if elapsed is not None:
                 elapsed.add(time.perf_counter_ns() - t0)
             if b is not None:
-                rows.add(int(b.num_rows))
+                rows.add(_profile.row_count(b))
                 batches.add(1)
         if b is None:
             return

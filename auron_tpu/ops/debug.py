@@ -8,6 +8,7 @@ from typing import Iterator
 
 from auron_tpu.columnar.arrow_bridge import to_arrow
 from auron_tpu.columnar.schema import Schema
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output
 
 logger = logging.getLogger("auron_tpu.debug")
@@ -37,7 +38,7 @@ class DebugOp(PhysicalOp):
             enabled = logger.isEnabledFor(logging.INFO)
             for i, batch in enumerate(self.child.execute(partition, ctx)):
                 if enabled:
-                    n = int(batch.num_rows)
+                    n = _profile.row_count(batch)
                     preview = ""
                     if n and self.max_preview_rows:
                         rb = to_arrow(batch, schema)

@@ -13,6 +13,7 @@ from typing import Iterator, Optional
 from auron_tpu.columnar.schema import Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import infer_dtype
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.ops.project import _project_kernel
 
@@ -87,7 +88,7 @@ class ExpandOp(PhysicalOp):
                         out = t.track(kern(batch, jnp.int32(partition),
                                            jnp.int64(row_off)))
                     yield out
-                row_off += int(batch.num_rows)
+                row_off += _profile.row_count(batch)
 
         return count_output(stream(), metrics)
 

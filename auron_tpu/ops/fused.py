@@ -296,6 +296,7 @@ class FusedStageOp(PhysicalOp):
                 # a fused limit exhausts: stop pulling the child (the
                 # slot readback is the same per-batch sync the unfused
                 # LimitOp paid on int(batch.num_rows))
+                # graft: disable=GL001 -- budgets came to the host in the timed_get above
                 if limit_slots and any(int(b) <= 0 for b in budgets):
                     break
 

@@ -590,7 +590,10 @@ class HashJoinOp(PhysicalOp):
                 probe, lo, counts, total, carries = t.track(
                     kern(raw, np.int32(partition), carries,
                          *side.index_args()))
-            f_rows.add(int(probe.num_rows))
+            # the first read of this launch: it holds the wait for the
+            # fused probe program (the candidate total below comes from
+            # the same launch and is there by then)
+            f_rows.add(_profile.row_count(probe))
             f_batches.add(1)
             yield from self._probe_one(probe, side, probe_schema,
                                        build_schema, elapsed, match,

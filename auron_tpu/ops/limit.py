@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from auron_tpu.columnar.batch import DeviceBatch, concat_batches, resize
 from auron_tpu.columnar.schema import Schema
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output
 from auron_tpu.utils.shapes import bucket_rows
 
@@ -56,7 +57,7 @@ class LimitOp(PhysicalOp):
             for batch in self.child.execute(partition, ctx):
                 if remaining <= 0:
                     break
-                n = int(batch.num_rows)
+                n = _profile.row_count(batch)
                 if n <= remaining:
                     remaining -= n
                     yield batch
@@ -126,7 +127,7 @@ class CoalesceBatchesOp(PhysicalOp):
             acc = None
             acc_rows = 0
             for batch in self.child.execute(partition, ctx):
-                n = int(batch.num_rows)
+                n = _profile.row_count(batch)
                 if n == 0:
                     continue
                 if n >= self.target_rows and acc is None:

@@ -19,6 +19,7 @@ from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import (EvalContext, evaluate, infer_dtype,
                                   infer_field)
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.runtime.programs import program_cache
 
@@ -125,7 +126,7 @@ class ProjectOp(PhysicalOp):
                 with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
-                row_off += int(batch.num_rows)
+                row_off += _profile.row_count(batch)
                 yield out
 
         return count_output(stream(), metrics)
@@ -179,7 +180,7 @@ class FilterOp(PhysicalOp):
                 with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
-                row_off += int(batch.num_rows)
+                row_off += _profile.row_count(batch)
                 yield out
 
         return count_output(stream(), metrics)
@@ -248,7 +249,7 @@ class FilterProjectOp(PhysicalOp):
                 with timer(elapsed) as t:
                     out = t.track(kern(batch, jnp.int32(partition),
                                        jnp.int64(row_off)))
-                row_off += int(batch.num_rows)
+                row_off += _profile.row_count(batch)
                 yield out
 
         return count_output(stream(), metrics)

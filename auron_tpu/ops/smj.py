@@ -48,6 +48,7 @@ from auron_tpu.columnar.batch import (DeviceBatch, PrimitiveColumn,
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, evaluate
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.ops.sort import _concat_all, sort_key_words
 from auron_tpu.utils.shapes import bucket_rows
@@ -96,7 +97,7 @@ def _pad_and_join(per_key, widths: tuple[int, ...]) -> jax.Array:
 def _host_row(per_key, row: int) -> tuple[np.ndarray, ...]:
     """One row's key words per key, on host (for window advance/evict
     decisions)."""
-    return tuple(np.asarray(w[row]) for w in per_key)
+    return tuple(_profile.timed_get([w[row] for w in per_key]))
 
 
 def _host_lex_le(a: tuple[np.ndarray, ...], b: tuple[np.ndarray, ...]) -> bool:
@@ -267,7 +268,7 @@ class _MergeWindow:
     def _account(self):
         self._bytes = batch_nbytes(self.batch) if self.batch is not None else 0
         if self.per_key is not None:
-            self._bytes += sum(int(w.size) * 8 for w in self.per_key)
+            self._bytes += sum(w.size * 8 for w in self.per_key)
         if self.mem is not None:
             self.mem.update_mem_used(self, self._bytes)
 
@@ -279,12 +280,12 @@ class _MergeWindow:
             from auron_tpu.columnar.serde import host_to_batch
             b = host_to_batch(self._host_batch,
                               bucket_rows(max(self._host_batch.num_rows, 1)))
-            parts.append((b, None, int(b.num_rows)))
+            parts.append((b, None, _profile.row_count(b)))
             self._host_batch = None
         elif self.batch is not None:
             parts.append((self.batch, self.per_key, self.n))
         for b, pk in self.pending:
-            parts.append((b, pk, int(b.num_rows)))
+            parts.append((b, pk, _profile.row_count(b)))
         self.pending = []
         if not parts:
             return
@@ -294,7 +295,7 @@ class _MergeWindow:
         batches = [p[0] for p in parts]
         merged = _concat_all(batches) if len(batches) > 1 else batches[0]
         self.batch = merged
-        self.n = int(merged.num_rows)
+        self.n = _profile.row_count(merged)
         cap = merged.capacity
         if any(pk is None for _b, pk, _n in parts):
             # reload after host offload: words must be re-encoded
@@ -350,7 +351,7 @@ class _MergeWindow:
             [self.matched[k:], np.zeros(k, bool)])
         self.n -= k
         self._account()
-        if unmatched is not None and int(unmatched.num_rows) == 0:
+        if unmatched is not None and _profile.row_count(unmatched) == 0:
             unmatched = None
         return unmatched
 
@@ -360,10 +361,10 @@ class _MergeWindow:
         cap = self.batch.capacity
         keep = self.batch.row_mask() & ~jnp.asarray(self.matched[:cap])
         out = compact(self.batch, keep)
-        return out if int(out.num_rows) > 0 else None
+        return out if _profile.row_count(out) > 0 else None
 
     def mark_matched(self, matched_dev) -> None:
-        self.matched |= np.asarray(matched_dev)
+        self.matched |= _profile.timed_get(matched_dev)
 
     def close(self) -> None:
         if self.mem is not None:
@@ -451,7 +452,7 @@ class SortMergeJoinOp(PhysicalOp):
             last_right_max = None
             try:
                 for left in self.probe.execute(partition, ctx):
-                    nL = int(left.num_rows)
+                    nL = _profile.row_count(left)
                     if nL == 0:
                         continue
                     kern = _key_words_kernel(self.probe_keys, left_schema,
@@ -467,7 +468,7 @@ class SortMergeJoinOp(PhysicalOp):
                         if rb is None:
                             right_done = True
                             break
-                        nR = int(rb.num_rows)
+                        nR = _profile.row_count(rb)
                         if nR == 0:
                             continue
                         rkern = _key_words_kernel(self.build_keys,
@@ -497,7 +498,7 @@ class SortMergeJoinOp(PhysicalOp):
                     if rest is not None:
                         yield null_extended_right(rest)
                     for rb in right_iter:
-                        if int(rb.num_rows) > 0:
+                        if _profile.row_count(rb) > 0:
                             yield null_extended_right(rb)
             finally:
                 win.close()
@@ -524,12 +525,11 @@ class SortMergeJoinOp(PhysicalOp):
         q_words = _pad_and_join(q_per_key, widths)
         win_cap = win.batch.capacity
 
-        pkern = _probe_kernel(int(win_words.shape[1]), win_cap, cap,
-                              left_outer)
+        pkern = _probe_kernel(win_words.shape[1], win_cap, cap, left_outer)
         with timer(elapsed) as t:
             lo, counts, emit, total = t.track(pkern(win_words, win.n, q_words,
                                                     q_dead, left.num_rows))
-        total_i = int(total)
+        total_i = int(_profile.timed_get(total))
 
         if jt in ("semi", "anti", "existence"):
             has = counts > 0
@@ -542,7 +542,7 @@ class SortMergeJoinOp(PhysicalOp):
                     col = PrimitiveColumn(has, jnp.ones(cap, bool))
                     out = DeviceBatch(left.columns + (col,), left.num_rows)
                 t.track(out)
-            if int(out.num_rows) > 0 or jt == "existence":
+            if _profile.row_count(out) > 0 or jt == "existence":
                 yield out
         elif total_i > 0:
             out_cap = bucket_rows(total_i)
@@ -559,7 +559,7 @@ class SortMergeJoinOp(PhysicalOp):
 
         # advance: window rows strictly below this batch's max key can
         # never match future (ascending) left rows
-        k = int(lo[nL - 1])
+        k = int(_profile.timed_get(lo[nL - 1]))
         evicted = win.evict_below(k, want_unmatched=track)
         if track and evicted is not None:
             yield null_extended_right(evicted)

@@ -33,6 +33,7 @@ from auron_tpu.columnar.schema import DataType, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, evaluate
 from auron_tpu.memmgr.consumer import BufferedSpillConsumer
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import (ExecContext, PhysicalOp, count_output,
                                 timer, yields_owned_batches)
 from auron_tpu.runtime import programs
@@ -272,7 +273,7 @@ def _concat_all(batches: list[DeviceBatch]) -> DeviceBatch:
     stacked_cap = sum(b.capacity for b in batches)
     from auron_tpu.columnar.batch import compact, resize
     live = jnp.concatenate([b.row_mask() for b in batches])
-    num = sum(int(b.num_rows) for b in batches)
+    num = sum(_profile.row_count(b) for b in batches)
     stacked = DeviceBatch(tuple(cols), jnp.asarray(stacked_cap, jnp.int32))
     compacted = compact(stacked, live)
     out = resize(compacted, total_cap) if total_cap >= stacked_cap else compacted
@@ -303,6 +304,7 @@ class _SortSpillConsumer(BufferedSpillConsumer):
         from auron_tpu.memmgr.merge import (ORDER_WORDS_EXTRA,
                                             WORD_LAYOUT_EXTRA)
         merged = _concat_all(batches) if len(batches) > 1 else batches[0]
+        # graft: disable=GL001 -- a host list of python ints
         layout = np.asarray(
             key_word_layout(self.op.sort_exprs, self.in_schema, merged),
             dtype=np.uint64)
@@ -313,10 +315,9 @@ class _SortSpillConsumer(BufferedSpillConsumer):
         # the sort-collect spill's semantic sync point: this readback
         # carries the device wait (booked as device when a timer frame
         # is open, obs/profile.timed_get)
-        from auron_tpu.obs import profile as _profile
         n = int(_profile.timed_get(run.num_rows))
         host = batch_to_host(run, n)
-        host_words = np.asarray(words[:n])
+        host_words = _profile.timed_get(words[:n])
         for lo in range(0, max(n, 1), self.frame_rows):
             hi = min(lo + self.frame_rows, n)
             spill.write_frame(serialize_host_batch(
@@ -350,7 +351,7 @@ class SortOp(PhysicalOp):
                 continue
             if remaining <= 0:
                 return
-            n = int(out.num_rows)
+            n = _profile.row_count(out)
             if n > remaining:
                 out = DeviceBatch(out.columns, jnp.asarray(remaining, jnp.int32))
             remaining -= n

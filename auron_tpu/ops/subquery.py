@@ -107,6 +107,7 @@ class ScalarSubqueryBinderOp(PhysicalOp):
         from auron_tpu.ir.serde import _P_TO_DT
         dt = _P_TO_DT[q.dtype]
         if dt == DataType.DECIMAL and isinstance(value, decimal.Decimal):
+            # graft: disable=GL001 -- a decimal.Decimal, host data
             return int(value.scaleb(q.scale).to_integral_value())
         if dt == DataType.DATE32 and isinstance(value, datetime.date):
             return (value - datetime.date(1970, 1, 1)).days

@@ -354,6 +354,7 @@ def current_plane() -> Optional[MeshPlane]:
         return _PLANE[1]
     conf = cfg.get_config()
     params = (bool(conf.get(cfg.MESH_ENABLED)),
+              # graft: disable=GL001 -- a configuration value, host data
               int(conf.get(cfg.MESH_DEVICES)),
               str(conf.get(cfg.MESH_AXIS)))
     with _PLANE_LOCK:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from auron_tpu.obs import profile as _profile
 from auron_tpu.runtime.programs import program_cache
 
 
@@ -348,7 +349,7 @@ def exchange_device_batches(mesh: Mesh, cols: tuple, pids, num_rows,
     quota = bucket_rows(initial_quota or (2 * cap) // n_dev)
     out_cols, out_nr, max_count = mesh_all_to_all(
         mesh, cols, pids, num_rows, quota, axis)
-    needed = int(np.max(np.asarray(max_count)))
+    needed = int(np.max(_profile.timed_get(max_count)))
     if needed <= quota:
         return out_cols, out_nr, quota
     quota = bucket_rows(needed)

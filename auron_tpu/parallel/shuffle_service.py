@@ -382,6 +382,7 @@ class FileShuffleService:
         try:
             with open(os.path.join(self._shuffle_dir(shuffle_id),
                                    "manifest")) as f:
+                # graft: disable=GL001 -- a manifest file's text, host data
                 return int(f.read().strip())
         except (OSError, ValueError):
             return 0

@@ -20,6 +20,7 @@ import pyarrow as pa
 
 from auron_tpu.columnar.arrow_bridge import to_arrow
 from auron_tpu.columnar.batch import DeviceBatch
+from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp
 
 logger = logging.getLogger("auron_tpu")
@@ -145,7 +146,6 @@ class ExecutionRuntime:
         benchmark's slice): the layer spans of obs/trace.py annotate
         it, and one task cannot own the process's one session."""
         from auron_tpu import errors
-        from auron_tpu.obs import profile as _profile
         from auron_tpu.obs import trace
         from auron_tpu.ops.base import TaskCancelled
         from auron_tpu.runtime import faults, watchdog
@@ -238,7 +238,6 @@ class ExecutionRuntime:
         deterministic lowering defect in the export path would retry as
         if transient."""
         from auron_tpu import errors
-        from auron_tpu.obs import profile as _profile
         from auron_tpu.obs import trace
         schema = self.plan.schema()
         profiling = _profile.enabled()
@@ -260,7 +259,7 @@ class ExecutionRuntime:
                     # and book the wait as device time — BEFORE the
                     # num_rows readback below silently absorbs it
                     _profile.device_fence(batch, fence_sink)
-                if int(batch.num_rows) > 0:
+                if _profile.row_count(batch) > 0:
                     t0 = (time.perf_counter_ns() if convert_c is not None
                           else 0)
                     try:
@@ -394,8 +393,11 @@ def run_task_with_retries(plan: PhysicalOp, partition: int,
     from auron_tpu.runtime import lifecycle
 
     conf = config if config is not None else cfg.get_config()
+    # graft: disable=GL001 -- configuration values, host data
     retries = max(0, int(conf.get(cfg.TASK_MAX_RETRIES)))
+    # graft: disable=GL001 -- configuration values, host data
     backoff = float(conf.get(cfg.TASK_RETRY_BACKOFF_S))
+    # graft: disable=GL001 -- configuration values, host data
     backoff_cap = float(conf.get(cfg.TASK_RETRY_BACKOFF_MAX_S))
     retry_stats = {"transient_retries": 0, "stall_retries": 0}
     last_err = None

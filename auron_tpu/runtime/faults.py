@@ -117,6 +117,7 @@ def parse_plan(plan: str) -> list[Rule]:
         try:
             site, rest = part.split(":", 1)
             kind, _, prob_s = rest.partition("@")
+            # graft: disable=GL001 -- a fault-plan token, host text
             prob = float(prob_s) if prob_s else 1.0
         except ValueError as e:
             raise ValueError(f"malformed fault rule {part!r} "

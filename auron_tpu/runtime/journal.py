@@ -827,6 +827,7 @@ def _load(path: str, conf=None) -> QueryJournal:
             f"journal plan bytes unreadable: {path}", reason="corrupt",
             site="journal.load") from e
     jr = QueryJournal(path, header.get("query_id", ""), plan_bytes,
+                      # graft: disable=GL001 -- a JSON header field, host data
                       int(header.get("num_partitions", 1)),
                       header.get("plan_fp", ""),
                       header.get("sources", {}),
@@ -1260,6 +1261,7 @@ def sweep_orphans(dir_: str, force: bool = False) -> int:
             return 0
         _SWEPT_DIRS.add(dir_)
     from auron_tpu import config as cfg
+    # graft: disable=GL001 -- a configuration value, host data
     retention_s = float(cfg.get_config().get(cfg.JOURNAL_RETENTION_S))
     now = time.time()
 

@@ -105,6 +105,7 @@ class Slot:
         self.scheduler = scheduler
         self.token = token
         self.query_id = getattr(token, "query_id", "") or ""
+        # graft: disable=GL001 -- a constructor argument, host data
         self.weight = max(float(weight), 1e-6)
         self.tasks_run = 0
         #: virtual-time origin, set at GRANT to the current minimum
@@ -209,7 +210,9 @@ class QueryScheduler:
     def _knobs(self) -> tuple[int, int]:
         from auron_tpu import config as cfg
         conf = self._conf()
+        # graft: disable=GL001 -- configuration values, host data
         return (max(int(conf.get(cfg.SCHED_MAX_CONCURRENT)), 1),
+                # graft: disable=GL001 -- configuration values, host data
                 max(int(conf.get(cfg.SCHED_QUEUE_DEPTH)), 0))
 
     def _queue_wait_p(self, p: float,
@@ -231,6 +234,7 @@ class QueryScheduler:
         if not waits:
             return 0.0
         waits.sort()
+        # graft: disable=GL001 -- host arithmetic on python numbers
         idx = min(int(p * len(waits)), len(waits) - 1)
         return waits[idx]
 
@@ -363,10 +367,12 @@ class QueryScheduler:
 
     def _admit_wait_limit(self) -> float:
         from auron_tpu import config as cfg
+        # graft: disable=GL001 -- a configuration value, host data
         return float(self._conf().get(cfg.SCHED_ADMIT_QUEUE_WAIT_P99_S))
 
     def _check_memory_signal(self) -> None:
         from auron_tpu import config as cfg
+        # graft: disable=GL001 -- a configuration value, host data
         ratio_limit = float(self._conf().get(cfg.SCHED_ADMIT_MEM_RATIO))
         if ratio_limit <= 0:
             return

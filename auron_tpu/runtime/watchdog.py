@@ -125,6 +125,7 @@ _MONITOR: Optional[threading.Thread] = None
 def stall_timeout_s(config=None) -> float:
     from auron_tpu import config as cfg
     conf = config if config is not None else cfg.get_config()
+    # graft: disable=GL001 -- a configuration value, host data
     return float(conf.get(cfg.WATCHDOG_STALL_TIMEOUT_S))
 
 

@@ -11,6 +11,12 @@
   the operators' part, whose ``counts`` are exact, and which keeps every
   version-1 key;
 - what was taken out (``auron.profile``, the hotspot tool) is gone.
+
+Since PR 40 a layer span reads the thread's CPU clock beside the wall
+clock: ``layers_cpu_s``, ``ops_s.<op>.cpu_s`` and ``scan_worker_cpu_s``
+say what of a self time was computing, and a batch's row count is read
+through ``obs/profile.row_count`` — one ``auron:op/readback`` and one
+``counts.row_syncs`` where the count is still on the device.
 """
 
 import ast
@@ -36,13 +42,26 @@ V1_KEYS = {"version", "query_id", "outcome", "device", "wall_s",
            "fleet"}
 V1_COMPILE_KEYS = {"xla_compiles", "seconds", "program_builds",
                    "program_hits"}
-COUNTS = ("program_calls", "readbacks", "d2h_bytes", "h2d_transfers",
-          "h2d_bytes", "encode_pyloop_values", "layer_spans")
+COUNTS = ("program_calls", "readbacks", "row_syncs", "d2h_bytes",
+          "h2d_transfers", "h2d_bytes", "encode_pyloop_values",
+          "layer_spans")
+#: the layers a span charges CPU to: ``layers_s`` less ``compile`` (its
+#: CPU is not known apart) and ``other`` (what no span covers)
+CPU_LAYERS = {"plan", "scan_wait", "op_host", "op_device_wait", "exchange",
+              "to_arrow", "send"}
 
 
 def _busy(seconds: float) -> None:
     end = time.perf_counter() + seconds
     while time.perf_counter() < end:
+        pass
+
+
+def _burn(cpu_seconds: float) -> None:
+    """Spin until this thread has SPENT that much CPU, however long the
+    machine or another thread keeps it waiting."""
+    end = time.thread_time() + cpu_seconds
+    while time.thread_time() < end:
         pass
 
 
@@ -120,8 +139,9 @@ class TestLayerSpanAccounting:
                 v2 = acc.sealed(1.0)
         split = v2["exchange_s"]
         assert set(split) == set(trace.EXCHANGE_KEYS)
+        # (six values, each rounded to the microsecond)
         assert sum(split.values()) == pytest.approx(
-            v2["layers_s"]["exchange"], abs=2e-6)
+            v2["layers_s"]["exchange"], abs=4e-6)
         assert 0.002 <= split["materialize"] < 0.004
         assert 0.004 <= split["gang_wait"] < 0.006
         # the round's one fence is no operator's: it stays in the span
@@ -171,7 +191,7 @@ class TestLayerSpanAccounting:
             with trace.worker_scope(acc):
                 for key in ("decode", "encode", "h2d"):
                     with trace.layer_span("scan", key):
-                        _busy(0.004)
+                        _burn(0.004)
                 trace.count("h2d_transfers", 7)
                 trace.count("h2d_bytes", 4096)
 
@@ -283,6 +303,326 @@ class TestLayerSpanAccounting:
             trace.reset()
         assert set(spans) == {"outer", "inside"}
         assert spans["inside"].parent_id == spans["outer"].span_id
+
+
+class TestCpuSelfTime:
+    """A span's CPU self time (``time.thread_time_ns`` beside the wall
+    clock) is charged where its wall self time is, in a task that is
+    CPU-timed."""
+
+    def _root(self, acc):
+        acc.cpu_timed = True
+        acc.start()
+        return trace.layer_span("serve", "task", query_id=acc.query_id)
+
+    def test_one_task_in_seven_is_cpu_timed(self, monkeypatch):
+        """The thread CPU clock is a system call, 6 µs on the chip's host
+        with the interpreter held: one task in ``CPU_TIMED_EVERY`` reads
+        it round its spans, the others never, and their sealed ledgers
+        say None where the CPU would stand."""
+        reads = []
+        clock = time.thread_time_ns
+
+        def counted():
+            reads.append(1)
+            return clock()
+
+        monkeypatch.setattr(trace.time, "thread_time_ns", counted)
+        timed, sealed = [], []
+        for _ in range(2 * trace.CPU_TIMED_EVERY):
+            with trace.task_scope("q-sampled") as acc:
+                timed.append(acc.cpu_timed)
+                acc.start()
+                before = len(reads)
+                with trace.layer_span("serve", "task"):
+                    with trace.layer_span("op", "agg"):
+                        pass
+                assert len(reads) - before == (4 if acc.cpu_timed else 0)
+                sealed.append(acc.sealed(1.0))
+        assert sum(timed) == 2
+        for was_timed, v2 in zip(timed, sealed):
+            assert (v2["layers_cpu_s"] is not None) == was_timed
+            assert (v2["scan_worker_cpu_s"] is not None) == was_timed
+            assert ("cpu_s" in v2["ops_s"]["agg"]) == was_timed
+            assert v2["ops_s"]["agg"]["batches"] == 1   # wall: always
+
+    def test_a_sleeping_span_books_wall_and_a_spinning_one_both(self):
+        with trace.task_scope("q-cpu") as acc:
+            with self._root(acc):
+                with trace.layer_span("op", "sleeper"):
+                    time.sleep(0.04)
+                with trace.layer_span("op", "spinner"):
+                    _burn(0.02)
+                v2 = acc.sealed(1.0)
+        sleeper, spinner = v2["ops_s"]["sleeper"], v2["ops_s"]["spinner"]
+        # (a collection of the interpreter's may fall into the sleep)
+        assert sleeper["host_s"] >= 0.04 > 2 * sleeper["cpu_s"]
+        assert 0.02 <= spinner["cpu_s"] <= spinner["host_s"]
+        assert v2["layers_cpu_s"]["op_host"] == pytest.approx(
+            sleeper["cpu_s"] + spinner["cpu_s"], abs=2e-6)
+
+    def test_a_childs_cpu_is_not_its_parents(self):
+        with trace.task_scope("q-child") as acc:
+            with self._root(acc):
+                with trace.layer_span("op", "parent"):
+                    time.sleep(0.04)
+                    with trace.layer_span("op", "child"):
+                        _burn(0.03)
+                    with trace.layer_span("op", "readback"):
+                        time.sleep(0.01)
+                v2 = acc.sealed(1.0)
+        parent, child = v2["ops_s"]["parent"], v2["ops_s"]["child"]
+        assert child["cpu_s"] >= 0.03
+        # the parent slept: its child's spin is not its own
+        assert parent["host_s"] >= 0.04 > 2 * parent["cpu_s"]
+        assert parent["device_wait_s"] >= 0.01
+        assert v2["layers_cpu_s"]["op_host"] == pytest.approx(
+            parent["cpu_s"] + child["cpu_s"], abs=2e-6)
+
+    def test_a_declared_wait_leaves_the_cpu_clock_alone(self, monkeypatch):
+        """The thread CPU clock is a system call (6 µs on the chip's host,
+        the interpreter held): the spans that wait by declaration — for
+        the device, the scan worker, the gang door, a slot — book no CPU
+        and do not read it; every other span reads it twice."""
+        reads = []
+        clock = time.thread_time_ns
+
+        def counted():
+            reads.append(1)
+            return clock()
+
+        with trace.task_scope("q-waits") as acc:
+            acc.cpu_timed = True
+            with trace.layer_span("serve", "queue"):
+                pass
+            with self._root(acc):
+                with trace.layer_span("op", "agg"):
+                    monkeypatch.setattr(trace.time, "thread_time_ns",
+                                        counted)
+                    for layer, key in sorted(trace.DECLARED_WAITS):
+                        with trace.layer_span(layer, key):
+                            pass
+                    in_waits = len(reads)
+                    with trace.layer_span("op", "hash_join"):
+                        pass
+                    in_all = len(reads)
+                    monkeypatch.undo()
+                v2 = acc.sealed(1.0)
+        assert trace.DECLARED_WAITS == {
+            ("op", "readback"), ("scan", "wait"),
+            ("exchange", "gang_wait"), ("serve", "queue")}
+        assert in_waits == 0 and in_all == 2
+        assert v2["layers_cpu_s"]["op_device_wait"] == 0.0
+        assert v2["layers_cpu_s"]["scan_wait"] == 0.0
+
+    def test_cpu_is_within_the_wall_layer_by_layer(self):
+        cpu0 = time.thread_time()
+        with trace.task_scope("q-within") as acc:
+            t0 = time.monotonic()
+            with self._root(acc):
+                with trace.layer_span("plan", "decode"):
+                    _burn(0.003)
+                with trace.layer_span("scan", "wait"):
+                    time.sleep(0.03)
+                with trace.layer_span("op", "agg"):
+                    _burn(0.004)
+                    with trace.layer_span("op", "readback"):
+                        time.sleep(0.003)
+                with trace.layer_span("exchange", "materialize"):
+                    _burn(0.002)
+                    with trace.layer_span("exchange", "gang_wait"):
+                        time.sleep(0.002)
+                with trace.layer_span("convert", "to_arrow"):
+                    _burn(0.002)
+                with trace.layer_span("serve", "send"):
+                    _burn(0.001)
+                v2 = acc.sealed(time.monotonic() - t0)
+        thread_cpu = time.thread_time() - cpu0
+        layers, cpu = v2["layers_s"], v2["layers_cpu_s"]
+        assert set(cpu) == CPU_LAYERS == set(layers) - {"compile", "other"}
+        for key in cpu:
+            assert 0 <= cpu[key] <= layers[key] + 1e-6, key
+        assert sum(cpu.values()) <= thread_cpu + 1e-6
+        assert sum(cpu.values()) <= v2["cpu_s"] + 1e-6
+        assert cpu["scan_wait"] == 0.0 and cpu["plan"] >= 0.003
+        assert cpu["op_host"] >= 0.004 and cpu["exchange"] >= 0.002
+
+    def test_a_compile_in_a_span_leaves_its_cpu_within_the_wall(self):
+        """The compile's seconds leave the span's wall (``on_compile``);
+        its CPU is not known apart, so the operator's CPU stops at what
+        is left of its wall (the cut is made key by key when the ledger
+        is sealed: a coarse CPU clock's tick may be larger than a span)."""
+        with trace.task_scope("q-compile-cpu") as acc:
+            with self._root(acc):
+                with trace.layer_span("op", "sort"):
+                    t0 = time.perf_counter()
+                    _burn(0.01)
+                    spun = time.perf_counter() - t0
+                    trace.on_compile(spun - 0.002)
+                v2 = acc.sealed(1.0)
+        sort = v2["ops_s"]["sort"]
+        # 10 ms of CPU in the span, 2 ms of wall left to it
+        assert 0.002 <= sort["host_s"] < 0.01
+        assert sort["cpu_s"] == sort["host_s"]
+
+    def test_a_thread_held_off_the_interpreter_is_off_cpu(self):
+        """A spinner thread that never leaves the interpreter takes the
+        lock in turns with the task's thread: the operator's span books
+        the whole wall and only its own turns as CPU."""
+        stop = threading.Event()
+
+        def spin():
+            while not stop.is_set():
+                pass
+
+        holder = threading.Thread(target=spin, daemon=True)
+        with trace.task_scope("q-lock") as acc:
+            with self._root(acc):
+                holder.start()
+                try:
+                    with trace.layer_span("op", "agg"):
+                        # 40 ms of CPU take at least seven turns of the
+                        # interpreter's 5 ms, each handed back for one
+                        _burn(0.04)
+                finally:
+                    stop.set()
+                    holder.join(timeout=30)
+                v2 = acc.sealed(1.0)
+        assert not holder.is_alive()
+        agg = v2["ops_s"]["agg"]
+        assert agg["cpu_s"] >= 0.04
+        assert agg["host_s"] - agg["cpu_s"] > 0.01
+        assert v2["layers_s"]["op_host"] - v2["layers_cpu_s"]["op_host"] \
+            == pytest.approx(agg["host_s"] - agg["cpu_s"], abs=2e-6)
+
+    def test_a_workers_cpu_is_split_by_span(self):
+        def worker(acc):
+            with trace.worker_scope(acc):
+                with trace.layer_span("scan", "decode"):
+                    time.sleep(0.03)
+                with trace.layer_span("scan", "encode"):
+                    _burn(0.01)
+                with trace.layer_span("scan", "h2d"):
+                    _burn(0.004)
+
+        with trace.task_scope("q-worker-cpu") as acc:
+            with self._root(acc):
+                th = threading.Thread(target=worker, args=(acc,))
+                th.start()
+                th.join(timeout=30)
+                v2 = acc.sealed(1.0)
+        assert not th.is_alive()
+        wall, cpu = v2["scan_worker_s"], v2["scan_worker_cpu_s"]
+        assert set(cpu) == set(wall) == {"decode", "encode", "h2d"}
+        for key in cpu:
+            assert 0 <= cpu[key] <= wall[key] + 1e-6, key
+        assert 2 * cpu["decode"] < 0.03 <= wall["decode"]
+        assert cpu["encode"] >= 0.01 and cpu["h2d"] >= 0.004
+        # a worker's spans are beside the task's thread: not in layers_s
+        assert v2["layers_cpu_s"] == dict.fromkeys(CPU_LAYERS, 0.0)
+
+
+class TestRowCount:
+    """``obs/profile.row_count``: the one way an operator reads a
+    batch's row count."""
+
+    def _task(self, body):
+        with trace.task_scope("q-rows") as acc:
+            acc.start()
+            with trace.layer_span("serve", "task", query_id=acc.query_id):
+                with trace.layer_span("op", "hash_join"):
+                    got = body()
+            return got, acc.sealed(1.0)
+
+    def test_a_device_count_is_one_readback_and_one_row_sync(self):
+        import jax.numpy as jnp
+
+        from auron_tpu.columnar.batch import DeviceBatch
+        from auron_tpu.obs import profile
+        batch = DeviceBatch((), jnp.asarray(7, jnp.int32))
+        conf = cfg.get_config()
+        conf.set(cfg.TRACE_ENABLED, True)
+        trace.reset()
+        try:
+            got, v2 = self._task(lambda: profile.row_count(batch))
+            names = [s.name for s in trace.tracer().spans()]
+        finally:
+            conf.unset(cfg.TRACE_ENABLED)
+            trace.reset()
+        assert got == 7 and type(got) is int
+        assert names.count("op.readback") == 1
+        assert v2["counts"]["row_syncs"] == 1
+        assert v2["counts"]["readbacks"] == 0
+        assert v2["counts"]["d2h_bytes"] == 0
+        # the read's wait is the operator's device wait, not its host time
+        assert v2["ops_s"]["hash_join"]["device_wait_s"] > 0
+        assert v2["layers_s"]["op_device_wait"] \
+            == v2["ops_s"]["hash_join"]["device_wait_s"]
+
+    @pytest.mark.parametrize("count", [5, "numpy"])
+    def test_a_host_count_opens_no_span(self, count):
+        import numpy as np
+
+        from auron_tpu.obs import profile
+
+        class Batch:
+            num_rows = np.int32(5) if count == "numpy" else count
+
+        got, v2 = self._task(lambda: profile.row_count(Batch()))
+        assert got == 5 and type(got) is int
+        assert v2["counts"]["row_syncs"] == 0
+        assert v2["counts"]["readbacks"] == 0
+        # the root's span and the operator's: the read opened none
+        assert v2["counts"]["layer_spans"] == 2
+        assert v2["layers_s"]["op_device_wait"] == 0.0
+
+    def test_a_count_handed_in_itself_is_read_the_same_way(self):
+        import jax.numpy as jnp
+
+        from auron_tpu.obs import profile
+        got, v2 = self._task(
+            lambda: profile.row_count(jnp.asarray(11, jnp.int32)))
+        assert got == 11
+        assert v2["counts"]["row_syncs"] == 1
+
+    def test_the_split_adds_no_time_to_the_operator(self):
+        """What ``count_output`` booked as the operator's host time (the
+        span round ``next()`` and the read of the row count) is now its
+        host time plus its device wait: the same interval, split."""
+        import jax.numpy as jnp
+
+        from auron_tpu.columnar.batch import DeviceBatch
+        from auron_tpu.ops.base import MetricsSet, count_output
+
+        def source():
+            for n in (3, 4):
+                _busy(0.002)
+                yield DeviceBatch((), jnp.asarray(n, jnp.int32))
+
+        with trace.task_scope("q-split") as acc:
+            acc.start()
+            with trace.layer_span("serve", "task", query_id=acc.query_id):
+                ms = MetricsSet(name="gen_op")
+                it = count_output(source(), ms)
+                inside = 0.0
+                while True:
+                    t0 = time.perf_counter()
+                    b = next(it, None)
+                    inside += time.perf_counter() - t0
+                    if b is None:
+                        break
+                    _busy(0.003)        # the consumer's, not the op's
+                v2 = acc.sealed(1.0)
+        op = v2["ops_s"]["gen_op"]
+        assert ms.snapshot()["output_rows"] == 7
+        assert v2["counts"]["row_syncs"] == 2
+        assert op["device_wait_s"] > 0 and op["host_s"] >= 0.004
+        # three next() calls: the spans lie inside them, and what they
+        # leave out is the generator's resume and the span's own
+        # enter / exit (some tens of microseconds each)
+        assert op["host_s"] + op["device_wait_s"] <= inside
+        assert op["host_s"] + op["device_wait_s"] == pytest.approx(
+            inside, abs=3e-3)
 
 
 def _encode_on_a_scan_worker(rb) -> dict:
@@ -505,7 +845,8 @@ class TestServedLedger:
         assert led["version"] == 2
         assert V1_KEYS <= set(led)
         assert V1_COMPILE_KEYS <= set(led["compile"])
-        assert {"queue_s", "layers_s", "ops_s", "scan_worker_s", "cpu_s",
+        assert {"queue_s", "layers_s", "layers_cpu_s", "ops_s",
+                "scan_worker_s", "scan_worker_cpu_s", "cpu_s",
                 "counts"} <= set(led)
         assert {"task_xla_compiles", "task_seconds"} <= set(led["compile"])
         # the old inclusive numbers keep their meaning beside the new
@@ -535,6 +876,44 @@ class TestServedLedger:
         assert wait == pytest.approx(led["layers_s"]["op_device_wait"],
                                      abs=1e-4)
         assert all(o["batches"] > 0 for o in ops.values())
+
+    def test_cpu_self_times_lie_within_the_wall(self, served):
+        # one task in CPU_TIMED_EVERY carries them, whichever thread or
+        # test ran the ones before it
+        runs = [served("q3") for _ in range(trace.CPU_TIMED_EVERY)]
+        timed = [led for led in runs if led["layers_cpu_s"] is not None]
+        assert len(timed) == 1
+        assert all("cpu_s" not in op for led in runs if led is not timed[0]
+                   for op in led["ops_s"].values())
+        led = timed[0]
+        layers, cpu = led["layers_s"], led["layers_cpu_s"]
+        assert set(cpu) == CPU_LAYERS
+        for key in cpu:
+            assert 0 <= cpu[key] <= layers[key] + 1e-3, key
+        assert cpu["op_host"] > 0 and cpu["plan"] > 0
+        # the task thread's CPU and its workers' make cpu_s
+        worker = led["scan_worker_cpu_s"]
+        assert set(worker) == {"decode", "encode", "h2d"}
+        for key in worker:
+            assert 0 < worker[key] <= led["scan_worker_s"][key] + 1e-3
+        assert sum(cpu.values()) <= led["cpu_s"] - sum(worker.values()) \
+            + 1e-3
+        ops = led["ops_s"]
+        # (each is cut at its own wall: a read's own CPU, which the
+        # readback span does not book, is its operator's)
+        assert sum(o["cpu_s"] for o in ops.values()) == pytest.approx(
+            cpu["op_host"], abs=5e-3)
+        assert all(0 <= o["cpu_s"] <= o["host_s"] + 1e-3
+                   for o in ops.values())
+
+    def test_every_row_count_read_is_a_row_sync(self, served):
+        """The counts of a q3 task over one split: the operators read
+        their batches' row counts through the one helper, and the
+        control readbacks are what they were."""
+        counts = served("q3")["counts"]
+        assert counts["row_syncs"] > counts["readbacks"] > 0
+        led = served("q3")
+        assert led["ops_s"]["hash_join"]["device_wait_s"] > 0
 
     def test_scan_worker_and_cpu(self, served):
         led = served("q3")

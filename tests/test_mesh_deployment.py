@@ -297,15 +297,27 @@ def test_one_chip_frame_has_no_mesh_count(plan, answers):
 
 
 def _recorded_spans(run):
-    """``run()`` with ``auron.trace.enabled`` and the spans it left."""
+    """``run()`` with ``auron.trace.enabled`` and the spans of ITS trace.
+
+    The tracer is the process's: whatever thread opens a span while the
+    setting is on records it when it closes, be that after this
+    function's ``trace.reset()`` — an earlier stage's root closes on
+    its server thread after the DONE frame has gone out, and a thread
+    another test file left behind in this worker closes its spans when
+    it pleases. Read whole, the tracer handed a later test such
+    strangers' spans (and let a stale ``serve.task`` end its wait for
+    the root). So the run gets a trace of its own — the client joins
+    the scope opened here and the server's handler thread adopts its id
+    off the wire — and only that trace's spans are the run's."""
     conf = cfg.get_config()
     conf.set(cfg.TRACE_ENABLED, True)
     try:
-        result = run()
+        with trace.query_scope("test_mesh_deployment") as scope:
+            result = run()
         # the root span closes on the server thread after the DONE frame
-        deadline = time.monotonic() + 5.0
+        deadline = time.monotonic() + 30.0
         while True:
-            spans = list(trace.tracer().spans())
+            spans = trace.tracer().spans(scope.trace_id)
             if any(s.name == "serve.task" for s in spans) \
                     or time.monotonic() > deadline:
                 return result, spans
@@ -313,6 +325,36 @@ def _recorded_spans(run):
     finally:
         conf.unset(cfg.TRACE_ENABLED)
         trace.reset()
+
+
+def test_recorded_spans_are_the_runs_own(stage, answers):
+    """A stranger's span — recorded under another trace while the run
+    is in flight, as a thread left over from an earlier stage records
+    its own — is not among the run's, and the run's root is."""
+    stop = threading.Event()
+
+    def stranger():
+        with trace.query_scope("stranger"):
+            while not stop.is_set():
+                with trace.layer_span("exchange", "mesh_round"):
+                    time.sleep(0.001)
+
+    th = threading.Thread(target=stranger, daemon=True)
+
+    def run():
+        th.start()
+        try:
+            return stage("q3", False)
+        finally:
+            stop.set()
+            th.join(timeout=30)
+
+    _result, spans = _recorded_spans(run)
+    assert not th.is_alive()
+    names = {s.name for s in spans}
+    assert "serve.task" in names and "op.hash_join" in names
+    assert len({s.trace_id for s in spans}) == 1
+    assert not names & set(MESH_SPANS)
 
 
 def test_the_three_spans_lie_inside_the_exchange_layer(stage, answers):

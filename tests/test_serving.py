@@ -690,3 +690,64 @@ def test_wire_resume_collect_scope_streams_every_partition(tmp_path):
             srv.shutdown()
     finally:
         restore()
+
+
+@pytest.mark.parametrize("ending", ["done", "error"])
+def test_a_slow_reader_of_a_finished_engine_gets_every_frame(ending,
+                                                             monkeypatch):
+    """The engine sends what its window allows, then DONE (or ERROR), and
+    closes; a reader the machine keeps waiting acknowledges its first
+    batch after that, the engine's host answers with a reset, and the
+    second ACK fails to write. The ACK is flow control for an engine that
+    no longer waits: the frames already sent are the answer (PR 40: under
+    the tier-1 workers this lost ``test_mesh_deployment.py``'s shared
+    stage answers to a ``BrokenPipeError``, and would have hidden an
+    ERROR frame the same way)."""
+    import json
+    import socket
+    import threading
+    import time
+
+    from auron_tpu import errors
+    from auron_tpu.runtime import serving
+
+    rb = pa.record_batch({"x": pa.array([1, 2, 3])})
+    listener = socket.socket()
+    listener.bind(("127.0.0.1", 0))
+    listener.listen(1)
+
+    def engine():
+        conn, _ = listener.accept()
+        with conn:
+            serving.read_frame(conn)                       # SUBMIT
+            for _ in range(3):
+                serving.write_frame(conn, serving.KIND_BATCH,
+                                    serving._ipc_bytes(rb))
+            if ending == "done":
+                serving.write_frame(conn, serving.KIND_DONE,
+                                    json.dumps({"rows": 9}).encode())
+            else:
+                serving.write_frame(conn, serving.KIND_ERROR, b"boom")
+
+    th = threading.Thread(target=engine, daemon=True)
+    th.start()
+    decode = serving._ipc_batch
+
+    def slow_decode(payload):
+        time.sleep(0.2)
+        return decode(payload)
+
+    monkeypatch.setattr(serving, "_ipc_batch", slow_decode)
+    client = AuronClient("127.0.0.1", listener.getsockname()[1],
+                         timeout_s=30)
+    try:
+        if ending == "done":
+            table, done = client.execute(b"task")
+            assert table.num_rows == 9 and done == {"rows": 9}
+        else:
+            with pytest.raises(errors.RemoteEngineError, match="boom"):
+                client.execute(b"task")
+    finally:
+        th.join(timeout=30)
+        listener.close()
+    assert not th.is_alive()
