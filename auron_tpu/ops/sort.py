@@ -214,6 +214,13 @@ def _sort_kernel(sort_exprs: tuple, in_schema: Schema, capacity: int,
                         donate_argnums=(0,) if donate else ())
 
 
+def pad_word(ascending: bool) -> int:
+    """What a string key's missing trailing words read in a narrower
+    width bucket: the chars past a string's end are 0, complemented when
+    the key descends — what the kernel itself would emit for them."""
+    return 0 if ascending else (1 << 64) - 1
+
+
 def key_word_layout(sort_exprs: tuple, in_schema: Schema,
                     batch: DeviceBatch) -> list[tuple[int, int]]:
     """Per sort key: (word count incl. null word, pad word). Word counts
@@ -232,8 +239,7 @@ def key_word_layout(sort_exprs: tuple, in_schema: Schema,
             n_value_words = (col.chars.shape[1] + 7) // 8
         else:
             n_value_words = 1
-        pad = 0 if s.ascending else (1 << 64) - 1
-        layout.append((1 + n_value_words, pad))
+        layout.append((1 + n_value_words, pad_word(s.ascending)))
     return layout
 
 
@@ -259,25 +265,65 @@ def _sort_with_words_kernel(sort_exprs: tuple, in_schema: Schema,
                         donate_argnums=(0,) if donate else ())
 
 
+@program_cache("ops.sort.concat", maxsize=256)
+def _concat_kernel(capacities: tuple, widths: tuple):
+    """Buffered batches into ONE capacity-bucketed batch: every leaf
+    stacked (string widths and list element counts unified first), the
+    live rows of each batch — a prefix of it — gathered to the front in
+    batch order, the rest padding. The row counts are operands and their
+    sum is computed here, so no count comes to the host; the key holds
+    the input capacities and widths, never a row count."""
+    stacked_cap = sum(capacities)
+    total_cap = bucket_rows(stacked_cap)
+
+    def auron_ops_sort_concat(batches: tuple):
+        cols = []
+        for i in range(batches[0].num_columns):
+            parts = unify_column_widths([b.columns[i] for b in batches])
+            merged = parts[0]
+            for p in parts[1:]:
+                merged = concat_columns(merged, p)
+            cols.append(merged)
+        counts = jnp.stack([jnp.asarray(b.num_rows, jnp.int32)
+                            for b in batches])
+        ends = jnp.cumsum(counts)
+        # output row j is row (j - live rows before its batch) of the
+        # batch it falls in: no sort, the live rows are prefixes
+        rows = jnp.arange(total_cap, dtype=jnp.int32)
+        k = jnp.minimum(
+            jnp.sum(rows[:, None] >= ends[None, :], axis=1,
+                    dtype=jnp.int32), len(capacities) - 1)
+        starts = jnp.cumsum(jnp.asarray((0,) + capacities[:-1], jnp.int32))
+        src = starts[k] + rows - (ends - counts)[k]
+        stacked = DeviceBatch(tuple(cols), ends[-1])
+        return gather_batch(stacked, jnp.clip(src, 0, stacked_cap - 1),
+                            ends[-1])
+
+    return programs.jit(auron_ops_sort_concat)
+
+
 def _concat_all(batches: list[DeviceBatch]) -> DeviceBatch:
-    """Concatenate buffered batches into one capacity-bucketed batch."""
-    total_cap = bucket_rows(sum(b.capacity for b in batches))
-    cols = []
-    ncols = batches[0].num_columns
-    for i in range(ncols):
-        parts = unify_column_widths([b.columns[i] for b in batches])
-        merged = parts[0]
-        for p in parts[1:]:
-            merged = concat_columns(merged, p)
-        cols.append(merged)
+    """Concatenate buffered batches into one capacity-bucketed batch:
+    one launch of ``ops.sort.concat``, no readback (the result's row
+    count stays on the device). How many batches a caller collected
+    follows its data, so the arity is rounded up to a power of two with
+    zero-row views of the smallest batch (its arrays, ``num_rows`` 0:
+    rows past ``num_rows`` are dead by the batch contract) as far as
+    that leaves the result in its capacity bucket — batches of one
+    capacity, as an exchange's reducer is handed, then compile for as
+    few shapes as the buckets the eager concatenation ended in."""
+    batches = list(batches)
+    small = min(batches, key=lambda b: b.capacity)
     stacked_cap = sum(b.capacity for b in batches)
-    from auron_tpu.columnar.batch import compact, resize
-    live = jnp.concatenate([b.row_mask() for b in batches])
-    num = sum(_profile.row_count(b) for b in batches)
-    stacked = DeviceBatch(tuple(cols), jnp.asarray(stacked_cap, jnp.int32))
-    compacted = compact(stacked, live)
-    out = resize(compacted, total_cap) if total_cap >= stacked_cap else compacted
-    return DeviceBatch(out.columns, jnp.asarray(num, jnp.int32))
+    spare = (bucket_rows(stacked_cap) - stacked_cap) // small.capacity
+    while len(batches) & (len(batches) - 1) and spare > 0:
+        batches.append(DeviceBatch(small.columns, 0))
+        spare -= 1
+    widths = tuple(tuple(leaf.shape[1:] for leaf in
+                         jax.tree_util.tree_leaves(b.columns))
+                   for b in batches)
+    kern = _concat_kernel(tuple(b.capacity for b in batches), widths)
+    return kern(tuple(batches))
 
 
 class _SortSpillConsumer(BufferedSpillConsumer):

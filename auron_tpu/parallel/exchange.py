@@ -48,7 +48,10 @@ from auron_tpu.ops.base import (ExecContext, PhysicalOp, count_output,
 from auron_tpu.parallel.partitioning import (HashPartitioning,
                                              RangePartitioning,
                                              RoundRobinPartitioning,
-                                             SinglePartitioning)
+                                             SinglePartitioning,
+                                             range_bounds,
+                                             range_partition_ids,
+                                             sample_range_words)
 from auron_tpu.runtime import programs
 from auron_tpu.runtime.programs import program_cache
 from auron_tpu.utils.shapes import bucket_rows
@@ -72,22 +75,6 @@ def _split_body(batch: DeviceBatch, pids, num_partitions: int):
         live.astype(jnp.int32), jnp.clip(key, 0, num_partitions),
         num_segments=num_partitions + 1)[:num_partitions]
     return sorted_batch, counts
-
-
-@program_cache("parallel.exchange.sort_by_pid", maxsize=256)
-def _sort_by_pid_kernel(num_partitions: int, capacity: int, donate: bool):
-    """ONE compaction for all partitions. ``donate`` hands the input
-    batch's buffers to XLA (the un-sorted input is dead after the call —
-    halves peak HBM for the split); callers pass it only for owned
-    input streams on non-CPU backends (see yields_owned_batches)."""
-
-    def auron_parallel_exchange_sort_by_pid(batch: DeviceBatch, pids):
-        return _split_body(batch, pids, num_partitions)
-
-    # graft: donation-ok -- callers gate on owned input streams;
-    # a task retry re-splits from source, never the donated array
-    return programs.jit(auron_parallel_exchange_sort_by_pid,
-                        donate_argnums=(0,) if donate else ())
 
 
 @program_cache("parallel.exchange.read_cut", maxsize=256)
@@ -120,9 +107,10 @@ def _cut(columns, starts, counts, capacity: int) -> tuple:
     return _read_cut_kernel(bounds.shape[1], capacity)(columns, bounds)
 
 
-#: fused split programs: the upstream fused-stage chain (when present),
-#: the partition-id computation and the sort-by-pid compaction in ONE
-#: XLA program — the whole-stage-fusion prologue of the exchange
+#: split programs: the upstream fused-stage chain (when one folds), the
+#: partition-id computation and the sort-by-pid compaction in ONE XLA
+#: program — the whole-stage-fusion prologue of the exchange, and with no
+#: chain the split of every exchange that folds none (``_Split``)
 _SPLIT_PROGRAMS = programs.register(
     programs.ProgramCache("parallel.exchange.fused_split", maxsize=256))
 
@@ -153,7 +141,8 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
         kind = part_sig[0]
 
         def auron_parallel_exchange_fused_split(batch: DeviceBatch,
-                                                partition_id, carries):
+                                                partition_id, carries,
+                                                bounds=None):
             outs, new_carries = thread_fragments(fragments, batch,
                                                  partition_id, carries)
             (b,) = outs   # fan-out chains never take this path
@@ -172,6 +161,9 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
                 start = carries[n_frags].astype(jnp.int32)
                 pids = (jnp.arange(b.capacity, dtype=jnp.int32) + start) \
                     % jnp.int32(n_out)
+            elif kind == "range":
+                pids = range_partition_ids(b, out_schema, part_sig[1],
+                                           n_out, *bounds, part_sig[2])
             else:   # single
                 pids = jnp.zeros(b.capacity, jnp.int32)
             sorted_batch, counts = _split_body(b, pids, n_out)
@@ -191,17 +183,109 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
          combine_sig), build)
 
 
-def _split_signature(partitioning) -> Optional[tuple]:
-    """Hashable partitioning signature for the fused split program, or
-    None when the partitioning cannot fuse (range bounds are sampled
-    host-side mid-stream)."""
+def _split_signature(partitioning) -> tuple:
+    """Hashable partitioning signature for the split program. A range
+    partitioning's holds its sort orders and the layout of its bounds,
+    never their values (the program's operand)."""
     if isinstance(partitioning, HashPartitioning):
-        return ("hash", partitioning.exprs)
+        return ("hash", tuple(partitioning.exprs))
     if isinstance(partitioning, RoundRobinPartitioning):
         return ("round_robin",)
-    if isinstance(partitioning, SinglePartitioning):
-        return ("single",)
-    return None
+    if isinstance(partitioning, RangePartitioning):
+        return ("range", tuple(partitioning.sort_orders),
+                tuple(partitioning.bound_layout))
+    assert isinstance(partitioning, SinglePartitioning), partitioning
+    return ("single",)
+
+
+def _sample_range_bounds(batches, partitioning: RangePartitioning,
+                         schema: Schema) -> tuple:
+    """Sample a range partitioning's bounds from the LEADING batches of
+    ``batches`` (one sample program and one readback a batch, its row
+    count riding along) -> (the partitioning with its bounds, the
+    batches drawn, which the caller still owes their split)."""
+    drawn, samples, rows = [], [], 0
+    for batch in batches:
+        drawn.append(batch)
+        samples.append(sample_range_words(batch, partitioning.sort_orders,
+                                          schema))
+        rows += len(samples[-1][0])
+        if rows >= _RANGE_SAMPLE_ROWS:
+            break
+    bounds, layout = range_bounds(samples, partitioning.sort_orders,
+                                  partitioning.num_partitions)
+    return RangePartitioning(partitioning.sort_orders,
+                             partitioning.num_partitions, bounds,
+                             layout), drawn
+
+
+class _Split:
+    """The map side's split of ONE exchange, whatever its partitioning
+    and whatever folded into it: one launch of the split program a batch
+    (the chain and the combine stage when ``fold`` brings them, the
+    partition ids, the sort by them) and its ONE sync, the counts
+    readback. Every route that splits on the host calls it — the
+    device-buffer fill, folded or not, a demoted mesh exchange's
+    continuation and the RSS writer."""
+
+    def __init__(self, partitioning, n_out: int, in_schema: Schema,
+                 out_schema: Schema, donate: bool, fold=None,
+                 kmetrics=None):
+        self.fragments, self.frag_keys, _input_op, self.combine, \
+            self.combine_sig = fold or ([], (), None, None, None)
+        self.part_sig = _split_signature(partitioning)
+        self.part_exprs = partitioning.exprs \
+            if isinstance(partitioning, HashPartitioning) else ()
+        self.n_out = n_out
+        self.in_schema, self.out_schema = in_schema, out_schema
+        self.donate = donate
+        #: a range partitioning's bounds, on the device once an exchange
+        self.bounds = jax.device_put(partitioning.bounds_operand()) \
+            if isinstance(partitioning, RangePartitioning) else None
+        self.built_c = self.hit_c = None
+        if kmetrics is not None:
+            self.built_c = kmetrics.counter("fused_split_programs_built")
+            self.hit_c = kmetrics.counter("fused_split_program_hits")
+
+    def carries(self, members=None, seen=None):
+        """The carry vector a map partition starts with: its members'
+        (their initial ones unless given) and the trailing slot, the
+        rows seen at the split so far (the round-robin start)."""
+        if members is None:
+            members = [f.init_carry for f in self.fragments]
+        # graft: disable=GL001 -- host values: initial carries, or a snapshot a timed_get brought
+        members = np.asarray(members, np.int64).reshape(-1)
+        if seen is None:
+            return np.concatenate([members, np.zeros(1, np.int64)])
+        return jnp.concatenate([jnp.asarray(members), seen])
+
+    def __call__(self, batch: DeviceBatch, in_p: int, carries, t):
+        """-> (sorted batch, host counts a partition, the carries after
+        it, pre-combine live rows or None). ``t`` is the open timer
+        frame the launch and the readback's wait are booked in."""
+        kern, built = _fused_split_program(
+            self.frag_keys, self.part_sig, self.in_schema,
+            self.out_schema, self.n_out, batch.capacity, self.donate,
+            self.fragments, self.part_exprs, self.combine,
+            self.combine_sig)
+        if self.built_c is not None:
+            (self.built_c if built else self.hit_c).add(1)
+        outs = t.track(kern(batch, np.int32(in_p), carries, self.bounds))
+        sorted_batch, counts, carries = outs[:3]
+        # the counts readback is the split's semantic sync point: read
+        # inside the timer frame, so that the wait is booked as device;
+        # the pre-combine live rows ride the SAME fence
+        fenced = _profile.timed_get((counts,) + tuple(outs[3:]))
+        # graft: disable=GL001 -- already host: read via timed_get above
+        counts_h = np.asarray(fenced[0])
+        comb_in_h = int(fenced[1]) if self.combine is not None else None   # graft: disable=GL001 -- same fenced readback
+        if self.combine is not None:
+            # a combined batch's row count is traced (the group count):
+            # pin the concrete live total so that buffer bookkeeping and
+            # spill slicing never sync on it
+            sorted_batch = DeviceBatch(sorted_batch.columns,
+                                       int(counts_h.sum()))
+        return sorted_batch, counts_h, carries, comb_in_h
 
 
 def _record_route(op, metrics, route: str, reason: str, **attrs) -> None:
@@ -303,9 +387,13 @@ class _ExchangeBuffer:
 
     # -- read side ----------------------------------------------------------
 
-    def _entry_partition(self, e, p: int) -> Optional[DeviceBatch]:
+    def _entry_partition(self, e, p: int,
+                         capacity: Optional[int] = None
+                         ) -> Optional[DeviceBatch]:
         """Partition ``p``'s rows of ONE entry (device slice or restored
-        host frame); None when the entry holds no rows for ``p``."""
+        host frame) as a batch of ``capacity`` rows (the bucket of its
+        own rows unless given); None when the entry holds no rows for
+        ``p``."""
         from auron_tpu.columnar.serde import (deserialize_host_batch,
                                               host_to_batch)
         offsets = e[2]
@@ -314,20 +402,27 @@ class _ExchangeBuffer:
         n_p = hi - lo
         if n_p <= 0:
             return None
+        capacity = capacity or bucket_rows(n_p)
         if e[0].startswith("dev"):
             # "dev" or "dev-spilling": the device batch in this
             # snapshot's entry list stays valid even if a concurrent
             # spill swaps the entry afterwards
-            (out,) = _cut(e[1].columns, [lo], [n_p], bucket_rows(n_p))
+            (out,) = _cut(e[1].columns, [lo], [n_p], capacity)
             return out
         host, _extras = deserialize_host_batch(e[1].frame_at(p))
-        return host_to_batch(host, bucket_rows(n_p))
+        return host_to_batch(host, capacity)
 
     def partition_batches(self, p: int) -> Iterator[DeviceBatch]:
         with self._lock:
             entries = list(self.entries)
+        # the partition's slices share ONE capacity, the bucket of its
+        # fullest slice (as a mesh round's do): what collects them — a
+        # sort's concatenation, a join's build — then compiles for one
+        # shape a bucket, not for every mix of its sources' buckets
+        # graft: disable=GL001 -- offsets are host ndarrays
+        top = max((int(e[2][p + 1] - e[2][p]) for e in entries), default=0)
         for e in entries:
-            out = self._entry_partition(e, p)
+            out = self._entry_partition(e, p, bucket_rows(top))
             if out is not None:
                 yield out
 
@@ -1018,11 +1113,15 @@ class ShuffleExchangeOp(PhysicalOp):
         computing the SAME rows."""
         n_out = self.num_partitions
         out_schema = self.child.schema()
-        part_exprs = self.partitioning.exprs
-        use_fused = bool(fragments) or combine is not None
         if input_op is None:
             input_op = self.child.input if fragments else self.child
-        in_schema = input_op.schema()
+        # the demoted path never donates: one launch of the split
+        # program a batch (chain — and the map-side combine, when the
+        # mesh program had one folded — rides along)
+        split = _Split(self.partitioning, n_out, input_op.schema(),
+                       out_schema, False,
+                       fold=(fragments, frag_keys, input_op, combine,
+                             combine_sig))
         host = _ExchangeBuffer(self, ctx.mem_manager, metrics, ctx.conf)
         sources: list[int] = []
         recompute_rows = 0
@@ -1034,41 +1133,14 @@ class ShuffleExchangeOp(PhysicalOp):
         def route_batch(in_p: int, batch: DeviceBatch, carries):
             nonlocal host_rows, comb_in_total, comb_out_total, \
                 comb_batches
-            # the demoted path never donates: a classic one-launch
-            # split per batch (chain — and the map-side combine, when
-            # the mesh program had one folded — rides along), entry
-            # tagged with its source map so the combined read path can
-            # interleave map-major
+            # entry tagged with its source map so the combined read
+            # path can interleave map-major
             with timer(write_time) as t:
-                if use_fused:
-                    kern, _built = _fused_split_program(
-                        frag_keys, ("hash", part_exprs), in_schema,
-                        out_schema, n_out, batch.capacity, False,
-                        fragments, part_exprs, combine, combine_sig)
-                    if combine is not None:
-                        sorted_batch, counts, carries, comb_in = \
-                            t.track(kern(batch, jnp.int32(in_p),
-                                         carries))
-                        counts_h, comb_in_h = _profile.timed_get(
-                            (counts, comb_in))
-                        counts_h = np.asarray(counts_h)   # graft: disable=GL001 -- already host: read via timed_get above
-                        comb_in_total += int(comb_in_h)   # graft: disable=GL001 -- same fenced readback
-                    else:
-                        sorted_batch, counts, carries = t.track(
-                            kern(batch, jnp.int32(in_p), carries))
-                        counts_h = np.asarray(
-                            _profile.timed_get(counts))
-                else:
-                    pids = self.partitioning.partition_ids(batch,
-                                                           out_schema)
-                    kern = _sort_by_pid_kernel(n_out, batch.capacity,
-                                               False)
-                    sorted_batch, counts = t.track(kern(batch, pids))
-                    counts_h = np.asarray(_profile.timed_get(counts))
+                sorted_batch, counts_h, carries, comb_in_h = split(
+                    batch, in_p, carries, t)
             n = int(counts_h.sum())
             if combine is not None:
-                # pin the concrete group count (see _materialize_fused)
-                sorted_batch = DeviceBatch(sorted_batch.columns, n)
+                comb_in_total += comb_in_h
                 comb_out_total += n
                 comb_batches += 1
             offsets = np.concatenate(
@@ -1083,15 +1155,10 @@ class ShuffleExchangeOp(PhysicalOp):
 
         try:
             for in_p in range(self.input_partitions):
-                if use_fused:
-                    # member carries from the last completed mesh round
-                    # + the trailing split-seen slot (round-robin only —
-                    # mesh routing is hash-only, the slot is inert)
-                    carries = jnp.concatenate([
-                        jnp.asarray(carries_h[in_p], jnp.int64),
-                        jnp.zeros((1,), jnp.int64)])
-                else:
-                    carries = None
+                # member carries from the last completed mesh round
+                # + the trailing split-seen slot (round-robin only —
+                # mesh routing is hash-only, the slot is inert)
+                carries = split.carries(members=carries_h[in_p])
                 pend = pending_by_map.pop(in_p, None)
                 if pend is not None:
                     # the lost round's re-route: its rows are the
@@ -1136,64 +1203,41 @@ class ShuffleExchangeOp(PhysicalOp):
         schema = self.child.schema()
         n_out = self.num_partitions
 
-        part_sig = _split_signature(self.partitioning)
+        partitioning = self.partitioning
+        # a range partitioning's bounds are sampled from the LEADING
+        # batches of this same pass, which the child must hand over
+        # unfolded — the child is never executed twice
+        unsampled = isinstance(partitioning, RangePartitioning) \
+            and not partitioning.bounds
         fold = self._fold_spec() \
-            if part_sig is not None and ctx.conf.get(cfg.FUSION_ENABLED) \
+            if not unsampled and ctx.conf.get(cfg.FUSION_ENABLED) \
             else None
         if fold is not None:
-            self._materialize_fused(ctx, buffer, write_time, part_sig,
-                                    fold)
+            self._materialize_fused(ctx, buffer, write_time, fold)
             return buffer
 
         batches = self._input_batches(ctx)
-        partitioning = self.partitioning
         pending: list[DeviceBatch] = []
-        if isinstance(partitioning, RangePartitioning) \
-                and not partitioning.bounds:
-            # sample bounds from the LEADING batches of this same pass —
-            # the child is never executed twice
-            from auron_tpu.parallel.partitioning import compute_range_bounds
-            sampled = 0
-            for batch in batches:
-                pending.append(batch)
-                sampled += _profile.row_count(batch)
-                if sampled >= _RANGE_SAMPLE_ROWS:
-                    break
-            bounds = compute_range_bounds(
-                pending, list(partitioning.sort_orders), schema,
-                partitioning.num_partitions)
-            partitioning = RangePartitioning(
-                partitioning.sort_orders, partitioning.num_partitions,
-                bounds)
+        if unsampled:
+            partitioning, pending = _sample_range_bounds(
+                batches, partitioning, schema)
             self.partitioning = partitioning
 
-        row_offset = 0
         donate = yields_owned_batches(self.child) \
             and jax.default_backend() != "cpu"
+        split = _Split(partitioning, n_out, schema, schema, donate)
+        # the rows seen so far (the round-robin start) ride the carry
+        carries = split.carries()
+        shuffle_bytes = ctx.metrics_for(self).counter("shuffle_bytes_live")
+        from auron_tpu.columnar.batch import batch_nbytes
         import itertools
         for batch in itertools.chain(pending, batches):
-            # donation hands the batch's buffers to XLA — read the row
-            # count BEFORE the call (afterwards the donated leaves are
-            # poisoned)
-            n_in = _profile.row_count(batch) if donate else None
             with timer(write_time) as t:
-                if isinstance(partitioning, RoundRobinPartitioning):
-                    part = RoundRobinPartitioning(n_out, row_offset)
-                    pids = part.partition_ids(batch, schema)
-                else:
-                    pids = partitioning.partition_ids(batch, schema)
-                kern = _sort_by_pid_kernel(n_out, batch.capacity, donate)
-                sorted_batch, counts = t.track(kern(batch, pids))
-                # the counts readback is the shuffle materialize's
-                # semantic sync point: read it inside the timer frame so
-                # the wait is booked as device, not serde
-                counts_h = np.asarray(_profile.timed_get(counts))
-            row_offset += n_in if donate else _profile.row_count(batch)
-            from auron_tpu.columnar.batch import batch_nbytes
-            live_rows = int(counts_h.sum())   # graft: disable=GL001 -- counts_h is a host ndarray (timed_get above)
+                sorted_batch, counts_h, carries, _ = split(
+                    batch, 0, carries, t)
+            live_rows = int(counts_h.sum())   # graft: disable=GL001 -- counts_h is a host ndarray (the split's timed_get)
             cap = max(int(sorted_batch.capacity), 1)   # graft: disable=GL001 -- capacity is a python int by construction
-            ctx.metrics_for(self).counter("shuffle_bytes_live").add(
-                batch_nbytes(sorted_batch) * live_rows // cap)
+            shuffle_bytes.add(batch_nbytes(sorted_batch) * live_rows // cap)
             offsets = np.concatenate(
                 [np.zeros(1, np.int64), np.cumsum(counts_h)])
             buffer.add(sorted_batch, offsets)
@@ -1201,11 +1245,9 @@ class ShuffleExchangeOp(PhysicalOp):
 
     def _split_fragments(self):
         """The child chain's fragments when they can fold into the split
-        program, else None (no chain / fused limit / fan-out members) —
-        None keeps the classic path, whose pid+sort kernel is keyed only
-        on (n_out, capacity) and therefore SHARES across queries; a
-        fragment-less per-schema split program would trade that sharing
-        away for nothing."""
+        program, else None (no chain / fused limit / fan-out members):
+        the exchange then splits its child's output with the same
+        program and no chain in it."""
         from auron_tpu.ops.fused import FusedStageOp
         if not isinstance(self.child, FusedStageOp) \
                 or self.child.has_limit():
@@ -1244,8 +1286,7 @@ class ShuffleExchangeOp(PhysicalOp):
         return fragments, frag_keys, self.child.input, None, None
 
     def _materialize_fused(self, ctx: ExecContext, buffer: _ExchangeBuffer,
-                           write_time, part_sig: tuple,
-                           fold: tuple) -> None:
+                           write_time, fold: tuple) -> None:
         """Whole-stage split: the child chain's member fragments join the
         exchange's partition-id + sort-by-pid program, so a
         filter→project chain feeding a hash shuffle is ONE XLA launch
@@ -1255,10 +1296,6 @@ class ShuffleExchangeOp(PhysicalOp):
         batch's groups before the split — the bytes entering the buffer
         (and its RSS spill frames) are per-batch GROUPS, not rows."""
         n_out = self.num_partitions
-        out_schema = self.child.schema()
-        kmetrics = ctx.metrics_for("kernels")
-        built_c = kmetrics.counter("fused_split_programs_built")
-        hit_c = kmetrics.counter("fused_split_program_hits")
         # the folded chain/agg still OWNS its plan node (see the
         # hash-join probe fold): the sorted batch's live count IS the
         # folded work's output count, and the one-launch program's time
@@ -1270,13 +1307,12 @@ class ShuffleExchangeOp(PhysicalOp):
         fmetrics.counter("split_folded").add(1)
         metrics = ctx.metrics_for(self)
 
-        fragments, frag_keys, input_op, combine, combine_sig = fold
-        in_schema = input_op.schema()
-        part_exprs = self.partitioning.exprs \
-            if isinstance(self.partitioning, HashPartitioning) else ()
+        _fragments, _frag_keys, input_op, combine, _combine_sig = fold
         donate = yields_owned_batches(input_op) \
             and jax.default_backend() != "cpu"
-        init = [f.init_carry for f in fragments]
+        split = _Split(self.partitioning, n_out, input_op.schema(),
+                       self.child.schema(), donate, fold=fold,
+                       kmetrics=ctx.metrics_for("kernels"))
         comb_in_total = 0
         comb_out_total = 0
         n_batches = 0
@@ -1285,46 +1321,23 @@ class ShuffleExchangeOp(PhysicalOp):
         # the trailing carry slot (rows seen at the split — the
         # round-robin start) persists across input partitions; member
         # carries reset per input partition like a fresh execute() would
-        split_seen = jnp.zeros((1,), jnp.int64)
+        split_seen = None
         for in_p in range(self.input_partitions):
             map_ctx = ctx.child(partition_id=in_p,
                                 num_partitions=self.input_partitions)
-            carries = jnp.concatenate(
-                [jnp.asarray(init, jnp.int64), split_seen])
+            carries = split.carries(seen=split_seen)
             for batch in input_op.execute(in_p, map_ctx):
                 map_ctx.checkpoint("shuffle.map")
-                kern, built = _fused_split_program(
-                    frag_keys, part_sig, in_schema, out_schema, n_out,
-                    batch.capacity, donate, fragments, part_exprs,
-                    combine, combine_sig)
-                (built_c if built else hit_c).add(1)
                 t0v = f_elapsed.value
                 with timer(f_elapsed) as t:
-                    if combine is not None:
-                        sorted_batch, counts, carries, comb_in = t.track(
-                            kern(batch, jnp.int32(in_p), carries))
-                        # pre-combine live rows ride the SAME readback
-                        # fence as the counts (no extra sync point)
-                        counts_h, comb_in_h = _profile.timed_get(
-                            (counts, comb_in))
-                        counts_h = np.asarray(counts_h)   # graft: disable=GL001 -- already host: read via timed_get above
-                        comb_in_total += int(comb_in_h)   # graft: disable=GL001 -- same fenced readback
-                    else:
-                        sorted_batch, counts, carries = t.track(
-                            kern(batch, jnp.int32(in_p), carries))
-                        # semantic sync point (see _materialize): the
-                        # wait books as device inside this frame
-                        counts_h = np.asarray(_profile.timed_get(counts))
+                    sorted_batch, counts_h, carries, comb_in_h = split(
+                        batch, in_p, carries, t)
                 # the shuffle node keeps its canonical write-time view
                 # of the same launch (chain + split are one program)
                 write_time.add(f_elapsed.value - t0v)
                 live = int(counts_h.sum())
                 if combine is not None:
-                    # a combined batch's row count is traced (the group
-                    # count) — pin the concrete live total so buffer
-                    # bookkeeping and spill slicing never sync on it
-                    sorted_batch = DeviceBatch(sorted_batch.columns,
-                                               live)
+                    comb_in_total += comb_in_h
                     comb_out_total += live
                     n_batches += 1
                 f_rows.add(live)
@@ -1456,20 +1469,8 @@ class RssShuffleExchangeOp(PhysicalOp):
                 # sample bounds from map 0's leading batches; all maps of
                 # this shuffle then share the same bounds (the reference
                 # samples once, driver-side)
-                from auron_tpu.parallel.partitioning import \
-                    compute_range_bounds
-                sampled = 0
-                for batch in batches:
-                    pending.append(batch)
-                    sampled += _profile.row_count(batch)
-                    if sampled >= _RANGE_SAMPLE_ROWS:
-                        break
-                bounds = compute_range_bounds(
-                    pending, list(partitioning.sort_orders), schema,
-                    partitioning.num_partitions)
-                partitioning = RangePartitioning(
-                    partitioning.sort_orders, partitioning.num_partitions,
-                    bounds)
+                partitioning, pending = _sample_range_bounds(
+                    batches, partitioning, schema)
                 self.partitioning = partitioning
             self._write_map(in_p, ctx, partitioning, pending, batches)
         self.service.commit_shuffle(self.shuffle_id, self.input_partitions)
@@ -1503,9 +1504,10 @@ class RssShuffleExchangeOp(PhysicalOp):
             map_ctx = ctx.child(partition_id=in_p,
                                 num_partitions=self.input_partitions)
             batches = self.child.execute(in_p, map_ctx)
-        row_offset = 0
         donate = yields_owned_batches(self.child) \
             and jax.default_backend() != "cpu"
+        split = _Split(partitioning, n_out, schema, schema, donate)
+        carries = split.carries()
         with trace.layer_span("exchange", "map_write", cat="shuffle",
                               name="rss.map_write",
                               shuffle=self.shuffle_id, map=in_p), \
@@ -1516,21 +1518,12 @@ class RssShuffleExchangeOp(PhysicalOp):
                 # aborts through the writer's context manager (no .part
                 # left behind) and the heartbeat shows write progress
                 ctx.checkpoint("rss.map_write")
-                n_in = _profile.row_count(batch) if donate else None
                 with timer(write_time) as t:
-                    if isinstance(partitioning, RoundRobinPartitioning):
-                        part = RoundRobinPartitioning(n_out, row_offset)
-                        pids = part.partition_ids(batch, schema)
-                    else:
-                        pids = partitioning.partition_ids(batch, schema)
-                    kern = _sort_by_pid_kernel(n_out, batch.capacity,
-                                               donate)
-                    sorted_batch, counts = t.track(kern(batch, pids))
-                row_offset += n_in if donate else _profile.row_count(batch)
-                counts_h = _profile.timed_get(counts)
+                    sorted_batch, counts_h, carries, _ = split(
+                        batch, in_p, carries, t)
                 offsets = np.concatenate(
                     [np.zeros(1, np.int64), np.cumsum(counts_h)])
-                n = _profile.row_count(sorted_batch)
+                n = int(offsets[-1])   # graft: disable=GL001 -- offsets is a host ndarray
                 with timer(write_time, bucket="serde"):
                     host = batch_to_host(sorted_batch, n)
                     for p in range(n_out):

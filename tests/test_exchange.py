@@ -313,3 +313,112 @@ def test_range_bounds_sampled_in_single_pass():
             out.extend(np.asarray(b.columns[0].data[:n]).tolist())
     assert sorted(out) == list(range(512))
     assert calls["n"] == 1, "child must execute exactly once"
+
+
+# -- the range split as a program (PR 41) ----------------------------------
+
+def _range_batches(seed=41, n=40, batches=3, long_at=()):
+    """Device batches of (nullable string, int, double) rows; the
+    batches at ``long_at`` hold strings of another width bucket."""
+    from auron_tpu.columnar.arrow_bridge import to_device
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(batches):
+        mask = rng.random(n) < 0.2
+        tail = "-a-longer-description" if i in long_at else ""
+        rb = pa.record_batch({
+            "s": pa.array([None if m else "it-%03d" % v + tail for v, m in
+                           zip(rng.integers(0, 25, n), mask)], pa.string()),
+            "x": pa.array(rng.integers(-100, 100, n), pa.int64()),
+            "d": pa.array(np.round(rng.normal(0, 9, n), 2), pa.float64()),
+        })
+        b, schema = to_device(rb, capacity=64)
+        out.append(b)
+    return out, schema
+
+
+_RANGE_ORDERS = (ir.SortOrder(C(0), False, False),
+                 ir.SortOrder(C(1), True, True),
+                 ir.SortOrder(C(2), False, True))
+
+
+def test_range_sample_picks_the_parents_bounds():
+    """The sample program's words, sorted and cut on the host as before:
+    bit for bit the bounds the eager sample chose on this seed (PR 40's
+    tree, same batches)."""
+    from auron_tpu.parallel.partitioning import (range_bounds,
+                                                 sample_range_words)
+    batches, schema = _range_batches()
+    samples = [sample_range_words(b, _RANGE_ORDERS, schema) for b in batches]
+    bounds, layout = range_bounds(samples, _RANGE_ORDERS, 4)
+    assert layout == (2, 2, 2)
+    assert bounds == (
+        (0, 10847995917421248511, 1, 9223372036854775852, 1,
+         13845022269457720934),
+        (0, 10847995917437960191, 1, 9223372036854775817, 1,
+         13841149173778182308),
+        (0, 10847995917438418943, 1, 9223372036854775741, 1,
+         13844082143035507343))
+
+
+def _reference_pids(words: np.ndarray, bounds) -> list:
+    """Plain lexicographic searchsorted (side right) over word tuples."""
+    return [sum(tuple(int(w) for w in row) >= tuple(b) for b in bounds)
+            for row in words]
+
+
+@pytest.mark.parametrize("case", ["plain", "deduplicated_bound",
+                                  "empty_batch", "null_keys",
+                                  "wider_batch", "narrower_batch"])
+def test_range_split_program_matches_lexicographic_searchsorted(case):
+    """The split program's partition ids — rows counted a partition, and
+    the rows each partition holds in their input order — equal a numpy
+    searchsorted over the same order words and bounds, row for row."""
+    from auron_tpu.columnar.arrow_bridge import to_arrow
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.ops.base import MetricsSet, timer
+    from auron_tpu.parallel.exchange import _Split
+    from auron_tpu.parallel.partitioning import (_align_words, range_bounds,
+                                                 sample_range_words)
+    long_at = {"wider_batch": (2,), "narrower_batch": (0, 1)}.get(case, ())
+    batches, schema = _range_batches(long_at=long_at)
+    sample, probe = batches[:2], batches[2]
+    samples = [sample_range_words(b, _RANGE_ORDERS, schema) for b in sample]
+    if case == "deduplicated_bound":
+        # a degenerate sample: one row, so that every bound is the same
+        samples = [(samples[0][0][:1], samples[0][1])]
+    bounds, layout = range_bounds(samples, _RANGE_ORDERS, 4)
+    assert len(bounds) == (1 if case == "deduplicated_bound" else 3)
+    if case == "empty_batch":
+        probe = DeviceBatch(probe.columns, 0)
+    part = RangePartitioning(_RANGE_ORDERS, 4, bounds, layout)
+
+    words, probe_layout = sample_range_words(probe, _RANGE_ORDERS, schema)
+    assert (probe_layout != layout) == (case in ("wider_batch",
+                                                 "narrower_batch"))
+    target = tuple(max(a, b) for a, b in zip(probe_layout, layout))
+    b_mat = _align_words(np.array(bounds, np.uint64), layout, target,
+                         _RANGE_ORDERS)
+    want = _reference_pids(
+        _align_words(words, probe_layout, target, _RANGE_ORDERS),
+        [tuple(int(x) for x in b) for b in b_mat])
+    if case == "null_keys":
+        assert probe.columns[0].validity[:len(want)].sum() < len(want)
+
+    # the traced ids themselves, bounds as an operand
+    mat, n_live = part.bounds_operand()
+    assert mat.shape[0] == 3 and n_live == len(bounds)
+    got = np.asarray(jax.jit(
+        lambda b, m, n: part.partition_ids(b, schema, (m, n)))(
+            probe, mat, n_live))[:len(want)]
+    assert got.tolist() == want
+
+    # and through the program every route splits with
+    split = _Split(part, 4, schema, schema, False)
+    with timer(MetricsSet(name="shuffle_exchange").counter("t")) as t:
+        sorted_batch, counts, _carries, _ = split(probe, 0, split.carries(),
+                                                  t)
+    assert counts.tolist() == [want.count(p) for p in range(4)]
+    rows = to_arrow(probe, schema).column("x").to_pylist()[:len(want)]
+    stable = [r for p in range(4) for r, w in zip(rows, want) if w == p]
+    assert to_arrow(sorted_batch, schema).column("x").to_pylist() == stable

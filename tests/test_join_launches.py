@@ -36,8 +36,9 @@ JOIN_TYPES = ("inner", "left", "right", "full", "semi", "anti", "existence")
 PROBE_BATCHES = 3
 
 
-def _join_launches(trace_dir) -> tuple:
-    """(eager, engine) launch names inside ``auron:op/hash_join``."""
+def _launches(trace_dir, spans=("auron:op/hash_join",)) -> tuple:
+    """(eager, engine) launch names whose innermost operator span is one
+    of ``spans``."""
     from jax.profiler import ProfileData
     (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
                         recursive=True)
@@ -54,14 +55,14 @@ def _join_launches(trace_dir) -> tuple:
                 if not name.startswith("PjitFunction("):
                     continue
                 over = [op for op in ops if op[0] <= s and e <= op[1]]
-                if not over or max(over)[2] != "auron:op/hash_join":
+                if not over or max(over)[2] not in spans:
                     continue
                 prog = name[len("PjitFunction("):-1]
                 (engine if prog.startswith("auron_") else eager).append(prog)
     return eager, engine
 
 
-def _traced(fn, trace_dir):
+def _traced(fn, trace_dir, spans=("auron:op/hash_join",)):
     import jax
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -71,7 +72,7 @@ def _traced(fn, trace_dir):
         out = fn()
     finally:
         jax.profiler.stop_trace()
-    return out, _join_launches(trace_dir)
+    return out, _launches(trace_dir, spans)
 
 
 def _join(join_type):

@@ -27,6 +27,11 @@ over 4 input splits, through ``AuronServer`` / ``AuronClient`` with
   count in the frame is those two, and the slices delivered are what the
   eager read delivered (16 / 44 a check / q65 stage);
 - a one-chip task's frame has none of them non-zero;
+- the stage's global sort runs as programs (PR 41): every map batch of
+  the range exchange and of the gather is split by ONE call of the split
+  program (the range bounds its operand) and sampled by one, a reducer's
+  batches are concatenated by one, and the stage reads fewer row counts
+  than PR 40's tree did; a one-partition task calls none of the three;
 - four stages at the gang door at once wait there, and answer right.
 """
 
@@ -58,6 +63,17 @@ WIDE_SLICES = dict.fromkeys(("q65sa", "q65sam"), 16) \
     | dict.fromkeys(("q65", "q65m"), 44)
 MESH_SPANS = ("exchange.gang_wait", "exchange.mesh_stack",
               "exchange.mesh_round")
+#: the programs of a stage's global sort (PR 41): the map side's split
+#: of an exchange left on ``device_buffer``, the range sample, and the
+#: concatenation of what a reducer collected
+SPLIT = "parallel.exchange.fused_split"
+RANGE_SAMPLE = "parallel.partitioning.range_sample"
+CONCAT = "ops.sort.concat"
+#: ``counts.row_syncs`` of each stage on PR 40's tree, this file's data
+#: (the eager split read a batch's row count twice, the range sample
+#: once more, the sort's concatenation once a collected batch)
+ROW_SYNCS_PR40 = {"q3": 80, "q42": 95, "q52": 90, "q55": 55, "q65": 276,
+                  "q65m": 244, "q65sa": 122, "q65sam": 106}
 SCALE = 0.2                  # 100,000 fact rows in the task: every plan answers
 SPLITS_PER_TASK = 4          # of the generator's 8 ``store_sales`` files
 
@@ -263,6 +279,65 @@ def test_mesh_stage_reads_its_exchanges_with_the_cut_program(plan, answers):
     _table, single = answers[plan]["single"]
     assert single["cost_ledger"]["counts"]["program_calls_by_site"][
         READ_CUT] == single["shuffle_exchange_read"]["output_batches"]
+
+
+@pytest.mark.parametrize("plan", PLANS)
+def test_mesh_stage_runs_its_global_sort_as_programs(plan, answers):
+    """The stage's tail — range exchange 4 → 4, a sort a partition, the
+    gather 4 → 1 — by site: one split call a map batch of the two
+    exchanges (and no ``sort_by_pid`` with eager partition ids), one
+    sample call a map batch of the range exchange, one sort a partition
+    and the limit's, and fewer row counts read than before."""
+    _table, done = answers[plan]["mesh"]
+    counts = done["cost_ledger"]["counts"]
+    sites = counts["program_calls_by_site"]
+    sampled = sites[RANGE_SAMPLE]
+    assert 1 <= sampled <= 4
+    # the range exchange's map batches, then the gather's (a partition
+    # the bounds left empty sends it nothing)
+    assert sampled + 1 <= sites[SPLIT] <= sampled + 4
+    assert "parallel.exchange.sort_by_pid" not in sites
+    assert sites["ops.sort.sort"] >= 2
+    if plan in WIDE:
+        # hundreds of rows from four maps: every partition collects
+        # more than one batch
+        assert sites[SPLIT] == 8 and sites[CONCAT] >= 4
+        assert sites["ops.sort.sort"] >= 4
+    assert counts["row_syncs"] < ROW_SYNCS_PR40[plan]
+    # the sample's readback is the one it always was: no readback added
+    assert counts["readbacks"] > 0
+
+
+def test_one_partition_task_calls_no_split_program(stage, tmp_path_factory):
+    """A one-chip cell's task — the benchmark's q3 and q65 over ONE
+    split, one partition — runs no exchange: no split, no range sample
+    and no read-cut call in its frame."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import cell, datagen
+
+    from auron_tpu.frontend.session import Session
+    root = str(tmp_path_factory.mktemp("tpcds_one_chip"))
+    arrow = datagen.generate(seed=2_147_483_659, scale=0.02)
+    splits = datagen.write_splits(root, "store_sales",
+                                  arrow["store_sales"], 16_384)
+    dims = {name: datagen.write_whole(root, name, arrow[name])
+            for name in arrow if name != "store_sales"}
+    session = Session()
+    try:
+        for plan in ("q3", "q65"):
+            task = cell.load_module("plans", plan).build(
+                session, dims, [splits[0][0]], 1).task_bytes(0)
+            table, done = stage.client().execute(task)
+            sites = done["cost_ledger"]["counts"]["program_calls_by_site"]
+            assert table.num_rows > 0 and sites["ops.sort.sort"] >= 1
+            assert not {SPLIT, RANGE_SAMPLE, READ_CUT} & set(sites), sites
+    finally:
+        session.close()
 
 
 @pytest.mark.parametrize("plan", STAR)

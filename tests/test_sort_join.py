@@ -428,3 +428,89 @@ def eager_rows():
 def test_join_rows_equal_the_eager_paths(join_type, kind, eager_rows):
     got = _plain_rows(collect(_identity_join(join_type, kind)))
     assert got == eager_rows[f"{join_type}.{kind}"]
+
+
+# -- the sort's concatenation as a program (PR 41) --------------------------
+
+def _concat_batches(n_batches: int, seed: int = 9):
+    """Device batches of differing capacity, live rows (one of them
+    none) and string width bucket, and the same rows as one table."""
+    from auron_tpu.columnar.arrow_bridge import to_device
+    from auron_tpu.columnar.batch import DeviceBatch
+    rng = np.random.default_rng(seed)
+    batches, tables = [], []
+    for i in range(n_batches):
+        n = int(rng.integers(3, 30))
+        mask = rng.random(n) < 0.2
+        tail = "-with-a-long-tail" * (i % 2)
+        rb = pa.record_batch({
+            "k": pa.array(rng.integers(0, 6, n), pa.int64()),
+            "s": pa.array([None if m else "s%02d" % v + tail for v, m in
+                           zip(rng.integers(0, 9, n), mask)], pa.string()),
+            "row": pa.array(np.arange(n) + 100 * i, pa.int64()),
+        })
+        b, schema = to_device(rb, capacity=32 if i % 3 else 64)
+        if i == 1:
+            b, rb = DeviceBatch(b.columns, 0), rb.slice(0, 0)
+        batches.append(b)
+        tables.append(rb)
+    return batches, schema, pa.Table.from_batches(tables)
+
+
+@pytest.mark.parametrize("n_batches", [2, 3, 4, 5])
+def test_concat_sort_equals_the_sort_of_the_host_concatenation(n_batches):
+    """``ops.sort.concat`` then ``ops.sort.sort`` over N collected
+    batches: the rows of their host concatenation in the stable order of
+    the keys (ties in input order), the row count their sum and still on
+    the device, every row past it padding of no validity."""
+    import jax
+    from auron_tpu.columnar.arrow_bridge import to_arrow
+    from auron_tpu.ops import sort as S
+    from auron_tpu.utils.shapes import bucket_rows
+    batches, schema, table = _concat_batches(n_batches)
+    orders = (ir.SortOrder(C(0), ascending=False, nulls_first=True),
+              ir.SortOrder(C(1), ascending=True, nulls_first=False))
+
+    merged = S._concat_all(batches)
+    assert isinstance(merged.num_rows, jax.Array)
+    assert int(merged.num_rows) == table.num_rows
+    assert merged.capacity == bucket_rows(sum(b.capacity for b in batches))
+    assert to_arrow(merged, schema).to_pylist() == table.to_pylist()
+    for leaf in (c.validity for c in merged.columns):
+        assert not np.asarray(leaf)[table.num_rows:].any()
+
+    out = S._sort_kernel(orders, schema, merged.capacity, False)(merged)
+    assert int(out.num_rows) == table.num_rows
+    for leaf in (c.validity for c in out.columns):
+        assert not np.asarray(leaf)[table.num_rows:].any()
+    null_last = table["s"].is_null().cast(pa.int8())
+    idx = pa.compute.sort_indices(
+        table.append_column("s_null", null_last),
+        sort_keys=[("k", "descending"), ("s_null", "ascending"),
+                   ("s", "ascending")])
+    assert to_arrow(out, schema).column("row").to_pylist() \
+        == table.take(idx).column("row").to_pylist()
+
+
+def test_concat_program_is_keyed_by_shapes_not_row_counts():
+    """Other row counts in the same capacities and widths build no new
+    program, and the arity is rounded up with zero-row views where that
+    leaves the capacity bucket as it is."""
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.ops import sort as S
+    batches, _schema, _table = _concat_batches(3)
+    S._concat_all(batches)
+    before = S._concat_kernel.cache.stats()
+    other = [DeviceBatch(b.columns, n) for b, n in zip(batches, (1, 2, 0))]
+    merged = S._concat_all(other)
+    after = S._concat_kernel.cache.stats()
+    assert int(merged.num_rows) == 3
+    assert after["builds"] == before["builds"]
+    assert after["hits"] == before["hits"] + 1
+    assert merged.capacity == 128      # 64 + 32 + 32: no room for a view
+    same = [batches[1]] * 3             # 3 x 32 -> 4 x 32, one program
+    S._concat_all(same)
+    S._concat_all(same + same[:1])
+    last = S._concat_kernel.cache.stats()
+    assert (last["builds"], last["hits"]) \
+        == (after["builds"] + 1, after["hits"] + 1)

@@ -95,3 +95,106 @@ def test_the_exchange_read_cut_compiles_for_the_chip(one_chip, layout,
     compiled = cut.lower(_columns(one_chip, 4 * quota, layout),
                          bounds).compile()
     assert compiled is not None
+
+
+#: the keys a stage's ``ORDER BY`` sorts on: q65's (store name, item
+#: description, the double revenue, a decimal held as one int64 word)
+#: and a star join's (a year, the decimal sum descending, a brand id)
+_SORT_KEYS = {
+    "q65": ((16, 64, "float64", "int64"),
+            ((0, True), (1, True), (2, True), (3, False))),
+    "star_join": (("int32", "int64", "int32", 64),
+                  ((0, True), (1, False), (2, True))),
+}
+
+
+def _schema_and_orders(key):
+    from auron_tpu.columnar.schema import DataType, Field, Schema
+    from auron_tpu.exprs import ir
+    layout, keys = _SORT_KEYS[key]
+    types = {"int32": DataType.INT32, "int64": DataType.INT64,
+             "float64": DataType.FLOAT64}
+    schema = Schema(tuple(
+        Field(f"c{i}", DataType.STRING if isinstance(k, int) else types[k])
+        for i, k in enumerate(layout)))
+    orders = tuple(ir.SortOrder(ir.ColumnRef(i), asc, True)
+                   for i, asc in keys)
+    return layout, schema, orders
+
+
+@pytest.fixture
+def split_double_word(monkeypatch):
+    """This process's backend is the CPU, so ``f64_order_word`` would
+    hand the chip's compiler the IEEE bitcast it refuses; on the chip
+    the fork takes the float32 pair."""
+    from auron_tpu.ops import sort
+    monkeypatch.setattr(sort, "f64_order_word", sort.f64_split_order_word)
+
+
+@pytest.mark.parametrize("key", list(_SORT_KEYS))
+def test_the_range_split_compiles_for_the_chip(one_chip, key,
+                                               split_double_word):
+    """The map side's split of a range exchange as ONE program (PR 41):
+    the rows' order words compared with the bounds, an operand of
+    ``uint64[3, W]`` whose string keys were sampled in a narrower width
+    bucket than this batch's, and the sort by partition id."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.parallel import exchange
+    layout, schema, orders = _schema_and_orders(key)
+    rows = 2048
+    # a bound's words a key: the null word and one word a value, two
+    # for a string sampled at 16 bytes
+    bound_layout = tuple(3 if isinstance(layout[o.expr.index], int) else 2
+                         for o in orders)
+    kern, _built = exchange._fused_split_program(
+        (), ("range", orders, bound_layout), schema, schema, 4, rows,
+        False, [], (), None, None)
+
+    def leaf(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    batch = DeviceBatch(_columns(one_chip, rows, layout),
+                        leaf((), jnp.int32))
+    bounds = (leaf((3, sum(bound_layout)), jnp.uint64), leaf((), jnp.int32))
+    compiled = kern.lower(batch, leaf((), jnp.int32), leaf((1,), jnp.int64),
+                          bounds).compile()
+    assert compiled is not None
+
+
+@pytest.mark.parametrize("key", list(_SORT_KEYS))
+def test_the_range_sample_compiles_for_the_chip(one_chip, key,
+                                                split_double_word):
+    """The sample's word matrix of one batch as ONE program (PR 41)."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.parallel import partitioning
+    layout, schema, orders = _schema_and_orders(key)
+    rows = 2048
+    kern = partitioning._range_sample_kernel(orders, schema, rows)
+    batch = DeviceBatch(
+        _columns(one_chip, rows, layout),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    assert kern.lower(batch).compile() is not None
+
+
+def test_the_sorts_concatenation_compiles_for_the_chip(one_chip):
+    """A reducer's four slices of a range exchange into one batch as ONE
+    program (PR 41): q65's row, a double among its payload, two of the
+    slices in another string width bucket."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.ops import sort
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    batches = tuple(
+        DeviceBatch(_columns(one_chip, 256, (16, width, "float64", "int64")),
+                    count)
+        for width in (64, 128, 64, 128))
+    widths = tuple(tuple(leaf.shape[1:] for leaf in
+                         jax.tree_util.tree_leaves(b.columns))
+                   for b in batches)
+    kern = sort._concat_kernel((256,) * 4, widths)
+    assert kern.lower(batches).compile() is not None
