@@ -344,3 +344,110 @@ def to_float64(h, l):
                      al.astype(jnp.float64))
     mag = ah.astype(jnp.float64) * (2.0 ** 64) + lo_u
     return jnp.where(neg, -mag, mag)
+
+
+# ---------------------------------------------------------------------------
+# decimal / decimal (Spark's Divide): the exact quotient, HALF_UP
+# ---------------------------------------------------------------------------
+
+def _uge128(ah, al, bh, bl):
+    """Unsigned a >= b over (hi, lo) bit patterns."""
+    return _ult(bh, ah) | ((ah == bh) & ~_ult(al, bl))
+
+
+def _shl_limbs(limbs, s: int):
+    """Limbs (most significant first, int64 bit patterns) shifted left by
+    the STATIC ``s`` bits within their own width."""
+    n = len(limbs)
+    words, bits = divmod(s, 64)
+    zero = jnp.zeros_like(limbs[0])
+    out = []
+    for i in range(n):
+        hi = limbs[i + words] if i + words < n else zero
+        if bits == 0:
+            out.append(hi)
+            continue
+        lo = limbs[i + words + 1] if i + words + 1 < n else zero
+        out.append((hi << bits)
+                   | ((lo >> (64 - bits)) & jnp.int64((1 << bits) - 1)))
+    return out
+
+
+def mul_pow10_u256(h, l, k: int):
+    """Unsigned (h, l) * 10^k as four int64 limbs, most significant
+    first: the dividend of a decimal division scaled to the quotient's
+    scale, where 128 bits do not hold it."""
+    ph, pl = _pow10_limbs(k)
+    ph, pl = jnp.int64(ph), jnp.int64(pl)
+    c0h, c0l = _mul_u64(l, pl)
+    c1h, c1l = _mul_u64(l, ph)
+    c2h, c2l = _mul_u64(h, pl)
+    c3h, c3l = _mul_u64(h, ph)
+
+    def add3(a, b, c, carry_in):
+        s1 = a + b
+        k1 = _ult(s1, a).astype(I64)
+        s2 = s1 + c
+        k2 = _ult(s2, s1).astype(I64)
+        s3 = s2 + carry_in
+        k3 = _ult(s3, s2).astype(I64)
+        return s3, k1 + k2 + k3
+
+    zero = jnp.zeros_like(l)
+    n1, carry = add3(c0h, c1l, c2l, zero)
+    n2, carry = add3(c1h, c2h, c3l, carry)
+    n3 = c3h + carry
+    return [n3, n2, n1, c0l]
+
+
+def div_scaled_half_up(ah, al, k: int, bh, bl, num_digits: int):
+    """round_half_up(a * 10^k / b) for UNSIGNED magnitudes with
+    a * 10^k < 10^``num_digits`` and b != 0: the exact quotient, one
+    rounding.
+
+    A restoring shift-and-subtract division, one quotient bit an
+    iteration: the chip has no 64-bit integer unit and no wide divide, a
+    compare, a subtract and two shifts on limb pairs it has, and every
+    row runs the same ``bits(10^num_digits)`` iterations (a static count
+    from the operand TYPES, 123 for decimal(17,2) / decimal(17,2)). The
+    dividend sits left-aligned in a register of two limbs, or four where
+    a * 10^k passes 128 bits; the quotient's bits enter at its low end
+    as the dividend's leave at the top, and the remainder stays under
+    b <= 10^38 < 2^127, so doubling it never leaves 128 unsigned bits.
+
+    Returns (qh, ql, fits): ``fits`` False where the rounded quotient
+    passes 2^127 - 1 and so cannot be a decimal(38)."""
+    nbits = (10 ** num_digits).bit_length()
+    if nbits <= 128:
+        reg = list(mul_pow10(ah, al, k))
+    else:
+        reg = mul_pow10_u256(ah, al, k)
+    width = 64 * len(reg)
+    assert nbits <= width, (num_digits, k)
+    reg = _shl_limbs(reg, width - nbits)
+    one = jnp.int64(1)
+
+    def step(_i, state):
+        *n, rh, rl = state
+        top = (n[0] < 0).astype(I64)
+        rh = (rh << 1) | ((rl >> 63) & one)
+        rl = (rl << 1) | top
+        ge = _uge128(rh, rl, bh, bl)
+        sh, sl = sub128(rh, rl, bh, bl)
+        rh = jnp.where(ge, sh, rh)
+        rl = jnp.where(ge, sl, rl)
+        n = _shl_limbs(n, 1)
+        n[-1] = n[-1] | ge.astype(I64)
+        return (*n, rh, rl)
+
+    zero = jnp.zeros_like(al)
+    *q, rh, rl = jax.lax.fori_loop(0, nbits, step, (*reg, zero, zero))
+    qh, ql = q[-2], q[-1]
+    fits = qh >= 0
+    for limb in q[:-2]:
+        fits = fits & (limb == 0)
+    # HALF_UP on magnitudes: 2r >= b, written r >= b - r (r < b)
+    dh, dl = sub128(bh, bl, rh, rl)
+    bump = _uge128(rh, rl, dh, dl).astype(I64)
+    qh, ql = add128(qh, ql, zero, bump)
+    return qh, ql, fits & (qh >= 0)

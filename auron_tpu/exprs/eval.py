@@ -143,6 +143,15 @@ def decimal_result_type(op: str, lp: int, ls: int, rp: int,
     if op == "*":
         p, s = lp + rp + 1, ls + rs
         full_s = ls + rs
+    elif op == "/":
+        # Spark's Divide: the quotient is computed AT the adjusted scale
+        # (one HALF_UP rounding of the exact quotient), so full_s is it
+        s = max(6, ls + rp + 1)
+        p = lp - ls + rs + s
+        if p > 38:
+            s = max(38 - (p - s), min(s, 6))
+            p = 38
+        return p, s, s
     else:   # + - and comparisons share add/sub typing
         s = max(ls, rs)
         p = max(lp - ls, rp - rs) + s + 1
@@ -153,6 +162,21 @@ def decimal_result_type(op: str, lp: int, ls: int, rp: int,
     min_scale = min(s, 6)
     adj_s = max(38 - digits_int, min_scale)
     return 38, adj_s, full_s
+
+
+def decimal_divides(exprs, schema: Schema) -> int:
+    """How many decimal / decimal nodes these expression trees hold: an
+    operator's factor of ``counts.decimal_div_rows``."""
+    n = 0
+    stack = list(exprs)
+    while stack:
+        e = stack.pop()
+        stack.extend(e.children())
+        if isinstance(e, ir.BinaryExpr) and e.op == "/" \
+                and infer_dtype(e.left, schema)[0] == DataType.DECIMAL \
+                and infer_dtype(e.right, schema)[0] == DataType.DECIMAL:
+            n += 1
+    return n
 
 
 def common_type(a: DataType, b: DataType) -> DataType:
@@ -262,6 +286,12 @@ def _evaluate(expr: ir.Expr, batch: DeviceBatch, schema: Schema,
 
     if isinstance(expr, ir.Negative):
         v = evaluate(expr.child, batch, schema, ctx)
+        from auron_tpu.columnar import decimal128 as D
+        if isinstance(v.col, D.Decimal128Column):
+            return TypedValue(
+                D.Decimal128Column(*D.neg128(v.col.hi, v.col.lo),
+                                   v.validity),
+                v.dtype, v.precision, v.scale)
         return TypedValue(PrimitiveColumn(-v.data, v.validity),
                           v.dtype, v.precision, v.scale)
 
@@ -365,8 +395,6 @@ def infer_dtype(expr: ir.Expr, schema: Schema) -> tuple[DataType, int, int]:
         if lt == DataType.DECIMAL and rt == DataType.DECIMAL:
             # Spark decimal result types (precision 19..38 runs on the
             # two-limb kernels, columnar/decimal128.py)
-            if expr.op == "/":
-                return DataType.FLOAT64, 0, 0
             p, s, _fs = decimal_result_type(expr.op, lp, ls, rp, rs)
             return DataType.DECIMAL, p, s
         out = common_type(lt, rt)
@@ -551,6 +579,8 @@ def _eval_decimal_binary(op, l: TypedValue, r: TypedValue, cap: int) -> TypedVal
     if r.dtype != DataType.DECIMAL:
         r = TypedValue(PrimitiveColumn(r.data.astype(jnp.int64), r.validity),
                        DataType.DECIMAL, 18, 0) if not r.dtype.is_floating else r
+    if op == "/" and l.dtype == r.dtype == DataType.DECIMAL:
+        return _eval_decimal_divide(l, r)
     if l.dtype.is_floating or r.dtype.is_floating or op == "/":
         lf = _decimal_to_f64(l)
         rf = _decimal_to_f64(r)
@@ -682,6 +712,39 @@ def _eval_decimal128_binary(op, l: TypedValue, r: TypedValue, rp: int,
     return _mk_decimal(oh, ol, validity & ok, rp, rs)
 
 
+def _eval_decimal_divide(l: TypedValue, r: TypedValue) -> TypedValue:
+    """decimal / decimal as Spark's Divide types and rounds it: the
+    result type of ``decimal_result_type("/")``, the exact quotient
+    rounded HALF_UP once at that scale, null on a zero divisor and where
+    the quotient passes the result precision. (Spark itself rounds twice,
+    at scale 38 or 39 by version and then at the result scale; the two
+    agree unless the unscaled divisor passes 10^(38 - scale - the
+    dividend's scale): 10^16 for decimal(17,2) / decimal(17,2).) An
+    operand past its declared precision is null, so the static iteration
+    count of the division covers every row it divides."""
+    from auron_tpu.columnar import decimal128 as D
+    lp = l.precision or (38 if isinstance(l.col, D.Decimal128Column) else 18)
+    rp_ = r.precision or (38 if isinstance(r.col, D.Decimal128Column) else 18)
+    p, s, _fs = decimal_result_type("/", lp, l.scale, rp_, r.scale)
+    k = s - l.scale + r.scale
+    lh, ll_ = _limbs_of(l)
+    rh, rl = _limbs_of(r)
+    ok = (l.validity & r.validity & ~((rh == 0) & (rl == 0))
+          & D.fits_precision(lh, ll_, lp) & D.fits_precision(rh, rl, rp_))
+    neg = D.is_negative(lh, ll_) ^ D.is_negative(rh, rl)
+    ah, al = D.abs128(lh, ll_)
+    bh, bl = D.abs128(rh, rl)
+    # a null row's limbs are whatever the producer left there: divide
+    # by one, the loop is total
+    bh = jnp.where(ok, bh, 0)
+    bl = jnp.where(ok, bl, 1)
+    qh, ql, fits = D.div_scaled_half_up(ah, al, k, bh, bl, lp + k)
+    ok = ok & fits & D.fits_precision(qh, ql, p)
+    nh, nl = D.neg128(qh, ql)
+    return _mk_decimal(jnp.where(neg, nh, qh), jnp.where(neg, nl, ql),
+                       ok, p, s)
+
+
 def _decimal_to_f64(v: TypedValue) -> TypedValue:
     from auron_tpu.columnar import decimal128 as D
     if isinstance(v.col, D.Decimal128Column):
@@ -715,21 +778,19 @@ def _eval_binary_simple(op, l: TypedValue, r: TypedValue) -> TypedValue:
 def _eval_case(expr: ir.CaseWhen, batch, schema, ctx) -> TypedValue:
     branches = [(evaluate(w, batch, schema, ctx), evaluate(t, batch, schema, ctx))
                 for w, t in expr.when_then]
+    from auron_tpu.columnar.decimal128 import Decimal128Column
     if expr.otherwise is not None:
         otherwise = evaluate(expr.otherwise, batch, schema, ctx)
     else:
         t0 = branches[0][1]
-        if isinstance(t0.col, StringColumn):
-            otherwise = TypedValue(
-                StringColumn(jnp.zeros_like(t0.col.chars),
-                             jnp.zeros_like(t0.col.lens),
-                             jnp.zeros(batch.capacity, bool)),
-                t0.dtype, t0.precision, t0.scale)
-        else:
-            otherwise = TypedValue(
-                PrimitiveColumn(jnp.zeros_like(t0.data),
-                                jnp.zeros(batch.capacity, bool)),
-                t0.dtype, t0.precision, t0.scale)
+        # no ELSE: all null, in the first branch's own column class
+        null_col = jax.tree_util.tree_map(jnp.zeros_like, t0.col)
+        otherwise = TypedValue(null_col, t0.dtype, t0.precision, t0.scale)
+
+    def limbs(v: TypedValue) -> Decimal128Column:
+        if isinstance(v.col, Decimal128Column):
+            return v.col
+        return Decimal128Column(*_limbs_of(v), v.validity)
 
     result = otherwise
     for cond, val in reversed(branches):
@@ -742,6 +803,16 @@ def _eval_case(expr: ir.CaseWhen, batch, schema, ctx) -> TypedValue:
                 jnp.where(take[:, None], vc.chars, rc.chars),
                 jnp.where(take, vc.lens, rc.lens),
                 jnp.where(take, vc.validity, rc.validity))
+        elif isinstance(val.col, Decimal128Column) \
+                or isinstance(result.col, Decimal128Column):
+            # a two-limb branch beside a one-word one (or a NULL
+            # literal's): both as limbs, the wider type the result's
+            vc, rc = limbs(val), limbs(result)
+            col = Decimal128Column(jnp.where(take, vc.hi, rc.hi),
+                                   jnp.where(take, vc.lo, rc.lo),
+                                   jnp.where(take, vc.validity, rc.validity))
+            if not isinstance(val.col, Decimal128Column):
+                val = result
         else:
             col = PrimitiveColumn(
                 jnp.where(take, val.data, result.data),

@@ -173,6 +173,11 @@ def _datediff(args, expr, batch, schema, ctx):
 @register("abs")
 def _abs(args, expr, batch, schema, ctx):
     v = args[0]
+    from auron_tpu.columnar import decimal128 as D
+    if isinstance(v.col, D.Decimal128Column):
+        return TypedValue(
+            D.Decimal128Column(*D.abs128(v.col.hi, v.col.lo), v.validity),
+            v.dtype, v.precision, v.scale)
     return TypedValue(PrimitiveColumn(jnp.abs(v.data), v.validity),
                       v.dtype, v.precision, v.scale)
 

@@ -478,7 +478,9 @@ _q("q12", "web revenue ratio within class")(_channel_ratio(
 _q("q20", "catalog revenue ratio within class")(_channel_ratio(
     "catalog_sales", "cs_sold_date_sk", "cs_item_sk",
     "cs_ext_sales_price", "q20"))
-_q("q98", "store revenue ratio within class")(_channel_ratio(
+_q("q98", "store revenue ratio within class; a simplified form (double "
+   "ratio, limit 100, a fixed day range): the real text, decimal(38,17), is "
+   "benchmark/plans/q98.py")(_channel_ratio(
     "store_sales", "ss_sold_date_sk", "ss_item_sk", "ss_ext_sales_price",
     "q98"))
 
@@ -1602,7 +1604,9 @@ def _q36_oracle(a):
                      ("i_class", "ascending")])
 
 
-_q("q36", "gross margin ROLLUP with grouping()-derived hierarchy level")(
+_q("q36", "gross margin ROLLUP with grouping()-derived hierarchy level; a "
+   "simplified form (money cast to double, no store filter, no rank): the "
+   "real text, decimal(37,20), is benchmark/plans/q36.py")(
     (_q36_run, _q36_oracle))
 
 
@@ -3030,7 +3034,9 @@ def _q53_oracle(a):
                                 preserve_index=False)
 
 
-_q("q53", "manufacturer quarterly sales vs yearly average (window)")(
+_q("q53", "manufacturer quarterly sales vs yearly average (window); a "
+   "simplified form (double money times quantity, one item arm): the real "
+   "text, decimal(21,6) / (38,16), is benchmark/plans/q53.py")(
     (_q53_run, _q53_oracle))
 
 
@@ -3179,7 +3185,9 @@ def _q59_oracle(a):
                                 preserve_index=False)
 
 
-_q("q59", "weekly store sales year-over-year by day of week")(
+_q("q59", "weekly store sales year-over-year by day of week; a simplified "
+   "form (double money, four days, week ranges, no store or date_dim "
+   "join): the real text, decimal(37,20), is benchmark/plans/q59.py")(
     (_q59_run, _q59_oracle))
 
 

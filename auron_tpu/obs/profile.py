@@ -319,6 +319,14 @@ def row_count(batch) -> int:
     return int(_get(n, "row_syncs"))
 
 
+def row_count_and(batch, vector):
+    """``row_count(batch)`` and the host values of ``vector`` (a small
+    integer array the program that made the batch also returned) in the
+    ONE read the row count costs: (rows, [values])."""
+    n, values = _get((batch.num_rows, vector), "row_syncs")
+    return int(n), values.tolist()
+
+
 # ---------------------------------------------------------------------------
 # aggregate views
 # ---------------------------------------------------------------------------

@@ -516,7 +516,14 @@ COUNT_KEYS = ("program_calls", "readbacks",
               "agg_demoted_to_sort",
               # reads of a shared subplan's result that found it held,
               # the producer having run for another parent (ops/reuse.py)
-              "subplan_reuse_hits")
+              "subplan_reuse_hits",
+              # the ratio reports' operators (PR 42), each counted from a
+              # row count the host reads anyway: rows into a window
+              # program and the partitions it found there, rows that
+              # left a program an expand ran in, and rows that left one
+              # that divided decimal by decimal, once a division
+              "window_rows", "window_partitions", "expand_rows_out",
+              "decimal_div_rows")
 
 _ANNOTATION = None
 

@@ -407,8 +407,15 @@ def yields_owned_batches(op: PhysicalOp) -> bool:
     return bool(owned)
 
 
-def count_output(stream, metrics: MetricsSet, timed: bool = False):
+def count_output(stream, metrics: MetricsSet, timed: bool = False,
+                 also: tuple = ()):
     """Wrap a batch stream with output_rows/output_batches counting.
+
+    ``also`` ((count key, factor), ...) are counts of the task's ledger
+    (``obs/trace.COUNT_KEYS``) that grow by ``factor`` x the rows of
+    every batch that leaves — the rows an expand put out, the rows a
+    decimal division ran for — from the ONE read of the row count this
+    wrapper makes anyway.
 
     ``timed=True`` additionally accrues the time spent INSIDE the
     producer's ``next()`` into ``elapsed_compute`` — the inclusive
@@ -436,8 +443,11 @@ def count_output(stream, metrics: MetricsSet, timed: bool = False):
             if elapsed is not None:
                 elapsed.add(time.perf_counter_ns() - t0)
             if b is not None:
-                rows.add(_profile.row_count(b))
+                n = _profile.row_count(b)
+                rows.add(n)
                 batches.add(1)
+                for key, factor in also:
+                    _trace.count(key, factor * n)
         if b is None:
             return
         yield b

@@ -71,7 +71,8 @@ class ExpandOp(PhysicalOp):
                 carry + jnp.asarray(batch.num_rows, jnp.int64)
 
         return KernelFragment(key=("expand", projections, in_schema),
-                              apply=apply, fanout=len(projections))
+                              apply=apply, fanout=len(projections),
+                              row_counts=(("expand_rows_out", 1),))
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator:
         metrics = ctx.metrics_for(self)
@@ -90,7 +91,8 @@ class ExpandOp(PhysicalOp):
                     yield out
                 row_off += _profile.row_count(batch)
 
-        return count_output(stream(), metrics)
+        return count_output(stream(), metrics,
+                            also=(("expand_rows_out", 1),))
 
     def __repr__(self):
         return f"ExpandOp[{len(self.projections)} projections]"

@@ -58,7 +58,10 @@ class KernelFragment:
     member. ``fanout`` is the number of output batches per input batch
     (ExpandOp > 1). ``init_carry`` seeds the member's carry slot at
     stream start; ``is_limit`` marks a carry that counts a remaining-row
-    budget the host must poll for early exit.
+    budget the host must poll for early exit. ``row_counts`` are the
+    member's ((count key, factor), ...) of ``ops/base.count_output``'s
+    ``also``: counts of the task's ledger that grow with the rows that
+    leave the program the member runs in.
     """
 
     key: tuple
@@ -66,6 +69,7 @@ class KernelFragment:
     fanout: int = 1
     init_carry: int = 0
     is_limit: bool = False
+    row_counts: tuple = ()
 
 
 #: the one compile site for fused stage programs, keyed on
@@ -193,6 +197,18 @@ class FusedStageOp(PhysicalOp):
         assert all(f is not None for f in fragments)
         return fragments, tuple(f.key for f in fragments)
 
+    def row_counts(self, fragments) -> tuple:
+        """The members' row counts, summed by key: what this stage's
+        output is counted as besides ``output_rows``. (A member's rows
+        are counted as they leave the PROGRAM: a division under a later
+        filter counts the survivors, one under a later expand each of
+        its copies.)"""
+        out: dict = {}
+        for frag in fragments:
+            for key, factor in frag.row_counts:
+                out[key] = out.get(key, 0) + factor
+        return tuple(out.items())
+
     def has_limit(self) -> bool:
         from auron_tpu.ops.limit import LimitOp
         return any(isinstance(m, LimitOp) for m in self.members)
@@ -257,7 +273,7 @@ class FusedStageOp(PhysicalOp):
                     self.input.execute(partition, ctx,
                                        _consumer=(self, fragments,
                                                   frag_keys)),
-                    metrics)
+                    metrics, also=self.row_counts(fragments))
         elapsed = metrics.counter("elapsed_compute")
         kmetrics = ctx.metrics_for("kernels")
         built_c = kmetrics.counter("fused_stage_programs_built")
@@ -300,7 +316,8 @@ class FusedStageOp(PhysicalOp):
                 if limit_slots and any(int(b) <= 0 for b in budgets):
                     break
 
-        return count_output(stream(), metrics)
+        return count_output(stream(), metrics,
+                            also=self.row_counts(fragments))
 
     def __repr__(self):
         inner = " -> ".join(repr(m) for m in self.members)

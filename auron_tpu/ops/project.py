@@ -17,11 +17,20 @@ import jax.numpy as jnp
 from auron_tpu.columnar.batch import DeviceBatch, compact
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
-from auron_tpu.exprs.eval import (EvalContext, evaluate, infer_dtype,
-                                  infer_field)
+from auron_tpu.exprs.eval import (EvalContext, decimal_divides, evaluate,
+                                  infer_dtype, infer_field)
 from auron_tpu.obs import profile as _profile
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.runtime.programs import program_cache
+
+
+def division_counts(exprs: tuple, in_schema: Schema) -> tuple:
+    """``count_output``'s ``also`` of an operator that evaluates
+    ``exprs``: the rows that leave it count once a decimal / decimal it
+    holds (a projection's input rows; under a filter in the same program
+    the survivors, a lower bound)."""
+    n = decimal_divides(exprs, in_schema)
+    return (("decimal_div_rows", n),) if n else ()
 
 
 def project_schema(exprs: tuple, names: tuple[str, ...], in_schema: Schema) -> Schema:
@@ -111,8 +120,9 @@ class ProjectOp(PhysicalOp):
             out = DeviceBatch(cols, batch.num_rows)
             return (out,), carry + jnp.asarray(batch.num_rows, jnp.int64)
 
-        return KernelFragment(key=("project", exprs, in_schema),
-                              apply=apply)
+        return KernelFragment(
+            key=("project", exprs, in_schema), apply=apply,
+            row_counts=division_counts(exprs, in_schema))
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
         metrics = ctx.metrics_for(self)
@@ -129,7 +139,8 @@ class ProjectOp(PhysicalOp):
                 row_off += _profile.row_count(batch)
                 yield out
 
-        return count_output(stream(), metrics)
+        return count_output(stream(), metrics,
+                            also=division_counts(self.exprs, in_schema))
 
     def __repr__(self):
         return f"ProjectOp[{', '.join(self.names)}]"
@@ -165,8 +176,9 @@ class FilterOp(PhysicalOp):
             out = compact(batch, keep)
             return (out,), carry + jnp.asarray(batch.num_rows, jnp.int64)
 
-        return KernelFragment(key=("filter", predicates, in_schema),
-                              apply=apply)
+        return KernelFragment(
+            key=("filter", predicates, in_schema), apply=apply,
+            row_counts=division_counts(predicates, in_schema))
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
         metrics = ctx.metrics_for(self)
@@ -183,7 +195,9 @@ class FilterOp(PhysicalOp):
                 row_off += _profile.row_count(batch)
                 yield out
 
-        return count_output(stream(), metrics)
+        return count_output(
+            stream(), metrics,
+            also=division_counts(self.predicates, in_schema))
 
     def __repr__(self):
         return f"FilterOp[{len(self.predicates)} predicates]"
@@ -234,7 +248,8 @@ class FilterProjectOp(PhysicalOp):
 
         return KernelFragment(
             key=("filter_project", predicates, exprs, in_schema),
-            apply=apply)
+            apply=apply,
+            row_counts=division_counts(predicates + exprs, in_schema))
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
         metrics = ctx.metrics_for(self)
@@ -252,7 +267,9 @@ class FilterProjectOp(PhysicalOp):
                 row_off += _profile.row_count(batch)
                 yield out
 
-        return count_output(stream(), metrics)
+        return count_output(
+            stream(), metrics,
+            also=division_counts(self.predicates + self.exprs, in_schema))
 
     def __repr__(self):
         return f"FilterProjectOp[{len(self.predicates)} predicates -> {', '.join(self.names)}]"

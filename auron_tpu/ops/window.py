@@ -35,6 +35,8 @@ from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import (EvalContext, TypedValue, evaluate,
                                   infer_dtype)
+from auron_tpu.obs import profile as _profile
+from auron_tpu.obs import trace as _trace
 from auron_tpu.ops.base import ExecContext, PhysicalOp, count_output, timer
 from auron_tpu.ops.sort import _concat_all, sort_permutation
 from auron_tpu.runtime.programs import program_cache
@@ -542,7 +544,10 @@ def _window_kernel(partition_exprs: tuple, order_by: tuple, fn_specs: tuple,
             from auron_tpu.columnar.batch import compact
             keep = (rank <= group_limit) & live
             result = compact(result, keep)
-        return result
+        # (rows in, partitions found): read with the output's row count
+        stats = jnp.stack([jnp.asarray(n, jnp.int32),
+                           jnp.sum(seg_new & live, dtype=jnp.int32)])
+        return result, stats
 
     return auron_ops_window_window
 
@@ -592,8 +597,14 @@ class WindowOp(PhysicalOp):
                 kern = _window_kernel(self.partition_by, self.order_by,
                                       self.functions, in_schema,
                                       merged.capacity, self.group_limit)
-                out = t.track(kern(merged))
-            yield out
+                out, stats = t.track(kern(merged))
+                # the one read of this operator: its output's row count,
+                # and with it what the program saw (``count_output``
+                # would read the count alone)
+                n_out, (n_in, n_parts) = _profile.row_count_and(out, stats)
+                _trace.count("window_rows", n_in)
+                _trace.count("window_partitions", n_parts)
+            yield DeviceBatch(out.columns, n_out)
 
         return count_output(stream(), metrics)
 

@@ -401,16 +401,17 @@ class TestDecimalArithVectors:
                        None]
         ASSERTIONS["n"] += 4
 
-    def test_div_returns_double(self):
-        rb = {"a": pa.array([D("1.00"), D("7.00"), None],
-                            pa.decimal128(10, 2)),
-              "b": pa.array([D("3.00"), D("2.00"), D("1.00")],
-                            pa.decimal128(10, 2))}
+    def test_div_is_sparks_decimal_divide(self):
+        """decimal(10,2) / decimal(10,2) = decimal(23,13), HALF_UP; a
+        double until PR 42 (16 of these digits)."""
+        rb = {"a": pa.array([D("1.00"), D("7.00"), None, D("-2.00"),
+                             D("5.00")], pa.decimal128(10, 2)),
+              "b": pa.array([D("3.00"), D("2.00"), D("1.00"), D("3.00"),
+                             D("0.00")], pa.decimal128(10, 2))}
         got = _run_expr(ir.BinaryExpr("/", C(0), C(1)), rb)
-        assert got[0] == pytest.approx(1 / 3)
-        assert got[1] == pytest.approx(3.5)
-        assert got[2] is None
-        ASSERTIONS["n"] += 3
+        assert got == [D("0.3333333333333"), D("3.5000000000000"), None,
+                       D("-0.6666666666667"), None]
+        ASSERTIONS["n"] += 5
 
 
 # ---------------------------------------------------------------------------

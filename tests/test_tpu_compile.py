@@ -57,21 +57,28 @@ def test_a_double_sort_key_compiles_for_the_chip(one_chip):
 
 def _columns(one_chip, rows, layout):
     """Shapes of a column tree on the described chip: ``layout`` names a
-    dtype a primitive column, or a string's width."""
+    dtype a primitive column, a string's width, or ``decimal128`` (the
+    two int64 limbs of a decimal past 18 digits)."""
     import jax
     import jax.numpy as jnp
     from auron_tpu.columnar.batch import PrimitiveColumn, StringColumn
+    from auron_tpu.columnar.decimal128 import Decimal128Column
 
     def leaf(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     valid = leaf((rows,), jnp.bool_)
-    return tuple(
-        StringColumn(leaf((rows, kind), jnp.uint8),
-                     leaf((rows,), jnp.int32), valid)
-        if isinstance(kind, int) else
-        PrimitiveColumn(leaf((rows,), kind), valid)
-        for kind in layout)
+
+    def column(kind):
+        if isinstance(kind, int):
+            return StringColumn(leaf((rows, kind), jnp.uint8),
+                                leaf((rows,), jnp.int32), valid)
+        if kind == "decimal128":
+            return Decimal128Column(leaf((rows,), jnp.int64),
+                                    leaf((rows,), jnp.int64), valid)
+        return PrimitiveColumn(leaf((rows,), kind), valid)
+
+    return tuple(column(kind) for kind in layout)
 
 
 @pytest.mark.parametrize("layout, quota, capacity", [
@@ -198,3 +205,131 @@ def test_the_sorts_concatenation_compiles_for_the_chip(one_chip):
                    for b in batches)
     kern = sort._concat_kernel((256,) * 4, widths)
     assert kern.lower(batches).compile() is not None
+
+
+# -- the ratio reports (PR 42): window, expand, decimal / decimal -----------
+
+def _fields(spec):
+    """A schema from (layout kind, DataType name, precision, scale)."""
+    from auron_tpu.columnar.schema import DataType, Field, Schema
+    return Schema(tuple(
+        Field(f"c{i}", getattr(DataType, dt), True, p, s)
+        for i, (_kind, dt, p, s) in enumerate(spec)))
+
+
+#: what the three windows of the ratio reports see: the columns under
+#: the window, its partition keys, its order keys, its one function
+_WINDOWS = {
+    # q36: rank() over (partition by lochierarchy, the parent category
+    # order by the decimal(37,20) margin)
+    "q36_rank": ((("decimal128", "DECIMAL", 37, 20), (64, "STRING", 0, 0),
+                  (64, "STRING", 0, 0), ("int64", "INT64", 0, 0),
+                  (64, "STRING", 0, 0)),
+                 (3, 4), (0,), ("rank_like", "rank", None)),
+    # q53: avg(sum_sales) over (partition by i_manufact_id): decimal(17,2)
+    # in one word, decimal(21,6) out in two
+    "q53_avg": ((("int64", "INT64", 0, 0), ("int64", "INT64", 0, 0),
+                 ("int64", "DECIMAL", 17, 2)),
+                (0,), (), ("agg", "avg", 2)),
+    # q98: sum(itemrevenue) over (partition by i_class): decimal(27,2)
+    "q98_sum": (((32, "STRING", 0, 0), (64, "STRING", 0, 0),
+                 (16, "STRING", 0, 0), (16, "STRING", 0, 0),
+                 ("int64", "DECIMAL", 7, 2), ("int64", "DECIMAL", 17, 2)),
+                (3,), (), ("agg", "sum", 5)),
+}
+
+
+@pytest.mark.parametrize("key", list(_WINDOWS))
+def test_the_window_program_compiles_for_the_chip(one_chip, key):
+    """The window operator had never met the chip's compiler (PR 42):
+    the sort by partition and order keys (strings, and a two-limb
+    decimal as the order key), the segment scans on limb pairs and the
+    HALF_UP average, each as the ONE program a capacity."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.exprs import ir
+    from auron_tpu.ops import window
+    spec, partition, order, (kind, fn, arg) = _WINDOWS[key]
+    rows = 4096
+    kern = window._window_kernel(
+        tuple(ir.ColumnRef(i) for i in partition),
+        tuple(ir.SortOrder(ir.ColumnRef(i), True, True) for i in order),
+        (window.WindowFunctionSpec(
+            kind, fn, None if arg is None else ir.ColumnRef(arg)),),
+        _fields(spec), rows, None)
+    batch = DeviceBatch(
+        _columns(one_chip, rows, tuple(k for k, *_ in spec)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    assert kern.lower(batch).compile() is not None
+
+
+class _Source:
+    """A stand-in child: the fragments under test only ask its schema."""
+
+    def __init__(self, schema):
+        self._schema = schema
+
+    def schema(self):
+        return self._schema
+
+
+def _compile_fragment(one_chip, op, spec, rows):
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    frag = op.build_kernel_fragment()
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    batch = DeviceBatch(
+        _columns(one_chip, rows, tuple(k for k, *_ in spec)), scalar)
+    carry = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    return frag, _compile(frag.apply, batch, scalar, carry)
+
+
+def test_the_expand_program_compiles_for_the_chip(one_chip):
+    """q36's ROLLUP(i_category, i_class) as the fused stage runs it: a
+    full scan batch of joined rows three times over, the rolled-up keys
+    null and the grouping id beside them."""
+    from auron_tpu.columnar.schema import DataType
+    from auron_tpu.exprs import ir
+    from auron_tpu.ops.expand import ExpandOp
+    spec = (("int64", "DECIMAL", 7, 2), ("int64", "DECIMAL", 7, 2),
+            (16, "STRING", 0, 0), (16, "STRING", 0, 0))
+    cols = [ir.ColumnRef(i) for i in range(4)]
+    null = ir.Literal(None, DataType.STRING)
+
+    def projection(keep, gid):
+        return cols + [cols[2] if keep > 0 else null,
+                       cols[3] if keep > 1 else null,
+                       ir.Literal(gid, DataType.INT32)]
+
+    op = ExpandOp(_Source(_fields(spec)),
+                  [projection(2, 0), projection(1, 1), projection(0, 3)])
+    frag, compiled = _compile_fragment(one_chip, op, spec, 1 << 16)
+    assert compiled is not None
+    assert frag.fanout == 3 and frag.row_counts == (("expand_rows_out", 1),)
+
+
+@pytest.mark.parametrize("left, right", [
+    # q36 / q59: one word a side, 123 iterations, decimal(37,20) out
+    (("int64", 17, 2), ("int64", 17, 2)),
+    # q98: decimal(21,2) / decimal(27,2) = decimal(38,17), two limbs
+    (("decimal128", 21, 2), ("decimal128", 27, 2)),
+    # q53: decimal(22,6) / decimal(21,6) = decimal(38,16)
+    (("decimal128", 22, 6), ("decimal128", 21, 6)),
+    # a dividend that passes 128 bits scaled: the four-limb register
+    (("decimal128", 38, 2), ("int64", 10, 2)),
+], ids=["17_2_by_17_2", "21_2_by_27_2", "22_6_by_21_6", "38_2_by_10_2"])
+def test_the_decimal_division_compiles_for_the_chip(one_chip, left, right):
+    """decimal / decimal inside a fused projection: a loop of shifts,
+    compares and subtracts on int64 limb pairs, on a chip with no 64-bit
+    integer unit."""
+    from auron_tpu.exprs import ir
+    from auron_tpu.ops.project import ProjectOp
+    spec = tuple((kind, "DECIMAL", p, s) for kind, p, s in (left, right))
+    op = ProjectOp(_Source(_fields(spec)),
+                   [ir.BinaryExpr("/", ir.ColumnRef(0), ir.ColumnRef(1))],
+                   ["q"])
+    frag, compiled = _compile_fragment(one_chip, op, spec, 4096)
+    assert compiled is not None
+    assert frag.row_counts == (("decimal_div_rows", 1),)
