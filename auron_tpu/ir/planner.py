@@ -908,8 +908,9 @@ def annotate_mesh(op: PhysicalOp, plane) -> PhysicalOp:
     """Stamp each node's resolved SPMD spec (``op.mesh_spec``) when the
     mesh plane is active:
 
-    - eligible hash exchanges become ``"gang"`` — their materialization
-      occupies the whole mesh (parallel/exchange._materialize_mesh);
+    - eligible hash exchanges become ``"gang"`` — each round of their
+      materialization occupies the whole mesh
+      (parallel/exchange._materialize_mesh);
     - nodes declaring a buffer kind (``mesh_buffer_kind``) resolve
       through the replicate-vs-shard table (parallel/mesh.buffer_spec):
       broadcast relations and hash-join build sides ``"replicate"``

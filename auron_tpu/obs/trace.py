@@ -496,6 +496,9 @@ COUNT_KEYS = ("program_calls", "readbacks",
               # received, and the padded slot buffers that held them
               "mesh_rounds", "mesh_escalations", "mesh_bytes",
               "mesh_slot_bytes",
+              # times the stage took the gang door (``MeshPlane.gang``,
+              # not a re-entrant pass): one a round, quota re-runs inside
+              "mesh_gang_acquires",
               # its read side: the non-empty (partition, source, round)
               # slices the reducers read, one gather each, and the
               # bytes of those that a device_put then moved from

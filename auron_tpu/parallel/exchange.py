@@ -806,10 +806,16 @@ class ShuffleExchangeOp(PhysicalOp):
         exchange program — the re-run path still needs them, whatever
         ``yields_owned_batches`` says about the child.
 
-        The stage occupies the whole mesh for its duration
-        (``plane.gang``): the PR 9 scheduler's WRR turn orders queries'
-        sharded stages, and the gang lock keeps two of them from ever
-        interleaving inside the mesh."""
+        A ROUND occupies the whole mesh (``plane.gang``), from its stack
+        to its fence and its quota re-run: the PR 9 scheduler's WRR turn
+        orders queries' sharded rounds, and the gang lock keeps two
+        sharded programs from ever interleaving inside the mesh. The map
+        side — every pull of ``input_op``, a child exchange that
+        mesh-routes among them, which takes the door for its own rounds
+        — and the bookkeeping after the fence run with the door open:
+        they are single-device programs and host work, which stages
+        already run concurrently off the mesh. Between two rounds of one
+        exchange another stage's round may run."""
         from auron_tpu import config as cfg
         from auron_tpu import errors
         from auron_tpu.obs import trace
@@ -873,33 +879,39 @@ class ShuffleExchangeOp(PhysicalOp):
                 yield b
 
         try:
-            with plane.gang(ctx.cancel_event, heartbeat=ctx.heartbeat):
-                iters = [polled(p) if p < self.input_partitions
-                         else iter(())
-                         for p in range(n_out)]
-                carries = jax.device_put(
-                    jnp.broadcast_to(
-                        jnp.asarray(init, jnp.int64), (n_out, len(init))),
-                    NamedSharding(mesh, _P(axis, None)))
-                while True:
-                    batches = [next(it, None) for it in iters]
-                    ref = next((b for b in batches if b is not None), None)
-                    if ref is None:
-                        break
-                    live = [(p, b) for p, b in enumerate(batches)
-                            if b is not None]
-                    n_live = len(live)
-                    # zero-copy empties for exhausted maps: a live
-                    # batch's arrays with num_rows=0 (rows past
-                    # num_rows are dead by the batch contract)
-                    batches = [b if b is not None else
-                               DeviceBatch(ref.columns,
-                                           jnp.asarray(0, jnp.int32))
-                               for b in batches]
-                    # the sharded-stage fault site (chaos battery): a
-                    # device fault mid-exchange must classify cleanly
-                    faults.maybe_fail("device.compute",
-                                      errors.DeviceExecutionError)
+            iters = [polled(p) if p < self.input_partitions
+                     else iter(())
+                     for p in range(n_out)]
+            carries = jax.device_put(
+                jnp.broadcast_to(
+                    jnp.asarray(init, jnp.int64), (n_out, len(init))),
+                NamedSharding(mesh, _P(axis, None)))
+            while True:
+                batches = [next(it, None) for it in iters]
+                ref = next((b for b in batches if b is not None), None)
+                if ref is None:
+                    break
+                live = [(p, b) for p, b in enumerate(batches)
+                        if b is not None]
+                n_live = len(live)
+                # zero-copy empties for exhausted maps: a live
+                # batch's arrays with num_rows=0 (rows past
+                # num_rows are dead by the batch contract)
+                batches = [b if b is not None else
+                           DeviceBatch(ref.columns,
+                                       jnp.asarray(0, jnp.int32))
+                           for b in batches]
+                # the sharded-stage fault site (chaos battery): a
+                # device fault mid-exchange must classify cleanly
+                faults.maybe_fail("device.compute",
+                                  errors.DeviceExecutionError)
+                # the door: held from the round's stack to its fence (and
+                # its quota re-run) and never across a pull of the map side
+                # above — what it keeps apart is two sharded programs
+                # interleaving their per-device launch order. Taken BEFORE
+                # the guard starts its clock: parking behind another
+                # stage's round is not this round's latency
+                with plane.gang(ctx.cancel_event, heartbeat=ctx.heartbeat):
                     # gang-aware round guard: flags downgraded to "slow"
                     # when the round completes; a raise below is the
                     # dead-device verdict (watchdog.MeshRoundGuard)
@@ -984,69 +996,68 @@ class ShuffleExchangeOp(PhysicalOp):
                         demote_reason = "device_loss"
                         self._emit_demote(metrics, err, rounds, plane)
                         break
-                    carries = new_carries
-                    rounds += 1
-                    # graft: disable=GL001 -- rc_h came to the host in the round's timed_get
-                    counts = np.asarray(rc_h).reshape(n_out, n_out)
-                    dest_rows += counts.sum(axis=1)
-                    bytes_moved += buffer.add_round(out_cols, counts,
-                                                    quota)
-                    if comb_h is not None:
-                        # per-shard pre-combine rows of the COMPLETED
-                        # round (escalation re-runs were discarded)
-                        comb_in_total += int(np.asarray(comb_h).sum())   # graft: disable=GL001 -- comb_h rode the round's host counts readback
-                        comb_out_total += int(counts.sum())
-                        comb_batches += n_live
-                    if fmetrics is not None:
-                        # the folded chain still owns its plan node:
-                        # post-chain live rows are what the exchange
-                        # moved (the _materialize_fused convention)
-                        fmetrics.counter("output_rows").add(
-                            int(counts.sum()))
-                        fmetrics.counter("output_batches").add(n_live)
-                    # straggler defense: judge THIS round against the
-                    # rolling p50 BEFORE it joins the window; a stall
-                    # flag the guard forgave is a straggler by
-                    # construction (the round outlived the watchdog
-                    # timeout and still completed). Rounds that BUILT a
-                    # program (first shape class, quota escalation) are
-                    # excluded from verdict AND window — compile time is
-                    # not chip latency, and billing it would demote a
-                    # healthy mesh / inflate the baseline
-                    if round_built:
-                        slow = False
-                    else:
-                        slow = guard.forgiven or plane.round_stats \
-                            .is_straggler(guard.elapsed_s,
-                                          straggler_factor)
-                        plane.round_stats.observe(guard.elapsed_s)
-                    if slow:
-                        plane.record_straggler()
-                        metrics.counter("mesh_stragglers").add(1)
-                        trace.event(
-                            "mesh", "mesh.straggler", op=repr(self),
-                            round=rounds - 1,
-                            elapsed_ms=round(guard.elapsed_s * 1e3, 3),
-                            p50_ms=round(
-                                (plane.round_stats.p50() or 0.0) * 1e3,
-                                3),
-                            forgiven_stall=guard.forgiven,
-                            demoting=bool(demote_on_straggler))
-                        if demote_on_straggler:
-                            # the slow round COMPLETED — its received
-                            # rows stay valid on the mesh; only the
-                            # remaining rounds re-route
-                            t_demote = time.perf_counter()
-                            carries_h = np.asarray(
-                                _profile.timed_get(carries))
-                            demote_reason = "straggler"
-                            self._emit_demote(metrics, None, rounds,
-                                              plane)
-                            break
-            # gang released HERE on every path (the with-block's exit):
-            # the demoted host continuation below must never hold the
-            # mesh, and neighbor queries are never wedged behind a dead
-            # one
+                carries = new_carries
+                rounds += 1
+                # graft: disable=GL001 -- rc_h came to the host in the round's timed_get
+                counts = np.asarray(rc_h).reshape(n_out, n_out)
+                dest_rows += counts.sum(axis=1)
+                bytes_moved += buffer.add_round(out_cols, counts,
+                                                quota)
+                if comb_h is not None:
+                    # per-shard pre-combine rows of the COMPLETED
+                    # round (escalation re-runs were discarded)
+                    comb_in_total += int(np.asarray(comb_h).sum())   # graft: disable=GL001 -- comb_h rode the round's host counts readback
+                    comb_out_total += int(counts.sum())
+                    comb_batches += n_live
+                if fmetrics is not None:
+                    # the folded chain still owns its plan node:
+                    # post-chain live rows are what the exchange
+                    # moved (the _materialize_fused convention)
+                    fmetrics.counter("output_rows").add(
+                        int(counts.sum()))
+                    fmetrics.counter("output_batches").add(n_live)
+                # straggler defense: judge THIS round against the
+                # rolling p50 BEFORE it joins the window; a stall
+                # flag the guard forgave is a straggler by
+                # construction (the round outlived the watchdog
+                # timeout and still completed). Rounds that BUILT a
+                # program (first shape class, quota escalation) are
+                # excluded from verdict AND window — compile time is
+                # not chip latency, and billing it would demote a
+                # healthy mesh / inflate the baseline
+                if round_built:
+                    slow = False
+                else:
+                    slow = guard.forgiven or plane.round_stats \
+                        .is_straggler(guard.elapsed_s,
+                                      straggler_factor)
+                    plane.round_stats.observe(guard.elapsed_s)
+                if slow:
+                    plane.record_straggler()
+                    metrics.counter("mesh_stragglers").add(1)
+                    trace.event(
+                        "mesh", "mesh.straggler", op=repr(self),
+                        round=rounds - 1,
+                        elapsed_ms=round(guard.elapsed_s * 1e3, 3),
+                        p50_ms=round(
+                            (plane.round_stats.p50() or 0.0) * 1e3,
+                            3),
+                        forgiven_stall=guard.forgiven,
+                        demoting=bool(demote_on_straggler))
+                    if demote_on_straggler:
+                        # the slow round COMPLETED — its received
+                        # rows stay valid on the mesh; only the
+                        # remaining rounds re-route
+                        t_demote = time.perf_counter()
+                        carries_h = np.asarray(
+                            _profile.timed_get(carries))
+                        demote_reason = "straggler"
+                        self._emit_demote(metrics, None, rounds,
+                                          plane)
+                        break
+            # the door was released at the round's exit on every path: the
+            # demoted host continuation below never holds the mesh, and
+            # neighbor queries are never wedged behind a dead one
             if demote_reason is None:
                 total = int(dest_rows.sum())
                 skew = (float(dest_rows.max()

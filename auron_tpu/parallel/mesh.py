@@ -20,11 +20,12 @@ them, and WHO may occupy the mesh right now:
 - :func:`stack_global_batch` / :func:`local_shard` move between the
   engine's per-partition DeviceBatches and mesh-global sharded arrays
   (one shard per map partition / one shard per reducer device).
-- :meth:`MeshPlane.gang` is the gang-scheduling door: a sharded stage
-  occupies the WHOLE mesh, so one stage runs at a time (FIFO tickets,
-  cancel-aware waits); the PR 9 scheduler's weighted-round-robin turn
-  is taken on entry, so fairness operates BETWEEN sharded stages and
-  never interleaves two inside the mesh.
+- :meth:`MeshPlane.gang` is the gang-scheduling door: a sharded LAUNCH
+  occupies the WHOLE mesh, so one round of one stage is between its
+  stack and its fence at a time (FIFO tickets, cancel-aware waits); the
+  PR 9 scheduler's weighted-round-robin turn is taken on entry, so
+  fairness operates BETWEEN sharded rounds and never interleaves two
+  inside the mesh. A stage's map side runs outside the door.
 
 Works identically on a virtual CPU mesh
 (``--xla_force_host_platform_device_count``, the tier-1 environment)
@@ -83,9 +84,9 @@ class MeshPlane:
         self.axis = axis
         self._meshes: dict = {}
         # gang scheduling: FIFO ticket queue + condition. A sharded
-        # stage holds the WHOLE mesh (one slot = the mesh); contenders
+        # round holds the WHOLE mesh (one slot = the mesh); contenders
         # park here, woken by release, polling their cancel token so a
-        # dead query never waits out a long stage.
+        # dead query never waits out a long round.
         self._cond = threading.Condition()
         self._queue: deque = deque()
         self._holder: Optional[str] = None
@@ -228,23 +229,27 @@ class MeshPlane:
 
     @contextmanager
     def gang(self, token=None, heartbeat=None):
-        """Occupy the whole mesh for one sharded stage.
+        """Occupy the whole mesh for one sharded launch: a mesh
+        exchange takes it once a ROUND, from the round's stack to its
+        fence (and its quota re-run), and never across a pull of its map
+        side (``ShuffleExchangeOp._materialize_mesh``).
 
         Takes the PR 9 scheduler's weighted-round-robin turn first (when
         the token carries a slot), so WRR fairness decides the order in
-        which queries' sharded stages reach the mesh — then serializes
-        them FIFO: two sharded stages never interleave inside the mesh.
+        which queries' sharded rounds reach the mesh — then serializes
+        them FIFO: two sharded programs never interleave their
+        per-device enqueue order inside the mesh.
         A cancel/deadline landing while parked dequeues with the token's
         classified error, never holding (or waiting for) a dead stage.
         ``heartbeat`` (the task's stall-watchdog TaskHeartbeat) is
         beaten every poll tick while parked: waiting behind another
-        query's long sharded stage is legitimate liveness, not a stall
+        query's sharded round is legitimate liveness, not a stall
         — the compile-credit precedent from the lifecycle plane."""
-        # RE-ENTRANT per thread: a stage driving the mesh may pull a
-        # child exchange that mesh-routes too (exchange above exchange);
-        # the nested stage belongs to the same gang occupation, and a
-        # second acquisition on this thread would deadlock against
-        # itself.
+        # RE-ENTRANT per thread: a holder that reaches the door again
+        # (no exchange does since the map side left it: a child exchange
+        # that mesh-routes is pulled with the door open and takes it for
+        # its own rounds) belongs to the same occupation, and a second
+        # acquisition on this thread would deadlock against itself.
         me = threading.current_thread()
         with self._cond:
             if self._holder_thread is me:
@@ -259,6 +264,9 @@ class MeshPlane:
         # and the FIFO behind another query's sharded stage
         with trace.layer_span("exchange", "gang_wait"):
             qid, wait_ns, contended = self._acquire_gang(token, heartbeat)
+        # one a door actually taken (never a re-entrant pass): a stage's
+        # frame reads it beside ``mesh_rounds``
+        trace.count("mesh_gang_acquires")
         trace.event("mesh", "mesh.gang", query=qid,
                     wait_ms=round(wait_ns / 1e6, 3), contended=contended)
         try:

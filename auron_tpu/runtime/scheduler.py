@@ -511,10 +511,10 @@ class QueryScheduler:
                 "queue_wait_p50_s": round(self._queue_wait_p(0.50), 4),
                 "queue_wait_p99_s": round(self._queue_wait_p(0.99), 4),
             }
-        # gang-slot accounting: a sharded stage occupies the WHOLE mesh
+        # gang-slot accounting: a sharded round occupies the WHOLE mesh
         # (one slot = the mesh — parallel/mesh.MeshPlane.gang takes this
         # scheduler's WRR turn on entry, so fairness operates BETWEEN
-        # sharded stages); surfaced here so load/mesh reports show the
+        # sharded rounds); surfaced here so load/mesh reports show the
         # mesh occupancy next to the query-slot numbers. The plane's
         # stats also carry its FAULT DOMAIN ledger (quarantined devices,
         # usable width, demotions by reason, straggler/device-loss

@@ -32,7 +32,13 @@ over 4 input splits, through ``AuronServer`` / ``AuronClient`` with
   program (the range bounds its operand) and sampled by one, a reducer's
   batches are concatenated by one, and the stage reads fewer row counts
   than PR 40's tree did; a one-partition task calls none of the three;
-- four stages at the gang door at once wait there, and answer right.
+- the gang door closes round a ROUND's sharded launch only (PR 43): four
+  stages' map sides are in flight at once and none of them ever pulls
+  with the door in its hand, a stage takes the door once a round
+  (``counts.mesh_gang_acquires``), two exchanges of several rounds
+  alternate at it with one of them between stack and fence at a time,
+  parking there is not a round's latency, and a cancel between two
+  rounds finds it open.
 """
 
 import threading
@@ -53,7 +59,8 @@ PLANS = STAR + WIDE
 MESH_EXCHANGES = dict.fromkeys(STAR + ("q65sa", "q65sam"), 1) \
     | dict.fromkeys(("q65", "q65m"), 3)
 MESH_COUNTS = ("mesh_rounds", "mesh_escalations", "mesh_bytes",
-               "mesh_slot_bytes", "mesh_read_batches", "mesh_home_bytes")
+               "mesh_slot_bytes", "mesh_gang_acquires", "mesh_read_batches",
+               "mesh_home_bytes")
 MESH_SPAN_KEYS = ("gang_wait", "mesh_stack", "mesh_round")
 #: the program the reduce side reads an exchange buffer with (PR 39)
 READ_CUT = "parallel.exchange.read_cut"
@@ -492,16 +499,48 @@ def test_every_hash_exchange_of_a_wide_stage_takes_the_mesh(plan, stage,
         "partitioning_RangePartitioning", "partitioning_SinglePartitioning"]
 
 
-def test_four_stages_at_the_gang_door_wait_and_answer_right(stage):
-    """As many clients as task slots, each with a wide stage: the map
-    side runs inside the door, so someone parks there, and the frame of
-    the stage that did says for how long."""
+def _meet_in_the_map_side(monkeypatch, parties, timeout_s=120.0):
+    """Hook every pull of a mesh exchange's map side (the
+    ``shuffle.map`` checkpoint that follows it): note whether the thread
+    that pulled held the gang door, and make each thread's FIRST pull
+    wait for ``parties`` threads to be at theirs. The barrier breaks —
+    and every waiter raises — unless that many map sides are in flight
+    at once."""
+    from auron_tpu.ops.base import ExecContext
+    from auron_tpu.parallel import mesh
+    barrier = threading.Barrier(parties, timeout=timeout_s)
+    met, pulls = set(), []
+    checkpoint = ExecContext.checkpoint
+
+    def hooked(self, site=""):
+        if site == "shuffle.map":
+            me = threading.current_thread()
+            pulls.append(mesh.current_plane()._holder_thread is me)
+            if me not in met:
+                met.add(me)
+                barrier.wait()
+        return checkpoint(self, site)
+
+    monkeypatch.setattr(ExecContext, "checkpoint", hooked)
+    return met, pulls
+
+
+def test_four_stages_at_the_gang_door_wait_and_answer_right(stage,
+                                                            monkeypatch):
+    """As many clients as task slots, each with a wide stage: the door
+    closes round a round's sharded launch only, so the four map sides
+    meet — each at its first pull, where a stage that held the door
+    through its map side would keep the other three parked behind it
+    until the barrier broke — and no pull of any exchange of the four
+    stages, nested ones included, is made by the thread that holds the
+    door. Every answer is the oracle's, on the mesh route."""
     from auron_tpu.it.comparator import QueryResultComparator
     from auron_tpu.parallel import mesh
     conf = cfg.get_config()
     conf.set(cfg.MESH_ENABLED, True)
     conf.set(cfg.MESH_DEVICES, 4)
     got, errors = {}, []
+    met, pulls = _meet_in_the_map_side(monkeypatch, len(WIDE))
 
     def client(plan):
         try:
@@ -510,19 +549,21 @@ def test_four_stages_at_the_gang_door_wait_and_answer_right(stage):
             errors.append((plan, e))
 
     try:
-        before = mesh.current_plane().stats()["gang_contended"]
         threads = [threading.Thread(target=client, args=(p,)) for p in WIDE]
         for t in threads:
             t.start()
         for t in threads:
             t.join()
-        contended = mesh.current_plane().stats()["gang_contended"] - before
+        stats = mesh.current_plane().stats()
     finally:
         conf.unset(cfg.MESH_ENABLED)
         conf.unset(cfg.MESH_DEVICES)
     assert not errors, errors
-    assert contended >= 1
-    waits = {}
+    assert len(met) == len(WIDE)
+    # four map batches an exchange and round, each pulled door open
+    assert len(pulls) >= 4 * sum(MESH_EXCHANGES[p] for p in WIDE)
+    assert not any(pulls)
+    assert stats["gang_holder"] is None and stats["gang_queued"] == 0
     for plan in WIDE:
         table, done = got[plan]
         res = QueryResultComparator(double_rel_tol=1e-7).compare(
@@ -532,13 +573,300 @@ def test_four_stages_at_the_gang_door_wait_and_answer_right(stage):
         assert _leaf_sum(done, "exchange_route_all_to_all") \
             == MESH_EXCHANGES[plan]
         assert _leaf_sum(done, "exchange_route_demoted") == 0
+        assert led["counts"]["mesh_gang_acquires"] \
+            == led["counts"]["mesh_rounds"] == MESH_EXCHANGES[plan]
         split = led["exchange_s"]
         assert sum(split.values()) == pytest.approx(
             led["layers_s"]["exchange"], abs=1e-5)
-        waits[plan] = split["gang_wait"]
-    # a parked ticket polls every 50 ms: the wait of the one that parked
-    # longest is far over the door's own cost
-    assert max(waits.values()) > 0.02, waits
+
+
+@pytest.mark.parametrize("plan", PLANS)
+def test_a_stage_takes_the_gang_door_once_a_round(plan, answers):
+    """``counts.mesh_gang_acquires`` beside ``counts.mesh_rounds``: one
+    door a round in a star-join stage, a check stage and a q65 stage —
+    whose three exchanges nest, the per-store average's map side reading
+    ``sa`` across ``sa``'s own exchange — and none off the mesh."""
+    _table, done = answers[plan]["mesh"]
+    counts = done["cost_ledger"]["counts"]
+    assert counts["mesh_gang_acquires"] == counts["mesh_rounds"] \
+        == MESH_EXCHANGES[plan]
+    _table, single = answers[plan]["single"]
+    assert single["cost_ledger"]["counts"]["mesh_gang_acquires"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the door's scope on an exchange of several rounds (no cell has one)
+# ---------------------------------------------------------------------------
+
+ROUNDS = 4
+
+
+def _rounds_exchange(seed, n_out=4):
+    """A hash exchange 2 -> 4 whose maps hold four batches each: four
+    all-to-all rounds on the 4-device mesh."""
+    import numpy as np
+    import pyarrow as pa
+
+    from auron_tpu.columnar.arrow_bridge import schema_from_arrow
+    from auron_tpu.exprs import ir
+    from auron_tpu.io.parquet import MemoryScanOp
+    from auron_tpu.parallel.exchange import ShuffleExchangeOp
+    from auron_tpu.parallel.partitioning import HashPartitioning
+    rng = np.random.default_rng(seed)
+    n = 2 * ROUNDS * 250
+    rb = pa.record_batch({
+        "k": pa.array(rng.integers(0, 37, n), pa.int64()),
+        "v": pa.array(list(range(n)), pa.int64()),
+    })
+    parts = [[rb.slice(o, 250) for o in range(m * n // 2,
+                                              (m + 1) * n // 2, 250)]
+             for m in range(2)]
+    scan = MemoryScanOp(parts, schema_from_arrow(rb.schema), capacity=256)
+    return ShuffleExchangeOp(scan, HashPartitioning((ir.ColumnRef(0),),
+                                                    n_out),
+                             input_partitions=2)
+
+
+def _drain(ex, ctx):
+    """Every partition of ``ex`` read in order, as one Arrow table."""
+    import pyarrow as pa
+
+    from auron_tpu.columnar.arrow_bridge import schema_to_arrow, to_arrow
+    batches = [b for p in range(4) for b in ex.execute(p, ctx)]
+    return pa.Table.from_batches(
+        [to_arrow(b, ex.schema()) for b in batches if int(b.num_rows)],
+        schema=schema_to_arrow(ex.schema()))
+
+
+@pytest.fixture(scope="module")
+def serial_rounds():
+    """The exchange's answer off the mesh, by seed (asked for before
+    ``mesh4`` turns the mesh on: the setting builds the plane anew)."""
+    from auron_tpu.ops.base import ExecContext
+    return {seed: _drain(_rounds_exchange(seed), ExecContext())
+            for seed in (17, 18)}
+
+
+@pytest.fixture()
+def mesh4(serial_rounds):
+    from auron_tpu.parallel import mesh
+    conf = cfg.get_config()
+    conf.set(cfg.MESH_ENABLED, True)
+    conf.set(cfg.MESH_DEVICES, 4)
+    try:
+        yield mesh.current_plane()
+    finally:
+        conf.unset(cfg.MESH_ENABLED)
+        conf.unset(cfg.MESH_DEVICES)
+
+
+def test_two_exchanges_of_four_rounds_alternate_at_the_door(
+        serial_rounds, mesh4, monkeypatch):
+    """Two stages, four rounds each, on two threads: the first waits
+    BETWEEN its first and its second round — in its map side, door open
+    — until the second has completed a round, so the rounds alternate
+    (a door held across the map side would keep the second parked and
+    the first waiting for it). Between a round's stack and the fence of
+    its last launch there is never another stage's: ``max_active`` 1.
+    Both answers are the serial ones."""
+    from auron_tpu.ops.base import ExecContext
+    from auron_tpu.parallel import exchange, mesh
+    plane = mesh4
+    lock = threading.Lock()
+    events = []                       # (thread name, "stack" | "fence")
+    completed = []                    # thread name, a completed round each
+    b_started, b_round_done = threading.Event(), threading.Event()
+    pulls = {"a": 0}
+
+    stack, run_round = mesh.stack_global_batch, exchange._run_mesh_round
+    add_round = exchange._MeshExchangeBuffer.add_round
+    checkpoint = ExecContext.checkpoint
+
+    def note(kind):
+        with lock:
+            events.append((threading.current_thread().name, kind))
+
+    def stacked(*a, **k):
+        assert plane._holder_thread is threading.current_thread()
+        note("stack")
+        return stack(*a, **k)
+
+    def fenced(*a, **k):
+        try:
+            return run_round(*a, **k)
+        finally:
+            assert plane._holder_thread is threading.current_thread()
+            note("fence")
+
+    def added(self, *a, **k):
+        me = threading.current_thread().name
+        assert plane._holder_thread is not threading.current_thread()
+        with lock:
+            completed.append(me)
+        if me == "b":
+            b_round_done.set()
+        elif completed.count("a") == 1:
+            b_started.set()           # a's first round is in: b may start
+        return add_round(self, *a, **k)
+
+    def hooked(self, site=""):
+        if site == "shuffle.map" \
+                and threading.current_thread().name == "a":
+            pulls["a"] += 1
+            if pulls["a"] == 3:       # the first pull of a's second round
+                assert plane._holder_thread is not threading.current_thread()
+                assert b_round_done.wait(120), \
+                    "b completed no round while a was between two of its own"
+        return checkpoint(self, site)
+
+    monkeypatch.setattr(mesh, "stack_global_batch", stacked)
+    monkeypatch.setattr(exchange, "_run_mesh_round", fenced)
+    monkeypatch.setattr(exchange._MeshExchangeBuffer, "add_round", added)
+    monkeypatch.setattr(ExecContext, "checkpoint", hooked)
+    got, errors = {}, []
+    acquired = plane.gang_acquired
+
+    def run(name, seed, gate):
+        try:
+            assert gate is None or gate.wait(120)
+            got[name] = _drain(_rounds_exchange(seed), ExecContext())
+        except BaseException as e:      # seen below, on the test's thread
+            errors.append((name, e))
+            b_started.set()
+            b_round_done.set()
+
+    threads = [threading.Thread(target=run, name="a", args=("a", 17, None)),
+               threading.Thread(target=run, name="b",
+                                args=("b", 18, b_started))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    assert not errors, errors
+    assert got["a"].equals(serial_rounds[17])
+    assert got["b"].equals(serial_rounds[18])
+    assert completed.count("a") == completed.count("b") == ROUNDS
+    # a's first, then one of b's before a's second
+    assert completed[0] == "a" and completed[1] == "b", completed
+    # one door a round (a quota re-run stays inside its round's)
+    assert plane.gang_acquired - acquired == 2 * ROUNDS
+    assert plane.gang_holder() is None
+    # a round's interval: its stack .. the last fence that thread made
+    # before it stacked again; never two of them open at once
+    intervals = []                    # [thread, first event, last event]
+    for i, (who, kind) in enumerate(events):
+        if kind == "stack":
+            intervals.append([who, i, i])
+        else:
+            next(iv for iv in reversed(intervals) if iv[0] == who)[2] = i
+    max_active = max(sum(a <= i <= b for _who, a, b in intervals)
+                     for i in range(len(events)))
+    assert max_active == 1, events
+    assert sum(k == "stack" for _w, k in events) == 2 * ROUNDS
+
+
+def test_parking_at_the_door_is_not_a_rounds_latency(serial_rounds, mesh4):
+    """The guard's clock starts AFTER the door: an exchange that parks
+    there for far over ten times a round's p50 — and over the straggler
+    factor's four — books no straggler and, with
+    ``auron.mesh.demote_on_straggler`` on, stays on the mesh."""
+    from auron_tpu.ops.base import ExecContext
+    from auron_tpu.runtime.lifecycle import CancelToken
+    plane = mesh4
+    for _ in range(2):                # arm the window: 4 rounds and more
+        assert _drain(_rounds_exchange(17), ExecContext()) \
+            .equals(serial_rounds[17])
+    p50 = plane.round_stats.p50()
+    assert p50 is not None and p50 > 0
+    stragglers, demotions = plane.stragglers, dict(plane.demotions)
+    conf = cfg.get_config()
+    conf.set(cfg.MESH_DEMOTE_ON_STRAGGLER, True)
+    got, errors = {}, []
+    ctx = ExecContext()
+
+    def run():
+        try:
+            got["t"] = _drain(_rounds_exchange(18), ctx)
+        except BaseException as e:      # seen below, on the test's thread
+            errors.append(e)
+
+    t = threading.Thread(target=run)
+    try:
+        with plane.gang(CancelToken("holder")):
+            t.start()
+            deadline = time.monotonic() + 60
+            while plane.stats()["gang_queued"] < 1:
+                assert time.monotonic() < deadline and t.is_alive(), errors
+                time.sleep(0.002)
+            time.sleep(max(0.3, 50 * p50))
+        t.join(120)
+    finally:
+        conf.unset(cfg.MESH_DEMOTE_ON_STRAGGLER)
+    assert not errors, errors
+    assert got["t"].equals(serial_rounds[18])
+    m = ctx.metrics["shuffle_exchange"]
+    assert m.counter("mesh_stragglers").value == 0
+    assert m.counter("exchange_route_all_to_all").value == 1
+    assert m.counter("exchange_route_demoted").value == 0
+    assert m.counter("mesh_rounds").value == ROUNDS
+    assert plane.stragglers == stragglers
+    assert plane.demotions == demotions
+    assert plane.gang_contended >= 1 and plane.gang_holder() is None
+
+
+def test_a_cancel_between_two_rounds_finds_the_door_open(mesh4, monkeypatch):
+    """The cancel battery's contract at the new seam: a cancel that lands
+    in the map side between the first round and the second — door open —
+    unwinds with the token's classified error, nobody holding the door or
+    queued at it, the completed round's buffer unregistered."""
+    import gc
+    import tempfile
+
+    from auron_tpu import errors
+    from auron_tpu.memmgr.manager import MemManager
+    from auron_tpu.memmgr.spill import SpillManager
+    from auron_tpu.ops.base import ExecContext
+    from auron_tpu.parallel import exchange
+    from auron_tpu.runtime.lifecycle import CancelToken
+    plane = mesh4
+    token = CancelToken("between-rounds")
+    checkpoint = ExecContext.checkpoint
+    add_round = exchange._MeshExchangeBuffer.add_round
+    seen = {"pulls": 0, "rounds": 0, "holder": "unset", "consumers": None}
+
+    def added(self, *a, **k):
+        seen["rounds"] += 1
+        return add_round(self, *a, **k)
+
+    def hooked(self, site=""):
+        if site == "shuffle.map":
+            seen["pulls"] += 1
+            if seen["pulls"] == 3:    # the first pull of the second round
+                seen["holder"] = plane.gang_holder()
+                seen["consumers"] = len(mm.status()["consumers"])
+                token.cancel("test: between two rounds")
+        return checkpoint(self, site)
+
+    monkeypatch.setattr(exchange._MeshExchangeBuffer, "add_round", added)
+    monkeypatch.setattr(ExecContext, "checkpoint", hooked)
+    acquired = plane.gang_acquired
+    with tempfile.TemporaryDirectory() as d:
+        mm = MemManager(total_bytes=1 << 24, min_trigger=0,
+                        spill_manager=SpillManager(
+                            host_budget_bytes=1 << 20, spill_dir=d))
+        ctx = ExecContext(mem_manager=mm, cancel_event=token)
+        with pytest.raises(errors.QueryCancelled):
+            _drain(_rounds_exchange(17), ctx)
+        # one round completed and its buffer was the manager's; the
+        # cancel found the door open
+        assert seen["rounds"] == 1 and seen["holder"] is None
+        assert seen["consumers"] >= 1
+        assert plane.gang_acquired - acquired == 1
+        assert plane.gang_holder() is None
+        assert plane.stats()["gang_queued"] == 0
+        gc.collect()
+        assert not mm.status()["consumers"]
+        assert mm.spill_manager.live_disk_files() == 0
 
 
 def test_one_chip_task_opens_none_of_the_three_spans(stage, answers):
