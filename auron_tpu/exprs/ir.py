@@ -283,3 +283,6 @@ class AggFunction:
     # bloom_filter sizing; 0 = engine defaults
     expected_items: int = 0
     fpp: float = 0.0
+    #: this function's own mode inside its aggregation (partial |
+    #: partial_merge | final | complete); None = the node's
+    mode: Optional[str] = None

@@ -327,67 +327,89 @@ class GroupedData:
         self.keys = [_wrap(k) if not isinstance(k, Col) else k
                      for k in keys]
 
-    def _rewrite_wide_distinct(self, aggs) -> Optional["DataFrame"]:
-        """count/sum/avg DISTINCT over DECIMAL: plan distinct the way a
-        vector engine wants it anyway — an inner regroup on (keys..., arg)
-        dedupes the pairs (wide-decimal group keys are first-class since
-        the limb-grouping work), then the plain decimal aggregate runs
-        over the deduped rows with exact Spark result types
-        (sum → decimal(p+10,s), avg → decimal(p+4,s+4) HALF_UP). The
-        set-accumulator path cannot do either: its single int64 word
-        cannot hold two-limb p>18 values, and its finalizers lose the
-        decimal type (float avg). Distributed plans fall out for free:
-        the inner agg exchanges on (keys, arg), the outer agg
-        re-exchanges on keys. Reference models distinct as expand-to-set
-        (agg/acc.rs); Spark similarly regroups distinct aggregates."""
-        schema = self.df.schema
+    #: the name of the DISTINCT argument as a group column of the first
+    #: three aggregates of a single-DISTINCT plan
+    _DISTINCT_ARG = "__distinct_arg__"
 
-        def dec_info(a: AggCol):
-            """(rewritable, needs): decimal distinct count/sum/avg can
-            join the regroup; it is REQUIRED when the set path cannot
-            serve the aggregate — two-limb p>18 values, or sum/avg whose
-            set finalizers lose the Spark decimal result type. Narrow
-            count-distinct alone stays on the set path (exact there), so
-            mixed queries like (count(distinct d), count_star()) keep
-            working."""
-            if not (a.distinct and a.fn in ("count", "sum", "avg")
-                    and a.arg is not None):
-                return False, False
-            dt, p, _s = infer_dtype(resolve(a.arg, schema), schema)
-            if dt != DataType.DECIMAL:
-                return False, False
-            return True, (p > 18 or a.fn in ("sum", "avg"))
+    @staticmethod
+    def _dedupes(a: ir.AggFunction) -> bool:
+        """DISTINCT changes what count / sum / avg see; min / max / first
+        see the same rows with it or without."""
+        return a.distinct and a.arg is not None \
+            and a.fn in ("count", "sum", "avg")
 
-        infos = [dec_info(a) for a in aggs]
-        if not any(needs for _r, needs in infos):
-            return None
-        dec = [a for a, (r, _n) in zip(aggs, infos) if r]
-        if len(dec) != len(aggs):
-            raise NotImplementedError(
-                "DISTINCT over decimal cannot be mixed with other "
-                "aggregates in one agg() call: the distinct regroup "
-                "rewrite would dedupe the other aggregates' input rows. "
-                "Split the decimal-distinct aggregates into their own "
-                "agg().")
-        arg_reprs = {repr(resolve(a.arg, schema)) for a in dec}
-        if len(arg_reprs) > 1:
-            raise NotImplementedError(
-                "decimal DISTINCT aggregates in one agg() call must "
-                "share one argument expression (one regroup dedupes one "
-                "column); split differing arguments into separate agg()s.")
+    @staticmethod
+    def _agg_node(child, group_exprs, agg_fns, mode, group_names,
+                  agg_names) -> pb.PlanNode:
+        return pb.PlanNode(agg=pb.AggNode(
+            child=child,
+            group_exprs=[serde.expr_to_proto(e) for e in group_exprs],
+            aggs=[serde.agg_to_proto(a) for a in agg_fns],
+            mode=mode, group_names=group_names, agg_names=agg_names))
 
-        dcol = dec[0].arg.alias("__wd_arg__")
-        inner = GroupedData(self.df, list(self.keys) + [dcol]).agg()
-        key_names = [k.out_name(f"k{i}") for i, k in enumerate(self.keys)]
-        outer_aggs = [AggCol(a.fn, col("__wd_arg__"), name=a.out_name(i))
-                      for i, a in enumerate(dec)]
-        return GroupedData(inner, [col(n) for n in key_names]).agg(
-            *outer_aggs)
+    @staticmethod
+    def _exchange(child, n_keys: int, n_part: int) -> pb.PlanNode:
+        """Hash exchange on the first ``n_keys`` columns; with no key,
+        every row to one partition."""
+        if n_keys > 0:
+            part = pb.PartitioningP(
+                kind="hash", num_partitions=n_part,
+                hash_keys=[serde.expr_to_proto(ir.ColumnRef(i))
+                           for i in range(n_keys)])
+        else:
+            part = pb.PartitioningP(kind="single", num_partitions=1)
+        return pb.PlanNode(shuffle_writer=pb.ShuffleWriterNode(
+            child=child, partitioning=part, input_partitions=n_part))
+
+    def _plan_one_distinct(self, group_exprs, group_names, agg_fns,
+                           agg_names, arg: ir.Expr) -> pb.PlanNode:
+        """count / sum / avg DISTINCT over ONE argument x, beside any
+        plain functions, as Spark plans it
+        (AggUtils.planAggregateWithOneDistinct) — four aggregates:
+
+          1. keys ++ [x], the plain functions ``partial``;
+          2. the same keys, those functions ``partial_merge`` (after a
+             hash exchange on keys ++ [x] where there are partitions):
+             the groups that leave are the distinct (keys, x) pairs;
+          3. the keys alone, the plain functions ``partial_merge`` and
+             the DISTINCT ones ``partial`` over the column x, in one node;
+          4. every function ``final`` (after a hash exchange on the keys,
+             or a gather to one partition where there are none).
+
+        At one partition the four operators stay, as in Spark, and no
+        exchange stands between them. The DISTINCT functions run as plain
+        ones over deduplicated rows, so their result types are the plain
+        functions': Spark's."""
+        n_keys, n_part = len(group_exprs), self.df.num_partitions
+        plain = [(a, nm) for a, nm in zip(agg_fns, agg_names)
+                 if not self._dedupes(a)]
+        plain_fns = [a for a, _ in plain]
+        plain_names = [nm for _, nm in plain]
+        pair_names = group_names + [self._DISTINCT_ARG]
+        pair_refs = [ir.ColumnRef(i) for i in range(n_keys + 1)]
+        merged = [ir.AggFunction(a.fn) for a in plain_fns]
+
+        node = self._agg_node(self.df.plan, list(group_exprs) + [arg],
+                              plain_fns, "partial", pair_names, plain_names)
+        if n_part > 1:
+            node = self._exchange(node, n_keys + 1, n_part)
+        node = self._agg_node(node, pair_refs, merged, "partial_merge",
+                              pair_names, plain_names)
+        # the third aggregate keeps the caller's order of functions: the
+        # merging ones find their states at the end of their child's
+        # output in that order whatever stands between them
+        third = [ir.AggFunction(a.fn, ir.ColumnRef(n_keys), mode="partial")
+                 if self._dedupes(a) else ir.AggFunction(a.fn)
+                 for a in agg_fns]
+        node = self._agg_node(node, pair_refs[:n_keys], third,
+                              "partial_merge", group_names, agg_names)
+        if n_part > 1:
+            node = self._exchange(node, n_keys, n_part)
+        return self._agg_node(node, pair_refs[:n_keys],
+                              [ir.AggFunction(a.fn) for a in agg_fns],
+                              "final", group_names, agg_names)
 
     def agg(self, *aggs: AggCol) -> "DataFrame":
-        rewritten = self._rewrite_wide_distinct(aggs)
-        if rewritten is not None:
-            return rewritten
         schema = self.df.schema
         group_exprs = [resolve(k, schema) for k in self.keys]
         group_names = [k.out_name(f"k{i}") for i, k in enumerate(self.keys)]
@@ -401,6 +423,21 @@ class GroupedData:
         out_partitions = n_part
         out_prov = self.df.partitioning
         if n_part > 1:
+            if n_keys > 0:
+                out_prov = ("hash", tuple(group_names), n_part)
+            else:
+                out_partitions = 1
+                out_prov = ("single",)
+        distinct_args = {a.arg for a in agg_fns if self._dedupes(a)}
+        if len(distinct_args) > 1:
+            raise NotImplementedError(
+                "DISTINCT aggregates in one agg() call must share one "
+                "argument expression (Spark plans differing arguments "
+                "through Expand); split them into separate agg()s.")
+        if distinct_args:
+            node = self._plan_one_distinct(group_exprs, group_names, agg_fns,
+                                           agg_names, distinct_args.pop())
+        elif n_part > 1:
             # Spark-shaped two-phase plan: partial agg on every map
             # partition → exchange → final agg (the reference converts
             # HashAggregateExec pairs the same way,
@@ -408,40 +445,16 @@ class GroupedData:
             # hash-exchange on the group keys; a GLOBAL agg (no keys)
             # coalesces every partial row into one partition — without
             # that, each partition would emit its own "global" row.
-            partial = pb.PlanNode(agg=pb.AggNode(
-                child=self.df.plan,
-                group_exprs=[serde.expr_to_proto(e) for e in group_exprs],
-                aggs=[serde.agg_to_proto(a) for a in agg_fns],
-                mode="partial", group_names=group_names,
-                agg_names=agg_names))
-            if n_keys > 0:
-                part = pb.PartitioningP(
-                    kind="hash", num_partitions=n_part,
-                    hash_keys=[serde.expr_to_proto(ir.ColumnRef(i))
-                               for i in range(n_keys)])
-                out_prov = ("hash", tuple(group_names), n_part)
-            else:
-                part = pb.PartitioningP(kind="single", num_partitions=1)
-                out_partitions = 1
-                out_prov = ("single",)
-            shuffle = pb.PlanNode(shuffle_writer=pb.ShuffleWriterNode(
-                child=partial, partitioning=part, input_partitions=n_part))
-            node = pb.PlanNode(agg=pb.AggNode(
-                child=shuffle,
-                group_exprs=[serde.expr_to_proto(ir.ColumnRef(i))
-                             for i in range(n_keys)],
-                aggs=[serde.agg_to_proto(
-                    ir.AggFunction(a.fn, None, a.distinct))
-                    for a in agg_fns],
-                mode="final", group_names=group_names,
-                agg_names=agg_names))
+            partial = self._agg_node(self.df.plan, group_exprs, agg_fns,
+                                     "partial", group_names, agg_names)
+            node = self._agg_node(
+                self._exchange(partial, n_keys, n_part),
+                [ir.ColumnRef(i) for i in range(n_keys)],
+                [ir.AggFunction(a.fn, None, a.distinct) for a in agg_fns],
+                "final", group_names, agg_names)
         else:
-            node = pb.PlanNode(agg=pb.AggNode(
-                child=self.df.plan,
-                group_exprs=[serde.expr_to_proto(e) for e in group_exprs],
-                aggs=[serde.agg_to_proto(a) for a in agg_fns],
-                mode="complete", group_names=group_names,
-                agg_names=agg_names))
+            node = self._agg_node(self.df.plan, group_exprs, agg_fns,
+                                  "complete", group_names, agg_names)
 
         # schema via a throwaway op build is overkill; compute directly
         key_fields = []
@@ -451,7 +464,9 @@ class GroupedData:
         out_fields = list(key_fields)
         from auron_tpu.ops.agg import make_acc_spec
         for a, nm in zip(agg_fns, agg_names):
-            spec = make_acc_spec(a, schema, "complete")
+            # a DISTINCT function's type is the plain function's
+            spec = make_acc_spec(ir.AggFunction(a.fn, a.arg), schema,
+                                 "complete")
             out_fields.append(Field(nm, spec.result[0], True,
                                     spec.result[1], spec.result[2],
                                     elem=spec.elem))
@@ -766,6 +781,12 @@ class DataFrame:
                 and a[2] == b[2] == self.num_partitions
                 == other.num_partitions)
 
+    def _broadcast_plan(self) -> pb.PlanNode:
+        """This frame collected once and replayed to every partition of
+        a join's probe side."""
+        return pb.PlanNode(broadcast_exchange=pb.BroadcastExchangeNode(
+            child=self.plan, input_partitions=self.num_partitions))
+
     def join(self, other: "DataFrame", on: Union[str, Sequence[str]],
              how: str = "inner") -> "DataFrame":
         keys = [on] if isinstance(on, str) else list(on)
@@ -780,10 +801,7 @@ class DataFrame:
             # join, reference: NativeBroadcastExchangeBase / SURVEY §3.4)
             # — without this, probe partition p silently only sees build
             # partition p
-            build_plan = pb.PlanNode(
-                broadcast_exchange=pb.BroadcastExchangeNode(
-                    child=other.plan,
-                    input_partitions=other.num_partitions))
+            build_plan = other._broadcast_plan()
         node = pb.PlanNode(hash_join=pb.HashJoinNode(
             probe=self.plan, build=build_plan, probe_keys=pk,
             build_keys=bk, join_type=how))
@@ -807,6 +825,28 @@ class DataFrame:
                            self.partitioning)
         return joined.select(*[Col(ir.ColumnRef(i, raw[i].name),
                                    raw[i].name) for i in keep])
+
+    def cross_join(self, other: "DataFrame", how: str = "inner",
+                   condition: Optional[Col] = None) -> "DataFrame":
+        """Every row beside every row of ``other`` (SQL's comma join with
+        no condition; Spark's BroadcastNestedLoopJoin BuildRight Inner).
+        ``other`` is the build side: collected whole, and replayed to
+        every partition of this side where either has more than one. An
+        outer type or a condition — what Spark's nested-loop join also
+        runs — is refused here, at plan time."""
+        if how != "inner" or condition is not None:
+            raise NotImplementedError(
+                f"cross_join(how={how!r}, condition="
+                f"{'given' if condition is not None else None}): only the "
+                "inner join without a condition; join on keys and filter")
+        both_single = self.num_partitions == 1 and other.num_partitions == 1
+        node = pb.PlanNode(cross_join=pb.CrossJoinNode(
+            probe=self.plan,
+            build=other.plan if both_single else other._broadcast_plan()))
+        return DataFrame(self.session, node,
+                         Schema(tuple(self.schema.fields)
+                                + tuple(other.schema.fields)),
+                         self.num_partitions, self.partitioning)
 
     def explode(self, c: Union[str, Col], outer: bool = False,
                 keep: Optional[Sequence[str]] = None) -> "DataFrame":

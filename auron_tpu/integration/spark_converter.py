@@ -588,7 +588,8 @@ class SparkPlanConverter:
 
     def _partitioning(self, tree: SparkNode,
                       ec: ExprConverter) -> tuple[pb.PartitioningP, int]:
-        cls = tree.simple_name
+        # a case object renders with Scala's trailing "$"
+        cls = tree.simple_name.rstrip("$")
         n_out = int(tree.fields.get("numPartitions", 1))
         if cls == "HashPartitioning":
             return pb.PartitioningP(
@@ -658,6 +659,32 @@ class SparkPlanConverter:
 
     _c_ShuffledHashJoinExec = _c_BroadcastHashJoinExec
 
+    def _c_BroadcastNestedLoopJoinExec(self, node: SparkNode) -> _Converted:
+        """The join without keys: Inner (or Cross), no condition, the
+        right side built. Anything else is a fallback boundary."""
+        jt = _object_name(node.fields.get("joinType", "Inner"))
+        if jt not in ("Inner", "Cross"):
+            raise NotImplementedError(f"nested-loop join type {jt}")
+        if node.fields.get("condition"):
+            raise NotImplementedError("nested-loop join condition")
+        side = _object_name(node.fields.get("buildSide", "BuildRight"))
+        if side != "BuildRight":
+            raise NotImplementedError("BuildLeft nested-loop join")
+        left = self._convert(node.children[0])
+        right = self._convert(node.children[1])
+        build = right.node
+        if right.partitions > 1:
+            # CartesianProductExec pairs every partition with every
+            # partition; here each left partition meets the whole right
+            build = pb.PlanNode(broadcast_exchange=pb.BroadcastExchangeNode(
+                child=right.node, input_partitions=right.partitions))
+        n = pb.PlanNode(cross_join=pb.CrossJoinNode(
+            probe=left.node, build=build))
+        return _Converted(n, list(left.attrs) + list(right.attrs),
+                          left.partitions)
+
+    _c_CartesianProductExec = _c_BroadcastNestedLoopJoinExec
+
     def _c_SortMergeJoinExec(self, node: SparkNode) -> _Converted:
         jt = self._join_common(node)
         left = self._convert(node.children[0])
@@ -685,20 +712,33 @@ class SparkPlanConverter:
 
     # -- aggregation --------------------------------------------------------
 
+    _AGG_MODE = {"Partial": "partial", "PartialMerge": "partial_merge",
+                 "Final": "final", "Complete": "complete"}
+
     def _agg_parts(self, node: SparkNode):
+        """(grouping trees, aggregate trees, the node's mode, each
+        function's own). A node emits either states (Partial,
+        PartialMerge) or results (Final, Complete); inside either a
+        function may differ from its neighbours — the third aggregate of
+        Spark's single-DISTINCT plan merges the plain functions and
+        starts the DISTINCT ones over the deduplicated column."""
         groups = node.field_trees("groupingExpressions")
         agg_exprs = node.field_trees("aggregateExpressions")
-        modes = {_object_name(a.fields.get("mode", "Complete"))
-                 for a in agg_exprs} or {"Complete"}
-        if len(modes) > 1:
-            raise NotImplementedError(f"mixed agg modes {modes}")
-        mode = modes.pop()
-        if mode not in ("Partial", "Final", "Complete"):
-            # e.g. PartialMerge (distinct rewrites / AQE re-optimizations):
-            # unsupported — must become a fallback boundary, not a plan
-            # that fails the engine's mode assertion later
-            raise NotImplementedError(f"aggregate mode {mode}")
-        return groups, agg_exprs, mode
+        modes = []
+        for a in agg_exprs:
+            m = _object_name(a.fields.get("mode", "Complete"))
+            if m not in self._AGG_MODE:
+                raise NotImplementedError(f"aggregate mode {m}")
+            modes.append(self._AGG_MODE[m])
+        have = set(modes) or {"complete"}
+        emits_state = have <= {"partial", "partial_merge"}
+        if not emits_state and have & {"partial", "partial_merge"}:
+            raise NotImplementedError(f"mixed agg modes {sorted(have)}")
+        if emits_state:
+            mode = "partial_merge" if "partial_merge" in have else "partial"
+        else:
+            mode = "final" if "final" in have else "complete"
+        return groups, agg_exprs, mode, modes
 
     def _agg_fn(self, agg_expr: SparkNode) -> tuple[str, SparkNode, bool]:
         fn_tree = agg_expr.children[0]
@@ -714,33 +754,37 @@ class SparkPlanConverter:
 
     def _c_HashAggregateExec(self, node: SparkNode) -> _Converted:
         child = self._convert(node.children[0])
-        groups, agg_exprs, mode = self._agg_parts(node)
+        groups, agg_exprs, mode, fn_modes = self._agg_parts(node)
         ec = ExprConverter(child.attrs, self.shims, self._convert_subplan)
         group_names = [g.fields.get("name", f"k{i}")
                        for i, g in enumerate(groups)]
 
         aggs, agg_attrs = [], []
-        for a in agg_exprs:
+        for a, fn_mode in zip(agg_exprs, fn_modes):
             fn, arg, distinct = self._agg_fn(a)
             rid = _expr_id(a.fields)
             fn_tree = a.children[0]
             agg_attrs.append(Attr(fn, rid,
                                   fn_tree.fields.get("dataType", "double")))
-            if mode == "Final":
-                aggs.append(pb.AggFunctionP(fn=fn, distinct=distinct))
-            else:
-                aggs.append(pb.AggFunctionP(
-                    fn=fn, distinct=distinct,
-                    arg=ec.convert(arg) if arg is not None else None))
+            # Spark keeps isDistinct on the functions of a single-DISTINCT
+            # plan's last two aggregates for show: their input is
+            # deduplicated by the plan, and they run as plain functions
+            out = pb.AggFunctionP(
+                fn=fn, distinct=distinct and fn_mode == "complete",
+                mode="" if fn_mode == mode else fn_mode)
+            if fn_mode in ("partial", "complete") and arg is not None:
+                out.arg.CopyFrom(ec.convert(arg))
+            aggs.append(out)
 
-        if mode == "Final":
-            # grouping refs must land on the leading columns of the
-            # partial layout flowing through the exchange
+        if "partial_merge" in fn_modes or "final" in fn_modes:
+            # the refs of a node that reads states are bound to attrs that
+            # stand one a function for a layout of one or two columns a
+            # state: only the leading (group) columns are where they say
             for i, g in enumerate(groups):
                 idx = ec.convert(g).column.index
                 if idx != i:
                     raise NotImplementedError(
-                        "final agg grouping not in partial column order")
+                        "merging agg grouping not in partial column order")
             group_protos = [pb.ExprNode(column=pb.ColumnRefE(index=i))
                             for i in range(len(groups))]
         else:
@@ -749,14 +793,14 @@ class SparkPlanConverter:
         agg_names = [a.name for a in agg_attrs]
         n = pb.PlanNode(agg=pb.AggNode(
             child=child.node, group_exprs=group_protos, aggs=aggs,
-            mode=mode.lower(), group_names=group_names,
+            mode=mode, group_names=group_names,
             agg_names=agg_names))
         group_attrs = [Attr(nm, _expr_id(g.fields),
                             g.fields.get("dataType", "long"))
                        for nm, g in zip(group_names, groups)]
         out = _Converted(n, group_attrs + agg_attrs, child.partitions)
 
-        if mode in ("Final", "Complete"):
+        if mode in ("final", "complete"):
             result = node.field_trees("resultExpressions")
             if result and not self._is_identity(result, out.attrs):
                 return self._project(out, result)

@@ -418,6 +418,16 @@ class PhysicalPlanner:
             join_type=n.join_type or "inner",
         )
 
+    def _plan_cross_join(self, n: pb.CrossJoinNode) -> PhysicalOp:
+        from auron_tpu.ops.joins import CrossJoinOp
+        if (n.join_type or "inner") != "inner" or n.HasField("condition"):
+            raise NotImplementedError(
+                f"join without keys: type {n.join_type or 'inner'!r}"
+                + (" with a condition" if n.HasField("condition") else "")
+                + "; only the inner join without a condition is planned")
+        return CrossJoinOp(self.create_plan(n.probe),
+                           self.create_plan(n.build))
+
     def _plan_sort_merge_join(self, n: pb.SortMergeJoinNode) -> PhysicalOp:
         from auron_tpu.ops.joins import SortMergeJoinOp
         return SortMergeJoinOp(
@@ -811,7 +821,7 @@ def _elide_agg_child_projection(op: PhysicalOp) -> PhysicalOp:
     from auron_tpu.exprs import ir as eir
     from auron_tpu.ops.agg import AggOp
     from auron_tpu.ops.project import ProjectOp
-    if not isinstance(op, AggOp) or op.mode not in ("partial", "complete"):
+    if not isinstance(op, AggOp) or not op.from_rows:
         return op
     child = op.children[0]
     if not isinstance(child, ProjectOp):
@@ -855,7 +865,7 @@ def _push_agg_projection(op: PhysicalOp) -> PhysicalOp:
     from auron_tpu.exprs import ir as eir
     from auron_tpu.ops.agg import AggOp
     from auron_tpu.ops.project import ProjectOp
-    if not isinstance(op, AggOp) or op.mode not in ("partial", "complete"):
+    if not isinstance(op, AggOp) or not op.from_rows:
         return op
     for a in op.aggs:
         # host-side accumulator states (bloom/udaf) evaluate their own

@@ -297,7 +297,8 @@ def parse_sort_order(p: pb.SortOrderP) -> ir.SortOrder:
 
 def agg_to_proto(a: ir.AggFunction) -> pb.AggFunctionP:
     out = pb.AggFunctionP(fn=a.fn, distinct=a.distinct,
-                          expected_items=a.expected_items, fpp=a.fpp)
+                          expected_items=a.expected_items, fpp=a.fpp,
+                          mode=a.mode or "")
     if a.arg is not None:
         out.arg.CopyFrom(expr_to_proto(a.arg))
     return out
@@ -306,4 +307,4 @@ def agg_to_proto(a: ir.AggFunction) -> pb.AggFunctionP:
 def parse_agg(p: pb.AggFunctionP) -> ir.AggFunction:
     arg = parse_expr(p.arg) if p.HasField("arg") else None
     return ir.AggFunction(p.fn, arg, p.distinct,
-                          p.expected_items, p.fpp)
+                          p.expected_items, p.fpp, p.mode or None)

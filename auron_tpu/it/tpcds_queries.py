@@ -4422,6 +4422,14 @@ _q("q80", "store sales with LEFT-joined returns by store, one period")(
 
 # ===========================================================================
 # q28: six price-band value profiles of store sales (scalar subqueries)
+#
+# A SIMPLIFIED form: three of the six bands, no price arms under the
+# quantity band, the average carried in double, no count(ss_list_price),
+# the bands put side by side by scalar subqueries. The query at its
+# published text, as Spark plans it (six scans, each into avg / count /
+# count DISTINCT as four aggregates, cross-joined, money decimal to the
+# answer), is benchmark/plans/q28.py. Its count(DISTINCT) is planned as
+# that regroup here too since PR 44 (GroupedData._plan_one_distinct).
 # ===========================================================================
 
 def _q28_run(s, t):

@@ -526,7 +526,13 @@ COUNT_KEYS = ("program_calls", "readbacks",
               # left a program an expand ran in, and rows that left one
               # that divided decimal by decimal, once a division
               "window_rows", "window_partitions", "expand_rows_out",
-              "decimal_div_rows")
+              "decimal_div_rows",
+              # a single-DISTINCT aggregate as Spark plans it (PR 44),
+              # from row counts read anyway: the groups that left the
+              # keyed partial_merge aggregation whose parent counts one
+              # of its group columns (the distinct (keys, argument)
+              # pairs), and the rows that left a join without keys
+              "agg_distinct_groups", "cross_join_rows")
 
 _ANNOTATION = None
 

@@ -739,10 +739,41 @@ def _host_string_column(values: list, cap: int) -> StringColumn:
                         jnp.asarray(val))
 
 
-def _contribution_columns(group_exprs, mode: str, aggs, specs,
+def _read_state_accs(fields, batch: DeviceBatch, idx: int, accs: list) -> int:
+    """Append the accumulators held by one function's state columns:
+    ``fields`` are its (name, dtype, kind) state fields, the first of
+    them column ``idx`` of ``batch``. Returns the column after them."""
+    for (fname, _fdt, kind) in fields:
+        col = batch.columns[idx]
+        idx += 1
+        if kind in HOST_KINDS:
+            continue              # merged host-side (_HostAggState)
+        if kind in ("collect_list", "collect_set"):
+            accs.append((col.values, jnp.where(col.validity, col.lens, 0)))
+        elif kind in _DCOLLECT:
+            accs.append((col.keys, col.values,
+                         jnp.where(col.validity, col.lens, 0)))
+        elif kind in _STR_KINDS:
+            accs.append((col.chars, col.lens, col.validity))
+        elif kind in _DEC_KINDS:
+            # limb pair; invalid state rows already hold the
+            # reduce-neutral (partial emit / passthrough neutralized
+            # them), so no re-masking needed
+            accs.append((col.hi, col.lo))
+        elif fname == "has":
+            accs.append(col.data.astype(jnp.bool_) & col.validity)
+        else:
+            accs.append(col.data)   # min/max/first: validity is 'has'
+    return idx
+
+
+def _contribution_columns(group_exprs, state_idx: tuple, aggs, specs,
                           batch: DeviceBatch, in_schema: Schema,
                           ctx: EvalContext):
     """Evaluate group keys and per-row initial accumulator columns.
+    ``state_idx`` says, function by function, where its input is: the
+    column its state begins at (a function that merges: partial_merge,
+    final), or None for one that starts from rows (partial, complete).
 
     Module-level (plan data in, columns out) so traced closures can use
     it without capturing the AggOp — the combine fold's stage closure
@@ -753,46 +784,10 @@ def _contribution_columns(group_exprs, mode: str, aggs, specs,
                  for e in group_exprs)
     accs = []
     live = batch.row_mask()
-    if mode == "final":
-        # state columns come in as-is
-        idx = len(group_exprs)
-        for spec in specs:
-            for k, (fname, fdt, kind) in enumerate(spec.state_fields):
-                col = batch.columns[idx]
-                if kind in HOST_KINDS:
-                    idx += 1      # merged host-side (_HostAggState)
-                    continue
-                if kind in ("collect_list", "collect_set"):
-                    accs.append((col.values,
-                                 jnp.where(col.validity, col.lens, 0)))
-                    idx += 1
-                    continue
-                if kind in _DCOLLECT:
-                    accs.append((col.keys, col.values,
-                                 jnp.where(col.validity, col.lens, 0)))
-                    idx += 1
-                    continue
-                if kind in _STR_KINDS:
-                    accs.append((col.chars, col.lens, col.validity))
-                    idx += 1
-                    continue
-                if kind in _DEC_KINDS:
-                    # limb pair; invalid state rows already hold the
-                    # reduce-neutral (partial emit / passthrough
-                    # neutralized them), so no re-masking needed
-                    accs.append((col.hi, col.lo))
-                    idx += 1
-                    continue
-                data = col.data
-                if fname == "has":
-                    data = data.astype(jnp.bool_) & col.validity
-                elif kind in ("min", "max") or kind == "first":
-                    data = data  # validity handled via 'has'
-                accs.append(data)
-                idx += 1
-        return keys, accs, live
-
-    for agg, spec in zip(aggs, specs):
+    for agg, spec, idx in zip(aggs, specs, state_idx):
+        if idx is not None:
+            _read_state_accs(spec.state_fields, batch, idx, accs)
+            continue
         if spec.state_fields and spec.state_fields[0][2] in HOST_KINDS:
             continue              # accumulated host-side
         if spec.state_fields[0][2] in ("collect_list", "collect_set"):
@@ -1111,14 +1106,8 @@ class _HostAggState:
         n = _profile.row_count(batch)
         n_keys = len(self.op.group_exprs)
         key_tuples = _key_tuples_host(batch.columns[:n_keys], n)
-        # state column index per spec in the partial layout
-        idx = n_keys
-        col_of = {}
-        for si, spec in enumerate(self.op.specs):
-            col_of[si] = idx
-            idx += len(spec.state_fields)
         for si, ent in self.entries.items():
-            col = batch.columns[col_of[si]]
+            col = batch.columns[self.op.state_idx[si]]
             states = _column_pyvalues(col, n)
             if ent[0] == "bloom":
                 from auron_tpu.exprs.bloom import SparkBloomFilter
@@ -1309,10 +1298,51 @@ class _HashPathCtl:
         self.disabled = False
 
 
+#: aggregation modes (reference: AggMode, agg/agg_ctx.rs; Spark's
+#: AggregateMode): which read state columns and which emit them
+AGG_MODES = ("partial", "partial_merge", "final", "complete")
+_READS_STATE = ("partial_merge", "final")
+_EMITS_STATE = ("partial", "partial_merge")
+
+
+def _state_columns(aggs, fn_modes, in_schema: Schema) -> tuple:
+    """Where each function's input state begins in the child's output
+    (None for a function that starts from rows). The functions that merge
+    read the child's LAST columns, in their own order: an aggregation
+    that emits state emits its group columns and then its states, and its
+    parent may group by fewer of them (Spark's third aggregate of a
+    single-DISTINCT plan drops the distinct column from the keys). A
+    state is one column or two; which, the last one says (``has``, a
+    bool, closes every two-column min / max / first state)."""
+    starts = [None] * len(aggs)
+    end = len(in_schema)
+    for i in reversed(range(len(aggs))):
+        if fn_modes[i] not in _READS_STATE:
+            continue
+        a = aggs[i]
+        if a.fn in ("sum", "avg") and not a.distinct:
+            width = 2
+        elif a.fn in ("min", "max", "first", "first_ignores_null"):
+            width = 2 if end and in_schema[end - 1].dtype == DataType.BOOL \
+                else 1
+        else:       # counts, lists, the DISTINCT set, bloom / udaf states
+            width = 1
+        end -= width
+        if end < 0:
+            raise ValueError(
+                f"aggregation in a merging mode over {len(in_schema)} "
+                f"columns: too few for the states of {len(aggs)} functions")
+        starts[i] = end
+    return tuple(starts)
+
+
 class AggOp(PhysicalOp):
-    """mode: 'partial' emits (keys..., state...); 'final' consumes state
-    columns; 'complete' does full agg in one op (reference: AggMode,
-    agg/agg_ctx.rs)."""
+    """mode: 'partial' emits (keys..., state...); 'partial_merge' consumes
+    state columns and emits them merged; 'final' consumes state columns;
+    'complete' does full agg in one op (reference: AggMode,
+    agg/agg_ctx.rs). A function may carry a mode of its own
+    (``AggFunction.mode``): a node that emits state may merge some
+    functions' states and start others from its input rows."""
 
     name = "agg"
 
@@ -1322,11 +1352,18 @@ class AggOp(PhysicalOp):
                  agg_names: Optional[list[str]] = None,
                  initial_capacity: int = 4096,
                  key_domain: Optional[int] = None):
-        assert mode in ("partial", "final", "complete")
+        assert mode in AGG_MODES
         self.child = child
         self.group_exprs = tuple(group_exprs)
         self.aggs = tuple(aggs)
         self.mode = mode
+        self.emits_state = mode in _EMITS_STATE
+        fn_modes = tuple(a.mode or mode for a in aggs)
+        for a, m in zip(aggs, fn_modes):
+            if m not in AGG_MODES or (m in _EMITS_STATE) != self.emits_state:
+                raise ValueError(
+                    f"{a.fn} in mode {m!r} inside a {mode!r} aggregation: "
+                    "a node emits either states or results")
         self.initial_capacity = initial_capacity
         #: exclusive upper bound on the (non-negative, non-null) group
         #: key when the planner can prove one from table stats; feeds
@@ -1339,21 +1376,37 @@ class AggOp(PhysicalOp):
         #: mesh-routed exchange moves through the all-to-all (the
         #: map-side-combine-before-exchange shape)
         self.mesh_buffer_kind = "agg_partial" if mode == "partial" else None
+        #: set by a parent that counts one of this node's group columns
+        #: (below): the groups that leave are then the distinct
+        #: (keys, argument) pairs, ``counts.agg_distinct_groups``
+        self.feeds_distinct = False
         in_schema = child.schema()
 
-        if mode == "final":
-            # input layout: group cols ++ flattened state cols, as produced
-            # by a partial AggOp with the same aggs
-            n_keys = len(group_exprs)
-            self.specs = []
-            idx = n_keys
-            for a in aggs:
-                # state fields of the partial side
-                spec = make_acc_spec_from_partial(a, in_schema, idx)
-                self.specs.append(spec)
-                idx += len(spec.state_fields)
-        else:
-            self.specs = [make_acc_spec(a, in_schema, mode) for a in aggs]
+        #: per function: the child column its state begins at, or None
+        self.state_idx = _state_columns(aggs, fn_modes, in_schema)
+        #: rows only in: what the pre-aggregation passes, the dense
+        #: kernels, the combine fold and the partial skip are written for
+        self.from_rows = mode in ("partial", "complete") \
+            and all(idx is None for idx in self.state_idx)
+        self.specs = [make_acc_spec(a, in_schema, mode) if idx is None
+                      else make_acc_spec_from_partial(a, in_schema, idx)
+                      for a, idx in zip(aggs, self.state_idx)]
+        if (len(set(fn_modes)) > 1 or mode == "partial_merge") and any(
+                f[2] in HOST_KINDS for spec in self.specs
+                for f in spec.state_fields):
+            raise NotImplementedError(
+                "bloom_filter / udaf aggregates in a partial_merge or "
+                "mixed-mode aggregation")
+        if mode == "partial_merge" and isinstance(child, AggOp) \
+                and child.mode == "partial_merge" and child.group_exprs:
+            # Spark's second and third aggregates of a single-DISTINCT
+            # plan: the child's groups are (keys, x), this node starts
+            # count(x) / sum(x) / avg(x) over the child's column x
+            n_child_keys = len(child.group_exprs)
+            child.feeds_distinct = any(
+                idx is None and isinstance(a.arg, ir.ColumnRef)
+                and a.arg.index < n_child_keys
+                for a, idx in zip(aggs, self.state_idx))
 
         self.group_names = list(group_names or
                                 [f"k{i}" for i in range(len(group_exprs))])
@@ -1366,7 +1419,7 @@ class AggOp(PhysicalOp):
             from auron_tpu.exprs.eval import infer_field
             key_fields.append(infer_field(e, in_schema, n))
 
-        if mode == "partial":
+        if self.emits_state:
             state_fields = []
             for spec, an in zip(self.specs, self.agg_names):
                 for fi, (fname, fdt, kind) in enumerate(spec.state_fields):
@@ -1404,8 +1457,9 @@ class AggOp(PhysicalOp):
     def _contributions(self, batch: DeviceBatch, in_schema: Schema,
                        ctx: EvalContext):
         """Evaluate group keys and per-row initial accumulator columns."""
-        return _contribution_columns(self.group_exprs, self.mode, self.aggs,
-                                     self.specs, batch, in_schema, ctx)
+        return _contribution_columns(self.group_exprs, self.state_idx,
+                                     self.aggs, self.specs, batch,
+                                     in_schema, ctx)
 
     # -- merge driver -------------------------------------------------------
     #
@@ -1704,7 +1758,7 @@ class AggOp(PhysicalOp):
         out_cols = list(keys)   # device columns; host cols spliced after
         host_slots = []         # (position, spec_index)
 
-        if self.mode == "partial":
+        if self.emits_state:
             i = 0
             for si, spec in enumerate(self.specs):
                 for (fname, fdt, kind) in spec.state_fields:
@@ -1851,7 +1905,7 @@ class AggOp(PhysicalOp):
             if pos in slot_map:
                 final_cols.append(host.result_column(
                     slot_map[pos], key_tuples, ng, out_cap,
-                    partial=self.mode == "partial"))
+                    partial=self.emits_state))
             else:
                 final_cols.append(device_batch.columns[di])
                 di += 1
@@ -1895,36 +1949,11 @@ class AggOp(PhysicalOp):
     def _state_contributions(self, batch: DeviceBatch):
         n_keys = len(self.group_exprs)
         keys = tuple(batch.columns[:n_keys])
-        live = batch.row_mask()
-        accs = []
+        accs: list = []
         idx = n_keys
         for spec in self.specs:
-            for (fname, _fdt, kind) in _device_fields(spec):
-                col = batch.columns[idx]
-                if kind in ("collect_list", "collect_set"):
-                    accs.append((col.values,
-                                 jnp.where(col.validity, col.lens, 0)))
-                    idx += 1
-                    continue
-                if kind in _DCOLLECT:
-                    accs.append((col.keys, col.values,
-                                 jnp.where(col.validity, col.lens, 0)))
-                    idx += 1
-                    continue
-                if kind in _STR_KINDS:
-                    accs.append((col.chars, col.lens, col.validity))
-                    idx += 1
-                    continue
-                if kind in _DEC_KINDS:
-                    accs.append((col.hi, col.lo))
-                    idx += 1
-                    continue
-                data = col.data
-                if fname == "has":
-                    data = data.astype(jnp.bool_) & col.validity
-                accs.append(data)
-                idx += 1
-        return keys, accs, live
+            idx = _read_state_accs(_device_fields(spec), batch, idx, accs)
+        return keys, accs, batch.row_mask()
 
     def _passthrough_batch(self, keys, accs, live, num_rows) -> DeviceBatch:
         """One input batch re-expressed in partial-state layout without
@@ -1952,7 +1981,7 @@ class AggOp(PhysicalOp):
     def combine_fold_reason(self) -> Optional[str]:
         """None when this agg can fold into a shuffle-split program as a
         map-side combine, else why not (explain/telemetry vocabulary)."""
-        if self.mode != "partial":
+        if self.mode != "partial" or not self.from_rows:
             return "not_partial"
         if not self.group_exprs:
             return "no_group_keys"
@@ -2003,7 +2032,8 @@ class AggOp(PhysicalOp):
         def apply(batch: DeviceBatch):
             ectx = EvalContext()
             keys, accs, live = _contribution_columns(
-                group_exprs, "partial", aggs, specs, batch, in_schema, ectx)
+                group_exprs, (None,) * len(aggs), aggs, specs, batch,
+                in_schema, ectx)
             rows_in = jnp.sum(live.astype(jnp.int32))
             if mode != "combine":
                 return (_passthrough_state_batch(keys, accs, live,
@@ -2046,8 +2076,7 @@ class AggOp(PhysicalOp):
     def _dense_dispatch(self, ctx: ExecContext):
         """Consult the kernel-selection policy (kernels/dispatch.py).
         Returns a dense KernelDecision, or None for the sort path."""
-        if self.key_domain is None or self.mode not in ("partial",
-                                                        "complete"):
+        if self.key_domain is None or not self.from_rows:
             return None
         from auron_tpu.kernels import dispatch as kdispatch
         in_schema = self.child.schema()
@@ -2249,7 +2278,8 @@ class AggOp(PhysicalOp):
         # adaptive partial-agg skipping: only meaningful for keyed partial
         # stages with pure device accumulators (host-side bloom/udaf state
         # cannot pass through row-wise)
-        skip_enabled = (self.mode == "partial" and bool(self.group_exprs)
+        skip_enabled = (self.mode == "partial" and self.from_rows
+                        and bool(self.group_exprs)
                         and conf.get(cfg.AGG_PARTIAL_SKIP_ENABLED))
         skip_ratio = conf.get(cfg.AGG_PARTIAL_SKIP_RATIO)
         skip_min_rows = conf.get(cfg.AGG_PARTIAL_SKIP_MIN_ROWS)
@@ -2275,7 +2305,7 @@ class AggOp(PhysicalOp):
                         yield self._passthrough_batch(keys, accs, live,
                                                       batch.num_rows)
                         continue
-                    if self.mode == "final":
+                    if self.mode in _READS_STATE:
                         host.merge_partial(batch)
                     else:
                         host.update(batch, ectx)
@@ -2354,7 +2384,10 @@ class AggOp(PhysicalOp):
                 if consumer is not None:
                     consumer.close()
 
-        return count_output(stream(), metrics)
+        return count_output(
+            stream(), metrics,
+            also=(("agg_distinct_groups", 1),) if self.feeds_distinct
+            else ())
 
     def _empty_global(self, host=None) -> DeviceBatch:
         from auron_tpu.columnar.batch import ListColumn
@@ -2385,6 +2418,11 @@ class AggOp(PhysicalOp):
                 cols.append(StringColumn(jnp.zeros((1, 1), jnp.uint8),
                                          jnp.zeros(1, jnp.int32),
                                          jnp.zeros(1, bool)))
+            elif dt == DataType.DECIMAL and spec.result[1] > 18:
+                from auron_tpu.columnar.decimal128 import Decimal128Column
+                cols.append(Decimal128Column(jnp.zeros(1, jnp.int64),
+                                             jnp.zeros(1, jnp.int64),
+                                             jnp.zeros(1, bool)))
             else:
                 jdt = _JNPT[dt]
                 cols.append(PrimitiveColumn(jnp.zeros(1, jdt),

@@ -657,6 +657,93 @@ class HashJoinOp(PhysicalOp):
         return f"HashJoinOp[{self.join_type}, {len(self.probe_keys)} keys]"
 
 
+#: the most output slots one cross-join program fills; a build side too
+#: long for it is taken in slices, a program each
+_CROSS_MAX_SLOTS = 1 << 22
+
+
+@program_cache("ops.joins.cross", maxsize=64)
+def _cross_program(probe_cap: int, build_cap: int, chunk: int):
+    """Every live row of a probe batch beside the (at most ``chunk``)
+    build rows from row ``offset`` on, probe row by probe row: with w
+    build rows in the slice, slot t holds probe row t // w and build row
+    offset + t % w, so the live slots are the first rows x w and nothing
+    is compacted. One program a (probe capacity, build capacity, slice)."""
+    out_cap = bucket_rows(probe_cap * chunk)
+
+    @jax.jit
+    def auron_ops_joins_cross(probe: DeviceBatch, build: DeviceBatch,
+                              offset):
+        # the operator asks for no slice past the build side's last row
+        w = jnp.minimum(jnp.asarray(build.num_rows, jnp.int32) - offset,
+                        chunk)
+        n_out = jnp.asarray(probe.num_rows, jnp.int32) * w
+        slots = jnp.arange(out_cap, dtype=jnp.int32)
+        live = slots < n_out
+        probe_idx = jnp.minimum(slots // w, probe_cap - 1)
+        build_idx = jnp.minimum(offset + slots % w, build_cap - 1)
+        return DeviceBatch(_take_cols(probe.columns, probe_idx, live)
+                           + _take_cols(build.columns, build_idx, live),
+                           n_out)
+
+    return auron_ops_joins_cross
+
+
+class CrossJoinOp(PhysicalOp):
+    """The inner join without keys and without a condition (Spark's
+    BroadcastNestedLoopJoinExec BuildRight Inner, CartesianProductExec):
+    the build side is collected whole, as a hash join's is, and every
+    probe batch leaves beside every row of it, probe row by probe row."""
+
+    name = "cross_join"
+
+    def __init__(self, probe: PhysicalOp, build: PhysicalOp):
+        self.probe = probe
+        self.build = build
+        self._schema = Schema(tuple(probe.schema().fields)
+                              + tuple(build.schema().fields))
+
+    @property
+    def children(self):
+        return [self.probe, self.build]
+
+    def schema(self) -> Schema:
+        return self._schema
+
+    def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
+        metrics = ctx.metrics_for(self)
+        elapsed = metrics.counter("elapsed_compute")
+
+        def stream():
+            with timer(metrics.counter("build_hash_map_time")):
+                batches = []
+                for b in self.build.execute(partition, ctx):
+                    ctx.checkpoint("join.build")
+                    batches.append(b)
+                if not batches:
+                    return        # no build row, no pair
+                build = _concat_all(batches) if len(batches) > 1 \
+                    else batches[0]
+                n_build = _profile.row_count(build)
+            if n_build == 0:
+                return
+            for probe in self.probe.execute(partition, ctx):
+                ctx.check_cancelled()
+                chunk = max(1, min(n_build,
+                                   _CROSS_MAX_SLOTS // probe.capacity))
+                kern = _cross_program(probe.capacity, build.capacity, chunk)
+                for offset in range(0, n_build, chunk):
+                    with timer(elapsed) as t:
+                        out = t.track(kern(probe, build, np.int32(offset)))
+                    yield out
+
+        return count_output(stream(), metrics,
+                            also=(("cross_join_rows", 1),))
+
+    def __repr__(self):
+        return "CrossJoinOp[inner]"
+
+
 def _null_column_like(col, cap):
     from auron_tpu.columnar.decimal128 import Decimal128Column
     if isinstance(col, StringColumn):

@@ -671,13 +671,14 @@ class TestWideDecimalAgg:
 
 
 class TestWideDistinctRewrite:
-    """count/sum/avg DISTINCT over decimal(p>18) via the frontend's regroup
-    rewrite (GroupedData._rewrite_wide_distinct): inner agg on
-    (keys, arg) dedupes the two-limb values with the wide group-key
-    machinery, then the plain wide aggregate runs over the deduped rows.
-    Reference semantics: Spark plans distinct aggregates as a regroup the
-    same way; the AggOp-level fail-fast (test above) still guards the
-    direct-proto path."""
+    """count/sum/avg DISTINCT over decimal(p>18) via the frontend's
+    single-DISTINCT plan (GroupedData._plan_one_distinct, PR 44; the
+    decimal-only rewrite it replaced, _rewrite_wide_distinct, was a
+    special case of it): aggregates on (keys, arg) dedupe the two-limb
+    values with the wide group-key machinery, then the plain wide
+    aggregate starts over the deduped column. Spark plans a distinct
+    aggregate the same way; the AggOp-level fail-fast (test above) still
+    guards the direct-proto path."""
 
     def _frame(self, seed=7, n=200, n_groups=4):
         import pyarrow as pa
@@ -759,28 +760,39 @@ class TestWideDistinctRewrite:
         assert row["a"] == (sum(dset) / len(dset)).quantize(
             decimal.Decimal(1).scaleb(-6), rounding=decimal.ROUND_HALF_UP)
 
-    def test_mixed_and_differing_args_fail_fast(self):
+    def test_a_mix_over_one_argument_answers_and_differing_args_fail(self):
+        """PR 44: decimal DISTINCT beside plain functions of the same or
+        another column is Spark's four aggregates now (this mix raised
+        NotImplementedError before); DISTINCT over two arguments is
+        Spark's Expand plan, and still refused."""
         import pyarrow as pa
         from auron_tpu.frontend.session import Session
         from auron_tpu.frontend.dataframe import functions as F, col
-        tbl = pa.table({"g": pa.array([0], pa.int64()),
-                        "d": pa.array([decimal.Decimal("1.00")],
-                                      pa.decimal128(25, 2)),
-                        "e": pa.array([decimal.Decimal("2.00")],
-                                      pa.decimal128(25, 2))})
+        D = decimal.Decimal
+        tbl = pa.table({"g": pa.array([0, 0, 0, 1], pa.int64()),
+                        "d": pa.array([D("1.00"), D("1.00"), D("2.50"),
+                                       None], pa.decimal128(25, 2)),
+                        "e": pa.array([D("2.00"), D("4.00"), None,
+                                       D("6.00")], pa.decimal128(25, 2))})
         s = Session(batch_capacity=16)
         df = s.from_arrow(tbl)
-        with pytest.raises(NotImplementedError, match="mixed"):
-            df.group_by("g").agg(F.sum(col("d"), distinct=True),
-                                 F.count(col("d")))
+        out = s.execute(df.group_by("g").agg(
+            F.sum(col("d"), distinct=True).alias("sd"),
+            F.count(col("d")).alias("c"),
+            F.sum(col("e")).alias("se")))
+        fs = {f.name: str(f.type) for f in out.schema}
+        assert fs["sd"] == fs["se"] == "decimal128(35, 2)", fs
+        rows = {r["g"]: r for r in out.to_pylist()}
+        assert rows[0] == {"g": 0, "sd": D("3.50"), "c": 3, "se": D("6.00")}
+        assert rows[1] == {"g": 1, "sd": None, "c": 0, "se": D("6.00")}
         with pytest.raises(NotImplementedError, match="one argument"):
             df.group_by("g").agg(F.sum(col("d"), distinct=True),
                                  F.count(col("e"), distinct=True))
 
-    def test_narrow_count_distinct_mixed_stays_on_set_path(self):
-        """Review finding: count-distinct over NARROW decimal mixed with
-        other aggregates must keep working via the set accumulator (the
-        regroup is only forced when the set path cannot serve)."""
+    def test_narrow_count_distinct_mixed_with_count_star(self):
+        """count-distinct over NARROW decimal mixed with other aggregates
+        (review finding of the rewrite this replaced: it must keep
+        working): one regroup plan, like every single-DISTINCT mix."""
         import pyarrow as pa
         from auron_tpu.frontend.session import Session
         from auron_tpu.frontend.dataframe import functions as F, col

@@ -256,3 +256,110 @@ class TestVersionShims:
             sh = SparkShims(v)
             assert sh.is_transparent_plan("CustomShuffleReaderExec")
             assert sh.is_transparent_plan("AQEShuffleReadExec")
+
+
+def _q28_band(files, ids, lo, hi, tag):
+    """One derived table of TPC-DS q28 as Spark's physical plan: Filter
+    into the four HashAggregates of ``planAggregateWithOneDistinct``
+    (Partial; PartialMerge; PartialMerge + Partial over the deduplicated
+    column in ONE node; Final), a hash exchange on the price and a gather
+    between them. ``ids`` numbers this band's expression ids."""
+    from spark_fixture_builder import (agg_expr, alias, attr, binop,
+                                       file_scan, filter_, hash_agg,
+                                       hash_partitioning, lit,
+                                       shuffle_exchange, single_partition)
+    qty = attr("ss_quantity", ids, "long")
+    price = attr("ss_list_price", ids + 1, "decimal(7,2)")
+    band = filter_(
+        binop("And",
+              binop("GreaterThanOrEqual", qty, lit(lo, "long")),
+              binop("LessThanOrEqual", qty, lit(hi, "long"))),
+        file_scan([qty, price], files))
+    avg_t, cnt_t = "decimal(11,6)", "long"
+
+    def plain(mode):
+        return [agg_expr("Average", price, mode, ids + 2, avg_t),
+                agg_expr("Count", price, mode, ids + 3, cnt_t)]
+
+    def counted(mode):   # Spark keeps isDistinct on these, for show
+        return agg_expr("Count", price, mode, ids + 4, cnt_t, distinct=True)
+
+    first = hash_agg([price], plain("Partial"), [], band)
+    second = hash_agg([price], plain("PartialMerge"), [],
+                      shuffle_exchange(hash_partitioning([price], 4), first))
+    third = hash_agg([], plain("PartialMerge") + [counted("Partial")], [],
+                     second)
+    results = [alias(attr("avg", ids + 2, avg_t), f"{tag}_LP", ids + 5),
+               alias(attr("count", ids + 3, cnt_t), f"{tag}_CNT", ids + 6),
+               alias(attr("count", ids + 4, cnt_t), f"{tag}_CNTD", ids + 7)]
+    return hash_agg([], plain("Final") + [counted("Final")], results,
+                    shuffle_exchange(single_partition(), third))
+
+
+def test_a_single_distinct_plan_converts_and_answers(tmp_path):
+    """Two bands of q28 as Spark plans them, joined by a
+    BroadcastNestedLoopJoin without a condition: ``PartialMerge``, the
+    node whose functions differ in mode, and the join without keys all
+    convert (no fallback boundary), and the answer is exact."""
+    import decimal
+
+    import pyarrow.parquet as pq
+    from spark_fixture_builder import SPARK_EXEC, T, broadcast_exchange
+    rng = np.random.default_rng(44)
+    files, rows = [], []
+    for i in range(4):
+        qty = rng.integers(1, 13, 60)
+        cents = rng.integers(100, 160, 60)
+        files.append(str(tmp_path / f"store_sales_{i}.parquet"))
+        pq.write_table(pa.table({
+            "ss_quantity": pa.array(qty, pa.int64()),
+            "ss_list_price": pa.array(
+                [decimal.Decimal(int(c)).scaleb(-2) for c in cents],
+                pa.decimal128(7, 2))}), files[-1])
+        rows += list(zip(qty.tolist(), cents.tolist()))
+    join = T(f"{SPARK_EXEC}.joins.BroadcastNestedLoopJoinExec",
+             [_q28_band(files, 100, 0, 5, "B1"),
+              broadcast_exchange(_q28_band(files, 200, 6, 10, "B2"))],
+             buildSide={"object": "org.apache.spark.sql.catalyst."
+                        "optimizer.BuildRight$"},
+             joinType={"object": "org.apache.spark.sql.catalyst.plans."
+                       "Inner$"},
+             condition=None)
+    conv = SparkPlanConverter()
+    node, report = conv.convert(join.flatten())
+    assert not report.never_converted, report.summary()
+    tagged = [c for c, ok, _ in report.tags if ok]
+    assert tagged.count("HashAggregateExec") == 8
+    assert tagged.count("BroadcastNestedLoopJoinExec") == 1
+    assert node.WhichOneof("node") == "cross_join"
+    # the third aggregate: one node, the count of the deduplicated column
+    # in a mode of its own and plain by now
+    third = node.cross_join.probe.project.child.agg.child \
+        .shuffle_writer.child.agg
+    assert third.mode == "partial_merge"
+    assert [(f.fn, f.mode, f.distinct) for f in third.aggs] == [
+        ("avg", "", False), ("count", "", False),
+        ("count", "partial", False)]
+    assert third.child.agg.mode == "partial_merge"
+
+    names = [f"B{b}_{c}" for b in (1, 2) for c in ("LP", "CNT", "CNTD")]
+    got = _execute(node, PlannerContext(), names).to_pylist()
+    want = {}
+    for tag, lo, hi in (("B1", 0, 5), ("B2", 6, 10)):
+        cents = [c for q, c in rows if lo <= q <= hi]
+        want[f"{tag}_LP"] = (decimal.Decimal(sum(cents)).scaleb(-2)
+                             / len(cents)).quantize(
+            decimal.Decimal("0.000001"), rounding=decimal.ROUND_HALF_UP)
+        want[f"{tag}_CNT"] = len(cents)
+        want[f"{tag}_CNTD"] = len(set(cents))
+    assert got == [want]
+
+    # an outer type or a condition stays a fallback boundary
+    for field, value in (("joinType", {"object": "org.apache.spark.sql."
+                                       "catalyst.plans.LeftOuter$"}),
+                         ("condition", [{"class": "x", "num-children": 0}])):
+        bad = T(join.cls, join.children, **{**join.fields, field: value,
+                                            "output": []})
+        conv = SparkPlanConverter()
+        with pytest.raises(NotImplementedError, match="nested-loop join"):
+            conv.convert(bad.flatten())

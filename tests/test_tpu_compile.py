@@ -333,3 +333,67 @@ def test_the_decimal_division_compiles_for_the_chip(one_chip, left, right):
     frag, compiled = _compile_fragment(one_chip, op, spec, 4096)
     assert compiled is not None
     assert frag.row_counts == (("decimal_div_rows", 1),)
+
+
+def _on_chip(one_chip, tree):
+    """The shapes of ``tree`` (made by ``jax.eval_shape``) placed on the
+    described chip."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+
+
+@pytest.mark.parametrize("rows, slots", [
+    # q28's first aggregate: a full scan batch, ~800 of its rows live,
+    # into the table at its grown capacity (half a minute to compile)
+    (1 << 16, 16384),
+    # its second (partial_merge): the first's ~6,500 groups in one batch
+    (8192, 16384),
+], ids=["scan_batch_into_16384", "partial_merge_of_8192"])
+def test_the_distinct_regroup_compiles_for_the_chip(one_chip, rows, slots):
+    """The keyed aggregates of a single-DISTINCT plan (PR 44) on the
+    chip's hash table: the group key is the DISTINCT argument itself, a
+    decimal(7,2) held as one int64 word, beside avg's sum and count and
+    count's count; ``partial`` and ``partial_merge`` run the SAME step
+    program (the latter's contributions are state columns read as they
+    come). And the table's export, an argsort of its slots."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.hashtable import agg as ht_agg, core
+    keys = _columns(one_chip, rows, ("int64",))
+    key_meta = core.key_meta(keys)
+    acc_meta = (("sum", "int64"),) * 3
+    words = core.total_words(key_meta)
+    state = _on_chip(one_chip, jax.eval_shape(lambda: (
+        jnp.full(slots, core.EMPTY, jnp.uint64),
+        jnp.zeros((slots, words), jnp.uint64),
+        core.empty_store(key_meta, slots),
+        *core.init_accs(acc_meta, slots))))
+    th, tw, store, accs, auxs = state
+    contribs = tuple(jax.ShapeDtypeStruct((rows,), jnp.int64,
+                                          sharding=one_chip)
+                     for _ in acc_meta)
+    live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    base = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    step = ht_agg._agg_step_kernel(key_meta, acc_meta, rows, slots, 64)
+    assert step.lower(th, tw, store, accs, auxs, keys, contribs, live,
+                      base).compile() is not None
+    export = ht_agg._export_kernel(key_meta, acc_meta, slots)
+    assert export.lower(th, store, accs).compile() is not None
+
+
+def test_the_cross_join_compiles_for_the_chip(one_chip):
+    """The join without keys (PR 44) as the one program a probe batch: a
+    full batch of 65,536 rows beside a one-row build side (q28's answers
+    are one row each, at the capacity their aggregation left them)."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar.batch import DeviceBatch
+    from auron_tpu.ops import joins
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    layout = ("int64", "int64", "int64")       # an average and two counts
+    probe = DeviceBatch(_columns(one_chip, 1 << 16, layout), scalar)
+    build = DeviceBatch(_columns(one_chip, 4096, layout), scalar)
+    cross = joins._cross_program(1 << 16, 4096, 1)
+    assert cross.lower(probe, build, scalar).compile() is not None
