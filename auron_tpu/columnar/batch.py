@@ -21,10 +21,12 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 
 from auron_tpu.columnar.decimal128 import Decimal128Column
-from typing import Sequence, Union
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+
+from auron_tpu.runtime.programs import program_cache
 
 
 @jax.tree_util.register_dataclass
@@ -624,6 +626,69 @@ def resize(batch: DeviceBatch, new_capacity: int) -> DeviceBatch:
         return PrimitiveColumn(data=c.data[:new_capacity], validity=c.validity[:new_capacity])
 
     return DeviceBatch(tuple(resize_col(c) for c in batch.columns), batch.num_rows)
+
+
+#: the shrink rule's two constants (``shrink_target``): the rungs a
+#: capacity may be cut to, as divisors of it — functions of the capacity
+#: alone, never of a row count's power of two, so a stream whose counts
+#: wander compiles for at most three shapes a capacity — and the least
+#: lanes a cut must save: a launch is ~0.5 ms of the host and a lane of
+#: a keyed update ~0.25-0.5 us of the chip, so under a few thousand
+#: lanes saved the launch costs more than it saves (PERF.md section 6,
+#: PR 45)
+SHRINK_RUNGS = (64, 8)
+SHRINK_MIN_LANES = 16_384
+
+
+def shrink_target(capacity: int, n: int) -> Optional[int]:
+    """The capacity a batch of ``capacity`` slots and ``n`` live rows is
+    handed on at, or None where it keeps its own: the smaller of
+    ``capacity // 64`` and ``capacity // 8`` that holds ``n``, where
+    that saves ``SHRINK_MIN_LANES``. 65,536 -> 1,024 or 8,192; 32,768
+    -> 512 or 4,096; nothing at or under 16,384."""
+    for div in SHRINK_RUNGS:
+        target = capacity // div
+        if n <= target and capacity - target >= SHRINK_MIN_LANES:
+            return target
+    return None
+
+
+def leaf_layout(columns) -> tuple:
+    """A column tree's kinds, widths and dtypes without its capacity:
+    hashable, the part of a program's key that stands for the schema
+    where the program reads no field of one."""
+    leaves, treedef = jax.tree_util.tree_flatten(columns)
+    return treedef, tuple((leaf.shape[1:], leaf.dtype) for leaf in leaves)
+
+
+@program_cache("columnar.batch.shrink", maxsize=256)
+def _shrink_kernel(layout: tuple, capacity: int, target: int):
+    """One program a (leaf layout, capacity, target): the prefix
+    ``[:target]`` of every leaf, whatever the column kinds — every leaf
+    of every kind leads with the capacity."""
+
+    @jax.jit
+    def auron_columnar_batch_shrink(batch: DeviceBatch):
+        cols = jax.tree_util.tree_map(lambda leaf: leaf[:target],
+                                      batch.columns)
+        return DeviceBatch(cols, jnp.asarray(batch.num_rows, jnp.int32))
+
+    return auron_columnar_batch_shrink
+
+
+def shrink(batch: DeviceBatch, n: int) -> DeviceBatch:
+    """``batch`` at the capacity ``shrink_target`` gives its ``n`` live
+    rows (the caller has read them: ``ops/base.count_output``), or
+    ``batch`` itself where the rule keeps it. The live rows of a batch
+    are a prefix of it, so a prefix is the whole answer: ONE launch of
+    ``columnar.batch.shrink``, no readback, ``num_rows`` handed through
+    on the device. (``resize`` called eagerly would be one launch a
+    leaf.)"""
+    target = shrink_target(batch.capacity, n)
+    if target is None:
+        return batch
+    return _shrink_kernel(leaf_layout(batch.columns), batch.capacity,
+                          target)(batch)
 
 
 def concat_batches(a: DeviceBatch, b: DeviceBatch) -> DeviceBatch:

@@ -532,7 +532,13 @@ COUNT_KEYS = ("program_calls", "readbacks",
               # keyed partial_merge aggregation whose parent counts one
               # of its group columns (the distinct (keys, argument)
               # pairs), and the rows that left a join without keys
-              "agg_distinct_groups", "cross_join_rows")
+              "agg_distinct_groups", "cross_join_rows",
+              # batches a filter (or the fused stage holding one) handed
+              # on at a capacity cut to their live rows (PR 45:
+              # ``ops/base.count_output(shrink=True)``, one
+              # ``columnar.batch.shrink`` launch each), and the lanes
+              # that went (capacity - target, summed)
+              "batch_shrinks", "batch_shrink_lanes")
 
 _ANNOTATION = None
 

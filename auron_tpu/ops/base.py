@@ -17,7 +17,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
 
-from auron_tpu.columnar.batch import DeviceBatch
+from auron_tpu.columnar.batch import DeviceBatch, shrink as shrink_batch
 from auron_tpu.columnar.schema import Schema
 from auron_tpu.obs import profile as _profile
 from auron_tpu.obs import trace as _trace
@@ -340,6 +340,13 @@ class PhysicalOp:
     #: projection); the fusion pass bounds the product along a chain.
     fusion_fanout: int = 1
 
+    #: does this op drop rows and hand its batch on at its INPUT's
+    #: capacity (the filters)? Its ``count_output`` — and that of the
+    #: fused stage that holds it — then asks for the batch to leave at
+    #: the capacity its live rows need. Operators that size their own
+    #: output (join, aggregation, sort) stay False.
+    drops_rows: bool = False
+
     #: does this op's fragment do real device compute? Pass-through
     #: fragments (limit's num_rows rewrite, rename's identity) are False:
     #: a stage made ONLY of those would compile a program for work the
@@ -408,8 +415,15 @@ def yields_owned_batches(op: PhysicalOp) -> bool:
 
 
 def count_output(stream, metrics: MetricsSet, timed: bool = False,
-                 also: tuple = ()):
+                 also: tuple = (), shrink: bool = False):
     """Wrap a batch stream with output_rows/output_batches counting.
+
+    ``shrink=True`` — asked by the operators that drop rows and keep
+    their input's capacity (``PhysicalOp.drops_rows``) — hands a batch
+    on at the capacity that fits its live rows where
+    ``columnar/batch.shrink_target`` says so, from the row count read
+    here anyway: one ``columnar.batch.shrink`` launch, no new read;
+    counted as ``batch_shrinks`` / ``batch_shrink_lanes``.
 
     ``also`` ((count key, factor), ...) are counts of the task's ledger
     (``obs/trace.COUNT_KEYS``) that grow by ``factor`` x the rows of
@@ -448,6 +462,13 @@ def count_output(stream, metrics: MetricsSet, timed: bool = False,
                 batches.add(1)
                 for key, factor in also:
                     _trace.count(key, factor * n)
+                if shrink:
+                    small = shrink_batch(b, n)
+                    if small is not b:
+                        _trace.count("batch_shrinks")
+                        _trace.count("batch_shrink_lanes",
+                                     b.capacity - small.capacity)
+                        b = small
         if b is None:
             return
         yield b

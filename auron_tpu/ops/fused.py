@@ -186,6 +186,10 @@ class FusedStageOp(PhysicalOp):
             return True
         return "inherit"
 
+    @property
+    def drops_rows(self):
+        return any(m.drops_rows for m in self.members)
+
     def schema(self) -> Schema:
         return self._schema
 
@@ -273,7 +277,8 @@ class FusedStageOp(PhysicalOp):
                     self.input.execute(partition, ctx,
                                        _consumer=(self, fragments,
                                                   frag_keys)),
-                    metrics, also=self.row_counts(fragments))
+                    metrics, also=self.row_counts(fragments),
+                    shrink=self.drops_rows)
         elapsed = metrics.counter("elapsed_compute")
         kmetrics = ctx.metrics_for("kernels")
         built_c = kmetrics.counter("fused_stage_programs_built")
@@ -317,7 +322,8 @@ class FusedStageOp(PhysicalOp):
                     break
 
         return count_output(stream(), metrics,
-                            also=self.row_counts(fragments))
+                            also=self.row_counts(fragments),
+                            shrink=self.drops_rows)
 
     def __repr__(self):
         inner = " -> ".join(repr(m) for m in self.members)

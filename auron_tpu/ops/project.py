@@ -150,6 +150,7 @@ class FilterOp(PhysicalOp):
     name = "filter"
     fusable = True
     fragment_computes = True
+    drops_rows = True
 
     def __init__(self, child: PhysicalOp, predicates: list[ir.Expr]):
         self.child = child
@@ -197,7 +198,7 @@ class FilterOp(PhysicalOp):
 
         return count_output(
             stream(), metrics,
-            also=division_counts(self.predicates, in_schema))
+            also=division_counts(self.predicates, in_schema), shrink=True)
 
     def __repr__(self):
         return f"FilterOp[{len(self.predicates)} predicates]"
@@ -209,6 +210,7 @@ class FilterProjectOp(PhysicalOp):
     name = "filter_project"
     fusable = True
     fragment_computes = True
+    drops_rows = True
 
     def __init__(self, child: PhysicalOp, predicates: list[ir.Expr],
                  exprs: list[ir.Expr], names: list[str]):
@@ -269,7 +271,8 @@ class FilterProjectOp(PhysicalOp):
 
         return count_output(
             stream(), metrics,
-            also=division_counts(self.predicates + self.exprs, in_schema))
+            also=division_counts(self.predicates + self.exprs, in_schema),
+            shrink=True)
 
     def __repr__(self):
         return f"FilterProjectOp[{len(self.predicates)} predicates -> {', '.join(self.names)}]"

@@ -350,7 +350,11 @@ def _on_chip(one_chip, tree):
     (1 << 16, 16384),
     # its second (partial_merge): the first's ~6,500 groups in one batch
     (8192, 16384),
-], ids=["scan_batch_into_16384", "partial_merge_of_8192"])
+    # its first since PR 45: the filtered scan batch arrives cut to its
+    # live bucket (65,536 -> 1,024), the table at its first capacity
+    (1024, 4096),
+], ids=["scan_batch_into_16384", "partial_merge_of_8192",
+        "shrunk_scan_batch_into_4096"])
 def test_the_distinct_regroup_compiles_for_the_chip(one_chip, rows, slots):
     """The keyed aggregates of a single-DISTINCT plan (PR 44) on the
     chip's hash table: the group key is the DISTINCT argument itself, a
@@ -397,3 +401,20 @@ def test_the_cross_join_compiles_for_the_chip(one_chip):
     build = DeviceBatch(_columns(one_chip, 4096, layout), scalar)
     cross = joins._cross_program(1 << 16, 4096, 1)
     assert cross.lower(probe, build, scalar).compile() is not None
+
+
+def test_the_batch_shrink_compiles_for_the_chip(one_chip):
+    """A filtered scan batch handed on at its live bucket (PR 45): the
+    prefix of every leaf as ONE program, 65,536 -> 1,024 — q28's
+    quantity and its three decimal(7,2) money words, beside a two-limb
+    decimal and a string."""
+    import jax
+    import jax.numpy as jnp
+    from auron_tpu.columnar import batch as cb
+    layout = ("int64",) * 4 + ("decimal128", 32)
+    batch = cb.DeviceBatch(
+        _columns(one_chip, 1 << 16, layout),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    assert cb.shrink_target(1 << 16, 790) == 1024
+    kern = cb._shrink_kernel(cb.leaf_layout(batch.columns), 1 << 16, 1024)
+    assert kern.lower(batch).compile() is not None
