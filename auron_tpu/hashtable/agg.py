@@ -2,10 +2,13 @@
 
 ``HashAggState`` is the open-addressing replacement for the sort path's
 incremental (batch-sort + searchsorted-merge) state: every batch runs ONE
-fused program — hash keys, insert (vectorized probe rounds), scatter the
-batch's accumulator contributions into the owning slots — and the O(S)
-state pass disappears entirely (the table IS the state; nothing re-sorts
-per batch). This is the reference AggTable's update loop
+fused program — the caller's evaluation of the batch's group keys and
+contributions (its ``front``, traced here), hash keys, insert (vectorized
+probe rounds), scatter the contributions into the owning slots — and the
+O(S) state pass disappears entirely (the table IS the state; nothing
+re-sorts per batch). The table's set-up is one program too
+(``hashtable.agg_init``); nothing here touches a device array outside a
+program of runtime/programs.py. This is the reference AggTable's update loop
 (datafusion-ext-plans/src/agg/agg_table.rs:68-356) with the row-at-a-time
 probe replaced by ``hashtable.core``'s lock-step rounds.
 
@@ -17,13 +20,14 @@ their slots). Pathological repeat overflow — adversarial hash collisions,
 not load — raises ``HashTableOverflow``, which the operator catches to
 fall back to the sort path mid-stream without losing state.
 
-``to_sorted_table()`` exports the slots as the agg path's canonical
-hash-sorted 5-tuple ``(keys, accs, num_groups, cap, hashes)`` — occupied
-slots sorted by hash ascending, dead slots carrying the shared sentinel
-last — so emit, spill (``memmgr`` bucket spills rely on the hash-sorted
-run invariant), and the partial-skip decision reuse the existing
-machinery unchanged, and hash-vs-sort results stay bit-identical down to
-group output order.
+``export_slots`` hands the slots on as the agg path's canonical
+hash-sorted ``(keys, accs, num_groups, hashes)`` — occupied slots sorted
+by hash ascending, dead slots carrying the shared sentinel last — so
+emit, spill (``memmgr`` bucket spills rely on the hash-sorted run
+invariant), and the partial-skip decision reuse the existing machinery
+unchanged, and hash-vs-sort results stay bit-identical down to group
+output order: traced inside the operator's emit program, or as the
+program of its own behind ``to_sorted_table()``.
 """
 
 from __future__ import annotations
@@ -57,14 +61,46 @@ def _hashes(keys, cap: int) -> jax.Array:
     return core.remap_hashes(h)
 
 
-@program_cache("hashtable.agg_step", maxsize=128)
-def _agg_step_kernel(key_meta: tuple, acc_meta: tuple, n: int, cap: int,
-                     rounds: int):
-    """One fused program per (key codec, acc layout, batch/table shape):
-    hash + insert + store winners + scatter accumulator contributions."""
+@program_cache("hashtable.agg_init", maxsize=128)
+def _init_kernel(key_meta: tuple, acc_meta: tuple, cap: int):
+    """The empty table — hashes, equality words, key store and every
+    accumulator at its neutral — as ONE program (a dozen ``jnp.full`` /
+    ``jnp.zeros`` launches before)."""
+    W = core.total_words(key_meta)
 
     @jax.jit
-    def auron_hashtable_agg_step(th, tw, store, accs, auxs, keys, contribs, live, ord_base):
+    def auron_hashtable_agg_init():
+        accs, auxs = core.init_accs(acc_meta, cap)
+        return (jnp.full(cap, core.EMPTY, jnp.uint64),
+                jnp.zeros((cap, W), jnp.uint64),
+                core.empty_store(key_meta, cap), accs, auxs)
+
+    return auron_hashtable_agg_init
+
+
+@program_cache("hashtable.agg_step", maxsize=256)
+def _agg_step_kernel(front, layout: tuple, n: int, table_meta: tuple,
+                     key_meta: tuple, acc_meta: tuple, cap: int,
+                     rounds: int):
+    """One fused program per (front, input layout and capacity, key
+    codec, acc layout, table shape): the front's evaluation of the
+    batch's group keys and contributions, hash + insert + store winners
+    + scatter accumulator contributions. ``front`` is plan data — a
+    hashable traceable ``front(*operands) -> (keys, contribs, live)``;
+    ``layout`` stands for its operands' shapes. ``table_meta`` is the
+    key codec the table comes in with, ``key_meta`` the one it leaves
+    with: a batch whose strings are wider than the store widens it here,
+    a narrower batch is padded here."""
+    widen = core.string_width_drift(key_meta, table_meta)
+
+    @jax.jit
+    def auron_hashtable_agg_step(th, tw, store, accs, auxs, ord_base,
+                                 *operands):
+        keys, contribs, live = front(*operands)
+        if widen:
+            tw, store, _meta = core.widen_string_store(tw, store,
+                                                       table_meta, widen)
+        keys = _pad_string_keys(keys, key_meta)
         h = _hashes(keys, n)
         w = core.key_words(keys, key_meta)
         claims, slot, resolved = core.insert_loop(th, tw, h, w, live,
@@ -72,7 +108,7 @@ def _agg_step_kernel(key_meta: tuple, acc_meta: tuple, n: int, cap: int,
         th2, tw2 = core.table_install(th, tw, h, w, claims)
         store2 = core.store_install(store, keys, key_meta, claims)
         accs2, auxs2 = core.agg_update(accs, auxs, acc_meta, slot,
-                                       resolved, contribs, ord_base)
+                                       resolved, tuple(contribs), ord_base)
         n_new = jnp.sum(core.batch_owned(claims).astype(jnp.int32))
         overflow = jnp.any(live & ~resolved)
         return th2, tw2, store2, accs2, auxs2, n_new, overflow
@@ -116,23 +152,32 @@ def _grow_kernel(key_meta: tuple, acc_meta: tuple, old_cap: int,
     return auron_hashtable_agg_grow
 
 
+def export_slots(th, store, accs, key_meta: tuple):
+    """Slots -> the hash-sorted group-table layout (dead slots last under
+    the shared sentinel), traced: the handoff that keeps emit / spill /
+    merge invariants — and output group order — identical to the sort
+    path. Returns (key columns, accs, num_groups, hashes)."""
+    from auron_tpu.columnar.batch import gather_column
+    cap = th.shape[0]
+    occupied = th != core.EMPTY
+    ng = jnp.sum(occupied.astype(jnp.int32))
+    perm = jnp.argsort(th, stable=True)     # EMPTY is max: dead last
+    out_valid = jnp.arange(cap, dtype=jnp.int32) < ng
+    cols = tuple(gather_column(c, perm, out_valid)
+                 for c in core.store_columns(store, key_meta))
+    return cols, tuple(a[perm] for a in accs), ng, th[perm]
+
+
 @program_cache("hashtable.agg_export", maxsize=64)
 def _export_kernel(key_meta: tuple, acc_meta: tuple, cap: int):
-    """Slots → the hash-sorted group-table layout (dead slots last under
-    the shared sentinel): the handoff that keeps emit/spill/merge
-    invariants — and output group order — identical to the sort path."""
-    from auron_tpu.columnar.batch import gather_column
+    """``export_slots`` as a program of its own: the table handed on as a
+    sorted STATE (the overflow fall-back's salvage, the partial-skip
+    decision). An operator's emit folds the export into
+    ``ops.agg.emit``."""
 
     @jax.jit
     def auron_hashtable_agg_export(th, store, accs):
-        occupied = th != core.EMPTY
-        ng = jnp.sum(occupied.astype(jnp.int32))
-        perm = jnp.argsort(th, stable=True)     # EMPTY is max: dead last
-        out_valid = jnp.arange(cap, dtype=jnp.int32) < ng
-        cols = tuple(gather_column(c, perm, out_valid)
-                     for c in core.store_columns(store, key_meta))
-        accs_out = tuple(a[perm] for a in accs)
-        return cols, accs_out, ng, th[perm]
+        return export_slots(th, store, accs, key_meta)
 
     return auron_hashtable_agg_export
 
@@ -186,29 +231,13 @@ class HashAggState:
 
     # -- state transitions ---------------------------------------------------
 
-    def _init_arrays(self, keys, contribs) -> None:
-        self.key_meta = core.key_meta(keys)
+    def _init_arrays(self, key_meta: tuple, contribs) -> None:
+        self.key_meta = key_meta
         self.acc_meta = tuple(
             (kind, str(np.dtype(v.dtype)))
             for kind, v in zip(self.kinds, contribs))
-        W = core.total_words(self.key_meta)
-        self.th = jnp.full(self.cap, core.EMPTY, jnp.uint64)
-        self.tw = jnp.zeros((self.cap, W), jnp.uint64)
-        self.store = core.empty_store(self.key_meta, self.cap)
-        self.accs, self.auxs = core.init_accs(self.acc_meta, self.cap)
-
-    def _unify_widths(self, keys):
-        """Reconcile per-batch string width buckets with the store's: pad
-        the narrower side (a wider batch widens the store, rebuilding the
-        word matrix with zero blocks in the new char-word positions)."""
-        meta = core.key_meta(keys)
-        if meta == self.key_meta:
-            return keys
-        widen = core.string_width_drift(meta, self.key_meta)
-        if widen:
-            self.tw, self.store, self.key_meta = core.widen_string_store(
-                self.tw, self.store, self.key_meta, widen)
-        return _pad_string_keys(keys, self.key_meta)
+        (self.th, self.tw, self.store, self.accs,
+         self.auxs) = _init_kernel(self.key_meta, self.acc_meta, self.cap)()
 
     def _grow(self) -> None:
         new_cap = self.cap * 2
@@ -232,24 +261,33 @@ class HashAggState:
             self.cap = new_cap
             return
 
-    def update(self, keys, contribs, live) -> None:
-        """Fold one batch (group-key columns + per-row accumulator
-        contributions + live mask) into the table. One fused program plus
-        one batched scalar readback — the same per-batch host-RTT budget
-        as the sort path's group-count readback."""
-        keys = tuple(keys)
-        contribs = tuple(contribs)
+    def update(self, front, operands: tuple, layout: tuple,
+               shapes) -> None:
+        """Fold one batch into the table: ``front(*operands)`` — the
+        group-key columns, the per-row accumulator contributions and the
+        live mask — is evaluated INSIDE the step's program; ``shapes``
+        is that triple in the abstract (what the host needs of it: the
+        key codec, the contributions' dtypes, the batch's capacity) and
+        ``layout`` the operands' part of the program's key. One fused
+        program plus one batched scalar readback — the same per-batch
+        host-RTT budget as the sort path's group-count readback."""
+        keys, contribs, live = shapes
+        batch_meta = core.key_meta(keys)
         if not self.built:
-            self._init_arrays(keys, contribs)
-        keys = self._unify_widths(keys)
+            self._init_arrays(batch_meta, contribs)
+        # reconcile per-batch string width buckets with the store's: a
+        # wider batch widens the store, a narrower one is padded — both
+        # inside the step
+        key_meta = core.widest_meta(batch_meta, self.key_meta)
         n = int(live.shape[0])
-        ord_base = jnp.asarray(self.rows_seen, jnp.int64)
+        ord_base = np.int64(self.rows_seen)
         while True:
-            kern = _agg_step_kernel(self.key_meta, self.acc_meta, n,
-                                    self.cap, self.rounds)
+            kern = _agg_step_kernel(front, layout, n, self.key_meta,
+                                    key_meta, self.acc_meta, self.cap,
+                                    self.rounds)
             th, tw, store, accs, auxs, n_new, overflow = kern(
                 self.th, self.tw, self.store, self.accs, self.auxs,
-                keys, contribs, live, ord_base)
+                ord_base, *operands)
             # this readback is the per-batch sync point (the wait is
             # attributed as device time). NOTE the donation
             # sweep deliberately skips the step/grow kernels: the
@@ -259,6 +297,7 @@ class HashAggState:
             if not bool(ovf):
                 self.th, self.tw, self.store = th, tw, store
                 self.accs, self.auxs = accs, auxs
+                self.key_meta = key_meta
                 self.count += int(n_new_h)
                 self.rows_seen += n
                 _trace.count("agg_hash_batches")

@@ -253,6 +253,15 @@ def string_width_drift(batch_meta: tuple, table_meta: tuple) -> dict:
     return widen
 
 
+def widest_meta(batch_meta: tuple, table_meta: tuple) -> tuple:
+    """The codec a table of ``table_meta`` holds after a batch of
+    ``batch_meta``: every string column at the wider of the two width
+    buckets."""
+    widen = string_width_drift(batch_meta, table_meta)
+    return tuple(("str", widen[i]) if i in widen else m
+                 for i, m in enumerate(table_meta))
+
+
 # ---------------------------------------------------------------------------
 # install-by-gather (the claims array IS the update)
 # ---------------------------------------------------------------------------

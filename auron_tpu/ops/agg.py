@@ -20,13 +20,25 @@ also gives the sorted-run invariant its bucket spills rely on.
 Aggregate set: sum/count/avg/min/max/first/first_ignores_null (reference:
 datafusion-ext-plans/src/agg/*.rs). Accumulators are flat device columns —
 the AccColumn idea (reference: agg/acc.rs) without the row-format detour.
+
+Everything the operator does to a device array happens inside a program
+handed out by runtime/programs.py (the rule ``hash_join`` and ``sort``
+keep): a batch's group keys and contributions are evaluated inside the
+program that folds them into the state (``hashtable.agg_step``,
+``ops.agg.batch_reduce``; the operator hands it its *front*, plan data),
+a table cut to its occupancy bucket is cut by the program that reads it
+next, and an operator's output — the hash table's export, the cut, every
+function's finalisation — is ONE ``ops.agg.emit``. The host keeps the
+control flow (growth retries, the path's choice, spills) and the
+host-side aggregates (bloom, UDAF). tests/test_agg_launches.py holds the
+budget: no eager launch, B + 2 programs an operator over B batches.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Iterator, Optional
+from functools import lru_cache
+from typing import Iterator, NamedTuple, Optional
 
 import numpy as np
 
@@ -34,7 +46,8 @@ import jax
 import jax.numpy as jnp
 
 from auron_tpu.columnar.batch import (DeviceBatch, PrimitiveColumn, StringColumn,
-                                      gather_column, unify_column_widths)
+                                      gather_column, leaf_layout,
+                                      unify_column_widths)
 from auron_tpu.columnar.schema import DataType, Field, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, TypedValue, evaluate, infer_dtype
@@ -538,21 +551,34 @@ def _reduce_sorted(keys_s, accs_s, live_s, h_s, acc_meta, out_cap):
     return new_keys, tuple(new_accs), h_out, num_groups, tuple(needed_elems)
 
 
+def _cut(tree, cap: int):
+    """The prefix ``[:cap]`` of every leaf of a group table's arrays
+    (every leaf leads with the capacity): a table cut to its occupancy
+    bucket. Live groups are a hash-sorted prefix, so the cut is a plain
+    slice — taken inside the program that reads the table next."""
+    return jax.tree_util.tree_map(lambda x: x[:cap], tree)
+
+
 @program_cache("ops.agg.batch_reduce", maxsize=256)
-def _batch_reduce_kernel(n_keys: int, acc_meta: tuple, cap: int,
+def _batch_reduce_kernel(front, layout: tuple, acc_meta: tuple, cap: int,
                          donate: bool = False):
-    """(keys, accs, live) of one batch → its own group table, hash-sorted.
-    One O(B log B) sort of the BATCH only — the state is never re-sorted
-    (it merges by binary search in _state_merge_kernel). acc_meta: tuple
-    of (kind, out_elems) per state column. Returns (keys, accs, hashes,
-    num_groups, needed_elems). ``donate`` hands the batch's key/acc/live
-    buffers to XLA — they are dead after the reduce when the child owns
-    its batches and no collect kind can force the caller's growth retry
-    (callers gate on exactly that; programs.jit keeps donation off the
-    advisory CPU backend)."""
+    """One batch → its own group table, hash-sorted: the front's
+    evaluation of the batch's group keys and contributions
+    (``front(columns, num_rows, partition_id) -> (keys, accs, live)``:
+    plan data, traced here; ``layout`` stands for the columns' shapes),
+    then one O(B log B) sort of the BATCH only — the state is never
+    re-sorted (it merges by binary search in _state_merge_kernel).
+    acc_meta: tuple of (kind, out_elems) per state column. Returns
+    (keys, accs, hashes, num_groups, needed_elems). ``donate`` hands the
+    batch's columns to XLA — they are dead after the reduce when the
+    child owns its batches and no collect kind can force the caller's
+    growth retry (callers gate on exactly that; programs.jit keeps
+    donation off the advisory CPU backend). The row count is an operand
+    of its own and never donated: the caller may still read it."""
     from auron_tpu.runtime import programs
 
-    def auron_ops_agg_batch_reduce(keys, accs, live):
+    def auron_ops_agg_batch_reduce(columns, num_rows, partition_id):
+        keys, accs, live = front(columns, num_rows, partition_id)
         h = hashing.xxhash64_columns(list(keys), cap).view(jnp.uint64)
         h = jnp.where(live, h, _HASH_SENTINEL)  # dead rows to the end
         perm = jnp.argsort(h, stable=True)
@@ -562,10 +588,10 @@ def _batch_reduce_kernel(n_keys: int, acc_meta: tuple, cap: int,
         accs_s = tuple(_gather_acc(a, perm) for a in accs)
         return _reduce_sorted(keys_s, accs_s, live_s, h[perm], acc_meta, cap)
 
-    # graft: donation-ok -- per-batch contribution temporaries;
-    # collect kinds/aliased leaves force donate=False upstream
+    # graft: donation-ok -- the owned batch's columns; collect
+    # kinds/aliased leaves force donate=False upstream
     return programs.jit(auron_ops_agg_batch_reduce,
-                        donate_argnums=(0, 1, 2) if donate else ())
+                        donate_argnums=(0,) if donate else ())
 
 
 def _scatter_acc(a_s, a_b, pos_s, pos_b, m: int):
@@ -591,11 +617,21 @@ def _state_merge_kernel(n_keys: int, acc_meta: tuple, cap_s: int,
     sides and the shared reduce folds duplicate groups. This is the
     incremental-update contract of the reference's AggTable (reference:
     datafusion-ext-plans/src/agg/agg_table.rs:68-356) with the
-    open-addressing probe replaced by the sorted-merge primitive."""
+    open-addressing probe replaced by the sorted-merge primitive.
+    ``cap_s`` / ``cap_b`` are the tables' occupancy buckets: a table
+    that comes at the capacity of the program that made it is cut to
+    its bucket here."""
 
     @jax.jit
     def auron_ops_agg_state_merge(keys_s, accs_s, h_s, n_s,
                                   keys_b, accs_b, h_b, n_b):
+        keys_s, accs_s, h_s = _cut((keys_s, accs_s, h_s), cap_s)
+        keys_b, accs_b, h_b = _cut((keys_b, accs_b, h_b), cap_b)
+        unified = [unify_column_widths([a, c])
+                   for a, c in zip(keys_s, keys_b)]
+        keys_s = tuple(p[0] for p in unified)
+        keys_b = tuple(p[1] for p in unified)
+        accs_s, accs_b = _unify_acc_pair(accs_s, accs_b)
         live_s = jnp.arange(cap_s, dtype=jnp.int32) < n_s
         live_b = jnp.arange(cap_b, dtype=jnp.int32) < n_b
         # dead slots on both sides hold _HASH_SENTINEL (state invariant +
@@ -653,11 +689,14 @@ def _state_merge_kernel(n_keys: int, acc_meta: tuple, cap_s: int,
 
 def _table_nbytes(tbl) -> int:
     from auron_tpu.columnar.batch import column_nbytes
-    keys, accs, _num_groups, _cap, hashes = tbl
-    return (sum(column_nbytes(k) for k in keys)
+    keys, accs, _num_groups, cap, hashes = tbl
+    held = (sum(column_nbytes(k) for k in keys)
             + hashes.nbytes
             + sum(sum(x.nbytes for x in a) if isinstance(a, tuple)
                   else a.nbytes for a in accs))
+    # a table not yet cut to its occupancy bucket (the program that reads
+    # it next cuts it) is accounted at the bucket
+    return held * cap // hashes.shape[0]
 
 
 def _lvl_nbytes(lvl) -> int:
@@ -889,6 +928,273 @@ def _passthrough_state_batch(keys, accs, live, num_rows) -> DeviceBatch:
         else:
             cols.append(PrimitiveColumn(a, live))
     return DeviceBatch(tuple(cols), num_rows)
+
+
+# ---------------------------------------------------------------------------
+# fronts: a batch → its group keys and contributions, inside the program
+# that consumes them
+# ---------------------------------------------------------------------------
+#
+# Everything the operator does to a device array runs inside a program of
+# runtime/programs.py. What turns an input batch into (keys, accs, live) is
+# therefore PLAN DATA — a hashable callable the consuming program traces:
+# the hash table's step (hashtable.agg_step), the batch reduce of the sort
+# path and of an aggregation without keys (ops.agg.batch_reduce), the
+# partial skip's pass-through. Aggregations with equal expressions over
+# equal column types share their programs.
+
+@dataclass(frozen=True)
+class _RowsFront:
+    """An operator's input batch — rows, or the state columns of the
+    functions that merge — to its group keys and per-row contributions
+    (``_contribution_columns``)."""
+    group_exprs: tuple
+    state_idx: tuple
+    aggs: tuple
+    specs: tuple
+    in_schema: Schema
+
+    def __call__(self, columns, num_rows, partition_id):
+        ctx = EvalContext(partition_id=partition_id, memo={})
+        keys, accs, live = _contribution_columns(
+            self.group_exprs, self.state_idx, self.aggs, self.specs,
+            DeviceBatch(tuple(columns), num_rows), self.in_schema, ctx)
+        return keys, tuple(accs), live
+
+
+@dataclass(frozen=True)
+class _StateFront:
+    """A batch in the operator's OWN state layout — its keys, then every
+    device accumulator — back to contributions: how a spilled run, and a
+    sorted state the hash table takes over, re-enter the merge
+    (associativity of the accumulators makes re-merging exact)."""
+    n_keys: int
+    specs: tuple
+
+    def __call__(self, columns, num_rows, partition_id):
+        batch = DeviceBatch(tuple(columns), num_rows)
+        accs: list = []
+        idx = self.n_keys
+        for spec in self.specs:
+            idx = _read_state_accs(_device_fields(spec), batch, idx, accs)
+        return tuple(columns[:self.n_keys]), tuple(accs), batch.row_mask()
+
+
+class _Input(NamedTuple):
+    """One batch as the merge takes it: ``front(*operands)`` is its
+    (keys, accs, live), evaluated INSIDE the program that folds them into
+    the state; ``layout`` stands for the operands' shapes in that
+    program's key and ``shapes`` is the triple in the abstract — what the
+    host needs of it beforehand (``_front_shapes``)."""
+    front: object
+    operands: tuple
+    layout: tuple
+    shapes: tuple
+
+    @property
+    def capacity(self) -> int:
+        return self.shapes[2].shape[0]
+
+
+@lru_cache(maxsize=512)
+def _front_shapes(front, layout: tuple, capacity: int):
+    """``front``'s (keys, accs, live) over a batch of this leaf layout
+    and capacity, in the abstract: what the host needs of a batch's
+    contributions before the program that evaluates them runs — the key
+    codec and the accumulators' dtypes (the hash table's layout), the
+    collect kinds' element widths (the reduce's). No program and no
+    device array: a memo of one ``jax.eval_shape`` over plan data."""
+    treedef, leaves = layout
+    columns = jax.tree_util.tree_unflatten(
+        treedef, [jax.ShapeDtypeStruct((capacity,) + shape, dtype)
+                  for shape, dtype in leaves])
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    return jax.eval_shape(front, columns, scalar, scalar)
+
+
+@program_cache("ops.agg.passthrough", maxsize=128)
+def _passthrough_kernel(front, layout: tuple, capacity: int):
+    """The partial skip's pass-through of one batch (each row its own
+    group, in state layout) as one program."""
+
+    @jax.jit
+    def auron_ops_agg_passthrough(columns, num_rows, partition_id):
+        keys, accs, live = front(columns, num_rows, partition_id)
+        return _passthrough_state_batch(keys, accs, live, num_rows)
+
+    return auron_ops_agg_passthrough
+
+
+# ---------------------------------------------------------------------------
+# emit: a group table → the operator's output batch, as one program
+# ---------------------------------------------------------------------------
+
+def _host_slots(specs: tuple, as_state: bool) -> list:
+    """(position among the output's aggregate columns, spec index) of
+    every column a host-side accumulator (bloom / udaf) fills: the emit
+    program returns the device columns, these are spliced in after it."""
+    slots, pos = [], 0
+    for si, spec in enumerate(specs):
+        fields = spec.state_fields
+        host = bool(fields) and fields[0][2] in HOST_KINDS
+        if host:
+            slots.append((pos, si))
+        pos += len(fields) if as_state else 1
+    return slots
+
+
+def _finalize_columns(specs: tuple, as_state: bool, accs, valid) -> list:
+    """The aggregate columns of an output batch from a group table's
+    accumulators — in state layout (``partial`` / ``partial_merge``; a
+    spilled run) or finalized (``final`` / ``complete``) — host-side
+    functions left out. Traced: ``ops.agg.emit``."""
+    from auron_tpu.columnar import decimal128 as d128
+    from auron_tpu.columnar.decimal128 import Decimal128Column
+    out_cols = []
+
+    def list_col(a):
+        return _list_column_from_acc(a, valid)
+
+    if as_state:
+        i = 0
+        for spec in specs:
+            for (fname, fdt, kind) in _device_fields(spec):
+                data = accs[i]
+                i += 1
+                if kind in _DCOLLECT:
+                    out_cols.append(_map_carrier_from_dacc(data, valid))
+                elif isinstance(data, tuple) and len(data) == 3:
+                    out_cols.append(StringColumn(
+                        data[0], data[1], data[2] & valid))
+                elif isinstance(data, tuple) and data[0].ndim == 1:
+                    out_cols.append(Decimal128Column(
+                        data[0], data[1], valid))
+                elif isinstance(data, tuple):
+                    out_cols.append(list_col(data))
+                else:
+                    out_cols.append(PrimitiveColumn(data, valid))
+        return out_cols
+
+    # final/complete: finalize each agg
+    i = 0
+    for spec in specs:
+        n_state = len(_device_fields(spec))
+        state_vals = accs[i: i + n_state]
+        i += n_state
+        fn = spec.fn
+        if fn in ("count", "count_star"):
+            out_cols.append(PrimitiveColumn(state_vals[0], valid))
+        elif fn == "sum":
+            s, has = state_vals
+            if isinstance(s, tuple):
+                h, l = s
+                # Spark non-ANSI: overflow beyond the declared
+                # precision nulls the group
+                fits = d128.fits_precision(h, l, spec.result[1])
+                out_cols.append(Decimal128Column(
+                    h, l, valid & has & fits))
+            else:
+                out_cols.append(PrimitiveColumn(s, valid & has))
+        elif fn == "avg":
+            s, cnt = state_vals
+            res_dt = spec.result[0]
+            safe = jnp.maximum(cnt, 1)
+            if isinstance(s, tuple):
+                # two-limb sum at the input scale: shift to the
+                # result scale inside the HALF_UP division; Spark
+                # nulls averages that overflow decimal(38)
+                k = spec.result[2] - spec.state_ps[0][1]
+                qh, ql, fits = d128.avg_pow10_div_half_up(
+                    s[0], s[1], safe, k)
+                out_cols.append(Decimal128Column(
+                    qh, ql, valid & (cnt > 0) & fits))
+            elif res_dt == DataType.DECIMAL:
+                # scaled-int64 sum at the input scale; same
+                # q*10^k + round(r*10^k/count) composition in
+                # int64, overflow past the 18-digit result → null
+                k = spec.result[2] - spec.state_ps[0][1]
+                shift = 10 ** k
+                a = jnp.abs(s)
+                q0 = a // safe
+                rem = a - q0 * safe
+                fits = q0 < 10 ** (18 - k)
+                frac = (2 * rem * shift + safe) // (2 * safe)
+                q = q0 * shift + frac
+                avg = jnp.where(s < 0, -q, q)
+                out_cols.append(PrimitiveColumn(
+                    avg, valid & (cnt > 0) & fits))
+            else:
+                avg = s.astype(jnp.float64) / safe
+                out_cols.append(PrimitiveColumn(
+                    avg, valid & (cnt > 0)))
+        elif fn in ("min", "max", "first", "first_ignores_null"):
+            if len(state_vals) == 1:   # string acc: validity inside
+                chars, lens, sv = state_vals[0]
+                out_cols.append(StringColumn(chars, lens, sv & valid))
+            elif isinstance(state_vals[0], tuple):
+                (h, l), has = state_vals
+                out_cols.append(Decimal128Column(h, l, valid & has))
+            else:
+                v, has = state_vals
+                out_cols.append(PrimitiveColumn(v, valid & has))
+        elif fn in ("collect_list", "collect_set"):
+            # empty list (not null) for groups with only nulls —
+            # Spark's collect_* semantics
+            if spec.state_fields[0][2] in _DCOLLECT:
+                out_cols.append(_map_carrier_from_dacc(
+                    state_vals[0], valid))
+            else:
+                out_cols.append(list_col(state_vals[0]))
+        elif fn in ("count_distinct", "sum_distinct", "avg_distinct"):
+            vals, lens = state_vals[0]  # deduped set per group
+            if fn == "count_distinct":
+                out_cols.append(PrimitiveColumn(
+                    lens.astype(jnp.int64), valid))
+            else:
+                e = vals.shape[1]
+                mask = (jnp.arange(e, dtype=jnp.int32)[None, :]
+                        < lens[:, None])
+                jdt = _JNPT[spec.result[0]]
+                s = jnp.sum(jnp.where(mask, vals, 0),
+                            axis=1).astype(jdt)
+                if fn == "avg_distinct":
+                    s = (s.astype(jnp.float64)
+                         / jnp.maximum(lens, 1))
+                # all-null group: no distinct values → NULL
+                out_cols.append(PrimitiveColumn(s, valid & (lens > 0)))
+        elif spec.state_fields and spec.state_fields[0][2] in HOST_KINDS:
+            continue            # spliced in by the host (_host_slots)
+        else:
+            raise NotImplementedError(fn)
+    return out_cols
+
+
+@program_cache("ops.agg.emit", maxsize=512)
+def _emit_kernel(specs: tuple, as_state: bool, out_cap: int,
+                 table_meta: Optional[tuple]):
+    """A group table → the operator's output batch (or its state as a
+    batch, for a spill and for the hash table's take-over of a sorted
+    state) as ONE program: the hash table's export where ``table_meta``
+    is its key codec (the operands are then its slots: hashes, key store,
+    accumulators), the cut to ``out_cap`` — the table's occupancy bucket,
+    which the host picks from a count it has — and every function's
+    finalisation. The operands' layouts key jax's own trace."""
+    from auron_tpu.hashtable.agg import export_slots
+
+    @jax.jit
+    def auron_ops_agg_emit(*table):
+        if table_meta is not None:
+            th, store, accs = table
+            keys, accs, num_groups, _h = export_slots(th, store, accs,
+                                                      table_meta)
+        else:
+            keys, accs, num_groups = table
+        keys, accs = _cut((keys, accs), out_cap)
+        valid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
+        cols = list(keys) + _finalize_columns(specs, as_state, accs, valid)
+        return DeviceBatch(tuple(cols), num_groups)
+
+    return auron_ops_agg_emit
 
 
 class _HostAggState:
@@ -1388,9 +1694,19 @@ class AggOp(PhysicalOp):
         #: kernels, the combine fold and the partial skip are written for
         self.from_rows = mode in ("partial", "complete") \
             and all(idx is None for idx in self.state_idx)
-        self.specs = [make_acc_spec(a, in_schema, mode) if idx is None
-                      else make_acc_spec_from_partial(a, in_schema, idx)
-                      for a, idx in zip(aggs, self.state_idx)]
+        self.specs = tuple(
+            make_acc_spec(a, in_schema, mode) if idx is None
+            else make_acc_spec_from_partial(a, in_schema, idx)
+            for a, idx in zip(aggs, self.state_idx))
+        #: what the programs trace to read a batch (plan data). Its schema
+        #: goes by position: expressions read columns by index, and
+        #: aggregations that differ in their columns' NAMES only — q28's
+        #: six bands — share their programs
+        self._front = _RowsFront(
+            self.group_exprs, self.state_idx, self.aggs, self.specs,
+            Schema(tuple(f.with_name(f"c{i}")
+                         for i, f in enumerate(in_schema))))
+        self._state_front = _StateFront(len(self.group_exprs), self.specs)
         if (len(set(fn_modes)) > 1 or mode == "partial_merge") and any(
                 f[2] in HOST_KINDS for spec in self.specs
                 for f in spec.state_fields):
@@ -1454,12 +1770,16 @@ class AggOp(PhysicalOp):
         return self._schema
 
     # -- input row → state contributions -----------------------------------
-    def _contributions(self, batch: DeviceBatch, in_schema: Schema,
-                       ctx: EvalContext):
-        """Evaluate group keys and per-row initial accumulator columns."""
-        return _contribution_columns(self.group_exprs, self.state_idx,
-                                     self.aggs, self.specs, batch,
-                                     in_schema, ctx)
+    def _input(self, batch: DeviceBatch, partition: int = 0,
+               front=None) -> _Input:
+        """One batch as the merge takes it. ``front`` defaults to the
+        operator's input; ``self._state_front`` reads a batch in its own
+        state layout (a restored spill run, a state handed over)."""
+        front = front or self._front
+        layout = leaf_layout(batch.columns)
+        return _Input(front,
+                      (batch.columns, batch.num_rows, np.int32(partition)),
+                      layout, _front_shapes(front, layout, batch.capacity))
 
     # -- merge driver -------------------------------------------------------
     #
@@ -1506,55 +1826,37 @@ class AggOp(PhysicalOp):
         return ok, out_cap
 
     def _shrink_table(self, tbl, ng: int):
-        """Slice a group table down to its occupancy bucket. Live groups
-        are a hash-sorted prefix, so shrinking is a plain slice; keeps
-        small-cardinality states from carrying batch-sized buffers through
-        every subsequent merge."""
+        """A group table at its occupancy bucket. Live groups are a
+        hash-sorted prefix, so shrinking is a plain slice — which the
+        program that reads the table next takes (``_cut``: the merge, the
+        emit): only the capacity changes here, and the arrays stay at the
+        capacity of the program that made them until then. Keeps
+        small-cardinality states from carrying batch-sized buffers
+        through every subsequent merge."""
         keys, accs, n, cap, h = tbl
-        new_cap = max(bucket_rows(max(ng, 1)), self.initial_capacity)
-        if new_cap >= cap:
-            return tbl
+        return (keys, accs, n, self._occupancy_cap(cap, ng), h)
 
-        def slice_col(c):
-            if isinstance(c, StringColumn):
-                return StringColumn(c.chars[:new_cap], c.lens[:new_cap],
-                                    c.validity[:new_cap])
-            from auron_tpu.columnar.batch import ListColumn, StructColumn
-            from auron_tpu.columnar.decimal128 import Decimal128Column
-            if isinstance(c, ListColumn):
-                return ListColumn(c.values[:new_cap], c.elem_valid[:new_cap],
-                                  c.lens[:new_cap], c.validity[:new_cap])
-            if isinstance(c, Decimal128Column):
-                return Decimal128Column(c.hi[:new_cap], c.lo[:new_cap],
-                                        c.validity[:new_cap])
-            if isinstance(c, StructColumn):
-                return StructColumn(tuple(slice_col(ch) for ch in c.children),
-                                    c.validity[:new_cap])
-            return PrimitiveColumn(c.data[:new_cap], c.validity[:new_cap])
+    def _occupancy_cap(self, cap: int, ng: int) -> int:
+        return min(cap, max(bucket_rows(max(ng, 1)), self.initial_capacity))
 
-        keys2 = tuple(slice_col(c) for c in keys)
-        accs2 = tuple(tuple(x[:new_cap] for x in a) if isinstance(a, tuple)
-                      else a[:new_cap] for a in accs)
-        return (keys2, accs2, n, new_cap, h[:new_cap])
-
-    def _reduce_batch(self, keys, accs, live, elapsed, donate=False):
-        """Step 1: one batch → its hash-sorted group table. ``donate``
-        (the owned-batch donation sweep) hands the contribution buffers
-        to XLA; callers may only pass it when the batch is owned and no
-        collect kind can grow elements (the retry below reuses the
-        inputs). Leaves that alias one buffer — sum(x) + avg(x)
-        evaluate to the SAME column object twice — are programs.jit's
-        to catch."""
-        kinds = [kind for spec in self.specs
-                 for (_n, _dt, kind) in _device_fields(spec)]
-        cap_b = live.shape[0]
-        out_elems = self._collect_elems(accs)
+    def _reduce_batch(self, inp, elapsed, donate=False):
+        """Step 1: one batch → its hash-sorted group table, its keys and
+        contributions evaluated inside the same program. ``donate`` (the
+        owned-batch donation sweep) hands the batch's columns to XLA;
+        callers may only pass it when the batch is owned and no collect
+        kind can grow elements (the retry below reuses the inputs).
+        Leaves that alias one buffer — columns of a scan-fed batch
+        commonly share one all-valid mask — are programs.jit's to
+        catch."""
+        kinds = self._device_kinds()
+        cap_b = inp.capacity
+        out_elems = self._collect_elems(inp.shapes[1])
         while True:
             meta = tuple(zip(kinds, out_elems))
-            kern = _batch_reduce_kernel(len(keys), meta, cap_b, donate)
+            kern = _batch_reduce_kernel(inp.front, inp.layout, meta, cap_b,
+                                        donate)
             with timer(elapsed) as t:
-                bk, ba, bh, bn, needed = kern(tuple(keys), tuple(accs),
-                                              live)
+                bk, ba, bh, bn, needed = kern(*inp.operands)
                 # one batched round trip for every control scalar — each
                 # separate int() readback is its own device→host sync.
                 # The readback IS the sync point: attributed as device
@@ -1574,15 +1876,11 @@ class AggOp(PhysicalOp):
                  for (_n, _dt, kind) in _device_fields(spec)]
         s_keys, s_accs, s_n, s_cap, s_h = s
         bk, ba, bn, cap_b, bh = b
-        # string/list columns may land in different width buckets per
-        # batch (and per restored spill run) — unify before the merge
-        unified = [unify_column_widths([a, c]) for a, c in zip(s_keys, bk)]
-        s_keys = tuple(p[0] for p in unified)
-        bk = tuple(p[1] for p in unified)
-        s_accs, ba = _unify_acc_pair(s_accs, ba)
-
         out_cap = max(s_cap, self.initial_capacity)
-        out_elems = self._collect_elems(s_accs)
+        # string / list columns may land in different width buckets per
+        # batch (and per restored spill run): the merge unifies them
+        out_elems = [max(e) for e in zip(self._collect_elems(s_accs),
+                                         self._collect_elems(ba))]
         while True:
             meta = tuple(zip(kinds, out_elems))
             kern = _state_merge_kernel(len(s_keys), meta, s_cap, cap_b,
@@ -1620,7 +1918,7 @@ class AggOp(PhysicalOp):
             has_float_sum=has_float_sum, conf=ctx.conf,
             metrics=ctx.metrics_for("kernels"))
 
-    def _merge_hash(self, state, keys, accs, live, elapsed, ht):
+    def _merge_hash(self, state, inp, elapsed, ht):
         """Hash-table update: the batch folds into the device table in
         one fused program (no per-batch state sort/merge). A sorted
         (tbl, None) state — the partial-skip decision's compaction, or a
@@ -1631,20 +1929,20 @@ class AggOp(PhysicalOp):
         from auron_tpu.hashtable import HashAggState, HashTableOverflow
         if state is not None and isinstance(state[0], HashAggState):
             hs = state[0]
-            pending = [(keys, accs, live)]
+            pending = [inp]
         else:
             hs = HashAggState(
                 self._device_kinds(),
                 initial_capacity=self.initial_capacity,
                 load_factor=ht.load_factor,
                 max_probe_rounds=ht.max_probe_rounds)
-            pending = [self._state_contributions(self._state_batch(lvl))
+            pending = [self._state_input(self._state_batch(lvl))
                        for lvl in (state or ()) if lvl is not None]
-            pending.append((keys, accs, live))
-        for i, (k2, a2, l2) in enumerate(pending):
+            pending.append(inp)
+        for i, batch_in in enumerate(pending):
             try:
                 with timer(elapsed):    # update syncs via its readback
-                    hs.update(k2, a2, l2)
+                    hs.update(*batch_in)
             except HashTableOverflow:
                 # fall back mid-stream: export whatever the table holds
                 # (updates are transactional — the failed batch is NOT
@@ -1657,22 +1955,20 @@ class AggOp(PhysicalOp):
                 tbl = hs.to_sorted_table()
                 sorted_state = None if tbl is None else \
                     (self._shrink_table(tbl, hs.count), None)
-                for (k3, a3, l3) in pending[i:]:
-                    sorted_state = self._merge_sorted(
-                        sorted_state, k3, a3, l3, elapsed)
+                for left in pending[i:]:
+                    sorted_state = self._merge_sorted(sorted_state, left,
+                                                      elapsed)
                 return sorted_state
         return (hs,)
 
-    def _merge(self, state, keys, accs, live, elapsed, ht=None,
-               donate=False):
+    def _merge(self, state, inp, elapsed, ht=None, donate=False):
         if ht is not None and not ht.disabled:
             # the hash step's overflow-retry protocol reuses its inputs
             # (PERF.md 'Pipelined execution'): no donation on this path
-            return self._merge_hash(state, keys, accs, live, elapsed, ht)
+            return self._merge_hash(state, inp, elapsed, ht)
         # graft: donation-ok -- sorted path only: the hash branch
         # above latched off (its overflow retry reuses inputs)
-        return self._merge_sorted(state, keys, accs, live, elapsed,
-                                  donate=donate)
+        return self._merge_sorted(state, inp, elapsed, donate=donate)
 
     def _donate_contributions(self, ctx: ExecContext) -> bool:
         """Owned-batch donation gate for the per-batch reduce: the child
@@ -1686,8 +1982,7 @@ class AggOp(PhysicalOp):
             k in ("collect_list", "collect_set") or k in _DCOLLECT
             for k in self._device_kinds())
 
-    def _merge_sorted(self, state, keys, accs, live, elapsed,
-                      donate=False):
+    def _merge_sorted(self, state, inp, elapsed, donate=False):
         """state: None | (main, hot), each None | (keys, accs, num_groups,
         capacity, hashes). Two-level update: every batch merges into the
         small hot table (O(B log B + hot)); the hot table folds into main
@@ -1698,9 +1993,8 @@ class AggOp(PhysicalOp):
         _trace.count("agg_sort_batches")
         # graft: donation-ok -- _donate_contributions gate (owned
         # child, no collect-kind growth retry, no aliased leaves)
-        batch_tbl = self._reduce_batch(keys, accs, live, elapsed,
-                                       donate=donate)
-        cap_b = live.shape[0]
+        batch_tbl = self._reduce_batch(inp, elapsed, donate=donate)
+        cap_b = inp.capacity
         main, hot = state if state is not None else (None, None)
         if hot is None:
             hot = batch_tbl
@@ -1718,7 +2012,9 @@ class AggOp(PhysicalOp):
     def _compact(self, state, elapsed):
         """Collapse (main, hot) into one table for emit / spill / the skip
         decision. Returns a 5-tuple or None. A hash-table-backed state
-        exports through its hash-sorted conversion."""
+        exports through its hash-sorted conversion (the skip decision,
+        which goes on with a sorted state; an emit exports inside its own
+        program: ``_emit``)."""
         if state is None:
             return None
         from auron_tpu.hashtable import HashAggState
@@ -1736,180 +2032,61 @@ class AggOp(PhysicalOp):
         return self._merge_tables(main, hot, elapsed)
 
     # -- finalize → output batch -------------------------------------------
-    def _emit(self, state, in_schema: Schema, host=None) -> DeviceBatch:
-        from auron_tpu.columnar.batch import ListColumn, resize
-        keys, accs, num_groups, cap, _hashes = state
-        valid = jnp.arange(cap, dtype=jnp.int32) < num_groups
+    def _table_batch(self, table, as_state: bool, bloom: bool = False):
+        """ONE program (``ops.agg.emit``) from a group table — a sorted
+        5-tuple, or the hash table itself, whose export is folded in — to
+        a batch of its groups at the table's occupancy bucket: the
+        operator's output less its host-side columns, or (``as_state``)
+        the state as a batch in the operator's own state layout (a spill
+        run; a sorted state the hash table takes over). Returns the batch
+        and its capacity."""
+        from auron_tpu.hashtable import HashAggState
+        if isinstance(table, HashAggState):
+            # the occupancy bucket from the count the host already has
+            cap = self._occupancy_cap(table.cap, table.count)
+            operands = (table.th, table.store, table.accs)
+            meta = table.key_meta
+        else:
+            keys, accs, num_groups, cap, _hashes = table
+            operands, meta = (keys, accs, num_groups), None
+        if bloom:
+            # A global bloom state serializes to ~100 KB+ per row; the
+            # (single-group) output leaves at the smallest capacity so
+            # the string column isn't materialized at state capacity.
+            cap = min(cap, bucket_rows(1, minimum=16))
+        return _emit_kernel(self.specs, as_state, cap, meta)(*operands), cap
+
+    def _emit(self, state, elapsed, host=None) -> Optional[DeviceBatch]:
+        """The operator's output batch from its state — the (main, hot)
+        levels or the hash table — or None where it never saw a row."""
+        from auron_tpu.hashtable import HashAggState
+        if state is not None and isinstance(state[0], HashAggState):
+            table = state[0] if state[0].built else None
+        else:
+            table = self._compact(state, elapsed)
+        if table is None:
+            return None
+        host_slots = _host_slots(self.specs, self.emits_state) \
+            if host is not None and not host.empty() else []
+        batch, out_cap = self._table_batch(
+            table, self.emits_state,
+            bloom=bool(host_slots) and host.has_bloom())
         # where the state's last merge was dispatched and not read (the
         # sort path's, the table's final fold) this read waits for it
-        ng = _profile.row_count(num_groups)
+        ng = _profile.row_count(batch)
         if self.group_exprs:
             _trace.count("agg_groups", ng)
-
-        # A global bloom state serializes to ~100 KB+ per row; shrink the
-        # (single-group) output capacity before attaching it so the string
-        # column isn't materialized at state capacity.
-        shrink = host is not None and host.has_bloom()
-        out_cap = bucket_rows(max(ng, 1), minimum=16) if shrink else cap
-
-        def list_col(a):
-            return _list_column_from_acc(a, valid)
-
-        out_cols = list(keys)   # device columns; host cols spliced after
-        host_slots = []         # (position, spec_index)
-
-        if self.emits_state:
-            i = 0
-            for si, spec in enumerate(self.specs):
-                for (fname, fdt, kind) in spec.state_fields:
-                    if kind in HOST_KINDS:
-                        host_slots.append((len(out_cols), si))
-                        out_cols.append(None)
-                        continue
-                    data = accs[i]
-                    i += 1
-                    if kind in _DCOLLECT:
-                        out_cols.append(
-                            _map_carrier_from_dacc(data, valid))
-                    elif isinstance(data, tuple) and len(data) == 3:
-                        out_cols.append(StringColumn(
-                            data[0], data[1], data[2] & valid))
-                    elif isinstance(data, tuple) and data[0].ndim == 1:
-                        from auron_tpu.columnar.decimal128 import \
-                            Decimal128Column
-                        out_cols.append(Decimal128Column(
-                            data[0], data[1], valid))
-                    elif isinstance(data, tuple):
-                        out_cols.append(list_col(data))
-                    else:
-                        out_cols.append(PrimitiveColumn(data, valid))
-        else:
-            # final/complete: finalize each agg
-            i = 0
-            for si, spec in enumerate(self.specs):
-                n_state = len(_device_fields(spec))
-                state_vals = accs[i: i + n_state]
-                i += n_state
-                fn = spec.fn
-                if fn in ("count", "count_star"):
-                    out_cols.append(PrimitiveColumn(state_vals[0], valid))
-                elif fn == "sum":
-                    s, has = state_vals
-                    if isinstance(s, tuple):
-                        from auron_tpu.columnar import decimal128 as d128
-                        from auron_tpu.columnar.decimal128 import \
-                            Decimal128Column
-                        h, l = s
-                        # Spark non-ANSI: overflow beyond the declared
-                        # precision nulls the group
-                        fits = d128.fits_precision(h, l, spec.result[1])
-                        out_cols.append(Decimal128Column(
-                            h, l, valid & has & fits))
-                    else:
-                        out_cols.append(PrimitiveColumn(s, valid & has))
-                elif fn == "avg":
-                    s, cnt = state_vals
-                    res_dt = spec.result[0]
-                    safe = jnp.maximum(cnt, 1)
-                    if isinstance(s, tuple):
-                        # two-limb sum at the input scale: shift to the
-                        # result scale inside the HALF_UP division; Spark
-                        # nulls averages that overflow decimal(38)
-                        from auron_tpu.columnar import decimal128 as d128
-                        from auron_tpu.columnar.decimal128 import \
-                            Decimal128Column
-                        k = spec.result[2] - spec.state_ps[0][1]
-                        qh, ql, fits = d128.avg_pow10_div_half_up(
-                            s[0], s[1], safe, k)
-                        out_cols.append(Decimal128Column(
-                            qh, ql, valid & (cnt > 0) & fits))
-                    elif res_dt == DataType.DECIMAL:
-                        # scaled-int64 sum at the input scale; same
-                        # q*10^k + round(r*10^k/count) composition in
-                        # int64, overflow past the 18-digit result → null
-                        k = spec.result[2] - spec.state_ps[0][1]
-                        shift = 10 ** k
-                        a = jnp.abs(s)
-                        q0 = a // safe
-                        rem = a - q0 * safe
-                        fits = q0 < 10 ** (18 - k)
-                        frac = (2 * rem * shift + safe) // (2 * safe)
-                        q = q0 * shift + frac
-                        avg = jnp.where(s < 0, -q, q)
-                        out_cols.append(PrimitiveColumn(
-                            avg, valid & (cnt > 0) & fits))
-                    else:
-                        avg = s.astype(jnp.float64) / safe
-                        out_cols.append(PrimitiveColumn(
-                            avg, valid & (cnt > 0)))
-                elif fn in ("min", "max", "first", "first_ignores_null"):
-                    if len(state_vals) == 1:   # string acc: validity inside
-                        chars, lens, sv = state_vals[0]
-                        out_cols.append(StringColumn(chars, lens,
-                                                     sv & valid))
-                    elif isinstance(state_vals[0], tuple):
-                        from auron_tpu.columnar.decimal128 import \
-                            Decimal128Column
-                        (h, l), has = state_vals
-                        out_cols.append(Decimal128Column(h, l, valid & has))
-                    else:
-                        v, has = state_vals
-                        out_cols.append(PrimitiveColumn(v, valid & has))
-                elif fn in ("collect_list", "collect_set"):
-                    # empty list (not null) for groups with only nulls —
-                    # Spark's collect_* semantics
-                    if spec.state_fields[0][2] in _DCOLLECT:
-                        out_cols.append(_map_carrier_from_dacc(
-                            state_vals[0], valid))
-                    else:
-                        out_cols.append(list_col(state_vals[0]))
-                elif fn in ("count_distinct", "sum_distinct",
-                            "avg_distinct"):
-                    vals, lens = state_vals[0]  # deduped set per group
-                    if fn == "count_distinct":
-                        out_cols.append(PrimitiveColumn(
-                            lens.astype(jnp.int64), valid))
-                    else:
-                        e = vals.shape[1]
-                        mask = (jnp.arange(e, dtype=jnp.int32)[None, :]
-                                < lens[:, None])
-                        jdt = _JNPT[spec.result[0]]
-                        s = jnp.sum(jnp.where(mask, vals, 0),
-                                    axis=1).astype(jdt)
-                        if fn == "avg_distinct":
-                            s = (s.astype(jnp.float64)
-                                 / jnp.maximum(lens, 1))
-                        # all-null group: no distinct values → NULL
-                        out_cols.append(PrimitiveColumn(
-                            s, valid & (lens > 0)))
-                elif spec.state_fields and spec.state_fields[0][2] in HOST_KINDS:
-                    host_slots.append((len(out_cols), si))
-                    out_cols.append(None)
-                else:
-                    raise NotImplementedError(fn)
-
         if not host_slots:
-            batch = DeviceBatch(tuple(out_cols), num_groups)
-            return resize(batch, out_cap) if out_cap != cap else batch
+            return batch
 
         # splice host-aggregated columns (bloom / udaf) at output capacity
-        device_batch = DeviceBatch(
-            tuple(c for c in out_cols if c is not None), num_groups)
-        if out_cap != cap:
-            device_batch = resize(device_batch, out_cap)
-        key_tuples = _key_tuples_host(device_batch.columns[:len(keys)], ng)
-        final_cols = []
-        di = 0
-        slot_map = dict(host_slots)
-        for pos in range(len(out_cols)):
-            if pos in slot_map:
-                final_cols.append(host.result_column(
-                    slot_map[pos], key_tuples, ng, out_cap,
-                    partial=self.emits_state))
-            else:
-                final_cols.append(device_batch.columns[di])
-                di += 1
-        return DeviceBatch(tuple(final_cols), num_groups)
+        n_keys = len(self.group_exprs)
+        key_tuples = _key_tuples_host(batch.columns[:n_keys], ng)
+        cols = list(batch.columns)
+        for pos, si in host_slots:
+            cols.insert(n_keys + pos, host.result_column(
+                si, key_tuples, ng, out_cap, partial=self.emits_state))
+        return DeviceBatch(tuple(cols), batch.num_rows)
 
     # -- spill support ------------------------------------------------------
     # The reference spills the in-mem hash table as sorted buckets and
@@ -1923,43 +2100,15 @@ class AggOp(PhysicalOp):
                 for (_f, _d, kind) in _device_fields(spec)]
 
     def _state_batch(self, state) -> DeviceBatch:
-        from auron_tpu.hashtable import HashAggState
-        if isinstance(state, HashAggState):
-            # spill / fold handoff: export restores the hash-sorted run
-            # invariant the bucket spills rely on
-            state = self._shrink_table(state.to_sorted_table(),
-                                       state.count)
-        keys, accs, num_groups, cap, _hashes = state
-        valid = jnp.arange(cap, dtype=jnp.int32) < num_groups
-        cols = list(keys)
-        for kind, a in zip(self._device_kinds(), accs):
-            if kind in _DCOLLECT:
-                cols.append(_map_carrier_from_dacc(a, valid))
-            elif isinstance(a, tuple) and len(a) == 3:
-                cols.append(StringColumn(a[0], a[1], a[2] & valid))
-            elif isinstance(a, tuple) and a[0].ndim == 1:
-                from auron_tpu.columnar.decimal128 import Decimal128Column
-                cols.append(Decimal128Column(a[0], a[1], valid))
-            elif isinstance(a, tuple):
-                cols.append(_list_column_from_acc(a, valid))
-            else:
-                cols.append(PrimitiveColumn(a, valid))
-        return DeviceBatch(tuple(cols), num_groups)
+        """A state level as a batch in the operator's own state layout:
+        the spill / fold handoff (a hash table's export restores the
+        hash-sorted run invariant the bucket spills rely on)."""
+        return self._table_batch(state, as_state=True)[0]
 
-    def _state_contributions(self, batch: DeviceBatch):
-        n_keys = len(self.group_exprs)
-        keys = tuple(batch.columns[:n_keys])
-        accs: list = []
-        idx = n_keys
-        for spec in self.specs:
-            idx = _read_state_accs(_device_fields(spec), batch, idx, accs)
-        return keys, accs, batch.row_mask()
-
-    def _passthrough_batch(self, keys, accs, live, num_rows) -> DeviceBatch:
-        """One input batch re-expressed in partial-state layout without
-        merging — each row is its own group (adaptive partial-agg
-        skipping, reference: agg/agg_ctx.rs:63-196)."""
-        return _passthrough_state_batch(keys, accs, live, num_rows)
+    def _state_input(self, batch: DeviceBatch):
+        """A batch in the operator's own state layout (``_state_batch``,
+        a restored spill run) as the merge takes it."""
+        return self._input(batch, front=self._state_front)
 
     # -- map-side combine fold (parallel/exchange + mesh_exchange) ----------
     #
@@ -2250,7 +2399,7 @@ class AggOp(PhysicalOp):
                     _JNPT[spec.state_fields[0][1]]))
                 accs.append(acc[1][take] > 0)
         tbl = (keys, tuple(accs), ng_dev, cap, jnp.zeros(cap, jnp.uint64))
-        yield self._emit(tbl, in_schema)
+        yield self._emit((tbl, None), elapsed)
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
         from auron_tpu import config as cfg
@@ -2299,25 +2448,28 @@ class AggOp(PhysicalOp):
                 for batch in self.child.execute(partition, ctx):
                     ctx.check_cancelled()
                     if skipping:
-                        keys, accs, live = self._contributions(
-                            batch, in_schema, ectx)
+                        # each row its own group, in state layout
+                        # (adaptive partial-agg skipping, reference:
+                        # agg/agg_ctx.rs:63-196)
+                        inp = self._input(batch, partition)
                         skipped_rows.add(_profile.row_count(batch))
-                        yield self._passthrough_batch(keys, accs, live,
-                                                      batch.num_rows)
+                        yield _passthrough_kernel(
+                            inp.front, inp.layout,
+                            inp.capacity)(*inp.operands)
                         continue
                     if self.mode in _READS_STATE:
                         host.merge_partial(batch)
                     else:
                         host.update(batch, ectx)
-                    keys, accs, live = self._contributions(batch, in_schema, ectx)
+                    inp = self._input(batch, partition)
                     if consumer is not None:
                         # state lives in the consumer between merges so an
                         # external victim spill can take it atomically
                         state = consumer.take_state()
                     # graft: donation-ok -- donate_contribs is the
                     # _donate_contributions gate resolved above
-                    state = self._merge(state, keys, accs, live, elapsed,
-                                        ht_ctl, donate=donate_contribs)
+                    state = self._merge(state, inp, elapsed, ht_ctl,
+                                        donate=donate_contribs)
                     if consumer is not None:
                         state = consumer.observe(state)
                     if not skip_pending:
@@ -2348,12 +2500,10 @@ class AggOp(PhysicalOp):
                         # state, then pass the rest of the input through
                         if consumer is not None:
                             for spilled in consumer.drain_spilled_states():
-                                k2, a2, l2 = self._state_contributions(
-                                    spilled)
-                                state = self._merge(state, k2, a2, l2,
-                                                    elapsed, ht_ctl)
-                        yield self._emit(self._compact(state, elapsed),
-                                         in_schema, host)
+                                state = self._merge(
+                                    state, self._state_input(spilled),
+                                    elapsed, ht_ctl)
+                        yield self._emit(state, elapsed, host)
                         state = None
                         skipping = True
                         if consumer is not None:
@@ -2369,16 +2519,16 @@ class AggOp(PhysicalOp):
                     # may have been spilled away since the last observe)
                     state = consumer.take_state()
                     for spilled in consumer.read_spilled_states():
-                        keys, accs, live = self._state_contributions(spilled)
-                        state = self._merge(state, keys, accs, live,
+                        state = self._merge(state,
+                                            self._state_input(spilled),
                                             elapsed, ht_ctl)
-                final_tbl = self._compact(state, elapsed)
-                if final_tbl is None:
-                    if not self.group_exprs and self.mode in ("final", "complete"):
-                        # global agg over empty input: one row of neutral results
-                        yield self._empty_global(host)
-                    return
-                yield self._emit(final_tbl, in_schema, host)
+                out = self._emit(state, elapsed, host)
+                if out is not None:
+                    yield out
+                elif not self.group_exprs \
+                        and self.mode in ("final", "complete"):
+                    # global agg over empty input: one row of neutral results
+                    yield self._empty_global(host)
             finally:
                 host.close()
                 if consumer is not None:

@@ -344,47 +344,64 @@ def _on_chip(one_chip, tree):
         tree)
 
 
-@pytest.mark.parametrize("rows, slots", [
+@pytest.mark.parametrize("mode, rows, slots", [
     # q28's first aggregate: a full scan batch, ~800 of its rows live,
     # into the table at its grown capacity (half a minute to compile)
-    (1 << 16, 16384),
+    ("partial", 1 << 16, 16384),
     # its second (partial_merge): the first's ~6,500 groups in one batch
-    (8192, 16384),
+    ("partial_merge", 8192, 16384),
     # its first since PR 45: the filtered scan batch arrives cut to its
     # live bucket (65,536 -> 1,024), the table at its first capacity
-    (1024, 4096),
+    ("partial", 1024, 4096),
 ], ids=["scan_batch_into_16384", "partial_merge_of_8192",
         "shrunk_scan_batch_into_4096"])
-def test_the_distinct_regroup_compiles_for_the_chip(one_chip, rows, slots):
+def test_the_distinct_regroup_compiles_for_the_chip(one_chip, mode, rows,
+                                                    slots):
     """The keyed aggregates of a single-DISTINCT plan (PR 44) on the
-    chip's hash table: the group key is the DISTINCT argument itself, a
-    decimal(7,2) held as one int64 word, beside avg's sum and count and
-    count's count; ``partial`` and ``partial_merge`` run the SAME step
-    program (the latter's contributions are state columns read as they
-    come). And the table's export, an argsort of its slots."""
+    chip's hash table, as the programs the operator runs since PR 46:
+    the group key is the DISTINCT argument itself, a decimal(7,2) held
+    as one int64 word, beside avg's sum and count and count's count.
+    ``partial`` evaluates the key and the contributions from the row
+    inside the step, ``partial_merge`` reads its child's state columns
+    there; the table's set-up is one program, and its export — an
+    argsort of its slots — runs inside the emit, cut to the occupancy
+    bucket."""
     import jax
     import jax.numpy as jnp
+    from auron_tpu.columnar.batch import leaf_layout
+    from auron_tpu.exprs import ir
     from auron_tpu.hashtable import agg as ht_agg, core
-    keys = _columns(one_chip, rows, ("int64",))
+    from auron_tpu.ops import agg
+    price = ("int64", "DECIMAL", 7, 2)
+    fns = [ir.AggFunction("avg", ir.ColumnRef(0)),
+           ir.AggFunction("count", ir.ColumnRef(0))]
+    op = agg.AggOp(_Source(_fields((price,))), [ir.ColumnRef(0)], fns,
+                   mode="partial")
+    if mode == "partial_merge":
+        op = agg.AggOp(op, [ir.ColumnRef(0)],
+                       [ir.AggFunction("avg"), ir.AggFunction("count")],
+                       mode="partial_merge")
+    width = len(op.child.schema())
+    columns = _columns(one_chip, rows, ("int64",) * width)
+    layout = leaf_layout(columns)
+    keys, contribs, _live = agg._front_shapes(op._front, layout, rows)
     key_meta = core.key_meta(keys)
-    acc_meta = (("sum", "int64"),) * 3
-    words = core.total_words(key_meta)
-    state = _on_chip(one_chip, jax.eval_shape(lambda: (
-        jnp.full(slots, core.EMPTY, jnp.uint64),
-        jnp.zeros((slots, words), jnp.uint64),
-        core.empty_store(key_meta, slots),
-        *core.init_accs(acc_meta, slots))))
-    th, tw, store, accs, auxs = state
-    contribs = tuple(jax.ShapeDtypeStruct((rows,), jnp.int64,
-                                          sharding=one_chip)
-                     for _ in acc_meta)
-    live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    acc_meta = tuple((kind, str(c.dtype))
+                     for kind, c in zip(op._device_kinds(), contribs))
+    assert key_meta == (("prim", "int64"),)
+    assert acc_meta == (("sum", "int64"),) * 3
+    init = ht_agg._init_kernel(key_meta, acc_meta, slots)
+    assert init.lower().compile() is not None
+    th, tw, store, accs, auxs = _on_chip(
+        one_chip, jax.eval_shape(lambda: init()))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     base = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
-    step = ht_agg._agg_step_kernel(key_meta, acc_meta, rows, slots, 64)
-    assert step.lower(th, tw, store, accs, auxs, keys, contribs, live,
-                      base).compile() is not None
-    export = ht_agg._export_kernel(key_meta, acc_meta, slots)
-    assert export.lower(th, store, accs).compile() is not None
+    step = ht_agg._agg_step_kernel(op._front, layout, rows, key_meta,
+                                   key_meta, acc_meta, slots, 64)
+    assert step.lower(th, tw, store, accs, auxs, base, columns, scalar,
+                      scalar).compile() is not None
+    emit = agg._emit_kernel(op.specs, True, slots // 2, key_meta)
+    assert emit.lower(th, store, accs).compile() is not None
 
 
 def test_the_cross_join_compiles_for_the_chip(one_chip):

@@ -263,12 +263,12 @@ def test_an_overflowing_hash_table_latches_the_sort_path(served,
     real = htagg.HashAggState.update
     first = []
 
-    def update(self, keys, contribs, live):
+    def update(self, *batch):
         if not first:
             first.append(self)
         if self is first[0] and self.rows_seen:
             raise HashTableOverflow("forced by the test")
-        return real(self, keys, contribs, live)
+        return real(self, *batch)
 
     monkeypatch.setattr(htagg.HashAggState, "update", update)
     table, led = served("q65m", fresh=True)
