@@ -728,37 +728,6 @@ FUSION_MAX_STAGE_OPS = _opt(
     "chains split into multiple stages — a bound on per-program trace "
     "size and compile time (an over-long chain compiles one huge XLA "
     "program whose build cost defeats the purpose).")
-FUSION_COMBINE = _opt(
-    "auron.fusion.combine", bool, True,
-    "Map-side combine: a hash exchange fed by an eligible partial "
-    "aggregation folds the agg's update/merge into the shuffle-split "
-    "program itself, so groups are combined per map batch (host route) "
-    "or per shard round (all_to_all route) BEFORE rows cross the "
-    "exchange. Eligibility mirrors the hashtable dispatch rule: "
-    "reassociation-exact accumulator kinds only (integer/decimal sums, "
-    "min/max, first, count) — float sums and element-collecting kinds "
-    "keep the unfolded partial-agg operator, so results stay "
-    "bit-identical either way. Off makes the folded exchange pass "
-    "state-layout rows through UNCOMBINED (the partial-skip "
-    "pass-through shape) — the honest A/B for shuffle-byte accounting, "
-    "and what the cost model picks per exchange when observed combine "
-    "ratios say combining does not pay. TRACE-SEMANTIC knob: it "
-    "changes what the compiled split program computes, so it is "
-    "resolved from the PROCESS-GLOBAL config and rides every "
-    "program-cache key (runtime/programs.py trace salt).")
-FUSION_COST_MODEL = _opt(
-    "auron.fusion.cost_model", bool, True,
-    "Cost-based fusion plan selection (ir/cost.py): the planner "
-    "enumerates candidate fusion decisions per site (combine vs "
-    "pass-through at each foldable exchange, probe-into-consumer fold "
-    "at each hash join) and scores them with a small cost model fed by "
-    "recorded per-site statistics from prior runs of the same plan "
-    "fingerprint (rows/batch, observed combine ratio), falling back to "
-    "a safe static prior when no history exists. Off restores "
-    "greedy-maximal fusion: always fold, always combine where "
-    "eligible. TRACE-SEMANTIC knob: the selected plan decides which "
-    "programs are built, so it rides every program-cache key "
-    "(runtime/programs.py trace salt).")
 
 # hand-written kernels (auron_tpu/kernels)
 KERNELS_ENABLED = _opt(
@@ -904,7 +873,7 @@ _GLOBAL = AuronConfig()
 #: the compiled program computes (not just how the plan is shaped).
 #: Their current values ride every program-cache key as the trace salt
 #: (runtime/programs.py), so flipping one can never serve a stale trace.
-TRACE_SEMANTIC_KEYS = (MAP_KEY_DEDUP_POLICY, FUSION_COMBINE, FUSION_COST_MODEL)
+TRACE_SEMANTIC_KEYS = (MAP_KEY_DEDUP_POLICY,)
 
 
 def trace_salt() -> tuple:

@@ -37,11 +37,6 @@ class PlannerContext:
     # None = resolve from the typed config (auron.batch.capacity)
     batch_capacity: Optional[int] = None
     config: Optional[Any] = None
-    #: plan fingerprint of the task being planned (runtime/journal
-    #: .plan_fingerprint, set by plan_from_bytes) — keys the ir/cost.py
-    #: per-site statistics history so a re-planned query sees what its
-    #: previous runs observed; None for ad-hoc trees
-    plan_fp: Optional[str] = None
     #: (table name, column index) -> (table ref, (min, max)) — memoizes
     #: the O(n) key-column stats scan the dense-kernel derivation needs,
     #: so repeated planning over a registered table pays it once. The
@@ -103,13 +98,11 @@ class PhysicalPlanner:
     def finalize_plan(self, op: PhysicalOp) -> PhysicalOp:
         """Post-planning passes over the materialized operator tree:
         whole-stage fusion (fuse_stages — greedy chains plus the
-        cost-selected combine/probe folds), then the SPMD mesh
-        annotation (annotate_mesh — a no-op while auron.mesh.enabled is
-        off)."""
+        map-side combine fold), then the SPMD mesh annotation
+        (annotate_mesh — a no-op while auron.mesh.enabled is off)."""
         from auron_tpu.parallel import mesh as mesh_mod
-        return annotate_mesh(
-            fuse_stages(op, self.ctx.config, plan_fp=self.ctx.plan_fp),
-            mesh_mod.current_plane())
+        return annotate_mesh(fuse_stages(op, self.ctx.config),
+                             mesh_mod.current_plane())
 
     def create_plan(self, node: pb.PlanNode) -> PhysicalOp:
         kind = node.WhichOneof("node")
@@ -611,14 +604,7 @@ def plan_from_bytes(data: bytes,
     """Decode a serialized TaskDefinition and materialize its plan — the
     `callNative` entry analogue (reference: auron/src/exec.rs:42-118)."""
     task = pb.TaskDefinition.FromString(data)
-    planner = PhysicalPlanner(ctx)
-    if planner.ctx.plan_fp is None:
-        # identity for the ir/cost.py site history (the PR 16 cache/
-        # journal key): same bytes → same fingerprint → prior runs'
-        # observed stats feed this planning pass
-        from auron_tpu.runtime import journal as journal_mod
-        planner.ctx.plan_fp = journal_mod.plan_fingerprint(data)
-    return planner.plan_task(task)
+    return PhysicalPlanner(ctx).plan_task(task)
 
 
 # ---------------------------------------------------------------------------
@@ -630,8 +616,7 @@ def plan_from_bytes(data: bytes,
 _MAX_STAGE_FANOUT = 16
 
 
-def fuse_stages(op: PhysicalOp, config=None,
-                plan_fp: Optional[str] = None) -> PhysicalOp:
+def fuse_stages(op: PhysicalOp, config=None) -> PhysicalOp:
     """Whole-stage fusion (ops/fused.py): greedily group maximal chains
     of fusable row-local operators into FusedStageOp nodes, and push the
     key/value projection of partial/complete aggregations below the agg
@@ -641,11 +626,11 @@ def fuse_stages(op: PhysicalOp, config=None,
     construction. Gated on ``auron.fusion.enabled``; chain length is
     bounded by ``auron.fusion.max_stage_ops``.
 
-    After the greedy chaining, the Fusion 2.0 selection pass
-    (_fold_combine) walks the tree: eligible exchange-over-partial-agg
-    shapes get the map-side combine fold stamped, and each decision site
-    is scored by ir/cost.py against recorded history keyed on
-    ``plan_fp`` (greedy-maximal when auron.fusion.cost_model is off)."""
+    After the greedy chaining, _fold_combine walks the tree and stamps
+    the map-side combine fold on every eligible
+    exchange-over-partial-agg shape. The tree is a function of the plan
+    and the configuration alone: nothing an earlier task ran decides
+    it."""
     from auron_tpu import config as cfg
     conf = config if config is not None else cfg.get_config()
     # the pre-agg projection normalization runs regardless of the fusion
@@ -660,7 +645,7 @@ def fuse_stages(op: PhysicalOp, config=None,
         return op
     max_ops = max(2, conf.get(cfg.FUSION_MAX_STAGE_OPS))
     op = _fuse(op, max_ops)
-    _fold_combine(op, conf, plan_fp)
+    _fold_combine(op)
     return op
 
 
@@ -723,31 +708,20 @@ def _fuse(op: PhysicalOp, max_ops: int) -> PhysicalOp:
     return op
 
 
-def _fold_combine(op: PhysicalOp, conf, plan_fp: Optional[str]) -> None:
-    """Fusion 2.0 selection walk (runs after _fuse): stamp the map-side
-    combine fold on every hash exchange whose child is an eligible
-    partial AggOp, and the probe-into-consumer decision on every hash
-    join. Each site gets a stable (plan_fp, label) identity so the
-    runtime can record observed stats into ir/cost.py and the next
-    planning of the SAME plan can select against them.
+def _fold_combine(op: PhysicalOp) -> None:
+    """Runs after _fuse: stamp the map-side combine fold on every hash
+    exchange whose child is an eligible partial AggOp, or the reason it
+    is not eligible.
 
     The fold keeps the agg node in the tree (schema, metrics and explain
     stay intact — the folded-chain convention); at materialize time the
     exchange executes the agg's child with the combine stage folded into
-    its split program. The fold mode is a TRACE-SEMANTIC decision
-    resolved from the PROCESS-GLOBAL config (auron.fusion.{combine,
-    cost_model} ride config.trace_salt()), never the session override."""
-    from auron_tpu import config as cfg
+    its split program."""
     from auron_tpu.exprs import ir as xir
-    from auron_tpu.ir import cost as cost_mod
     from auron_tpu.ops.agg import AggOp
-    from auron_tpu.ops.joins import HashJoinOp
     from auron_tpu.parallel.exchange import ShuffleExchangeOp
     from auron_tpu.parallel.partitioning import (HashPartitioning,
                                                  SinglePartitioning)
-    gconf = cfg.get_config()
-    capacity = conf.get(cfg.BATCH_CAPACITY)
-    sites = iter(range(1 << 30))
 
     def keys_only(exchange, n_keys: int) -> bool:
         # every partitioning expr must be a plain ref into the group-key
@@ -761,35 +735,18 @@ def _fold_combine(op: PhysicalOp, conf, plan_fp: Optional[str]) -> None:
                    for e in exchange.partitioning.exprs)
 
     def walk(o: PhysicalOp) -> None:
-        if isinstance(o, ShuffleExchangeOp):
-            site = (plan_fp, f"x{next(sites)}") if plan_fp else None
-            o.cost_site = site
+        if isinstance(o, ShuffleExchangeOp) and isinstance(o.child, AggOp):
             child = o.child
-            if isinstance(child, AggOp):
-                reason = child.combine_fold_reason()
-                if reason is None \
-                        and not keys_only(o, len(child.group_exprs)):
-                    reason = "partitioning_not_on_keys"
-                if reason is None:
-                    if not gconf.get(cfg.FUSION_COMBINE):
-                        mode, why = "passthrough", "combine_off"
-                    else:
-                        mode, why = cost_mod.choose_exchange_mode(
-                            gconf, site, capacity)
-                    o.combine_mode, o.combine_why = mode, why
-                    cost_mod.record_decision(site, "exchange", mode)
-                    # a lone computing op under the agg folds as a chain
-                    child.child = _wrap_single(child.child)
-                else:
-                    o.combine_mode, o.combine_why = None, reason
-        elif isinstance(o, HashJoinOp):
-            site = (plan_fp, f"j{next(sites)}") if plan_fp else None
-            o.cost_site = site
-            o.probe_fold_consumer = cost_mod.choose_probe_fold(gconf,
-                                                               site)
-            cost_mod.record_decision(
-                site, "probe_fold",
-                "fold" if o.probe_fold_consumer else "unfused")
+            reason = child.combine_fold_reason()
+            if reason is None \
+                    and not keys_only(o, len(child.group_exprs)):
+                reason = "partitioning_not_on_keys"
+            if reason is None:
+                o.combine_mode = "combine"
+                # a lone computing op under the agg folds as a chain
+                child.child = _wrap_single(child.child)
+            else:
+                o.combine_why = reason
         for c in o.children:
             walk(c)
 

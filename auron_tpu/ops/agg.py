@@ -2156,21 +2156,19 @@ class AggOp(PhysicalOp):
             return "float_sum_inexact"
         return None
 
-    def combine_signature(self, mode: str) -> tuple:
+    def combine_signature(self) -> tuple:
         """Hashable trace signature of the folded combine stage — rides
         the split-program cache key (schema/capacity ride separately)."""
-        return ("combine_v1", mode, self.group_exprs, self.aggs)
+        return ("combine_v1", self.group_exprs, self.aggs)
 
-    def build_combine_stage(self, mode: str):
+    def build_combine_stage(self):
         """Traced (DeviceBatch → (partial-layout DeviceBatch, rows_in))
-        stage folded into a shuffle-split program. mode 'combine' merges
-        the batch's groups (one stable hash-sort + segment reduce, the
+        stage folded into a shuffle-split program: merges the batch's
+        groups (one stable hash-sort + segment reduce, the
         _batch_reduce_kernel body inlined — no carries, no growth retry:
-        eligibility excluded collect kinds); mode 'passthrough' emits
-        state-layout rows uncombined (the partial-skip shape — what the
-        cost model picks on high-cardinality sites, and the combine=off
-        A/B arm). rows_in is the pre-combine live-row count, read by the
-        caller in its existing readback fence (combine telemetry)."""
+        eligibility excluded collect kinds). rows_in is the pre-combine
+        live-row count, read by the caller in its existing readback
+        fence (combine telemetry)."""
         in_schema = self.child.schema()
         kinds = self._device_kinds()
         # plan DATA only below — this closure is stored in the process-wide
@@ -2184,9 +2182,6 @@ class AggOp(PhysicalOp):
                 group_exprs, (None,) * len(aggs), aggs, specs, batch,
                 in_schema, ectx)
             rows_in = jnp.sum(live.astype(jnp.int32))
-            if mode != "combine":
-                return (_passthrough_state_batch(keys, accs, live,
-                                                 batch.num_rows), rows_in)
             cap = int(live.shape[0])   # graft: disable=GL001 -- .shape[0] is a static python int, never device data
             h = hashing.xxhash64_columns(list(keys), cap).view(jnp.uint64)
             h = jnp.where(live, h, _HASH_SENTINEL)

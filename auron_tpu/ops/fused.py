@@ -221,16 +221,12 @@ class FusedStageOp(PhysicalOp):
         """(fragments, frag_keys) when this stage's input is an inner
         hash join whose matched output can run through the join's
         match program (ops/joins._match_program, with this chain) — the
-        probe-into-consumer fold. The planner's cost pass gates it per
-        site via ``probe_fold_consumer`` (ir/cost.choose_probe_fold);
-        fan-out members and fused limits keep the stage on its own
-        program (the gather program yields exactly one batch and never
-        polls a budget)."""
+        probe-into-consumer fold. Fan-out members and fused limits keep
+        the stage on its own program (the gather program yields exactly
+        one batch and never polls a budget)."""
         from auron_tpu.ops.joins import HashJoinOp
         j = self.input
         if not isinstance(j, HashJoinOp) or j.join_type != "inner":
-            return None
-        if not getattr(j, "probe_fold_consumer", True):
             return None
         if self.has_limit():
             return None

@@ -341,12 +341,10 @@ def _keys_match(probe_keys, probe_idx, build_keys, build_idx) -> jax.Array:
 class _MatchState:
     """What one ``HashJoinOp.execute`` threads through its probe batches'
     match programs: the folded consumer chain (or none) with its carries,
-    the program-cache counters, and the per-run probe statistics the
-    ir/cost history reads."""
+    and the program-cache counters."""
 
     __slots__ = ("partition", "fragments", "frag_keys", "carries",
-                 "built_c", "hit_c", "fold_built_c", "fold_hit_c",
-                 "rows_out", "batches")
+                 "built_c", "hit_c", "fold_built_c", "fold_hit_c")
 
     def __init__(self, partition: int, built_c, hit_c):
         self.partition = partition
@@ -355,7 +353,6 @@ class _MatchState:
         self.carries = None
         self.built_c, self.hit_c = built_c, hit_c
         self.fold_built_c = self.fold_hit_c = None
-        self.rows_out = self.batches = 0
 
     def count(self, built: bool) -> None:
         (self.built_c if built else self.hit_c).add(1)
@@ -377,15 +374,6 @@ class HashJoinOp(PhysicalOp):
     #: probe shard reads the full relation, so a sharded probe stage
     #: never exchanges build rows; probe batches shard on the batch dim.
     mesh_build_kind = "hash_build"
-
-    #: Fusion 2.0 plan facts, stamped per-instance by the planner's
-    #: _fold_combine pass; class defaults keep hand-built op trees (and
-    #: plans produced with the fusion pass disabled) on sane behavior.
-    #: cost_site is the (plan_fp, site) key for the ir/cost history;
-    #: probe_fold_consumer gates the probe-into-consumer fold the
-    #: downstream FusedStageOp asks for (ir/cost.choose_probe_fold).
-    cost_site = None
-    probe_fold_consumer = True
 
     def __init__(self, probe: PhysicalOp, build: PhysicalOp,
                  probe_keys: list[ir.Expr], build_keys: list[ir.Expr],
@@ -508,10 +496,6 @@ class HashJoinOp(PhysicalOp):
             finally:
                 if consumer is not None:
                     consumer.close()
-                if match.batches:
-                    from auron_tpu.ir import cost as cost_mod
-                    cost_mod.observe(self.cost_site, match.rows_out,
-                                     match.rows_out, match.batches)
 
         return count_output(stream(), metrics)
 
@@ -613,11 +597,8 @@ class HashJoinOp(PhysicalOp):
         else:   # the fused probe program already ran the candidate search
             lo, counts, total = pre
         # the one sync a probe batch: the exact candidate count sizes the
-        # match program's output capacity; it is also the cost history's
-        # per-run probe statistic (ir/cost), so observing adds no sync
+        # match program's output capacity
         total_i = int(_profile.timed_get(total))
-        match.rows_out += total_i
-        match.batches += 1
         if total_i == 0 and self.join_type in ("inner", "right"):
             # no candidates → no pair batch, and a folded consumer chain
             # (with its carries) never sees one

@@ -127,10 +127,10 @@ def _fused_split_program(frag_keys: tuple, part_sig: tuple,
     the split (the round-robin start offset, kept on device).
 
     ``combine`` (ops/agg.AggOp.build_combine_stage) is the map-side
-    combine fold: the elided partial agg's per-batch combine (or
-    state-layout passthrough) runs between the chain and the partition-id
-    computation, so ``out_schema``/``part_exprs`` see the partial state
-    layout and groups merge BEFORE the split. Stateless — no carries —
+    combine fold: the elided partial agg's per-batch combine runs
+    between the chain and the partition-id computation, so
+    ``out_schema``/``part_exprs`` see the partial state layout and
+    groups merge BEFORE the split. Stateless — no carries —
     and the program grows one extra output: the pre-combine live-row
     count, read by the caller in its existing counts fence (combine
     telemetry never adds a sync point). ``combine_sig`` keys the trace."""
@@ -683,18 +683,13 @@ class ShuffleExchangeOp(PhysicalOp):
         self._lock = threading.Lock()
         self._buffer: Optional[_ExchangeBuffer] = None
         #: map-side combine fold (ir/planner._fold_combine): when the
-        #: child is an eligible partial AggOp, the planner stamps the
-        #: fold mode here and the agg's combine stage joins the split
-        #: program — 'combine' merges groups per batch/round BEFORE the
-        #: rows cross, 'passthrough' ships state-layout rows uncombined
-        #: (cost-model choice for high-cardinality sites / the
-        #: auron.fusion.combine=off arm). None = no fold (ineligible
-        #: child or fusion off); the agg then executes as its own op.
+        #: child is an eligible partial AggOp, the planner stamps
+        #: 'combine' here and the agg's combine stage joins the split
+        #: program, merging groups per batch/round BEFORE the rows
+        #: cross. None = no fold (ineligible child — combine_why says
+        #: why — or fusion off); the agg then executes as its own op.
         self.combine_mode: Optional[str] = None
         self.combine_why: str = ""
-        #: (plan fingerprint, preorder site label) — the ir/cost.py
-        #: history key; None for ad-hoc plans without a fingerprint
-        self.cost_site: Optional[tuple] = None
         #: (live rows in, live rows out) of the last materialization's
         #: combine stage, for the route record (set under _lock)
         self._combine_stats: Optional[tuple] = None
@@ -773,20 +768,14 @@ class ShuffleExchangeOp(PhysicalOp):
                     combine_ratio=round(rows_out / rows_in, 4)
                     if rows_in else 1.0)
 
-    def _note_combine(self, metrics, rows_in: int, rows_out: int,
-                      batches: int) -> None:
-        """Book one materialization's combine figures: metric counters,
-        the route-event stash, and the ir/cost.py per-site history (only
-        COMBINE-mode runs feed history — a passthrough run ships every
-        row and would record a fake ratio of 1.0 over the honest one)."""
+    def _note_combine(self, metrics, rows_in: int, rows_out: int) -> None:
+        """Book one materialization's combine figures: metric counters
+        and the route-event stash."""
         rows_in = int(rows_in)     # graft: disable=GL001 -- summed on host from the fold's fenced counts readback
         rows_out = int(rows_out)   # graft: disable=GL001 -- host int like rows_in
         self._combine_stats = (rows_in, rows_out)
         metrics.counter("combine_rows_in").add(rows_in)
         metrics.counter("combine_rows_out").add(rows_out)
-        if self.combine_mode == "combine":
-            from auron_tpu.ir import cost as cost_mod
-            cost_mod.observe(self.cost_site, rows_in, rows_out, batches)
 
     def _materialize_mesh(self, ctx: ExecContext, metrics, write_time,
                           reason: str) -> "_MeshExchangeBuffer":
@@ -844,7 +833,6 @@ class ShuffleExchangeOp(PhysicalOp):
         self._combine_stats = None
         comb_in_total = 0
         comb_out_total = 0
-        comb_batches = 0
         in_schema = input_op.schema()
         part_exprs = self.partitioning.exprs
         part_key = ("hash", part_exprs)
@@ -1008,7 +996,6 @@ class ShuffleExchangeOp(PhysicalOp):
                     # round (escalation re-runs were discarded)
                     comb_in_total += int(np.asarray(comb_h).sum())   # graft: disable=GL001 -- comb_h rode the round's host counts readback
                     comb_out_total += int(counts.sum())
-                    comb_batches += n_live
                 if fmetrics is not None:
                     # the folded chain still owns its plan node:
                     # post-chain live rows are what the exchange
@@ -1067,7 +1054,7 @@ class ShuffleExchangeOp(PhysicalOp):
                 metrics.counter("mesh_quota_escalations").add(escalations)
                 if combine is not None:
                     self._note_combine(metrics, comb_in_total,
-                                       comb_out_total, comb_batches)
+                                       comb_out_total)
                 _record_route(self, metrics, "all_to_all", reason,
                               rounds=rounds, escalations=escalations,
                               bytes=bytes_moved, rows=total,
@@ -1082,7 +1069,7 @@ class ShuffleExchangeOp(PhysicalOp):
             ctx, metrics, write_time, buffer, iters, pending, carries_h,
             demote_reason, rounds, escalations, bytes_moved, fragments,
             frag_keys, fmetrics, t_demote, input_op, combine, combine_sig,
-            (comb_in_total, comb_out_total, comb_batches))
+            (comb_in_total, comb_out_total))
 
     def _emit_demote(self, metrics, err, rounds_done: int, plane) -> None:
         """Put the demotion DECISION on the timeline the moment it is
@@ -1106,7 +1093,7 @@ class ShuffleExchangeOp(PhysicalOp):
                         bytes_moved: int, fragments, frag_keys,
                         fmetrics, t_demote: float, input_op=None,
                         combine=None, combine_sig=None,
-                        comb_totals=(0, 0, 0)):
+                        comb_totals=(0, 0)):
         """Host continuation of a demoted exchange: the REMAINING rounds
         re-route down the existing ladder (``all_to_all`` → host
         ``device_buffer``; RSS stays the durable tier below it), run
@@ -1138,12 +1125,11 @@ class ShuffleExchangeOp(PhysicalOp):
         recompute_rows = 0
         recompute_bytes = 0
         host_rows = 0
-        comb_in_total, comb_out_total, comb_batches = comb_totals
+        comb_in_total, comb_out_total = comb_totals
         pending_by_map = dict(pending)
 
         def route_batch(in_p: int, batch: DeviceBatch, carries):
-            nonlocal host_rows, comb_in_total, comb_out_total, \
-                comb_batches
+            nonlocal host_rows, comb_in_total, comb_out_total
             # entry tagged with its source map so the combined read
             # path can interleave map-major
             with timer(write_time) as t:
@@ -1153,7 +1139,6 @@ class ShuffleExchangeOp(PhysicalOp):
             if combine is not None:
                 comb_in_total += comb_in_h
                 comb_out_total += n
-                comb_batches += 1
             offsets = np.concatenate(
                 [np.zeros(1, np.int64), np.cumsum(counts_h)])
             host.add(sorted_batch, offsets)
@@ -1192,8 +1177,7 @@ class ShuffleExchangeOp(PhysicalOp):
         metrics.counter("mesh_rounds").add(rounds_done)
         metrics.counter("mesh_quota_escalations").add(escalations)
         if combine is not None:
-            self._note_combine(metrics, comb_in_total, comb_out_total,
-                               comb_batches)
+            self._note_combine(metrics, comb_in_total, comb_out_total)
         _record_route(self, metrics, "demoted", demote_reason,
                       rounds=rounds_done, escalations=escalations,
                       bytes=bytes_moved, rows=host_rows,
@@ -1275,7 +1259,7 @@ class ShuffleExchangeOp(PhysicalOp):
         With a planner-stamped ``combine_mode`` the child IS the partial
         AggOp being elided: the exchange executes the agg's OWN child
         (chain fragments when one fused below it) and folds the agg's
-        combine/passthrough stage into the split program. Without one,
+        combine stage into the split program. Without one,
         this is exactly the PR 2 chain fold (_split_fragments)."""
         from auron_tpu.ops.fused import FusedStageOp
         if self.combine_mode is not None:
@@ -1288,8 +1272,7 @@ class ShuffleExchangeOp(PhysicalOp):
                     fragments, frag_keys, input_op = \
                         frags, keys, inner.input
             return (fragments, frag_keys, input_op,
-                    agg.build_combine_stage(self.combine_mode),
-                    agg.combine_signature(self.combine_mode))
+                    agg.build_combine_stage(), agg.combine_signature())
         frag_info = self._split_fragments()
         if frag_info is None:
             return None
@@ -1326,7 +1309,6 @@ class ShuffleExchangeOp(PhysicalOp):
                        kmetrics=ctx.metrics_for("kernels"))
         comb_in_total = 0
         comb_out_total = 0
-        n_batches = 0
         from auron_tpu.columnar.batch import batch_nbytes
 
         # the trailing carry slot (rows seen at the split — the
@@ -1350,7 +1332,6 @@ class ShuffleExchangeOp(PhysicalOp):
                 if combine is not None:
                     comb_in_total += comb_in_h
                     comb_out_total += live
-                    n_batches += 1
                 f_rows.add(live)
                 f_batches.add(1)
                 # honest data-movement figure for the host route: live
@@ -1365,8 +1346,7 @@ class ShuffleExchangeOp(PhysicalOp):
                 buffer.add(sorted_batch, offsets)
             split_seen = carries[-1:]
         if combine is not None:
-            self._note_combine(metrics, comb_in_total, comb_out_total,
-                               n_batches)
+            self._note_combine(metrics, comb_in_total, comb_out_total)
 
     # -- reduce side --------------------------------------------------------
 

@@ -121,8 +121,8 @@ def stage_exchange_program(mesh: Mesh, axis: str, n_dev: int,
 
     ``combine`` (ops/agg.AggOp.build_combine_stage, keyed by
     ``combine_sig``) is the map-side combine fold: each shard merges its
-    round's groups (or re-lays rows out in partial-state form) BETWEEN
-    the chain and the partition-id compute, so what crosses
+    round's groups BETWEEN the chain and the partition-id compute, so
+    what crosses
     ``lax.all_to_all`` is per-shard GROUPS — fewer live rows through the
     collective, the cheapest scale-out win available. Stateless, so the
     escalation re-run and the demoted host path replay it exactly.

@@ -327,18 +327,16 @@ def bench_profile_q01() -> dict:
 
 
 def bench_fusion2() -> dict:
-    """Map-side combine A/B (Fusion 2.0): the dup-heavy grouped-agg
-    shape — a q01-style multi-partition sum/count group-by whose key
-    domain is tiny relative to the row count — executed with
-    ``auron.fusion.combine`` on and off. Records the live shuffle bytes
-    both ways (``shuffle_bytes_live`` counts exactly what crosses the
-    exchange: batch bytes scaled by live rows), the reduction, and the
-    combined run's end-to-end rows/s. Additive like every satellite
-    metric: tools/perf_gate.py --smoke gates the reduction floor."""
+    """Map-side combine (Fusion 2.0): the dup-heavy grouped-agg shape —
+    a q01-style multi-partition sum/count group-by whose key domain is
+    tiny relative to the row count. Records the live shuffle bytes
+    (``shuffle_bytes_live`` counts exactly what crosses the exchange:
+    batch bytes scaled by live rows), the combine stage's rows in and
+    out, and the end-to-end rows/s. Additive like every satellite
+    metric: tools/perf_gate.py --smoke gates that the combine engaged."""
     import numpy as np
     import pyarrow as pa
 
-    from auron_tpu import config as cfg
     from auron_tpu.frontend import Session, col
     from auron_tpu.frontend import functions as F
     from auron_tpu.ops.base import ExecContext
@@ -349,39 +347,32 @@ def bench_fusion2() -> dict:
         "k": pa.array(rng.integers(0, 200, n), pa.int64()),
         "v": pa.array(rng.integers(0, 1000, n), pa.int64()),
     })
-    conf = cfg.get_config()
 
-    def run(combine: bool):
-        if not combine:
-            conf.set("auron.fusion.combine", "false")
-        try:
-            s = Session()
-            s.register("fusion2_bench", tbl)
-            df = (s.table("fusion2_bench").repartition(4).group_by("k")
-                  .agg(F.sum(col("v")).alias("sv"),
-                       F.count(col("v")).alias("c")))
-            op = s.plan_physical(df)
-            ctx = ExecContext()
-            t0 = time.perf_counter()
-            for p in range(df.num_partitions):
-                for _ in op.execute(p, ctx):
-                    pass
-            wall = time.perf_counter() - t0
-            m = ctx.metrics["shuffle_exchange"]
-            return m.counter("shuffle_bytes_live").value, wall
-        finally:
-            if not combine:
-                conf.unset("auron.fusion.combine")
+    def run():
+        s = Session()
+        s.register("fusion2_bench", tbl)
+        df = (s.table("fusion2_bench").repartition(4).group_by("k")
+              .agg(F.sum(col("v")).alias("sv"),
+                   F.count(col("v")).alias("c")))
+        op = s.plan_physical(df)
+        ctx = ExecContext()
+        t0 = time.perf_counter()
+        for p in range(df.num_partitions):
+            for _ in op.execute(p, ctx):
+                pass
+        wall = time.perf_counter() - t0
+        m = ctx.metrics["shuffle_exchange"]
+        return (m.counter("shuffle_bytes_live").value,
+                m.counter("combine_rows_in").value,
+                m.counter("combine_rows_out").value, wall)
 
-    run(True)   # warm programs so the timed runs measure execution
-    run(False)
-    b_on, w_on = run(True)
-    b_off, _w_off = run(False)
+    run()   # warm programs so the timed run measures execution
+    nbytes, rows_in, rows_out, wall = run()
     return {
-        "combine_shuffle_bytes_on": int(b_on),
-        "combine_shuffle_bytes_off": int(b_off),
-        "combine_byte_reduction": round(1.0 - b_on / max(1, b_off), 4),
-        "fusion2_rows_per_sec": round(n / w_on, 1),
+        "combine_shuffle_bytes": int(nbytes),
+        "combine_rows_in": int(rows_in),
+        "combine_rows_out": int(rows_out),
+        "fusion2_rows_per_sec": round(n / wall, 1),
     }
 
 

@@ -62,7 +62,11 @@ class TestRegistry:
         # finds nothing under tests/)
         "auron.pipeline" + ".enabled",
         "auron.metrics.device" + "_sync",
-    ], ids=["never_defined", "pipeline_knob", "timer_sync_knob"])
+        # and the two that selected the fusion pass's other arms
+        "auron.fusion" + ".combine",
+        "auron.fusion.cost" + "_model",
+    ], ids=["never_defined", "pipeline_knob", "timer_sync_knob",
+            "fusion_combine_knob", "fusion_cost_model_knob"])
     def test_unknown_key_rejected(self, key):
         conf = cfg.AuronConfig()
         with pytest.raises(KeyError):

@@ -316,13 +316,11 @@ def test_combine_eligibility_vocabulary(fusion_on):
     assert partials[0].combine_fold_reason() == "float_sum_inexact"
 
 
-def test_planner_stamps_combine_mode_and_knob(fusion_on):
-    """The selection walk stamps the exchange: combine by default on an
-    eligible site, passthrough (state rows cross uncombined) when the
-    combine knob is off, and no fold at all — with the explain reason —
-    on an ineligible float sum."""
+def test_planner_stamps_combine_mode(fusion_on):
+    """The fold walk stamps the exchange: combine on an eligible site,
+    and no fold at all — with the explain reason — on an ineligible
+    float sum."""
     from auron_tpu.parallel.exchange import ShuffleExchangeOp
-    conf = cfg.get_config()
     s = _grouped_session()
     df = (s.table("g").repartition(4)
           .group_by("k").agg(F.sum(col("v")).alias("sv")))
@@ -334,13 +332,6 @@ def test_planner_stamps_combine_mode_and_knob(fusion_on):
         return ex[0]
 
     assert exchange_of(df).combine_mode == "combine"
-    conf.set(cfg.FUSION_COMBINE, False)
-    try:
-        ex = exchange_of(df)
-        assert ex.combine_mode == "passthrough"
-        assert ex.combine_why == "combine_off"
-    finally:
-        conf.unset(cfg.FUSION_COMBINE)
     df_f = (s.table("g").repartition(4)
             .group_by("k").agg(F.sum(col("f")).alias("sf")))
     ex = exchange_of(df_f)
@@ -348,17 +339,17 @@ def test_planner_stamps_combine_mode_and_knob(fusion_on):
     assert ex.combine_why == "float_sum_inexact"
 
 
-def test_combine_bit_identical_and_fewer_shuffle_bytes(fusion_on):
-    """The fold's whole contract in one run: combine on vs off return
-    byte-identical tables (values AND order) while the combined run
-    ships strictly fewer live bytes across the exchange and books its
-    rows-in/rows-out counters honestly."""
+def test_combine_bit_identical_and_fewer_shuffle_rows():
+    """The fold's whole contract in one run: the combined plan and the
+    unfused one (``auron.fusion.enabled`` off: the partial aggregate
+    runs as its own operator) return byte-identical tables (values AND
+    order), while the combined run books its rows-in/rows-out counters
+    honestly — fewer rows cross the exchange than entered the fold."""
     from auron_tpu.ops.base import ExecContext
     conf = cfg.get_config()
 
-    def run(combine: bool):
-        if not combine:
-            conf.set(cfg.FUSION_COMBINE, False)
+    def run(fused: bool):
+        conf.set("auron.fusion.enabled", fused)
         try:
             s = _grouped_session(seed=3)
             df = (s.table("g").repartition(4)
@@ -379,72 +370,24 @@ def test_combine_bit_identical_and_fewer_shuffle_bytes(fusion_on):
                     m.counter("combine_rows_in").value,
                     m.counter("combine_rows_out").value)
         finally:
-            conf.unset(cfg.FUSION_COMBINE)
+            conf.unset("auron.fusion.enabled")
 
     rows_on, bytes_on, in_on, out_on = run(True)
     rows_off, bytes_off, in_off, out_off = run(False)
     assert rows_on == rows_off          # bit-identical, order included
-    assert 0 < bytes_on < bytes_off
+    assert bytes_on > 0 and bytes_off > 0
     assert in_on > out_on > 0           # the fold merged groups...
-    assert in_off == out_off            # ...passthrough ships them all
+    assert in_off == out_off == 0       # ...the unfused plan has no fold
 
 
-def test_cost_model_selects_against_history():
-    """ir/cost.choose_exchange_mode: greedy when the model is off; the
-    static prior combines on a fresh site; an observed ratio of ~1.0
-    (high-cardinality keys — the sort buys nothing) flips the SAME site
-    to passthrough while a dup-heavy site keeps combining."""
-    from auron_tpu.ir import cost
-    conf = cfg.get_config()
-    cost.clear()
-    site, site2 = ("fp-unit", "x0"), ("fp-unit", "x1")
-    try:
-        conf.set(cfg.FUSION_COST_MODEL, False)
-        try:
-            assert cost.choose_exchange_mode(conf, site, 65536) \
-                == ("combine", "greedy")
-        finally:
-            conf.unset(cfg.FUSION_COST_MODEL)
-        mode, why = cost.choose_exchange_mode(conf, site, 65536)
-        assert mode == "combine" and why.startswith("prior")
-        cost.observe(site, 100_000, 100_000, 2)
-        mode, why = cost.choose_exchange_mode(conf, site, 65536)
-        assert mode == "passthrough" and why.startswith("observed")
-        cost.observe(site2, 100_000, 500, 2)
-        assert cost.choose_exchange_mode(conf, site2, 65536)[0] \
-            == "combine"
-    finally:
-        cost.clear()
-
-
-def test_probe_fold_declined_on_starved_history():
-    """choose_probe_fold: fold by default (greedy and the no-history
-    prior), declined once observed probe output rows per batch fall
-    under the amortization floor."""
-    from auron_tpu.ir import cost
-    conf = cfg.get_config()
-    cost.clear()
-    site = ("fp-unit", "j0")
-    try:
-        assert cost.choose_probe_fold(conf, site)
-        cost.observe(site, 10, 10, 100)   # 0.1 rows/batch: starved
-        assert not cost.choose_probe_fold(conf, site)
-        site2 = ("fp-unit", "j1")
-        cost.observe(site2, 100_000, 100_000, 10)
-        assert cost.choose_probe_fold(conf, site2)
-    finally:
-        cost.clear()
-
-
-def test_probe_into_consumer_fold_counted_and_bit_identical(
-        fusion_on, monkeypatch):
+def test_probe_into_consumer_fold_counted_and_bit_identical():
     """An inner join under a fused consumer chain runs gather + chain
     as ONE program (probe_consumer_folded counts it) and returns the
-    same table as the unfused plan, which a monkeypatched selector
-    forces for the B side."""
+    same table as the unfused plan (``auron.fusion.enabled`` off)."""
     from auron_tpu.ops.base import ExecContext
     from auron_tpu.ops.fused import FusedStageOp
     from auron_tpu.ops.joins import HashJoinOp
+    conf = cfg.get_config()
     rng = np.random.default_rng(9)
     n = 8000
     left = pa.table({
@@ -456,35 +399,36 @@ def test_probe_into_consumer_fold_counted_and_bit_identical(
         "w": pa.array(rng.integers(0, 9, 600), pa.int64()),
     })
 
-    def run():
-        s = Session()
-        s.register("l", left)
-        s.register("r", right)
-        df = (s.table("l").join(s.table("r"), on="k")
-              .filter(col("v") > 100)
-              .with_column("z", col("v") + col("w")))
-        op = s.plan_physical(df)
-        stages = [o for o in _walk(op) if isinstance(o, FusedStageOp)
-                  and isinstance(o.input, HashJoinOp)]
-        assert stages, "consumer chain did not fuse over the join"
-        ctx = ExecContext()
-        rows = []
-        for p in range(df.num_partitions):
-            for b in op.execute(p, ctx):
-                m = int(b.num_rows)
-                rows.extend(zip(*(np.asarray(c.data[:m]).tolist()
-                                  for c in b.columns)))
-        folded = ctx.metrics["fused_stage"].counter(
-            "probe_consumer_folded").value
-        return sorted(rows), folded
+    def run(fused: bool):
+        conf.set("auron.fusion.enabled", fused)
+        try:
+            s = Session()
+            s.register("l", left)
+            s.register("r", right)
+            df = (s.table("l").join(s.table("r"), on="k")
+                  .filter(col("v") > 100)
+                  .with_column("z", col("v") + col("w")))
+            op = s.plan_physical(df)
+            stages = [o for o in _walk(op) if isinstance(o, FusedStageOp)
+                      and isinstance(o.input, HashJoinOp)]
+            assert bool(stages) == fused, \
+                "consumer chain did not fuse over the join"
+            ctx = ExecContext()
+            rows = []
+            for p in range(df.num_partitions):
+                for b in op.execute(p, ctx):
+                    m = int(b.num_rows)
+                    rows.extend(zip(*(np.asarray(c.data[:m]).tolist()
+                                      for c in b.columns)))
+            folded = ctx.metrics["fused_stage"].counter(
+                "probe_consumer_folded").value if fused else 0
+            return sorted(rows), folded
+        finally:
+            conf.unset("auron.fusion.enabled")
 
-    rows_folded, n_folded = run()
+    rows_folded, n_folded = run(True)
     assert n_folded >= 1
-    from auron_tpu.ir import cost
-    monkeypatch.setattr(cost, "choose_probe_fold",
-                        lambda conf, site: False)
-    rows_unfused, n_unfused = run()
-    assert n_unfused == 0
+    rows_unfused, _ = run(False)
     assert rows_folded == rows_unfused
 
 
@@ -511,3 +455,222 @@ def test_combined_exchange_program_reused_across_runs(fusion_on):
     assert d.builds == 0, \
         f"second identical combined run rebuilt {d.builds} program(s)"
     assert d.hits >= 1
+
+
+# ---------------------------------------------------------------------------
+# a plan is a function of its bytes (ISSUE 47): what a task's operator
+# tree depends on is its bytes, the configuration and the platform —
+# nothing the process ran before
+# ---------------------------------------------------------------------------
+
+def _describe(op) -> str:
+    """The operator tree as a planner decision record: every node's
+    repr, each exchange's combine stamp and, per fused stage, whether it
+    folds into the join beneath it."""
+    from auron_tpu.ops.fused import FusedStageOp
+    from auron_tpu.parallel.exchange import ShuffleExchangeOp
+    lines = []
+
+    def walk(o, depth):
+        line = "  " * depth + repr(o)
+        if isinstance(o, ShuffleExchangeOp):
+            line += f" combine={o.combine_mode!r} why={o.combine_why!r}"
+        if isinstance(o, FusedStageOp):
+            line += f" consumer_fold={o._consumer_fold(None) is not None}"
+        lines.append(line)
+        for c in o.children:
+            walk(c, depth + 1)
+
+    walk(op, 0)
+    return "\n".join(lines)
+
+
+def _drain(op, n_partitions=1):
+    from auron_tpu.ops.base import ExecContext
+    ctx = ExecContext()
+    for p in range(n_partitions):
+        for _ in op.execute(p, ctx):
+            pass
+
+
+def _starved_probe(s, tmp_path=None):
+    """An inner join whose probe finds a handful of candidates a batch,
+    under a fused consumer chain (over parquet files when a server, which
+    sees no session's tables, is to run it)."""
+    rng = np.random.default_rng(5)
+    tables = {"l": pa.table({
+        "k": pa.array(rng.integers(0, 100_000, 8000), pa.int64()),
+        "v": pa.array(rng.integers(0, 1000, 8000), pa.int64())}),
+        "r": pa.table({
+            "k": pa.array(np.arange(40), pa.int64()),
+            "w": pa.array(np.arange(40), pa.int64())})}
+    if tmp_path is None:
+        for name, table in tables.items():
+            s.register(name, table)
+        left, right = s.table("l"), s.table("r")
+    else:
+        import pyarrow.parquet as pq
+        for name, table in tables.items():
+            pq.write_table(table, str(tmp_path / f"{name}.parquet"))
+        left = s.read_parquet(str(tmp_path / "l.parquet"))
+        right = s.read_parquet(str(tmp_path / "r.parquet"))
+    return (left.join(right, on="k")
+            .filter(col("v") >= 0).with_column("z", col("v") + col("w")))
+
+
+def _high_cardinality_exchange(s):
+    """A grouped sum whose every row is its own group: the map-side
+    combine merges nothing."""
+    s.register("u", pa.table({
+        "k": pa.array(np.arange(20_000), pa.int64()),
+        "v": pa.array(np.arange(20_000) % 7, pa.int64())}))
+    return (s.table("u").repartition(4)
+            .group_by("k").agg(F.sum(col("v")).alias("sv")))
+
+
+def _scalar_subquery(s):
+    """The starved join again, its filter bound by a scalar subquery:
+    ``ScalarSubqueryBinderOp`` plans the tree only once it runs."""
+    from auron_tpu.frontend import scalar_subquery
+    df = _starved_probe(s)
+    low = scalar_subquery(s.table("r").group_by().agg(
+        F.min(col("w")).alias("m")))
+    return df.filter(col("z") >= low)
+
+
+@pytest.fixture(scope="module")
+def tpcds_tables():
+    import tempfile
+    from auron_tpu.it.tpcds import generate
+    with tempfile.TemporaryDirectory(prefix="fusion_same_tree_") as d:
+        yield generate(d, scale=0.02)
+
+
+@pytest.mark.parametrize("case", [
+    "starved_probe", "high_cardinality_exchange", "q65", "q96",
+    "mesh_exchange", "scalar_subquery"])
+def test_the_same_bytes_plan_the_same_tree(case, fusion_on, monkeypatch,
+                                           request):
+    """Plan and run the same task bytes three times in one process: the
+    three operator trees — node for node, with every exchange's combine
+    stamp and every stage's consumer fold — are one tree. (The parent
+    planned the first run from a prior and the later ones from what the
+    earlier runs had counted: a starved probe lost its consumer fold, a
+    high-cardinality exchange its combine.)"""
+    import jax
+    from auron_tpu.frontend import session as session_mod
+    if case == "mesh_exchange":
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 virtual devices")
+        fusion_on.set(cfg.MESH_ENABLED, True)
+        request.addfinalizer(lambda: fusion_on.unset(cfg.MESH_ENABLED))
+    planned: dict = {}
+    real = session_mod.plan_from_bytes
+
+    def spy(data, ctx=None):
+        op = real(data, ctx)
+        planned.setdefault(data, []).append(op)
+        return op
+
+    monkeypatch.setattr(session_mod, "plan_from_bytes", spy)
+    if case in ("q65", "q96"):
+        from auron_tpu.it.tpcds_queries import QUERIES
+        tables = request.getfixturevalue("tpcds_tables")
+        q = next(q for q in QUERIES if q.name == case)
+        for _ in range(3):
+            q.run(Session(), tables)
+    else:
+        build = {"starved_probe": _starved_probe,
+                 "high_cardinality_exchange": _high_cardinality_exchange,
+                 "mesh_exchange": _high_cardinality_exchange,
+                 "scalar_subquery": _scalar_subquery}[case]
+        s = Session()
+        df = build(s)
+        for _ in range(3):
+            _drain(s.plan_physical(df), df.num_partitions)
+    assert planned
+    for data, ops in planned.items():
+        assert len(ops) == 3, "each run plans the task's bytes once"
+        trees = [_describe(op) for op in ops]
+        assert trees[0] == trees[1] == trees[2], \
+            f"{case}: the same {len(data)} bytes planned differently:\n" \
+            + "\n--- then ---\n".join(trees)
+    described = "\n".join(_describe(ops[0]) for ops in planned.values())
+    if case in ("high_cardinality_exchange", "mesh_exchange"):
+        assert "combine='combine'" in described
+    else:
+        assert "consumer_fold=True" in described
+
+
+def test_a_served_task_runs_the_same_programs_on_every_send(fusion_on,
+                                                            tmp_path):
+    """Through ``AuronServer``: the first and the third send of the same
+    task bytes report the same ``program_calls`` by site — no send's
+    programs depend on what an earlier send counted."""
+    from auron_tpu.runtime.serving import AuronClient, AuronServer
+    blob = _starved_probe(Session(), tmp_path).task_bytes(0)
+    server = AuronServer()
+    server.serve_background()
+    try:
+        client = AuronClient(*server.address)
+        by_site = []
+        for _ in range(3):
+            _table, done = client.execute(blob)
+            counts = done["cost_ledger"]["counts"]
+            by_site.append(counts["program_calls_by_site"])
+        assert by_site[0] and by_site[0] == by_site[2], by_site
+    finally:
+        server.shutdown()
+
+
+def test_planning_reads_no_process_state():
+    """The fusion pass takes the tree and the configuration, nothing
+    else: no fingerprint parameter, and no file under ``auron_tpu/ir``
+    asks the journal for one."""
+    import inspect
+    import pathlib
+
+    import auron_tpu.ir as ir_pkg
+    from auron_tpu.ir import planner
+    assert list(inspect.signature(planner.fuse_stages).parameters) \
+        == ["op", "config"]
+    assert list(inspect.signature(planner._fold_combine).parameters) \
+        == ["op"]
+    assert "plan_fp" not in {f.name for f in
+                             planner.PlannerContext.__dataclass_fields__
+                             .values()}
+    for path in pathlib.Path(ir_pkg.__file__).parent.glob("*.py"):
+        text = path.read_text()
+        assert "plan_fingerprint" not in text, path.name
+        assert "plan_fp" not in text, path.name
+    assert not (pathlib.Path(ir_pkg.__file__).parent / "cost.py").exists()
+
+
+def test_combine_stage_has_one_form(fusion_on):
+    """The folded combine stage has one form: its builder and its trace
+    signature take no mode, two plannings of one aggregate give one
+    signature, and the second run of a high-cardinality aggregate — the
+    site the parent re-planned as a pass-through — builds no program."""
+    import inspect
+    from auron_tpu.ops.agg import AggOp
+    from auron_tpu.parallel.exchange import ShuffleExchangeOp
+    assert list(inspect.signature(AggOp.combine_signature).parameters) \
+        == ["self"]
+    assert list(inspect.signature(AggOp.build_combine_stage).parameters) \
+        == ["self"]
+    s = Session()
+    df = _high_cardinality_exchange(s)
+    sigs = []
+
+    def run():
+        op = s.plan_physical(df)
+        (ex,) = [o for o in _walk(op) if isinstance(o, ShuffleExchangeOp)
+                 and o.combine_mode == "combine"]
+        sigs.append(ex.child.combine_signature())
+        _drain(op, df.num_partitions)
+
+    run()
+    p0 = programs.totals()
+    run()
+    assert sigs[0] == sigs[1]
+    assert programs.delta(p0).builds == 0

@@ -1155,7 +1155,7 @@ def test_auron_profile_and_the_hotspot_tool_are_gone():
     names = {l.split("`")[1] for l in rows}
     assert knob not in names and knob + ".dir" not in names
     assert knob + ".enabled" in names
-    assert len(rows) == 79
+    assert len(rows) == 77
     assert not os.path.exists(os.path.join(_REPO, "tools", tool))
     from auron_tpu.obs import profile
     assert not hasattr(profile, export)

@@ -301,13 +301,11 @@ class TestPerfGate:
         assert last["ops_gate"] == "pass"
         assert last["ops_scrapes"] >= 1
         # the Fusion 2.0 gate (PR 17): map-side combine engaged (the
-        # combined run shipped strictly fewer live shuffle bytes) and
-        # the reduction clears the baseline floor
+        # combine stage shipped strictly fewer rows than it took) and
+        # the exchange's live-bytes ledger is lit
         assert last["fusion_gate"] == "pass"
-        assert 0 < last["combine_shuffle_bytes_on"] \
-            < last["combine_shuffle_bytes_off"]
-        assert last["combine_byte_reduction"] \
-            >= last["combine_byte_reduction_floor"]
+        assert last["combine_shuffle_bytes"] > 0
+        assert last["combine_rows_in"] > last["combine_rows_out"] > 0
         # the fleet-observability gate (ISSUE 20): trace propagation +
         # the cost ledger engaged on every on-arm query, disengaged
         # off-arm, and cost under the overhead limit
@@ -381,7 +379,7 @@ class TestPerfGate:
         monkeypatch.setattr(perf_gate, "run_lint_gate",
                             lambda: {"lint_gate": "pass", "lint_new": 0})
         monkeypatch.setattr(perf_gate, "run_fusion_gate",
-                            lambda smoke: {"fusion_gate": "pass"})
+                            lambda: {"fusion_gate": "pass"})
         monkeypatch.setattr(perf_gate, "run_obs_fleet_gate",
                             lambda smoke: {"obs_fleet_gate": "pass",
                                            "obs_fleet_overhead_pct": 0.1,
@@ -414,7 +412,7 @@ class TestPerfGate:
         monkeypatch.setattr(perf_gate, "run_lint_gate",
                             lambda: {"lint_gate": "pass", "lint_new": 0})
         monkeypatch.setattr(perf_gate, "run_fusion_gate",
-                            lambda smoke: {"fusion_gate": "pass"})
+                            lambda: {"fusion_gate": "pass"})
         monkeypatch.setattr(perf_gate, "run_obs_fleet_gate",
                             lambda smoke: {"obs_fleet_gate": "pass",
                                            "obs_fleet_overhead_pct": 0.1,
@@ -430,42 +428,43 @@ class TestPerfGate:
 
     def test_fusion_gate_fails_on_disengaged_combine(self, monkeypatch):
         """The fusion arm's seeded regression: a map-side combine that
-        SILENTLY disengaged (the A/B ships identical live shuffle
-        bytes both ways — exactly what a broken eligibility check or a
-        dead fold would measure) must fail the arm loudly, not pass on
-        a vacuous 0% reduction, and a dark byte ledger (zero counters)
-        must fail rather than divide its way to a pass. Runs the arm
-        directly on stubbed bench numbers — the engagement checks are
-        pure verdict logic."""
+        SILENTLY disengaged (the stage ships as many rows as it took —
+        exactly what a broken eligibility check or a dead fold would
+        measure) must fail the arm loudly, a combine stage that never
+        ran (zero rows in and out) likewise, and a dark byte ledger
+        (zero counter) must fail rather than pass on rows alone. Runs
+        the arm directly on stubbed bench numbers — the engagement
+        checks are pure verdict logic."""
         import bench
         monkeypatch.setattr(bench, "bench_fusion2", lambda: {
-            "combine_shuffle_bytes_on": 9_400_000,
-            "combine_shuffle_bytes_off": 9_400_000,
-            "combine_byte_reduction": 0.0,
+            "combine_shuffle_bytes": 9_400_000,
+            "combine_rows_in": 200_000, "combine_rows_out": 200_000,
             "fusion2_rows_per_sec": 1.0})
-        out = perf_gate.run_fusion_gate({})
+        out = perf_gate.run_fusion_gate()
         assert out["fusion_gate"] == "fail"
         assert "silently disengaged" in out["fusion_error"]
         monkeypatch.setattr(bench, "bench_fusion2", lambda: {
-            "combine_shuffle_bytes_on": 0,
-            "combine_shuffle_bytes_off": 0,
-            "combine_byte_reduction": 0.0,
+            "combine_shuffle_bytes": 9_400_000,
+            "combine_rows_in": 0, "combine_rows_out": 0,
             "fusion2_rows_per_sec": 1.0})
-        out = perf_gate.run_fusion_gate({})
+        out = perf_gate.run_fusion_gate()
+        assert out["fusion_gate"] == "fail"
+        assert "silently disengaged" in out["fusion_error"]
+        monkeypatch.setattr(bench, "bench_fusion2", lambda: {
+            "combine_shuffle_bytes": 0,
+            "combine_rows_in": 200_000, "combine_rows_out": 800,
+            "fusion2_rows_per_sec": 1.0})
+        out = perf_gate.run_fusion_gate()
         assert out["fusion_gate"] == "fail"
         assert "ledger went dark" in out["fusion_error"]
-        # a half-broken fold (reduction below the floor but nonzero)
-        # fails on the floor, with the measured number in the verdict
+        # an engaged fold passes, with the measured rows in the verdict
         monkeypatch.setattr(bench, "bench_fusion2", lambda: {
-            "combine_shuffle_bytes_on": 8_000_000,
-            "combine_shuffle_bytes_off": 9_400_000,
-            "combine_byte_reduction": 0.149,
+            "combine_shuffle_bytes": 3_700_000,
+            "combine_rows_in": 200_000, "combine_rows_out": 800,
             "fusion2_rows_per_sec": 1.0})
-        out = perf_gate.run_fusion_gate(
-            {"combine_byte_reduction_floor": 0.40})
-        assert out["fusion_gate"] == "fail"
-        assert "floor" in out["fusion_error"]
-        assert out["combine_byte_reduction_floor"] == 0.40
+        out = perf_gate.run_fusion_gate()
+        assert out["fusion_gate"] == "pass"
+        assert out["combine_rows_out"] == 800
 
     def test_obs_fleet_gate_rejects_seeded_regressions(self):
         """The ISSUE 20 satellite: a seeded +10% trace-propagation /

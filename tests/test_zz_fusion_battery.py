@@ -50,106 +50,34 @@ def test_query_bit_identical_fused_vs_unfused(qname, tables):
 
 
 # ---------------------------------------------------------------------------
-# Fusion 2.0: map-side combine + cost-based selection (same contract —
-# both knobs may only change which programs run, never a value or an
-# order)
+# Fusion 2.0: the map-side combine rides the fused side of the battery
+# above (its unfused side runs the unfolded partial aggregate)
 # ---------------------------------------------------------------------------
 
-import jax
+def test_combine_engages_on_battery_plans(tables, monkeypatch):
+    """Anti-vacuity for the battery above: the grouped-agg-over-shuffle
+    queries' plans must actually STAMP the combine fold on an exchange —
+    all-ineligible plans would make the differential pass trivially."""
+    from auron_tpu.frontend import session as session_mod
+    from auron_tpu.parallel.exchange import ShuffleExchangeOp
+    planned = []
+    real = session_mod.plan_from_bytes
 
-from auron_tpu.ir import cost as _cost
+    def spy(data, ctx=None):
+        op = real(data, ctx)
+        planned.append(op)
+        return op
 
-needs_mesh = pytest.mark.skipif(len(jax.devices()) < 8,
-                                reason="needs 8 virtual devices")
+    def walk(op):
+        yield op
+        for c in op.children:
+            yield from walk(c)
 
-#: grouped-agg-over-shuffle shapes — the plans where the combine fold
-#: and the cost model's exchange decision actually engage
-_COMBINE_NAMES = ["q1", "q43", "q62", "q73", "q96"]
-
-
-@pytest.mark.parametrize("qname", _COMBINE_NAMES)
-def test_query_bit_identical_combine_on_vs_off(qname, tables):
-    """auron.fusion.combine on vs off: the map-side combine merges each
-    shard's groups before the exchange, so combined runs reduce the
-    SAME per-group contributions in a different grouping — the fold's
-    eligibility gate (exact kinds only, no float sums) is what makes
-    this equality exact rather than approximate."""
-    conf = cfg.get_config()
-    q = _q(qname)
-    try:
-        conf.set("auron.fusion.combine", False)
-        off = q.run(Session(), tables)
-    finally:
-        conf.unset("auron.fusion.combine")
-    on = q.run(Session(), tables)
-    assert on.num_rows == off.num_rows
-    assert on.equals(off), \
-        f"{qname}: combined result differs from combine-off " \
-        f"(values or order)"
-
-
-def test_combine_engages_on_battery_plans(tables):
-    """Anti-vacuity for the A/B above: the battery queries' plans must
-    actually STAMP combine decisions (recorded at plan time keyed on
-    the plan fingerprint) — all-ineligible plans would make the
-    differential pass trivially."""
-    _cost.clear()
-    try:
-        for qname in ("q62", "q96"):
-            _q(qname).run(Session(), tables)
-        mix = {}
-        for _kind, mode in _cost.decisions_snapshot().values():
-            mix[mode] = mix.get(mode, 0) + 1
-        assert mix.get("combine", 0) >= 1, \
-            f"no combine decision on any battery plan: {mix}"
-    finally:
-        _cost.clear()
-
-
-@pytest.mark.parametrize("qname", ["q62", "q96"])
-def test_query_bit_identical_cost_selected_vs_greedy(qname, tables):
-    """auron.fusion.cost_model selection is plan-SHAPE only: the greedy
-    run (model off), the history-seeding first selected run, and the
-    re-planned steady-state run all return identical tables — whatever
-    fold/probe decisions the model flips with real statistics."""
-    conf = cfg.get_config()
-    q = _q(qname)
-    _cost.clear()
-    try:
-        conf.set("auron.fusion.cost_model", False)
-        try:
-            greedy = q.run(Session(), tables)
-        finally:
-            conf.unset("auron.fusion.cost_model")
-        seeded = q.run(Session(), tables)     # run 1 records history
-        selected = q.run(Session(), tables)   # run 2 re-plans with it
-    finally:
-        _cost.clear()
-    assert seeded.equals(greedy), \
-        f"{qname}: first selected run differs from greedy"
-    assert selected.equals(greedy), \
-        f"{qname}: history-selected plan changed values or order"
-
-
-@needs_mesh
-@pytest.mark.parametrize("qname", ["q62", "q96"])
-def test_query_bit_identical_mesh_combine_on_vs_off(qname, tables):
-    """The fold rides the SPMD route too: with the mesh on, the
-    per-shard combine stage runs INSIDE the staged exchange program
-    (stage_exchange_program's 6th output is the pre-combine row count),
-    and combine on vs off stay bit-identical there as well."""
-    conf = cfg.get_config()
-    q = _q(qname)
-    conf.set(cfg.MESH_ENABLED, True)
-    try:
-        on = q.run(Session(), tables)
-        conf.set("auron.fusion.combine", False)
-        try:
-            off = q.run(Session(), tables)
-        finally:
-            conf.unset("auron.fusion.combine")
-    finally:
-        conf.unset(cfg.MESH_ENABLED)
-    assert on.num_rows == off.num_rows
-    assert on.equals(off), \
-        f"{qname}: mesh combined result differs from combine-off"
+    monkeypatch.setattr(session_mod, "plan_from_bytes", spy)
+    for qname in ("q62", "q96"):
+        planned.clear()
+        _q(qname).run(Session(), tables)
+        modes = [o.combine_mode for op in planned for o in walk(op)
+                 if isinstance(o, ShuffleExchangeOp)]
+        assert "combine" in modes, \
+            f"{qname}: no exchange of its plan is combined: {modes}"

