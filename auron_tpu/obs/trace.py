@@ -500,10 +500,12 @@ COUNT_KEYS = ("program_calls", "readbacks",
               # not a re-entrant pass): one a round, quota re-runs inside
               "mesh_gang_acquires",
               # its read side: the non-empty (partition, source, round)
-              # slices the reducers read, one gather each, and the
-              # bytes of those that a device_put then moved from
-              # another chip to the home chip
-              "mesh_read_batches", "mesh_home_bytes",
+              # slices the reducers read, one gather each, the live rows
+              # of those slices (a host count the buffer holds: rows ÷
+              # batches is the size of what a reducer steps on), and the
+              # bytes that a device_put then moved from another chip to
+              # the home chip
+              "mesh_read_batches", "mesh_read_rows", "mesh_home_bytes",
               # the general (unbounded-key) aggregation: batches folded
               # into the hash table's state and into the sort path's,
               # groups the keyed aggregations emitted, capacity

@@ -597,6 +597,8 @@ class _MeshExchangeBuffer:
         for live, batches in _cuts:
             if live[source] > 0:
                 trace.count("mesh_read_batches")
+                # graft: disable=GL001 -- live is the round's host counts row (numpy)
+                trace.count("mesh_read_rows", int(live[source]))
                 yield batches[source]
 
     def partition_batches(self, p: int) -> Iterator[DeviceBatch]:

@@ -60,7 +60,7 @@ MESH_EXCHANGES = dict.fromkeys(STAR + ("q65sa", "q65sam"), 1) \
     | dict.fromkeys(("q65", "q65m"), 3)
 MESH_COUNTS = ("mesh_rounds", "mesh_escalations", "mesh_bytes",
                "mesh_slot_bytes", "mesh_gang_acquires", "mesh_read_batches",
-               "mesh_home_bytes")
+               "mesh_read_rows", "mesh_home_bytes")
 MESH_SPAN_KEYS = ("gang_wait", "mesh_stack", "mesh_round")
 #: the program the reduce side reads an exchange buffer with (PR 39)
 READ_CUT = "parallel.exchange.read_cut"
@@ -251,6 +251,8 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
     assert counts["mesh_rounds"] == MESH_EXCHANGES[plan]
     # every reducer partition reads at most one slice a source and round
     assert 0 < counts["mesh_read_batches"] <= 16 * counts["mesh_rounds"]
+    # a slice handed on holds at least a row
+    assert counts["mesh_read_rows"] >= counts["mesh_read_batches"]
     # three of the four partitions live on another chip than the home one
     assert counts["mesh_home_bytes"] > 0
     assert counts["mesh_escalations"] >= 0

@@ -15,13 +15,12 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -29,8 +28,14 @@ def one_chip():
     # and cannot be read back without one: keep it out
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes):
@@ -435,3 +440,88 @@ def test_the_batch_shrink_compiles_for_the_chip(one_chip):
     assert cb.shrink_target(1 << 16, 790) == 1024
     kern = cb._shrink_kernel(cb.leaf_layout(batch.columns), 1 << 16, 1024)
     assert kern.lower(batch).compile() is not None
+
+
+def test_the_q28_stage_program_compiles_for_the_mesh(topo, tmp_path):
+    """The sharded stage program of a q28 band's hash exchange (PR 48),
+    for the four chips of the described v5e 2x2: the band's select ->
+    filter chain and the ``partial`` aggregate's combine at 65,536 slots
+    folded into the ``shard_map`` program, murmur3 of the decimal(7,2)
+    key (one int64 word), the split and ``lax.all_to_all`` of the
+    ``partial_merge`` state columns at the first quota. No decimal key
+    and no aggregate state had crossed the mesh on the chip before; one
+    of the six programs (they differ in their filters' constants) takes
+    the TPU's compiler over a minute."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import pyarrow.parquet as pq
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from auron_tpu.columnar.batch import PrimitiveColumn
+    from auron_tpu.frontend import Session
+    from auron_tpu.ir.planner import PlannerContext, plan_from_bytes
+    from auron_tpu.parallel import mesh_exchange as mex
+    from auron_tpu.parallel.exchange import ShuffleExchangeOp
+    from auron_tpu.parallel.partitioning import HashPartitioning
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import cell, datagen
+    fact = datagen.generate(seed=7, scale=0.002,
+                            tables=("store_sales",))["store_sales"]
+    paths = []
+    for i in range(4):
+        paths.append(str(tmp_path / f"store_sales_{i}.parquet"))
+        pq.write_table(fact.slice(i * 1000, 1000), paths[-1])
+    session = Session()
+    try:
+        blob = cell.load_module("plans", "q28").build(
+            session, {}, paths, 4).task_bytes(0)
+    finally:
+        session.close()
+    found = []
+
+    def walk(op):
+        if isinstance(op, ShuffleExchangeOp) \
+                and isinstance(op.partitioning, HashPartitioning):
+            found.append(op)
+        for child in op.children:
+            walk(child)
+
+    walk(plan_from_bytes(blob, PlannerContext()))
+    assert len(found) == 6 and all(x.combine_mode == "combine"
+                                   for x in found)
+    exchange = found[0]
+    fragments, frag_keys, input_op, combine, sig = exchange._fold_spec()
+    assert fragments and combine is not None
+    in_schema, axis = input_op.schema(), "data"
+    capacity, quota = 1 << 16, 1 << 15      # a scan batch; the first quota
+    assert len(in_schema) == 4          # the quantity and three money words
+    mesh = Mesh(np.array(topo.devices), (axis,))
+    # the registry's key does not hold the mesh: build for this one
+    mex._STAGE_EXCHANGE_PROGRAMS.clear()
+    try:
+        kern, built = mex.stage_exchange_program(
+            mesh, axis, 4, frag_keys, ("hash", exchange.partitioning.exprs),
+            in_schema, exchange.child.schema(), capacity, quota, fragments,
+            exchange.partitioning.exprs, combine, sig)
+    finally:
+        mex._STAGE_EXCHANGE_PROGRAMS.clear()
+    assert built
+
+    def sharded(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    columns = tuple(
+        PrimitiveColumn(sharded((4 * capacity,), jnp.int64, P(axis)),
+                        sharded((4 * capacity,), jnp.bool_, P(axis)))
+        for _ in in_schema)
+    compiled = kern.lower(
+        columns, sharded((4,), jnp.int32, P(axis)),
+        sharded((4, len(fragments)), jnp.int64, P(axis, None))).compile()
+    assert "all-to-all" in compiled.as_text()
