@@ -18,10 +18,13 @@ Batches are pytrees, so they pass straight through jit / shard_map / scan.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, replace
 
 from auron_tpu.columnar.decimal128 import Decimal128Column
 from typing import Optional, Sequence, Union
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -500,6 +503,32 @@ def concat_columns(a: Column, b: Column) -> Column:
         data=jnp.concatenate([a.data, b.data]),
         validity=jnp.concatenate([a.validity, b.validity]),
     )
+
+
+def concat_live_rows(batches: Sequence[DeviceBatch],
+                     capacity: int) -> DeviceBatch:
+    """The live rows of ``batches`` — a prefix of each — one batch after
+    another, as ONE batch of ``capacity`` rows, the rest padding (traced;
+    for a program's body). Every leaf is stacked (string widths and list
+    element counts unified first) and output row j is row (j - live rows
+    before its batch) of the batch it falls in: no sort, one gather. The
+    row counts are operands and their sum is computed here."""
+    cols = tuple(
+        functools.reduce(concat_columns, unify_column_widths(
+            [b.columns[i] for b in batches]))
+        for i in range(batches[0].num_columns))
+    counts = jnp.stack([jnp.asarray(b.num_rows, jnp.int32)
+                        for b in batches])
+    ends = jnp.cumsum(counts)
+    rows = jnp.arange(capacity, dtype=jnp.int32)
+    k = jnp.minimum(
+        jnp.sum(rows[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        len(batches) - 1)
+    capacities = [b.capacity for b in batches]
+    starts = jnp.asarray(np.cumsum([0] + capacities[:-1]), jnp.int32)
+    src = starts[k] + rows - (ends - counts)[k]
+    return gather_batch(DeviceBatch(cols, ends[-1]),
+                        jnp.clip(src, 0, sum(capacities) - 1), ends[-1])
 
 
 def compact(batch: DeviceBatch, keep: jax.Array) -> DeviceBatch:

@@ -499,13 +499,16 @@ COUNT_KEYS = ("program_calls", "readbacks",
               # times the stage took the gang door (``MeshPlane.gang``,
               # not a re-entrant pass): one a round, quota re-runs inside
               "mesh_gang_acquires",
-              # its read side: the non-empty (partition, source, round)
-              # slices the reducers read, one gather each, the live rows
-              # of those slices (a host count the buffer holds: rows ÷
-              # batches is the size of what a reducer steps on), and the
-              # bytes that a device_put then moved from another chip to
-              # the home chip
+              # its read side: the batches the reducers were handed (one
+              # a partition where its rows fit one), their live rows (a
+              # host count the buffer holds: rows ÷ batches is the size
+              # of what a reducer steps on), the bytes that a device_put
+              # moved from another chip to the home chip, and the
+              # non-empty (partition, source, round) slices merged into
+              # those batches (slices ÷ batches: how often the merge
+              # engages, 1.0 where a partition hears from one source)
               "mesh_read_batches", "mesh_read_rows", "mesh_home_bytes",
+              "mesh_read_slices",
               # the general (unbounded-key) aggregation: batches folded
               # into the hash table's state and into the sort path's,
               # groups the keyed aggregations emitted, capacity

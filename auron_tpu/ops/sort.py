@@ -27,8 +27,7 @@ import jax.numpy as jnp
 
 from auron_tpu.columnar.batch import (DeviceBatch, ListColumn,
                                       PrimitiveColumn, StringColumn,
-                                      unify_column_widths,
-                                      concat_columns, gather_batch)
+                                      concat_live_rows, gather_batch)
 from auron_tpu.columnar.schema import DataType, Schema
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import EvalContext, evaluate
@@ -267,37 +266,16 @@ def _sort_with_words_kernel(sort_exprs: tuple, in_schema: Schema,
 
 @program_cache("ops.sort.concat", maxsize=256)
 def _concat_kernel(capacities: tuple, widths: tuple):
-    """Buffered batches into ONE capacity-bucketed batch: every leaf
-    stacked (string widths and list element counts unified first), the
-    live rows of each batch — a prefix of it — gathered to the front in
-    batch order, the rest padding. The row counts are operands and their
-    sum is computed here, so no count comes to the host; the key holds
-    the input capacities and widths, never a row count."""
-    stacked_cap = sum(capacities)
-    total_cap = bucket_rows(stacked_cap)
+    """Buffered batches into ONE capacity-bucketed batch
+    (``columnar/batch.concat_live_rows``): the live rows of each batch
+    gathered to the front in batch order, the rest padding. The row
+    counts are operands and their sum is computed there, so no count
+    comes to the host; the key holds the input capacities and widths,
+    never a row count."""
+    total_cap = bucket_rows(sum(capacities))
 
     def auron_ops_sort_concat(batches: tuple):
-        cols = []
-        for i in range(batches[0].num_columns):
-            parts = unify_column_widths([b.columns[i] for b in batches])
-            merged = parts[0]
-            for p in parts[1:]:
-                merged = concat_columns(merged, p)
-            cols.append(merged)
-        counts = jnp.stack([jnp.asarray(b.num_rows, jnp.int32)
-                            for b in batches])
-        ends = jnp.cumsum(counts)
-        # output row j is row (j - live rows before its batch) of the
-        # batch it falls in: no sort, the live rows are prefixes
-        rows = jnp.arange(total_cap, dtype=jnp.int32)
-        k = jnp.minimum(
-            jnp.sum(rows[:, None] >= ends[None, :], axis=1,
-                    dtype=jnp.int32), len(capacities) - 1)
-        starts = jnp.cumsum(jnp.asarray((0,) + capacities[:-1], jnp.int32))
-        src = starts[k] + rows - (ends - counts)[k]
-        stacked = DeviceBatch(tuple(cols), ends[-1])
-        return gather_batch(stacked, jnp.clip(src, 0, stacked_cap - 1),
-                            ends[-1])
+        return concat_live_rows(batches, total_cap)
 
     return programs.jit(auron_ops_sort_concat)
 

@@ -31,14 +31,15 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Iterator, NamedTuple, Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from auron_tpu.columnar.batch import DeviceBatch, gather_batch
+from auron_tpu.columnar.batch import (DeviceBatch, concat_live_rows,
+                                      gather_batch)
 from auron_tpu.columnar.schema import Schema
 from auron_tpu.exprs.eval import EvalContext, evaluate
 from auron_tpu.obs import profile as _profile
@@ -77,34 +78,121 @@ def _split_body(batch: DeviceBatch, pids, num_partitions: int):
     return sorted_batch, counts
 
 
-@program_cache("parallel.exchange.read_cut", maxsize=256)
-def _read_cut_kernel(n_slices: int, capacity: int):
-    """The reduce side's read: ``n_slices`` row ranges of ONE column
-    tree, each cut out as a batch of ``capacity`` rows. The ranges are
-    an OPERAND (``bounds``: int32[2, n_slices], starts over live
-    counts), so the key holds shapes only and no run's counts compile;
-    rows past a slice's count are padding, masked invalid — the same
-    rows in the same order the eager ``gather_batch`` gave."""
+class _Slice(NamedTuple):
+    """Rows ``[start, start + rows)`` of a column tree on the device:
+    what one map sent one reducer in one batch — a range of a sorted
+    map batch, of a mesh round's shard, or a restored frame (``whole``:
+    all of a tree that holds nothing else, padding invalid)."""
+    tree: tuple
+    start: int
+    rows: int
+    whole: bool = False
 
-    def auron_parallel_exchange_read_cut(columns, bounds):
-        base = DeviceBatch(columns, bounds[1, 0])
-        last = base.capacity - 1
-        rows = jnp.arange(capacity, dtype=jnp.int32)
-        return tuple(
-            gather_batch(base, jnp.minimum(bounds[0, i] + rows, last),
-                         bounds[1, i])
-            for i in range(n_slices))
+
+@program_cache("parallel.exchange.read_cut", maxsize=256)
+def _read_cut_kernel(tree_of_slice: tuple, window: int, capacity: int):
+    """The reduce side's read: one row range (a slice) of each of
+    ``len(tree_of_slice)`` column trees — slice ``k`` lies in tree
+    ``tree_of_slice[k]`` — as the columns of ONE batch of ``capacity``
+    rows, the slices' live rows one after another in slice order. Each
+    slice is gathered as a window of ``window`` rows (no slice holds
+    more) and the windows' live rows are gathered into the one batch
+    (``columnar/batch.concat_live_rows``: output row ``j`` comes from
+    the window that the running sum of the live counts says). The ranges are an OPERAND
+    (``bounds``: int32[2, slices], starts over live counts), so the key
+    holds shapes only and no run's counts compile; rows past the
+    counts' sum are padding, masked invalid — the same rows in the same
+    order the eager ``gather_batch`` of every slice gave."""
+
+    def auron_parallel_exchange_read_cut(trees, bounds):
+        rows = jnp.arange(window, dtype=jnp.int32)
+        windows = []
+        for k, t in enumerate(tree_of_slice):
+            base = DeviceBatch(trees[t], bounds[1, k])
+            windows.append(gather_batch(
+                base, jnp.minimum(bounds[0, k] + rows, base.capacity - 1),
+                bounds[1, k]))
+        if len(windows) == 1:
+            return windows[0].columns
+        return concat_live_rows(windows, capacity).columns
 
     return programs.jit(auron_parallel_exchange_read_cut)
 
 
-def _cut(columns, starts, counts, capacity: int) -> tuple:
-    """One launch of the read-cut program: rows ``[starts[i], starts[i]
-    + counts[i])`` of ``columns`` as a batch of ``capacity`` rows, for
-    every i, on the device the columns live on. The host's starts and
-    counts ride the call as its one small operand."""
-    bounds = np.array([starts, counts], np.int32)
-    return _read_cut_kernel(bounds.shape[1], capacity)(columns, bounds)
+def _cut(run: list, batch_capacity: int) -> DeviceBatch:
+    """One launch of the read-cut program over ``run`` (``_Slice``s on
+    one device): their live rows, one slice after another, as ONE batch
+    there. The host's starts and counts ride the call as its one small
+    operand, and their sum is the batch's row count: a host integer,
+    which no consumer has to read from the device. The batch's capacity
+    follows the run's SHAPE — its slices x the bucket of its fullest
+    (past ``batch_capacity``: the bucket of its live rows) — so a run
+    compiles, here and in what consumes it, for the shapes its slices
+    handed on one by one compiled for, not for every sum of them. A
+    slice that is a whole batch is handed on as it is."""
+    bounds = np.array([[piece.start for piece in run],
+                       [piece.rows for piece in run]], np.int32)
+    rows = np.int32(bounds[1].sum())
+    if len(run) == 1 and run[0].whole:
+        return DeviceBatch(run[0].tree, rows)
+    # a tree (a round's shard) may hold several of the run's slices
+    trees = list({id(piece.tree): piece.tree for piece in run}.values())
+    at = {id(tree): i for i, tree in enumerate(trees)}
+    window = bucket_rows(int(bounds[1].max()))   # graft: disable=GL001 -- the slices' host counts (numpy)
+    capacity = len(run) * window
+    if capacity > batch_capacity:
+        capacity = int(rows)   # graft: disable=GL001 -- a numpy sum of host counts
+    columns = _read_cut_kernel(
+        tuple(at[id(piece.tree)] for piece in run), window,
+        bucket_rows(capacity))(tuple(trees), bounds)
+    return DeviceBatch(columns, rows)
+
+
+def _merged(slices, batch_capacity: int) -> Iterator[DeviceBatch]:
+    """What a reducer is handed of ``slices`` (``_Slice``s in the order
+    their rows are due): the slices packed greedily, in that order,
+    into runs whose live rows fit ``batch_capacity`` (a slice larger
+    than that is a run of its own), ONE batch and one launch a run
+    (``_cut``). An empty slice rides the
+    run it falls in (a program's shapes follow the slices, never their
+    counts); a run of empty slices is no batch. EVERY read of an
+    exchange — the mesh buffer's, the host buffer's, a demoted one's,
+    the shuffle service's — hands its slices on through here, so the
+    same rows reach a reducer in the same batches whatever the route,
+    whatever spilled and whatever was demoted on the way: a double sum
+    adds up in the same order."""
+    run, rows = [], 0
+    for piece in slices:
+        if run and rows + piece.rows > batch_capacity:
+            if rows:
+                yield _cut(run, batch_capacity)
+            run, rows = [], 0
+        run.append(piece)
+        rows += piece.rows
+    if rows:
+        yield _cut(run, batch_capacity)
+
+
+def _counted(batches) -> Iterator[DeviceBatch]:
+    """``counts.mesh_read_batches``: the batches a reducer of a
+    mesh-routed exchange is handed."""
+    from auron_tpu.obs import trace
+    for batch in batches:
+        trace.count("mesh_read_batches")
+        yield batch
+
+
+def _frame_slice(frame: bytes) -> Optional[_Slice]:
+    """A serialized frame (a spilled entry's partition, a shuffle
+    service's map output) restored to the device, whole; None for an
+    empty one."""
+    from auron_tpu.columnar.serde import (deserialize_host_batch,
+                                          host_to_batch)
+    host, _extras = deserialize_host_batch(frame)
+    if not host.num_rows:
+        return None
+    batch = host_to_batch(host, bucket_rows(host.num_rows))
+    return _Slice(batch.columns, 0, host.num_rows, whole=True)
 
 
 #: split programs: the upstream fused-stage chain (when one folds), the
@@ -315,6 +403,8 @@ class _ExchangeBuffer:
         self.mem = mem_manager
         self.metrics = metrics
         self.codec_level = conf.get(cfg.SPILL_CODEC_LEVEL)
+        #: the most live rows of a batch handed to a reducer
+        self.capacity = conf.get(cfg.BATCH_CAPACITY)
         self.consumer_name = f"exchange-{id(op):x}"
         #: entry = ["dev", DeviceBatch, offsets] | ["dev-spilling", ...] |
         #: ["spill", SpillRef, offsets, num_rows]
@@ -387,55 +477,33 @@ class _ExchangeBuffer:
 
     # -- read side ----------------------------------------------------------
 
-    def _entry_partition(self, e, p: int,
-                         capacity: Optional[int] = None
-                         ) -> Optional[DeviceBatch]:
-        """Partition ``p``'s rows of ONE entry (device slice or restored
-        host frame) as a batch of ``capacity`` rows (the bucket of its
-        own rows unless given); None when the entry holds no rows for
-        ``p``."""
-        from auron_tpu.columnar.serde import (deserialize_host_batch,
-                                              host_to_batch)
-        offsets = e[2]
-        # graft: disable=GL001 -- offsets is a host ndarray
-        lo, hi = int(offsets[p]), int(offsets[p + 1])
-        n_p = hi - lo
-        if n_p <= 0:
-            return None
-        capacity = capacity or bucket_rows(n_p)
-        if e[0].startswith("dev"):
-            # "dev" or "dev-spilling": the device batch in this
-            # snapshot's entry list stays valid even if a concurrent
-            # spill swaps the entry afterwards
-            (out,) = _cut(e[1].columns, [lo], [n_p], capacity)
-            return out
-        host, _extras = deserialize_host_batch(e[1].frame_at(p))
-        return host_to_batch(host, capacity)
+    def entry_slices(self, p: int, indices=None) -> Iterator[_Slice]:
+        """Partition ``p``'s rows of the entries (of those at
+        ``indices`` only: a demoted exchange's per-source read — a spill
+        swaps entries IN PLACE, so indices stay stable across pressure),
+        in append order: a device entry's range of its sorted batch,
+        empty or not; a spilled entry's own frame restored, unless it is
+        empty."""
+        with self._lock:
+            entries = list(self.entries) if indices is None \
+                else [self.entries[i] for i in indices]
+        for e in entries:
+            offsets = e[2]
+            # graft: disable=GL001 -- offsets is a host ndarray
+            lo, hi = int(offsets[p]), int(offsets[p + 1])
+            if e[0].startswith("dev"):
+                # "dev" or "dev-spilling": the device batch in this
+                # snapshot's entry list stays valid even if a concurrent
+                # spill swaps the entry afterwards
+                yield _Slice(e[1].columns, lo, hi - lo)
+            elif hi > lo:
+                yield _frame_slice(e[1].frame_at(p))
 
     def partition_batches(self, p: int) -> Iterator[DeviceBatch]:
-        with self._lock:
-            entries = list(self.entries)
-        # the partition's slices share ONE capacity, the bucket of its
-        # fullest slice (as a mesh round's do): what collects them — a
-        # sort's concatenation, a join's build — then compiles for one
-        # shape a bucket, not for every mix of its sources' buckets
-        # graft: disable=GL001 -- offsets are host ndarrays
-        top = max((int(e[2][p + 1] - e[2][p]) for e in entries), default=0)
-        for e in entries:
-            out = self._entry_partition(e, p, bucket_rows(top))
-            if out is not None:
-                yield out
-
-    def entry_batches(self, p: int, indices) -> Iterator[DeviceBatch]:
-        """Partition ``p``'s rows of the entries at ``indices`` only —
-        the demoted read path's per-source slice (a spill swaps entries
-        IN PLACE, so indices stay stable across pressure)."""
-        with self._lock:
-            picked = [self.entries[i] for i in indices]
-        for e in picked:
-            out = self._entry_partition(e, p)
-            if out is not None:
-                yield out
+        """Partition ``p``'s rows, entry by entry in append order, in
+        as few batches as hold them (``_merged``): the small batches of
+        a gather or of a stage's tail reach their reducer as one."""
+        return _merged(self.entry_slices(p), self.capacity)
 
     def close(self) -> None:
         if self.mem is not None:
@@ -470,22 +538,27 @@ class _MeshExchangeBuffer:
     reducer partition p's rows in ``[src * quota + r]`` layout), the
     host recv-count matrix ``[n_dev, n_dev]`` (dest × source) and the
     round's quota. ``partition_batches(p)`` reads device p's shard
-    zero-copy and slices per SOURCE — source-major, rounds-minor — so a
-    reducer sees exactly the map-major batch sequence the host
-    device-buffer path yields (the bit-identity contract of the mesh
-    battery). Registered with the memory manager for visibility and the
-    per-device footprint ledger; entries are device-resident by design
+    zero-copy and hands the reducer its rows as ONE batch (as few as
+    hold them, past the configured batch capacity), the slices a
+    SOURCE and round in it source-major, rounds-minor — so a reducer
+    sees exactly the map-major row sequence the host device-buffer path
+    yields (the bit-identity contract of the mesh battery). Registered
+    with the memory manager for visibility and the per-device footprint
+    ledger; entries are device-resident by design
     and do not spill (``spill`` returns 0 — the mesh route is chosen
     only when the whole exchange fits the mesh; RSS remains the
     durable tier)."""
 
     def __init__(self, op, mesh, axis: str, n_out: int, mem_manager,
-                 metrics):
+                 metrics, conf=None):
+        from auron_tpu import config as cfg
         self.mesh = mesh
         self.axis = axis
         self.n_out = n_out
         self.mem = mem_manager
         self.metrics = metrics
+        #: the most live rows of a batch handed to a reducer
+        self.capacity = (conf or cfg.get_config()).get(cfg.BATCH_CAPACITY)
         self.consumer_name = f"mesh-exchange-{id(op):x}"
         #: [(out_cols tree, counts np[n_dev, n_dev], quota), ...]
         self.entries: list = []
@@ -548,31 +621,26 @@ class _MeshExchangeBuffer:
     def spill(self) -> int:
         return 0   # device-resident by design (see class docstring)
 
-    def partition_cuts(self, p: int) -> list:
-        """Partition ``p``'s received rows on the home device, cut by
-        source: for every round that brought ``p`` anything, the row of
-        its live counts a source and the ``n_out`` batches of ONE
-        read-cut call (``parallel.exchange.read_cut``) over device
-        ``p``'s shard. A shard on another chip crosses to the engine's
-        home device first, in ONE ``device_put`` of its tree, and is
-        cut there: downstream operators mix these rows with build sides
-        and aggregation state committed at home, and one program serves
-        every partition (cut where it lies, the program would be
-        loaded a chip and its ``n_out`` slices would cross leaf by
-        leaf). The slices of a round share one capacity, the bucket of
-        its fullest slice. Hoisted ONCE a partition by both read
-        paths."""
+    def partition_shards(self, p: int) -> list:
+        """Partition ``p``'s received rows on the home device: (the
+        shard of device ``p``, its live counts a source, its quota) of
+        every round that brought ``p`` anything. A shard on another
+        chip crosses to the engine's home device first, in ONE
+        ``device_put`` of its tree, and is cut there: downstream
+        operators mix these rows with build sides and aggregation state
+        committed at home, and one program serves every partition (cut
+        where it lies, the program would be loaded a chip). Hoisted
+        ONCE a partition by both read paths."""
         from auron_tpu.obs import trace
         from auron_tpu.parallel import mesh as mesh_mod
         with self._lock:
             entries = list(self.entries)
         home = self.mesh.devices.flat[0]
         away = self.mesh.devices.flat[p] != home
-        cuts = []
+        shards = []
         for cols, counts, quota in entries:
             live = counts[p]
-            top = int(live.max())
-            if top <= 0:
+            if live.max() <= 0:
                 continue
             shard = jax.tree_util.tree_map(
                 lambda a: mesh_mod.local_shard(a, p, self.mesh), cols)
@@ -581,32 +649,32 @@ class _MeshExchangeBuffer:
                 trace.count("mesh_home_bytes", sum(
                     l.nbytes for l in jax.tree_util.tree_leaves(shard)))
                 shard = jax.device_put(shard, home)
-            cuts.append((live, _cut(
-                shard, np.arange(self.n_out) * quota, live,
-                bucket_rows(top))))
-        return cuts
+            shards.append((shard, live, quota))
+        return shards
 
-    def source_batches(self, p: int, source: int,
-                       _cuts=None) -> Iterator[DeviceBatch]:
-        """Partition ``p``'s rows received from ONE source map, rounds
-        in order — the per-source slice the demoted read path
-        interleaves with host entries; empty slices are skipped."""
+    def source_slices(self, shards: list, source: int) -> Iterator[_Slice]:
+        """What ONE source map sent the partition of ``shards``, rounds
+        in order (an empty slice rides along: a partition's read then
+        has one shape whichever sources reached it); the non-empty ones
+        are counted here."""
         from auron_tpu.obs import trace
-        if _cuts is None:
-            _cuts = self.partition_cuts(p)
-        for live, batches in _cuts:
-            if live[source] > 0:
-                trace.count("mesh_read_batches")
-                # graft: disable=GL001 -- live is the round's host counts row (numpy)
-                trace.count("mesh_read_rows", int(live[source]))
-                yield batches[source]
+        for shard, live, quota in shards:
+            n = int(live[source])   # graft: disable=GL001 -- live is the round's host counts row (numpy)
+            if n:
+                trace.count("mesh_read_slices")
+                trace.count("mesh_read_rows", n)
+            yield _Slice(shard, source * quota, n)
 
     def partition_batches(self, p: int) -> Iterator[DeviceBatch]:
-        cuts = self.partition_cuts(p)
-        # SOURCE-major, rounds-minor: map s's round-r rows appear where
-        # the host path's entry (map s, batch r) would
-        for s in range(self.n_out):
-            yield from self.source_batches(p, s, _cuts=cuts)
+        """Partition ``p``'s received rows as ONE batch where they fit
+        the configured batch capacity (else packed, slice by slice, into
+        as few as hold them: ``_merged``), SOURCE-major, rounds-minor:
+        map s's round-r rows appear where the host path's entry (map s,
+        batch r) would, so a reducer's group order is the host route's."""
+        shards = self.partition_shards(p)
+        return _counted(_merged(
+            (piece for s in range(self.n_out)
+             for piece in self.source_slices(shards, s)), self.capacity))
 
     def close(self) -> None:
         if self.mem is not None:
@@ -626,11 +694,13 @@ class _DemotedExchangeBuffer:
     host ``_ExchangeBuffer`` (``host_sources[i]`` = the map partition
     host entry ``i`` came from). The read path interleaves them
     SOURCE-major: for each map, first its mesh rounds (rounds-minor),
-    then its host entries in append order — exactly the map-major batch
-    sequence both the pure-mesh and pure-host paths yield, so the
-    bit-identity contract (group order included) survives the
-    demotion. Both sub-buffers stay registered with the memory manager
-    (the host half spills under pressure like any classic exchange)."""
+    then its host entries in append order — exactly the map-major
+    slice sequence both the pure-mesh and pure-host paths hand to
+    ``_merged``, which packs it into the same batches, so the
+    bit-identity contract (group order and the order a double sum adds
+    up in included) survives the demotion. Both sub-buffers stay
+    registered with the memory manager (the host half spills under
+    pressure like any classic exchange)."""
 
     def __init__(self, mesh_buffer: "_MeshExchangeBuffer",
                  host_buffer: "_ExchangeBuffer", host_sources: list,
@@ -644,14 +714,18 @@ class _DemotedExchangeBuffer:
         by_source: dict[int, list[int]] = {}
         for i, s in enumerate(self.host_sources):
             by_source.setdefault(s, []).append(i)
-        # the partition's ONE cut a round, hoisted as the pure-mesh
-        # read path hoists it
-        cuts = self.mesh_buffer.partition_cuts(p)
-        for s in range(self.n_out):
-            yield from self.mesh_buffer.source_batches(p, s, _cuts=cuts)
-            idxs = by_source.get(s)
-            if idxs:
-                yield from self.host_buffer.entry_batches(p, idxs)
+        # the partition's shards come home ONCE, as the pure-mesh read
+        # path brings them
+        shards = self.mesh_buffer.partition_shards(p)
+
+        def slices():
+            for s in range(self.n_out):
+                yield from self.mesh_buffer.source_slices(shards, s)
+                idxs = by_source.get(s)
+                if idxs:
+                    yield from self.host_buffer.entry_slices(p, idxs)
+
+        return _counted(_merged(slices(), self.mesh_buffer.capacity))
 
     def close(self) -> None:
         self.mesh_buffer.close()
@@ -848,7 +922,7 @@ class ShuffleExchangeOp(PhysicalOp):
         from auron_tpu.runtime import watchdog
 
         buffer = _MeshExchangeBuffer(self, mesh, axis, n_out,
-                                     ctx.mem_manager, metrics)
+                                     ctx.mem_manager, metrics, ctx.conf)
         rounds = escalations = 0   # rounds = COMPLETED mesh rounds
         bytes_moved = 0   # LIVE bytes through the all-to-all (unpadded)
         quota: Optional[int] = None   # sticky: escalated once, reused
@@ -1609,9 +1683,7 @@ class RssShuffleExchangeOp(PhysicalOp):
         metrics = ctx.metrics_for(self, "_read")
         read_time = metrics.counter("shuffle_read_total_time")
 
-        def stream():
-            from auron_tpu.columnar.serde import (deserialize_host_batch,
-                                                  host_to_batch)
+        def slices():
             # map-by-map fetch: each map's frames are fully verified
             # before any is yielded, so corruption recovery never
             # re-yields data a downstream operator already consumed
@@ -1623,14 +1695,14 @@ class RssShuffleExchangeOp(PhysicalOp):
                     # yield under the timer would bill the consumer's
                     # compute to shuffle_read_total_time
                     with timer(read_time, bucket="serde"):
-                        host, _ = deserialize_host_batch(frame)
-                        batch = (host_to_batch(host,
-                                               bucket_rows(host.num_rows))
-                                 if host.num_rows else None)
-                    if batch is not None:
-                        yield batch
+                        piece = _frame_slice(frame)
+                    if piece is not None:
+                        yield piece
 
-        return count_output(stream(), metrics, timed=True)
+        from auron_tpu import config as cfg
+        return count_output(
+            _merged(slices(), ctx.conf.get(cfg.BATCH_CAPACITY)), metrics,
+            timed=True)
 
     def __repr__(self):
         return (f"RssShuffleExchangeOp[{type(self.partitioning).__name__} "
@@ -1660,21 +1732,19 @@ class RssShuffleReadOp(PhysicalOp):
         metrics = ctx.metrics_for(self)
         read_time = metrics.counter("shuffle_read_total_time")
 
-        def stream():
-            from auron_tpu.columnar.serde import (deserialize_host_batch,
-                                                  host_to_batch)
+        def slices():
             for frame in self.service.partition_frames(self.shuffle_id,
                                                        partition):
                 # yield outside the timer (see RssShuffleExchangeOp)
                 with timer(read_time, bucket="serde"):
-                    host, _ = deserialize_host_batch(frame)
-                    batch = (host_to_batch(host,
-                                           bucket_rows(host.num_rows))
-                             if host.num_rows else None)
-                if batch is not None:
-                    yield batch
+                    piece = _frame_slice(frame)
+                if piece is not None:
+                    yield piece
 
-        return count_output(stream(), metrics, timed=True)
+        from auron_tpu import config as cfg
+        return count_output(
+            _merged(slices(), ctx.conf.get(cfg.BATCH_CAPACITY)), metrics,
+            timed=True)
 
     def __repr__(self):
         return f"RssShuffleReadOp[shuffle={self.shuffle_id}]"

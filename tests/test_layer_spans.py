@@ -160,8 +160,8 @@ class TestLayerSpanAccounting:
                 v2 = acc.sealed(1.0)
         assert v2["exchange_s"] == dict.fromkeys(trace.EXCHANGE_KEYS, 0.0)
         assert all(v2["counts"][k] == 0 for k in (
-            "mesh_read_batches", "mesh_read_rows", "mesh_home_bytes",
-            "mesh_slot_bytes"))
+            "mesh_read_batches", "mesh_read_rows", "mesh_read_slices",
+            "mesh_home_bytes", "mesh_slot_bytes"))
 
     def test_readback_outside_an_operator_stays_in_its_layer(self):
         with trace.task_scope("q-fence") as acc:
@@ -839,7 +839,8 @@ class TestServedLedger:
         assert counts["program_calls"] > 0
         assert "parallel.exchange.read_cut" not in \
             counts["program_calls_by_site"]
-        assert counts["mesh_read_batches"] == counts["mesh_read_rows"] == 0
+        assert counts["mesh_read_batches"] == counts["mesh_read_rows"] \
+            == counts["mesh_read_slices"] == 0
 
     def test_version_2_keeps_every_version_1_key(self, served):
         led = served("q3")

@@ -60,12 +60,13 @@ MESH_EXCHANGES = dict.fromkeys(STAR + ("q65sa", "q65sam"), 1) \
     | dict.fromkeys(("q65", "q65m"), 3)
 MESH_COUNTS = ("mesh_rounds", "mesh_escalations", "mesh_bytes",
                "mesh_slot_bytes", "mesh_gang_acquires", "mesh_read_batches",
-               "mesh_read_rows", "mesh_home_bytes")
+               "mesh_read_rows", "mesh_home_bytes", "mesh_read_slices")
 MESH_SPAN_KEYS = ("gang_wait", "mesh_stack", "mesh_round")
 #: the program the reduce side reads an exchange buffer with (PR 39)
 READ_CUT = "parallel.exchange.read_cut"
 #: non-empty (partition, source, round) slices a wide stage's reducers
-#: read: 16 an ``sa`` exchange, 12 the per-store average's (12 stores)
+#: read (merged into one batch a partition since PR 49): 16 an ``sa``
+#: exchange, 12 the per-store average's (12 stores)
 WIDE_SLICES = dict.fromkeys(("q65sa", "q65sam"), 16) \
     | dict.fromkeys(("q65", "q65m"), 44)
 MESH_SPANS = ("exchange.gang_wait", "exchange.mesh_stack",
@@ -249,10 +250,13 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
     assert all(split[k] > 0 for k in MESH_SPAN_KEYS + ("materialize",))
     counts = led["counts"]
     assert counts["mesh_rounds"] == MESH_EXCHANGES[plan]
-    # every reducer partition reads at most one slice a source and round
-    assert 0 < counts["mesh_read_batches"] <= 16 * counts["mesh_rounds"]
+    # every reducer partition reads at most one slice a source and round,
+    # merged into one batch a partition and exchange (one round each)
+    assert 0 < counts["mesh_read_batches"] <= 4 * counts["mesh_rounds"]
+    assert counts["mesh_read_batches"] <= counts["mesh_read_slices"] \
+        <= 16 * counts["mesh_rounds"]
     # a slice handed on holds at least a row
-    assert counts["mesh_read_rows"] >= counts["mesh_read_batches"]
+    assert counts["mesh_read_rows"] >= counts["mesh_read_slices"]
     # three of the four partitions live on another chip than the home one
     assert counts["mesh_home_bytes"] > 0
     assert counts["mesh_escalations"] >= 0
@@ -268,22 +272,24 @@ def test_mesh_stage_frame_has_the_exchange_layer_and_its_counts(
 
 @pytest.mark.parametrize("plan", PLANS)
 def test_mesh_stage_reads_its_exchanges_with_the_cut_program(plan, answers):
-    """One call a mesh partition and round that brought it anything, one
-    a batch the host route delivers (the range and the single exchange
-    of the stage's tail); the batches delivered do not change."""
+    """One call a batch: one a mesh partition that was brought anything
+    — its sources' slices merged into the one batch (PR 49) — and one a
+    batch the host route delivers (the range and the single exchange of
+    the stage's tail)."""
     _table, done = answers[plan]["mesh"]
     counts = done["cost_ledger"]["counts"]
-    slices = counts["mesh_read_batches"]
+    batches, slices = counts["mesh_read_batches"], counts["mesh_read_slices"]
     delivered = done["shuffle_exchange_read"]["output_batches"]
     mesh_cuts = counts["program_calls_by_site"][READ_CUT] \
-        - (delivered - slices)
-    partition_rounds = 4 * counts["mesh_rounds"]
-    assert -(-slices // 4) <= mesh_cuts <= partition_rounds
+        - (delivered - batches)
+    partitions = 4 * counts["mesh_rounds"]      # one round an exchange
+    assert mesh_cuts == batches <= partitions
+    assert batches <= slices <= 4 * batches
     if plan in WIDE:
         assert slices == WIDE_SLICES[plan]
         # thousands of (store, item) groups fill every partition of an
         # ``sa`` exchange; the 12 stores of the third may leave one empty
-        assert mesh_cuts >= partition_rounds - (plan in ("q65", "q65m"))
+        assert batches >= partitions - (plan in ("q65", "q65m"))
     # the route without a mesh reads every batch with one call
     _table, single = answers[plan]["single"]
     assert single["cost_ledger"]["counts"]["program_calls_by_site"][
@@ -296,7 +302,8 @@ def test_mesh_stage_runs_its_global_sort_as_programs(plan, answers):
     gather 4 → 1 — by site: one split call a map batch of the two
     exchanges (and no ``sort_by_pid`` with eager partition ids), one
     sample call a map batch of the range exchange, one sort a partition
-    and the limit's, and fewer row counts read than before."""
+    and the limit's — no concatenation before it — and fewer row counts
+    read than before."""
     _table, done = answers[plan]["mesh"]
     counts = done["cost_ledger"]["counts"]
     sites = counts["program_calls_by_site"]
@@ -307,10 +314,13 @@ def test_mesh_stage_runs_its_global_sort_as_programs(plan, answers):
     assert sampled + 1 <= sites[SPLIT] <= sampled + 4
     assert "parallel.exchange.sort_by_pid" not in sites
     assert sites["ops.sort.sort"] >= 2
+    # a partition's maps' rows reach its sort as ONE batch (PR 49):
+    # nothing is left to concatenate first (q65 / q65m sort more than
+    # their tail: their joins' inputs)
+    assert plan in ("q65", "q65m") or CONCAT not in sites
     if plan in WIDE:
-        # hundreds of rows from four maps: every partition collects
-        # more than one batch
-        assert sites[SPLIT] == 8 and sites[CONCAT] >= 4
+        # hundreds of rows from four maps in every partition
+        assert sites[SPLIT] == 8
         assert sites["ops.sort.sort"] >= 4
     assert counts["row_syncs"] < ROW_SYNCS_PR40[plan]
     # the sample's readback is the one it always was: no readback added

@@ -19,7 +19,9 @@ and a gather to one partition between the third and the fourth. Held here:
   second aggregates are the answer's distinct counts; and
   ``counts.mesh_read_rows`` is the rows the six exchanges delivered — the
   distinct prices of every (band, partition), which the map-side combine
-  left of the rows that passed the band's filter;
+  left of the rows that passed the band's filter — handed to the second
+  aggregates as ONE batch a (band, partition): 24 table steps on 96
+  merged slices (PR 49);
 - **the control this deployment adds**: the distinct count taken per split
   and summed — what a stage without the exchange would answer — differs
   from the exact reference in every band that holds a price in two splits,
@@ -251,10 +253,23 @@ def test_the_stage_counts_its_rounds_its_groups_and_no_fallback(stage):
     assert counts["batch_shrinks"] == 0
     sites = counts["program_calls_by_site"]
     assert sites["parallel.mesh_exchange.stage"] == 6
-    # one table a (band, partition), stepped once a received slice
+    # one table a (band, partition), stepped ONCE: the reduce side hands
+    # the second aggregate one batch a partition, its four sources'
+    # slices merged (PR 49; 96 steps on 96 slices before)
     assert sites["hashtable.agg_init"] == 24
     assert sites["hashtable.agg_step"] == counts["agg_hash_batches"] \
-        == counts["mesh_read_batches"]
+        == counts["mesh_read_batches"] == 24
+    assert counts["mesh_read_slices"] == 96
+    # one launch of the read a partition and exchange, and one a gather:
+    # the four maps' one-row batches reach a band's last aggregate as
+    # one batch of four rows, so it reduces once and merges no state
+    assert sites["parallel.exchange.read_cut"] == 24 + 6
+    assert sites["ops.agg.batch_reduce"] == 24 + 6
+    assert "ops.agg.state_merge" not in sites
+    # no row count is read on the reduce side: a batch's is the host sum
+    # of its slices' counts (the scan's 24, the aggregates' 108, the
+    # cross joins' 10 and the limit's 2 are left)
+    assert counts["row_syncs"] == 144
 
 
 @needs_4
@@ -267,9 +282,11 @@ def test_mesh_read_rows_is_what_the_six_exchanges_delivered(stage, data):
     passed = sum(part[f"B{i}_CNT"] for part in data.local for i in BANDS)
     counts = stage.counts
     assert counts["mesh_read_rows"] == delivered
-    # at most one slice a (band, partition, source); none of them empty
-    assert 0 < counts["mesh_read_batches"] <= 6 * 4 * 4
-    assert counts["mesh_read_rows"] >= counts["mesh_read_batches"]
+    # one slice a (band, partition, source), none of them empty, merged
+    # into one batch a (band, partition)
+    assert counts["mesh_read_slices"] == 6 * 4 * 4
+    assert counts["mesh_read_batches"] == 6 * 4
+    assert counts["mesh_read_rows"] >= counts["mesh_read_slices"]
     # the combine's own figures: the rows in are the filter's survivors,
     # the rows out what crossed — the key is the price, so it merges
     # next to nothing

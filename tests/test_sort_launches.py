@@ -6,8 +6,10 @@ touches a device array between one sync and the next runs inside a
 program handed out by ``runtime/programs.py``: one
 ``parallel.partitioning.range_sample`` a sampled batch, one
 ``parallel.exchange.fused_split`` a map batch (the partition ids — range
-bounds an operand — and the sort by them), one ``ops.sort.concat`` a
-reducer partition that was handed more than one batch, one
+bounds an operand — and the sort by them), one
+``parallel.exchange.read_cut`` a reducer partition, which hands it its
+maps' rows as ONE batch (PR 49: no ``ops.sort.concat``, which a
+partition handed more than one batch would launch first), one
 ``ops.sort.sort`` a partition. Before PR 41 the range exchange launched
 122–215 eager single-primitive programs a batch (every bound × every key
 word) and the sort's concatenation ≈ 100 a partition; this file is the
@@ -49,6 +51,7 @@ SPLIT = "parallel.exchange.fused_split"
 SAMPLE = "parallel.partitioning.range_sample"
 CONCAT = "ops.sort.concat"
 SORT = "ops.sort.sort"
+READ_CUT = "parallel.exchange.read_cut"
 
 
 def _key(kind: str, rng) -> pa.Array:
@@ -129,15 +132,17 @@ def test_a_global_sort_launches_programs_only(kind, ascending, nulls_first,
                                                            SPANS)
     assert len(eager) <= BUDGET, sorted(set(eager))
     # a map batch is sampled once and split twice (the range exchange
-    # and the gather); a partition is sorted once, concatenated first
-    # where more than one map sent it rows
+    # and the gather); a partition's rows are read with one launch —
+    # ONE batch, whatever maps sent it rows (PR 49) — and sorted once,
+    # with nothing left to concatenate; the gather's read is one more
     assert sites[SAMPLE] == MAPS
     assert sites[SPLIT] == MAPS + MAPS
     assert sites[SORT] == MAPS
-    assert 1 <= sites.get(CONCAT, 0) <= MAPS
+    assert sites[READ_CUT] == MAPS + 1
+    assert CONCAT not in sites
     assert "parallel.exchange.sort_by_pid" not in sites
     assert set(engine) >= {"auron_" + s.replace(".", "_")
-                           for s in (SAMPLE, SPLIT, SORT, CONCAT)}
+                           for s in (SAMPLE, SPLIT, SORT)}
     # the same rows in the same order as the plain reference, ties in
     # the maps' own order
     want = _oracle(table, orders, 150)

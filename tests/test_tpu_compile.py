@@ -86,26 +86,43 @@ def _columns(one_chip, rows, layout):
     return tuple(column(kind) for kind in layout)
 
 
-@pytest.mark.parametrize("layout, quota, capacity", [
+@pytest.mark.parametrize("layouts, quota, window", [
     # q65's partial state across the all_to_all: (store, item), the
-    # double partial sum and the count; a shard of 4 x 2,048 slots
-    (("int32", "int32", "float64", "int64"), 2048, 2048),
+    # double partial sum and the count; a shard of 4 x 2,048 slots, its
+    # four slices into one batch
+    ([("int32", "int32", "float64", "int64")], 2048, 2048),
     # the star joins': a brand string beside its ids and a decimal sum
     # held as one int64 word; 4 x 32,768 slots, a dozen rows a slice
-    ((64, "int32", "int32", "int64"), 32768, 16),
-], ids=["q65_state", "star_join_string_key"])
-def test_the_exchange_read_cut_compiles_for_the_chip(one_chip, layout,
-                                                     quota, capacity):
-    """The reduce side's read as one program (PR 39): a double partial
-    sum is a gathered PAYLOAD in it, never a key or a bitcast, and the
-    slice bounds are a small int32 operand."""
+    ([(64, "int32", "int32", "int64")], 32768, 16),
+    # q28's: the price (a Decimal64 word) beside the partial state; a
+    # band and partition's ~ 750 distinct prices from four sources
+    ([("int64", "int64", "int64", "int64")], 32768, 256),
+    # the two-round form: eight slices of two shards, source-major and
+    # rounds-minor, the rounds' string widths unified before the gather
+    ([(64, "int32", "int32", "int64"), (32, "int32", "int32", "int64")],
+     2048, 512),
+], ids=["q65_state", "star_join_string_key", "q28_price",
+        "two_rounds_two_widths"])
+def test_the_exchange_read_cut_compiles_for_the_chip(one_chip, layouts,
+                                                     quota, window):
+    """The reduce side's read as one program (PR 39) that gathers a
+    partition's slices — a window a (source, round), stacked — into ONE
+    batch (PR 49): a double partial sum is a gathered PAYLOAD in it,
+    never a key or a bitcast, and the slice bounds are a small int32
+    operand."""
     import jax
     import jax.numpy as jnp
     from auron_tpu.parallel import exchange
-    cut = exchange._read_cut_kernel(4, capacity)
-    bounds = jax.ShapeDtypeStruct((2, 4), jnp.int32, sharding=one_chip)
-    compiled = cut.lower(_columns(one_chip, 4 * quota, layout),
-                         bounds).compile()
+    rounds = len(layouts)
+    # source-major, rounds-minor: slice k of tree k % rounds
+    tree_of_slice = tuple(k % rounds for k in range(4 * rounds))
+    cut = exchange._read_cut_kernel(tree_of_slice, window,
+                                    len(tree_of_slice) * window)
+    bounds = jax.ShapeDtypeStruct((2, len(tree_of_slice)), jnp.int32,
+                                  sharding=one_chip)
+    trees = tuple(_columns(one_chip, 4 * quota, layout)
+                  for layout in layouts)
+    compiled = cut.lower(trees, bounds).compile()
     assert compiled is not None
 
 

@@ -642,20 +642,26 @@ def test_fatal_mid_combined_exchange_demotes_bit_identical(
         return rows, ctx
 
     conf = cfg.get_config()
-    conf.unset(cfg.MESH_ENABLED)
-    df, op = plan()
-    classic, _ = run(op, df.num_partitions)
-    conf.set(cfg.MESH_ENABLED, True)
+    # the repartition's read hands a map its 2,000 rows in batches of
+    # the configured capacity (PR 49: one batch where they fit one)
+    conf.set(cfg.BATCH_CAPACITY, 512)
+    try:
+        conf.unset(cfg.MESH_ENABLED)
+        df, op = plan()
+        classic, _ = run(op, df.num_partitions)
+        conf.set(cfg.MESH_ENABLED, True)
 
-    armed(f"mesh.all_to_all:fatal@{_PROB}",
-          _seed_for_round(round_idx, "fatal", _PROB))
-    df, op = plan()
-    ex = [o for o in walk(op) if isinstance(o, ShuffleExchangeOp)]
-    # the exchange really is combined — this must not silently decay
-    # into a plain-exchange demotion test
-    assert ex and ex[0].combine_mode == "combine", \
-        f"exchange not combined: {ex and ex[0].combine_why}"
-    got, ctx = run(op, df.num_partitions)
+        armed(f"mesh.all_to_all:fatal@{_PROB}",
+              _seed_for_round(round_idx, "fatal", _PROB))
+        df, op = plan()
+        ex = [o for o in walk(op) if isinstance(o, ShuffleExchangeOp)]
+        # the exchange really is combined — this must not silently decay
+        # into a plain-exchange demotion test
+        assert ex and ex[0].combine_mode == "combine", \
+            f"exchange not combined: {ex and ex[0].combine_why}"
+        got, ctx = run(op, df.num_partitions)
+    finally:
+        conf.unset(cfg.BATCH_CAPACITY)
     assert got == classic, \
         f"demotion at round {round_idx} mid-combined-exchange " \
         f"diverged from the single-device run (values or order)"
