@@ -13,7 +13,7 @@ into any jit program:
   (``core.agg_update``; fused per-batch into ``HashAggState.update``).
 
 Every compile site registers with the central program-cache registry
-(runtime/programs.py): hashtable.agg_init / agg_step / agg_grow /
+(runtime/programs.py): hashtable.agg_step / agg_grow /
 agg_export / build / probe / grow / join_index — visible in tools/compile_report.py
 and bounded by ``auron.max_live_programs``.
 """

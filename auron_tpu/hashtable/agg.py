@@ -6,9 +6,10 @@ fused program — the caller's evaluation of the batch's group keys and
 contributions (its ``front``, traced here), hash keys, insert (vectorized
 probe rounds), scatter the contributions into the owning slots — and the
 O(S) state pass disappears entirely (the table IS the state; nothing
-re-sorts per batch). The table's set-up is one program too
-(``hashtable.agg_init``); nothing here touches a device array outside a
-program of runtime/programs.py. This is the reference AggTable's update loop
+re-sorts per batch). The table's set-up rides its first step (the
+``fresh`` form of ``hashtable.agg_step`` builds the empty table inside the
+program); nothing here touches a device array outside a program of
+runtime/programs.py. This is the reference AggTable's update loop
 (datafusion-ext-plans/src/agg/agg_table.rs:68-356) with the row-at-a-time
 probe replaced by ``hashtable.core``'s lock-step rounds.
 
@@ -61,23 +62,6 @@ def _hashes(keys, cap: int) -> jax.Array:
     return core.remap_hashes(h)
 
 
-@program_cache("hashtable.agg_init", maxsize=128)
-def _init_kernel(key_meta: tuple, acc_meta: tuple, cap: int):
-    """The empty table — hashes, equality words, key store and every
-    accumulator at its neutral — as ONE program (a dozen ``jnp.full`` /
-    ``jnp.zeros`` launches before)."""
-    W = core.total_words(key_meta)
-
-    @jax.jit
-    def auron_hashtable_agg_init():
-        accs, auxs = core.init_accs(acc_meta, cap)
-        return (jnp.full(cap, core.EMPTY, jnp.uint64),
-                jnp.zeros((cap, W), jnp.uint64),
-                core.empty_store(key_meta, cap), accs, auxs)
-
-    return auron_hashtable_agg_init
-
-
 @program_cache("hashtable.agg_step", maxsize=256)
 def _agg_step_kernel(front, layout: tuple, n: int, table_meta: tuple,
                      key_meta: tuple, acc_meta: tuple, cap: int,
@@ -90,12 +74,25 @@ def _agg_step_kernel(front, layout: tuple, n: int, table_meta: tuple,
     ``layout`` stands for its operands' shapes. ``table_meta`` is the
     key codec the table comes in with, ``key_meta`` the one it leaves
     with: a batch whose strings are wider than the store widens it here,
-    a narrower batch is padded here."""
-    widen = core.string_width_drift(key_meta, table_meta)
+    a narrower batch is padded here. ``table_meta`` None is the FRESH
+    form, an operator's first step: no table comes in — the empty one
+    (hashes, equality words, key store, every accumulator at its neutral)
+    is built here, at ``key_meta`` and ``cap``, so a table's set-up is no
+    launch of its own. The program takes ``(table, ord_base,
+    *operands)``: ``table`` is ``(th, tw, store, accs, auxs)``, or ``()``
+    in the fresh form."""
+    fresh = table_meta is None
+    widen = () if fresh else core.string_width_drift(key_meta, table_meta)
 
     @jax.jit
-    def auron_hashtable_agg_step(th, tw, store, accs, auxs, ord_base,
-                                 *operands):
+    def auron_hashtable_agg_step(table, ord_base, *operands):
+        if fresh:
+            accs, auxs = core.init_accs(acc_meta, cap)
+            th = jnp.full(cap, core.EMPTY, jnp.uint64)
+            tw = jnp.zeros((cap, core.total_words(key_meta)), jnp.uint64)
+            store = core.empty_store(key_meta, cap)
+        else:
+            th, tw, store, accs, auxs = table
         keys, contribs, live = front(*operands)
         if widen:
             tw, store, _meta = core.widen_string_store(tw, store,
@@ -209,7 +206,7 @@ class HashAggState:
         self.rounds = int(max_probe_rounds)
         self.count = 0          # occupied slots (host mirror)
         self.rows_seen = 0      # global row ordinal base for 'first'
-        self.key_meta = None    # set lazily on the first update
+        self.key_meta = None    # set by the first step that commits
         self.acc_meta = None
         self.th = self.tw = self.store = self.accs = self.auxs = None
 
@@ -231,14 +228,6 @@ class HashAggState:
 
     # -- state transitions ---------------------------------------------------
 
-    def _init_arrays(self, key_meta: tuple, contribs) -> None:
-        self.key_meta = key_meta
-        self.acc_meta = tuple(
-            (kind, str(np.dtype(v.dtype)))
-            for kind, v in zip(self.kinds, contribs))
-        (self.th, self.tw, self.store, self.accs,
-         self.auxs) = _init_kernel(self.key_meta, self.acc_meta, self.cap)()
-
     def _grow(self) -> None:
         new_cap = self.cap * 2
         while True:
@@ -246,15 +235,19 @@ class HashAggState:
                 raise HashTableOverflow(
                     f"hash table stuck at {self.count} keys despite "
                     f"capacity {new_cap} (probe rounds {self.rounds})")
-            kern = _grow_kernel(self.key_meta, self.acc_meta, self.cap,
-                                new_cap, self.rounds)
-            nth, ntw, nstore, naccs, nauxs, ovf = kern(
-                self.th, self.store, self.accs, self.auxs)
-            if bool(_profile.timed_get(ovf)):
-                new_cap *= 2
-                continue
-            self.th, self.tw, self.store = nth, ntw, nstore
-            self.accs, self.auxs = naccs, nauxs
+            # a first step that overflowed committed nothing: there is
+            # nothing to re-bucket, the retry builds its empty table at
+            # the doubled capacity
+            if self.built:
+                kern = _grow_kernel(self.key_meta, self.acc_meta, self.cap,
+                                    new_cap, self.rounds)
+                nth, ntw, nstore, naccs, nauxs, ovf = kern(
+                    self.th, self.store, self.accs, self.auxs)
+                if bool(_profile.timed_get(ovf)):
+                    new_cap *= 2
+                    continue
+                self.th, self.tw, self.store = nth, ntw, nstore
+                self.accs, self.auxs = naccs, nauxs
             # one a doubling: a re-bucket that overflowed doubled again
             _trace.count("agg_state_grows",
                          (new_cap // self.cap).bit_length() - 1)
@@ -273,21 +266,28 @@ class HashAggState:
         host-RTT budget as the sort path's group-count readback."""
         keys, contribs, live = shapes
         batch_meta = core.key_meta(keys)
-        if not self.built:
-            self._init_arrays(batch_meta, contribs)
+        if self.acc_meta is None:
+            self.acc_meta = tuple(
+                (kind, str(np.dtype(v.dtype)))
+                for kind, v in zip(self.kinds, contribs))
         # reconcile per-batch string width buckets with the store's: a
         # wider batch widens the store, a narrower one is padded — both
-        # inside the step
-        key_meta = core.widest_meta(batch_meta, self.key_meta)
+        # inside the step. Until a step has committed there is no table:
+        # the step is the fresh form, which builds it at the batch's codec
+        key_meta = (core.widest_meta(batch_meta, self.key_meta)
+                    if self.built else batch_meta)
         n = int(live.shape[0])
         ord_base = np.int64(self.rows_seen)
         while True:
             kern = _agg_step_kernel(front, layout, n, self.key_meta,
                                     key_meta, self.acc_meta, self.cap,
                                     self.rounds)
+            # read afresh each attempt: a retry steps into the table
+            # ``_grow`` re-bucketed, not the one that overflowed
+            table = ((self.th, self.tw, self.store, self.accs, self.auxs)
+                     if self.built else ())
             th, tw, store, accs, auxs, n_new, overflow = kern(
-                self.th, self.tw, self.store, self.accs, self.auxs,
-                ord_base, *operands)
+                table, ord_base, *operands)
             # this readback is the per-batch sync point (the wait is
             # attributed as device time). NOTE the donation
             # sweep deliberately skips the step/grow kernels: the

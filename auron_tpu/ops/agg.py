@@ -1826,15 +1826,21 @@ class AggOp(PhysicalOp):
         return ok, out_cap
 
     def _shrink_table(self, tbl, ng: int):
-        """A group table at its occupancy bucket. Live groups are a
+        """A group table at its occupancy bucket, carrying the count the
+        host read when it last merged into it. Live groups are a
         hash-sorted prefix, so shrinking is a plain slice — which the
         program that reads the table next takes (``_cut``: the merge, the
         emit): only the capacity changes here, and the arrays stay at the
         capacity of the program that made them until then. Keeps
         small-cardinality states from carrying batch-sized buffers
-        through every subsequent merge."""
-        keys, accs, n, cap, h = tbl
-        return (keys, accs, n, self._occupancy_cap(cap, ng), h)
+        through every subsequent merge. The ``num_groups`` slot holds
+        ``ng`` as a HOST integer (a numpy int32: the programs that take it
+        as an operand compile for the aval the device scalar had), so
+        whoever reads the table next — the merge, the emit, the spill, the
+        partial-skip decision — asks the chip for nothing the host
+        holds."""
+        keys, accs, _n, cap, h = tbl
+        return (keys, accs, np.int32(ng), self._occupancy_cap(cap, ng), h)
 
     def _occupancy_cap(self, cap: int, ng: int) -> int:
         return min(cap, max(bucket_rows(max(ng, 1)), self.initial_capacity))
@@ -1851,18 +1857,29 @@ class AggOp(PhysicalOp):
         kinds = self._device_kinds()
         cap_b = inp.capacity
         out_elems = self._collect_elems(inp.shapes[1])
+        # without keys the live rows are one group, so where the batch's
+        # row count is a host integer (a merged exchange read, another
+        # aggregate's output) the host knows the group count, and with no
+        # collect kind ``needed`` is empty: nothing to read. A batch whose
+        # count is on the device keeps the read
+        rows = inp.operands[1]
+        counted = not self.group_exprs and not self._collects() \
+            and not isinstance(rows, jax.Array)
         while True:
             meta = tuple(zip(kinds, out_elems))
             kern = _batch_reduce_kernel(inp.front, inp.layout, meta, cap_b,
                                         donate)
             with timer(elapsed) as t:
                 bk, ba, bh, bn, needed = kern(*inp.operands)
-                # one batched round trip for every control scalar — each
-                # separate int() readback is its own device→host sync.
-                # The readback IS the sync point: attributed as device
-                # wait, obs/profile.timed_get
-                ng, needed_h = _profile.timed_get([bn, needed])
-                ng = int(ng)   # graft: disable=GL001 -- read by timed_get above
+                if counted:
+                    ng, needed_h = min(_profile.row_count(rows), 1), ()
+                else:
+                    # one batched round trip for every control scalar —
+                    # each separate int() readback is its own device→host
+                    # sync. The readback IS the sync point: attributed as
+                    # device wait, obs/profile.timed_get
+                    ng, needed_h = _profile.timed_get([bn, needed])
+                    ng = int(ng)   # graft: disable=GL001 -- read by timed_get above
             ok, _cap = self._grow_check(kinds, out_elems, ng, cap_b,
                                         needed_h)
             if ok:
@@ -1976,11 +1993,14 @@ class AggOp(PhysicalOp):
         may be present — collect-element growth retries the reduce with
         the same inputs, which donation would have invalidated."""
         from auron_tpu.ops.base import yields_owned_batches
-        if not yields_owned_batches(self.child):
-            return False
-        return not any(
-            k in ("collect_list", "collect_set") or k in _DCOLLECT
-            for k in self._device_kinds())
+        return yields_owned_batches(self.child) and not self._collects()
+
+    def _collects(self) -> bool:
+        """Whether any accumulator is a collect kind: its element buffers
+        grow by a host-side retry of the program that found them short
+        (``_grow_check``), on a width only the device knows."""
+        return any(k in ("collect_list", "collect_set") or k in _DCOLLECT
+                   for k in self._device_kinds())
 
     def _merge_sorted(self, state, inp, elapsed, donate=False):
         """state: None | (main, hot), each None | (keys, accs, num_groups,
@@ -2039,26 +2059,36 @@ class AggOp(PhysicalOp):
         operator's output less its host-side columns, or (``as_state``)
         the state as a batch in the operator's own state layout (a spill
         run; a sorted state the hash table takes over). Returns the batch
-        and its capacity."""
+        and its capacity. The batch leaves with the HOST's count of the
+        table's groups (the hash table's ``count``, a sorted table's
+        ``num_groups`` slot: ``_shrink_table``) — exactly what the program
+        returns as its row count, so nobody waits for the program to learn
+        it (the pattern of ``parallel/exchange._cut``)."""
         from auron_tpu.hashtable import HashAggState
         if isinstance(table, HashAggState):
             # the occupancy bucket from the count the host already has
+            count = np.int32(table.count)
             cap = self._occupancy_cap(table.cap, table.count)
             operands = (table.th, table.store, table.accs)
             meta = table.key_meta
         else:
-            keys, accs, num_groups, cap, _hashes = table
-            operands, meta = (keys, accs, num_groups), None
+            keys, accs, count, cap, _hashes = table
+            operands, meta = (keys, accs, count), None
         if bloom:
             # A global bloom state serializes to ~100 KB+ per row; the
             # (single-group) output leaves at the smallest capacity so
             # the string column isn't materialized at state capacity.
             cap = min(cap, bucket_rows(1, minimum=16))
-        return _emit_kernel(self.specs, as_state, cap, meta)(*operands), cap
+        batch = _emit_kernel(self.specs, as_state, cap, meta)(*operands)
+        return DeviceBatch(batch.columns, count), cap
 
     def _emit(self, state, elapsed, host=None) -> Optional[DeviceBatch]:
         """The operator's output batch from its state — the (main, hot)
-        levels or the hash table — or None where it never saw a row."""
+        levels or the hash table — or None where it never saw a row. Reads
+        nothing from the chip: the batch's row count is the host's
+        (``_table_batch``), so ``count_output``'s read of it is free and a
+        state whose last merge was dispatched and not read is not waited
+        for here."""
         from auron_tpu.hashtable import HashAggState
         if state is not None and isinstance(state[0], HashAggState):
             table = state[0] if state[0].built else None
@@ -2071,9 +2101,7 @@ class AggOp(PhysicalOp):
         batch, out_cap = self._table_batch(
             table, self.emits_state,
             bloom=bool(host_slots) and host.has_bloom())
-        # where the state's last merge was dispatched and not read (the
-        # sort path's, the table's final fold) this read waits for it
-        ng = _profile.row_count(batch)
+        ng = _profile.row_count(batch)      # the host's: no read
         if self.group_exprs:
             _trace.count("agg_groups", ng)
         if not host_slots:
@@ -2393,7 +2421,9 @@ class AggOp(PhysicalOp):
                 accs.append(acc[0][take].astype(
                     _JNPT[spec.state_fields[0][1]]))
                 accs.append(acc[1][take] > 0)
-        tbl = (keys, tuple(accs), ng_dev, cap, jnp.zeros(cap, jnp.uint64))
+        # the table leaves with the count the one readback above brought
+        tbl = (keys, tuple(accs), np.int32(ng), cap,
+               jnp.zeros(cap, jnp.uint64))
         yield self._emit((tbl, None), elapsed)
 
     def execute(self, partition: int, ctx: ExecContext) -> Iterator[DeviceBatch]:
@@ -2483,6 +2513,8 @@ class AggOp(PhysicalOp):
                     # present in both hot and main would count twice
                     tbl = self._compact(state, elapsed)
                     state = None if tbl is None else (tbl, None)
+                    # a compacted table carries the host's count
+                    # (``_shrink_table``): this reads nothing
                     ng = 0 if tbl is None else _profile.row_count(tbl[2])
                     # groups living only in spill runs are invisible in the
                     # in-memory table; without them a pre-decision spill

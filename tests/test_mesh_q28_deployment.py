@@ -255,8 +255,9 @@ def test_the_stage_counts_its_rounds_its_groups_and_no_fallback(stage):
     assert sites["parallel.mesh_exchange.stage"] == 6
     # one table a (band, partition), stepped ONCE: the reduce side hands
     # the second aggregate one batch a partition, its four sources'
-    # slices merged (PR 49; 96 steps on 96 slices before)
-    assert sites["hashtable.agg_init"] == 24
+    # slices merged (PR 49; 96 steps on 96 slices before), and a
+    # table's set-up rides that step: no launch of its own
+    assert "hashtable.agg_init" not in sites
     assert sites["hashtable.agg_step"] == counts["agg_hash_batches"] \
         == counts["mesh_read_batches"] == 24
     assert counts["mesh_read_slices"] == 96
@@ -267,9 +268,15 @@ def test_the_stage_counts_its_rounds_its_groups_and_no_fallback(stage):
     assert sites["ops.agg.batch_reduce"] == 24 + 6
     assert "ops.agg.state_merge" not in sites
     # no row count is read on the reduce side: a batch's is the host sum
-    # of its slices' counts (the scan's 24, the aggregates' 108, the
-    # cross joins' 10 and the limit's 2 are left)
-    assert counts["row_syncs"] == 144
+    # of its slices' counts. Nor does an aggregate's emit read one: it
+    # leaves with the host's count of its groups, which is also what the
+    # cross joins read of their build sides. Left: the scan's 24, the
+    # cross joins' outputs 5, the limit's 2
+    assert counts["row_syncs"] == 31
+    # the 24 steps' (n_new, overflow) and what is not the aggregation's;
+    # the 30 keyless reduces over counted batches read nothing
+    assert counts["readbacks"] == 55
+    assert counts["program_calls"] == 173
 
 
 @needs_4

@@ -385,9 +385,9 @@ def test_the_distinct_regroup_compiles_for_the_chip(one_chip, mode, rows,
     as one int64 word, beside avg's sum and count and count's count.
     ``partial`` evaluates the key and the contributions from the row
     inside the step, ``partial_merge`` reads its child's state columns
-    there; the table's set-up is one program, and its export — an
-    argsort of its slots — runs inside the emit, cut to the occupancy
-    bucket."""
+    there; the table's set-up rides its first step (PR 51: the fresh
+    form, which takes no table), and its export — an argsort of its
+    slots — runs inside the emit, cut to the occupancy bucket."""
     import jax
     import jax.numpy as jnp
     from auron_tpu.columnar.batch import leaf_layout
@@ -412,15 +412,18 @@ def test_the_distinct_regroup_compiles_for_the_chip(one_chip, mode, rows,
                      for kind, c in zip(op._device_kinds(), contribs))
     assert key_meta == (("prim", "int64"),)
     assert acc_meta == (("sum", "int64"),) * 3
-    init = ht_agg._init_kernel(key_meta, acc_meta, slots)
-    assert init.lower().compile() is not None
-    th, tw, store, accs, auxs = _on_chip(
-        one_chip, jax.eval_shape(lambda: init()))
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     base = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    fresh = ht_agg._agg_step_kernel(op._front, layout, rows, None,
+                                    key_meta, acc_meta, slots, 64)
+    assert fresh.lower((), base, columns, scalar,
+                       scalar).compile() is not None
+    th, tw, store, accs, auxs = _on_chip(one_chip, jax.eval_shape(
+        lambda *operands: fresh(*operands),
+        (), base, columns, scalar, scalar)[:5])
     step = ht_agg._agg_step_kernel(op._front, layout, rows, key_meta,
                                    key_meta, acc_meta, slots, 64)
-    assert step.lower(th, tw, store, accs, auxs, base, columns, scalar,
+    assert step.lower((th, tw, store, accs, auxs), base, columns, scalar,
                       scalar).compile() is not None
     emit = agg._emit_kernel(op.specs, True, slots // 2, key_meta)
     assert emit.lower(th, store, accs).compile() is not None

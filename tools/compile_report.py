@@ -83,7 +83,7 @@ def run_report(suite: str, scale: float, names, data_dir=None) -> dict:
     from auron_tpu import config as cfg
     sites = {k: v for k, v in programs.snapshot().items() if v["builds"]}
     # hash-table subsystem attribution: every hashtable.* compile site
-    # (agg_init/agg_step/agg_grow/agg_export/build/probe/grow/join_index)
+    # (agg_step/agg_grow/agg_export/build/probe/grow/join_index)
     # rides the central registry like any other builder — break its share
     # out so hash-path compile costs are visible at a glance (the
     # aggregation's other programs are ops.agg.batch_reduce / state_merge
